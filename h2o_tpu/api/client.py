@@ -339,9 +339,9 @@ def init(url: str | None = None, port: int = 54321, name: str = "h2o_tpu",
     from ..utils import compile_cache
     from .server import H2OServer
 
-    # boot-an-in-process-server path: this process will compile — arm the
-    # knob-gated persistent XLA compile cache with the cloud (idempotent;
-    # deploy_entry's server mode arms it the same way)
+    # boot-an-in-process-server path: this process will compile — place
+    # the persistent XLA compile cache with the cloud (idempotent;
+    # deploy_entry's server mode does the same)
     compile_cache.ensure()
     server = H2OServer(port=port, name=name, hash_login=hash_login).start()
     _conn = H2OConnection(server.url, username, password,

@@ -6,17 +6,19 @@ shapes: the GBM/DRF level-histogram scan (bin codes → per-(feature, node,
 bin) channel sums) and the GLM/PCA weighted Gram (XᵀWX + XᵀWz). This
 package owns BOTH implementations of each:
 
-- **xla** — the blocked ``lax.scan`` formulation (the pre-kernels
-  production path, verbatim). Default on CPU, and the bit-parity ORACLE
-  everywhere: the Pallas path must reproduce it bit-for-bit.
-- **pallas** — the same per-block math compiled as ONE fused
-  ``pl.pallas_call``: codes stream HBM→VMEM a row block at a time, the
-  sub-int32 upcast and the accumulate happen in VMEM, and no per-block
-  one-hot/segment intermediate ever round-trips through HBM. On this
-  container's CPU mesh the kernel runs under ``interpret=True`` (the
-  Mosaic interpreter executes the identical jaxpr, which is what makes
-  bit-parity checkable without a chip); on a real TPU backend it compiles
-  through Mosaic.
+- **xla** — the blocked ``lax.scan`` formulation. The default on EVERY
+  backend (it is the formulation that compiles for the TPU — see
+  tests/test_chip_compile.py — and the one the chip has run), and the
+  bit-parity ORACLE: the Pallas path must reproduce it bit-for-bit.
+- **pallas** — the same per-block math as ONE fused ``pl.pallas_call``:
+  codes stream HBM→VMEM a row block at a time, the sub-int32 upcast and
+  the accumulate happen in VMEM, and no per-block one-hot/segment
+  intermediate round-trips through HBM. An explicit request only. Off-TPU
+  the kernel runs under ``interpret=True`` (the interpreter executes the
+  identical jaxpr, which is what makes bit-parity checkable without a
+  chip). On a TPU backend it goes to Mosaic, which today REFUSES both
+  kernels at HIGGS width (ROADMAP S2 lists the refusals); the compiler's
+  error surfaces as raised — nothing substitutes the scan.
 
 Parity is BY CONSTRUCTION, not by tolerance: both backends call the same
 block-contribution functions (`hist._flat_contrib` / `hist._group_contrib`
@@ -30,9 +32,9 @@ Backend selection (``H2O_TPU_HIST_KERNEL``):
 =========  ================================================================
  value      meaning
 =========  ================================================================
- ``auto``   (default) pallas on real TPU backends, xla everywhere else
- ``xla``    force the scan formulation (also the oracle in parity tests)
- ``pallas`` force the fused kernel; interpreted off-TPU
+ ``auto``   (default) xla on every backend
+ ``xla``    the scan formulation (also the oracle in parity tests)
+ ``pallas`` the fused kernel; interpreted off-TPU, Mosaic-compiled on TPU
 =========  ================================================================
 
 graftlint rule 12 (``direct-pallas-call``) pins this package as the only
@@ -64,9 +66,7 @@ def hist_backend() -> str:
 
     v = (get_str("H2O_TPU_HIST_KERNEL") or "auto").strip().lower()
     if v == "auto":
-        import jax
-
-        return "pallas" if jax.default_backend() == "tpu" else "xla"
+        return "xla"
     if v not in ("pallas", "xla"):
         raise ValueError(
             f"H2O_TPU_HIST_KERNEL={v!r} — expected pallas, xla or auto")
@@ -76,7 +76,7 @@ def hist_backend() -> str:
 def interpret_mode() -> bool:
     """True when ``pl.pallas_call`` must run interpreted (no Mosaic
     compiler for this backend) — every non-TPU backend, including the CPU
-    mesh this container trains on."""
+    mesh the tests run on. Never true on a TPU backend."""
     import jax
 
     return jax.default_backend() != "tpu"

@@ -10,17 +10,16 @@ rehydrates transparently on next `.data` access (`frame/vec.py`).
 
 Budget resolution order:
 - ``H2O_TPU_HBM_LIMIT_BYTES`` env (tests pin this for determinism),
-- ``jax.local_devices()[0].memory_stats()['bytes_limit']`` × 0.85 when the
-  backend reports it (real TPUs do; the CPU test backend does not) — resolved
-  once and cached,
-- on a TPU backend whose transport hides memory_stats: the chip's physical
-  HBM from a ``device_kind`` lookup table × 0.85 (``device_hbm_bytes``),
-- otherwise unlimited (the Cleaner only observes).
+- ``memory_stats()['bytes_limit']`` × 0.85 as the backend reports it —
+  resolved once and cached. A TPU backend that reports none is an ERROR
+  (``hbm_stats``): real allocations are sized from this number and nothing
+  here guesses it from a device name,
+- otherwise (the CPU test mesh) unlimited: the Cleaner only observes.
 
-``hbm_budget_bytes()`` exposes the same resolution (sans the TPU-only
-last-resort) to compute planners — the tree engine's histogram row blocks,
-the binning sketch's column blocks, and the frame rollup batcher all size
-their intermediates from it instead of hardcoded constants.
+``hbm_budget_bytes()`` exposes the same resolution to compute planners —
+the tree engine's histogram row blocks, the binning sketch's column blocks,
+and the frame rollup batcher all size their intermediates from it instead
+of hardcoded constants.
 
 Accounting is a running counter (track/spill/rehydrate/GC adjust it), not a
 per-call scan; spill files are removed on rehydrate, on overwrite, and by a
@@ -51,45 +50,36 @@ _UNRESOLVED = object()
 
 
 def hbm_stats() -> dict | None:
-    """Per-device memory stats when the backend exposes them (TPU does)."""
+    """``memory_stats()`` of the local device with the MOST bytes in use —
+    the chip a per-device budget has to fit on a multi-chip host — or None
+    where the backend reports none (the CPU mesh).
+
+    On a TPU backend missing stats (or stats without ``bytes_limit``) raise:
+    the hardware is asked, never assumed."""
     import jax
 
-    try:
-        stats = jax.local_devices()[0].memory_stats()
-    except Exception:
-        return None
-    return dict(stats) if stats else None
+    per_dev = [d.memory_stats() for d in jax.local_devices()]
+    if all(s and s.get("bytes_limit") for s in per_dev):
+        return dict(max(per_dev, key=lambda s: s.get("bytes_in_use", 0)))
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            "TPU backend reports no memory_stats()['bytes_limit'] — the "
+            "HBM budget cannot be resolved (set H2O_TPU_HBM_LIMIT_BYTES "
+            "to pin one explicitly)")
+    return None
 
-
-#: per-device HBM by device_kind substring (GiB), most specific first — the
-#: fallback when the transport hides memory_stats. v2/v3 devices are cores
-#: (8/16 GiB each); v4+ are chips.
-_KIND_HBM_GIB = (("v6 lite", 32), ("v6e", 32), ("v5 lite", 16), ("v5e", 16),
-                 ("v5p", 95), ("v5", 95), ("v4", 32), ("v3", 16), ("v2", 8))
 
 _HW_BYTES = _UNRESOLVED  # cached device_hbm_bytes result
 
 
 def device_hbm_bytes() -> int | None:
-    """Physical per-device HBM: ``memory_stats()['bytes_limit']`` when the
-    backend reports it, else a ``device_kind`` table lookup (remote device
-    tunnels hide memory_stats but still name the chip), else None (CPU and
-    unknown accelerators)."""
+    """Physical per-device HBM as the backend reports it
+    (``memory_stats()['bytes_limit']``); None on backends without memory
+    stats (CPU). Resolved once."""
     global _HW_BYTES
-    if _HW_BYTES is not _UNRESOLVED:
-        return _HW_BYTES
-    stats = hbm_stats()
-    if stats and stats.get("bytes_limit"):
-        _HW_BYTES = int(stats["bytes_limit"])
-        return _HW_BYTES
-    import jax
-
-    try:
-        kind = jax.devices()[0].device_kind.lower()
-    except Exception:
-        kind = ""
-    _HW_BYTES = next((gib << 30 for tag, gib in _KIND_HBM_GIB if tag in kind),
-                     None)
+    if _HW_BYTES is _UNRESOLVED:
+        stats = hbm_stats()
+        _HW_BYTES = int(stats["bytes_limit"]) if stats else None
     return _HW_BYTES
 
 
@@ -217,20 +207,8 @@ class Cleaner:
     @guarded_by("_lock")
     def _resolve_stats_limit_locked(self) -> int | None:
         if self._stats_limit is _UNRESOLVED:
-            stats = hbm_stats()
-            limit = (int(stats["bytes_limit"] * 0.85)
-                     if stats and stats.get("bytes_limit") else None)
-            if limit is None:
-                import jax
-
-                if jax.default_backend() == "tpu":
-                    # some transports (remote device tunnels) hide
-                    # memory_stats; derive the budget from the chip's
-                    # device_kind (a v5p must not spill at a v5e budget),
-                    # keeping the smallest current-generation chip (v5e:
-                    # 16 GiB) only as the last resort for unknown kinds
-                    hw = device_hbm_bytes() or 16 * (1 << 30)
-                    limit = int(hw * 0.85)
+            hw = device_hbm_bytes()
+            limit = int(hw * 0.85) if hw else None
             self._stats_limit = limit
             telemetry.set_gauge("cleaner.hbm.limit.bytes", limit or 0)
         return self._stats_limit

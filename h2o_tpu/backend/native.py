@@ -31,18 +31,20 @@ def _build() -> str | None:
         if not os.path.exists(src):
             return None
         os.makedirs(_CACHE, exist_ok=True)
-        # content-hashed artifact name: a stale or foreign .so (different
-        # source, different machine — -march=native is not portable) never
-        # gets picked up; rebuilds happen exactly when the source changes
+        # no -march=native: the build directory is git-ignored but travels
+        # with a copied tree, so the artefact must run on ANY x86-64 host
+        # it lands on. The name hashes source AND flags — a stale .so
+        # never gets picked up; rebuilds happen exactly when either changes
         import hashlib
 
+        flags = ["-O3", "-std=c++17", "-shared", "-fPIC", "-pthread"]
         with open(src, "rb") as f:
-            tag = hashlib.sha256(f.read()).hexdigest()[:12]
+            tag = hashlib.sha256(
+                f.read() + " ".join(flags).encode()).hexdigest()[:12]
         out = os.path.join(_CACHE, f"libh2otpu-{tag}.so")
         if os.path.exists(out):
             return out
-        cmd = ["g++", "-O3", "-march=native", "-std=c++17", "-shared", "-fPIC",
-               "-pthread", src, "-o", out]
+        cmd = ["g++", *flags, src, "-o", out]
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
         return out
     except (subprocess.SubprocessError, OSError) as e:

@@ -440,10 +440,10 @@ class ModelBuilder:
         def run():
             from ..utils import compile_cache, compilemeter, telemetry
 
-            # knob-gated persistent XLA compile cache, armed before the
-            # job's first dispatch: ANY process that trains gets warm-start
-            # compiles when H2O_TPU_COMPILE_CACHE is set (idempotent — the
-            # server/cluster entry points arm it earlier when they ran)
+            # persistent XLA compile cache, placed before the job's first
+            # dispatch: ANY process that trains on an accelerator replays
+            # its compiles (idempotent — the server/cluster entry points
+            # place it earlier when they ran)
             compile_cache.ensure()
             t0 = time.time()
             # one root span per training job: everything recorded under it

@@ -22,25 +22,11 @@ import math
 
 import jax
 import numpy as np
+# the single import point of ``shard_map`` for the repo (graftlint rule
+# `direct-shard-map`): every SPMD program is built through this name, so
+# where programs meet the mesh stays reviewable in one place
+from jax import shard_map  # noqa: F401
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # jax >= 0.6: top-level export, replication check spelled check_vma
-    from jax import shard_map as _shard_map
-    _REP_KW = "check_vma"
-except ImportError:  # jax 0.4.x: experimental module, spelled check_rep
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _REP_KW = "check_rep"
-
-
-def shard_map(f, mesh=None, in_specs=None, out_specs=None, check_vma=True):
-    """Version-portable ``shard_map`` — the single import point for the repo.
-
-    Callers use the modern (jax >= 0.6) spelling; on older jax the call is
-    forwarded to ``jax.experimental.shard_map`` with ``check_vma`` mapped to
-    its earlier name ``check_rep``."""
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **{_REP_KW: check_vma})
-
 
 ROWS = "rows"
 COLS = "cols"
@@ -75,8 +61,8 @@ def default_mesh() -> Mesh:
         # default). Read once, at lazy construction: every Frame placed
         # afterwards shards against this mesh, so flipping the knob
         # mid-process would strand existing columns on the old layout
-        # (the bench `sharded` leg runs each shard count in its own
-        # subprocess for exactly this reason).
+        # (code that compares shard counts in one process installs
+        # explicit meshes with `use_mesh` instead — bench `sharded` leg).
         shards = get_int("H2O_TPU_ROW_SHARDS")
         _active_mesh = make_mesh(row_parallel=shards if shards > 0 else None)
     return _active_mesh
@@ -175,12 +161,18 @@ def padded_len(nrow: int, mesh: Mesh | None = None, multiple: int | None = None)
     per-chunk start offsets we use equal-size shards plus a global row count; rows
     beyond ``nrow`` are padding and masked out of every computation.
 
-    The per-shard multiple scales with nrow (8 for small frames, 8192 for large)
-    so the tree engine's row-block scan always gets evenly divisible shards
-    without wasting memory on tiny frames.
+    The per-shard multiple scales with nrow (8 for small frames, 65,536 for
+    large) so the tree engine's row-block scan always gets evenly divisible
+    shards without wasting memory on tiny frames. Large frames pad to EIGHT
+    8192-row blocks, not one: the TPU compiler's time on a blocked scan is
+    linear in the block count whenever that count is not a multiple of 8
+    (compile-only v5e target: the HIGGS train step took 195 s at 1343
+    blocks and 2.4 s at 1344 — tests/test_chip_compile.py, PERF.md). The
+    padding costs at most 0.6% of an 11M-row shard.
     """
     shards = n_row_shards(mesh)
     if multiple is None:
-        multiple = 8192 if nrow >= 1_000_000 else (256 if nrow >= 10_000 else 8)
+        multiple = (65_536 if nrow >= 1_000_000
+                    else (256 if nrow >= 10_000 else 8))
     q = shards * multiple
     return int(math.ceil(max(nrow, 1) / q) * q)

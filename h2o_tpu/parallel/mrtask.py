@@ -106,9 +106,7 @@ def _build_driver_program(map_fn, mesh: Mesh, nrow: int, reduce_key, avt,
         return {k: jax.tree.map(lambda x: _REDUCERS[reduce[k]](x, ROWS), v)
                 for k, v in out.items()}
 
-    # build each spec in ONE constructor call: on jax 0.4.x PartitionSpec is
-    # a tuple subclass whose __add__ returns a plain tuple, which shard_map
-    # rejects
+    # each spec is built in ONE constructor call (graftlint `pspec-concat`)
     in_specs = tuple(P(ROWS, *([None] * (len(shape) - 1)))
                      for shape, _ in avt)
     out_specs = P(ROWS) if out_rows else P()

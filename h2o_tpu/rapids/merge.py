@@ -227,8 +227,8 @@ _EXPAND_PROGS: dict = {}
 
 def _sharded_expand_program(mesh, total: int, plen: int, n_l: int, n_r: int):
     """Phase 2 as explicit per-shard work inside ``shard_map`` — the fix for
-    the jax-0.4.x GSPMD mis-partition that kept this phase pinned replicated
-    since PR 1 (GSPMD computed the Δ-scatter + cumsum fills per-shard on
+    the GSPMD mis-partition that kept this phase pinned replicated since
+    PR 1 (GSPMD computed the Δ-scatter + cumsum fills per-shard on
     row-sharded operands, so outputs diverged at the first shard boundary).
 
     The key structural fact: every phase-2 output row depends only on the
@@ -366,7 +366,7 @@ def _merge_device(left: Frame, right: Frame, key: str, all_x: bool) -> Frame:
                                      domain=v.domain) for _, v in sch])
     l_cols = tuple(left.vec(n).data[:ln] for n in left.names)
     # Phase 2's Δ-scatter + cumsum fills are exact only over the whole
-    # array, and the jax-0.4.x GSPMD partitioner computes them per-shard on
+    # array, and the GSPMD partitioner was seen computing them per-shard on
     # row-sharded operands (outputs diverge at the first shard boundary —
     # caught by __graft_entry__'s multichip dry run). The production path
     # therefore runs the fills as EXPLICIT per-shard work inside shard_map

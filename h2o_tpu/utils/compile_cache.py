@@ -1,20 +1,23 @@
-"""Persistent XLA compilation cache switch — shared by the server entry
-point, cluster init, the first `train_model` of a process, and bench.py.
+"""Persistent XLA compilation cache placement — ONE rule, shared by the
+server entry point, cluster init, `h2o.init()`, the first `train_model` of
+a process, `bench.py` and `chip_smoke.py`.
 
-On accelerator backends the cache is pure win (the standard TPU deployment
-practice): a fresh server/bench process replays its compiles from disk in
-seconds instead of paying the ~25-70 s cold-start the first full-length
-train otherwise costs. CPU stays opt-in because jax 0.9.0's CPU executable
-serializer segfaulted once mid-suite (tests/conftest.py history) — setting
-``H2O_TPU_COMPILE_CACHE`` to a directory opts in explicitly on any
-backend; '0' disables everywhere.
+The cache directory is part of the cache's key, so it must not move
+between processes that are meant to share it:
 
-:func:`ensure` is the idempotent wiring point: `model_base.train` calls it
-before a job's first dispatch and `api.client.init` / `parallel.cluster
-.init_cluster` call it at cluster formation, so ANY process with the knob
-set gets the cache without touching `deploy_entry` — closing the ROADMAP
-cold-start item (BENCH_r03/r04 measured 49-94 s cold vs 10.5 s warm; the
-bench ``cold_start`` leg keeps the delta on the record)."""
+- ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself; this module sets
+  NO directory in code (the deployment placed the cache — honour it, on any
+  backend, CPU included).
+- otherwise, on an accelerator backend: ``<checkout>/.xla_cache`` — a fixed
+  path beside the package (git-ignored), never ``~/.cache``, a temp name, a
+  pid or a time.
+- otherwise (CPU, variable unset): off. jax 0.9.0's CPU executable
+  serializer segfaulted once mid-suite, so the test mesh opts in only
+  through the environment variable.
+
+An unusable directory is an error, not a warning: a process that was
+meant to replay its compiles and silently recompiles them instead is the
+failure the chip budget cannot afford to hide."""
 
 from __future__ import annotations
 
@@ -23,52 +26,37 @@ import os
 _ENSURED = False
 _LOC: str | None = None
 
-
-def enable(default_dir: str | None = None) -> str | None:
-    import jax
-
-    from .knobs import raw
-
-    loc = raw("H2O_TPU_COMPILE_CACHE")
-    if loc == "0":
-        return None
-    if not loc:  # unset OR empty (a bare env entry must not makedirs(''))
-        if jax.default_backend() == "cpu":
-            return None
-        loc = default_dir or os.path.expanduser("~/.cache/h2o_tpu_xla")
-    os.makedirs(loc, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", loc)
-    # cache EVERYTHING: the cold-start gap is the sum of dozens of small
-    # programs (PR 6's acceptance run counted 32 for one GBM leg), and a
-    # time floor would leave every sub-threshold program recompiling in
-    # the "warm" process — the bench cold_start leg pins uncached ≤ 2
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    return loc
+#: ``<checkout>/.xla_cache`` — fixed relative to the package, so every
+#: process started from one checkout shares one cache
+DEFAULT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".xla_cache")
 
 
-def ensure(default_dir: str | None = None) -> str | None:
-    """Enable once per process (knob-gated; no-op thereafter). Returns the
-    active cache dir, or None when the cache is off for this process.
-
-    The cache is an optimization, never a gate: an unwritable dir (bad
-    knob value, read-only home on an accelerator container) degrades to
-    running without the cache, exactly like gbm.py's AOT fallback — a
-    training job must not die for its warm-start insurance."""
+def ensure() -> str | None:
+    """Apply the placement rule once per process (idempotent); returns the
+    directory in effect, or None when the cache is off. Raises OSError when
+    the directory cannot be created or written."""
     global _ENSURED, _LOC
     if _ENSURED:
         return _LOC
-    _ENSURED = True
-    try:
-        _LOC = enable(default_dir)
-    except OSError as e:
-        from .log import warn
+    import jax
 
-        warn(f"persistent compile cache disabled: cache dir unusable "
-             f"({e!r})")
-        _LOC = None
-        return None
-    if _LOC:
+    loc = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not loc and jax.default_backend() != "cpu":
+        loc = DEFAULT_DIR
+        jax.config.update("jax_compilation_cache_dir", loc)
+    if loc:
+        os.makedirs(loc, exist_ok=True)
+        if not os.access(loc, os.W_OK | os.X_OK):
+            raise PermissionError(
+                f"compile cache dir {loc!r} is not writable")
+        # cache EVERYTHING: the cold start is the sum of dozens of small
+        # programs, and JAX's default 1 s floor would leave every one of
+        # them recompiling in the "warm" process
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
         from .log import info
 
-        info(f"persistent XLA compile cache at {_LOC}")
+        info(f"persistent XLA compile cache at {loc}")
+    _ENSURED, _LOC = True, loc or None
     return _LOC

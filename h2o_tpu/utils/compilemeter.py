@@ -8,7 +8,7 @@ compiles are *countable*, which jax exposes through `jax.monitoring`: the
 dispatch layer records one `/jax/core/compile/backend_compile_duration`
 event per program that reaches the compile path.
 
-One version-measured caveat (jax 0.4.x): that event wraps
+One measured caveat: that event wraps
 ``compiler.compile_or_get_cached``, so it fires for PERSISTENT-CACHE HITS
 too — a program replayed from the `utils/compile_cache.py` disk cache
 counts as a "compile" even though no XLA compilation ran. The cache layer
@@ -18,7 +18,7 @@ this module tracks both and exposes the number that actually costs wall:
 - ``count()``       — programs through the compile path (builds + replays);
 - ``cache_hits()``  — persistent-cache replays among them;
 - ``uncached_count()`` — real XLA compilations (count − cache_hits), the
-  cold-start acceptance metric of the bench ``cold_start`` leg.
+  cold-start acceptance metric ``chip_smoke.py`` prints on its second run.
 
 `count()` deltas remain the right meter where NO compile activity at all
 is the contract (serving steady state: both numbers are zero). Callers

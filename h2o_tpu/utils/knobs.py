@@ -79,7 +79,8 @@ _knob("H2O_TPU_NPS_DIR", "str", "",
 # -- memory / frames --------------------------------------------------------
 _knob("H2O_TPU_HBM_LIMIT_BYTES", "int", 0,
       "pin the Cleaner/planner HBM budget exactly (0/unset = backend "
-      "resolution: memory_stats -> device_kind table -> unlimited)")
+      "resolution: memory_stats bytes_limit; an error on a TPU that "
+      "reports none, unlimited on CPU)")
 _knob("H2O_TPU_MAX_FRAME_BYTES", "int", 12 * 1024 ** 3,
       "refuse parses whose f32 frame would exceed this (FrameSizeMonitor)")
 _knob("H2O_TPU_BINNED_STORE", "bool", True,
@@ -89,8 +90,7 @@ _knob("H2O_TPU_ROW_SHARDS", "int", 0,
       "row shards of the lazily-built default mesh (parallel/mesh.py): "
       "how many devices split the data-parallel 'rows' axis; 0/unset = "
       "all devices (the historic default). Read ONCE at mesh "
-      "construction — set it before any frame is placed (the bench "
-      "'sharded' leg runs each value in its own subprocess)")
+      "construction — set it before any frame is placed")
 _knob("H2O_TPU_SHARDED_MERGE", "bool", True,
       "run the rapids merge expansion phase-2 sharded over the mesh rows "
       "axis (explicit per-shard delta-scatter+cumsum fills inside "
@@ -124,17 +124,15 @@ _knob("H2O_TPU_GOSS", "str", "",
       "forest (a sampler, not an oracle-parity mode); empty = off")
 _knob("H2O_TPU_HIST_KERNEL", "str", "auto",
       "kernels-layer backend for the level-histogram and Gram "
-      "accumulations (backend/kernels/): 'pallas' = fused pl.pallas_call "
-      "(interpreted off-TPU), 'xla' = the blocked lax.scan oracle, "
-      "'auto' = pallas on real TPU backends, xla elsewhere")
+      "accumulations (backend/kernels/): 'xla' = the blocked lax.scan "
+      "(what 'auto' resolves to on every backend), 'pallas' = fused "
+      "pl.pallas_call — interpreted off-TPU; on TPU it goes to Mosaic and "
+      "a refused kernel raises, nothing substitutes the scan")
 _knob("H2O_TPU_CLEAR_CACHES_EVERY", "int", 64,
       "drop live XLA executables every N models (long-server hygiene; "
       "0 = never)")
 _knob("H2O_TPU_PDP_BATCH_ROWS", "int", 2_000_000,
       "row budget per batched partial-dependence predict")
-_knob("H2O_TPU_COMPILE_CACHE", "str", "",
-      "persistent XLA compile cache dir ('0' disables; empty = backend "
-      "default: on for accelerators, off for CPU)")
 
 # -- serving (h2o_tpu/serving/ online scoring runtime) ----------------------
 _knob("H2O_TPU_SERVING_BUCKETS", "str", "1,4,16,64,128,256,512",
@@ -418,7 +416,7 @@ _knob("H2O_TPU_BENCH_BINNED_ROWS", "int", 8_000_000,
       "rows for the binned-store stacked-vs-binned leg")
 _knob("H2O_TPU_BENCH_WORKLOADS", "str",
       "gbm,glm,cod,gam,rulefit,sort,merge,binned,serving,serving_wire,"
-      "recovery,cold_start,sharded,airlines,workload",
+      "recovery,sharded,airlines,workload",
       "comma list of bench workloads to run")
 _knob("H2O_TPU_BENCH_WORKLOAD_TENANTS", "int", 3,
       "tenants for the multi-tenant workload bench leg (each runs "
@@ -426,15 +424,11 @@ _knob("H2O_TPU_BENCH_WORKLOAD_TENANTS", "int", 3,
 _knob("H2O_TPU_BENCH_WORKLOAD_ROWS", "int", 40_000,
       "rows per tenant frame in the workload bench leg")
 _knob("H2O_TPU_BENCH_SHARDED_ROWS", "int", 400_000,
-      "rows for the sharded leg (same GBM at 1 vs N row shards, each in "
-      "its own subprocess; per-shard peak matrix bytes + psum payload + "
-      "wall land in the sidecar)")
+      "rows for the sharded leg (same GBM at 1 vs N row shards; "
+      "per-shard peak matrix bytes + psum payload + wall land in the "
+      "sidecar)")
 _knob("H2O_TPU_BENCH_RECOVERY_ROWS", "int", 500_000,
       "rows for the recovery leg (checkpoint overhead + resume-to-parity)")
-_knob("H2O_TPU_BENCH_COLDSTART_ROWS", "int", 60_000,
-      "rows for the cold_start leg's subprocess GBM train+score (first "
-      "process cold vs second process on a warmed persistent compile "
-      "cache)")
 _knob("H2O_TPU_BENCH_SERVING_REQS", "int", 4000,
       "single-row requests issued by the concurrent serving bench leg")
 _knob("H2O_TPU_BENCH_SERVING_THREADS", "int", 16,
@@ -454,8 +448,6 @@ _knob("H2O_TPU_BENCH_GATE_BANDS", "str", "",
       "defaults (wall +25%, peak bytes +25%, AUC drop 0.02)")
 
 # -- test harness -----------------------------------------------------------
-_knob("H2O_TPU_TEST_CACHE", "str", "",
-      "opt-in persistent XLA compile cache dir for the test suite")
 _knob("H2O_TPU_KEY_STRICT", "bool", False,
       "fail tests on leaked KVStore keys instead of reaping them")
 

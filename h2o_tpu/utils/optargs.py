@@ -92,9 +92,6 @@ class OptArgs:
                                  "over the wire to execute")
 
     # -- engine knobs (sys.ai.h2o.* expert-prop analog) --------------------
-    compile_cache: str = _flag("", "H2O_TPU_COMPILE_CACHE",
-                               "persistent XLA compile cache dir "
-                               "('0' disables; empty = backend default)")
     exact_bin_rows: int = _flag(16384, "H2O_TPU_EXACT_BIN_ROWS",
                                 "rows at or below which tree binning uses "
                                 "exact small-data cut points")

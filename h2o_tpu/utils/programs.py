@@ -34,10 +34,10 @@ off-TPU by design.
 :func:`tracked` wraps a jitted callable: the first dispatch per argument
 signature AOT-lowers and compiles (the SAME single compile the jit
 dispatch would have paid — the jitted twin's own cache is never populated),
-registers the executable's analyses, and dispatches the compiled object;
-any mismatch (tracers, re-sharded inputs, executable input rejection)
-falls back to the jitted twin permanently for that signature, so behavior
-can only ever degrade to exactly the pre-registry dispatch. Results are
+registers the executable's analyses, and dispatches the compiled object.
+A compile error raises as is; an enclosing trace steps the wrapper aside;
+an executable that REJECTS its inputs (re-sharded arguments) falls back to
+the jitted twin permanently for that signature, with a warning. Results are
 bit-identical either way: both paths execute the XLA program lowered from
 the same arguments.
 
@@ -229,14 +229,13 @@ class Tracked:
         key = tuple(self._sig_of(a) for a in args)
         ent = self._compiled.get(key)
         if ent is None:
-            try:
-                ent = self._jitted.lower(*args).compile()
-                sig = tuple((s[0], s[1]) for s in key
-                            if isinstance(s, tuple) and len(s) == 3)
-                self._pids[key] = register_compiled(
-                    self.name, ent, self.kind, sig=sig, **self.labels)
-            except Exception:
-                ent = False
+            # a compile error surfaces HERE, once — the jitted twin would
+            # hand the same program to the same compiler and fail again
+            ent = self._jitted.lower(*args).compile()
+            sig = tuple((s[0], s[1]) for s in key
+                        if isinstance(s, tuple) and len(s) == 3)
+            self._pids[key] = register_compiled(
+                self.name, ent, self.kind, sig=sig, **self.labels)
             self._compiled[key] = ent
         if ent is False:
             return self._jitted(*args)

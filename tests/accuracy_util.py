@@ -31,6 +31,17 @@ def regression_dataset(n=4000, seed=12):
     return Frame.from_dict({"x1": x1, "x2": x2, "y": y})
 
 
+def linear_fit_rmse(fr) -> float:
+    """RMSE of the plain numpy least-squares fit of ``y`` on the other
+    columns + intercept — the float64 reference a learned model is
+    sanity-checked against."""
+    y = fr.vec("y").to_numpy().astype(np.float64)
+    A = np.stack([fr.vec(n).to_numpy() for n in fr.names if n != "y"]
+                 + [np.ones_like(y)], axis=1).astype(np.float64)
+    beta, *_ = np.linalg.lstsq(A, y, rcond=None)
+    return float(np.sqrt(np.mean((A @ beta - y) ** 2)))
+
+
 def multinomial_dataset(n=3000, seed=13):
     rng = np.random.default_rng(seed)
     x1 = rng.normal(size=n)

@@ -96,3 +96,25 @@ def test_compute_bin_edges_streams_above_exact_limit(monkeypatch):
         assert len(cuts) >= 15
         assert np.all(np.diff(cuts) >= 0)
         assert abs(cuts[len(cuts) // 2]) < 0.1  # median cut near 0
+
+
+def test_sharded_sketch_is_bit_equal_to_single_device():
+    """Rows split over the mesh go through shard_map (per-shard scan +
+    psum); histogram cells are exact counts, so the quantiles must match
+    the single-device program bit for bit — including a column whose rows
+    on one shard are all NaN (padding) and an all-NaN column."""
+    from h2o_tpu.parallel.mesh import default_mesh, n_row_shards
+
+    rng = np.random.default_rng(4)
+    ns = n_row_shards(default_mesh())
+    R = ns * 1536                       # 1.5 blocks per shard: pads inside
+    X = rng.normal(size=(R, 4)).astype(np.float32)
+    X[::5, 1] = np.nan
+    X[-R // ns:, 2] = np.nan            # last shard: nothing but NaN
+    X[:, 3] = np.nan
+    qs = tuple(np.linspace(0, 1, 21)[1:-1])
+    single = np.asarray(binning._hist_quantile_rows(X, qs, nb=256, rb=1024))
+    sharded = np.asarray(binning._sketch_block(X, qs, 256, 1024))
+    assert ns > 1
+    np.testing.assert_array_equal(sharded, single)
+    assert np.isnan(single[:, 3]).all() and np.isfinite(single[:, :3]).all()

@@ -59,13 +59,10 @@ def test_dl_multinomial():
         training_frame=fr, response_column="y", hidden=[16],
         epochs=40, seed=4, mini_batch_size=64,
     )).train_model()
-    # bound calibrated on newer jax; 0.4.x RNG/optimizer numerics land this
-    # run at ~0.506 (random 3-class logloss ≈ 1.1, so still learning) —
-    # version-gated so a genuine regression on jax >= 0.6 still trips 0.5
-    import jax as _jax
-
-    bound = 0.55 if _jax.__version__.startswith("0.4.") else 0.5
-    assert m.output.training_metrics.logloss < bound
+    # 0.4193 on the installed jax 0.9.0. The bound is what a plain numpy
+    # softmax REGRESSION reaches on the same data (0.487; the class prior
+    # alone gives 1.018): the hidden layer has to earn its keep
+    assert m.output.training_metrics.logloss < 0.48
     pred = m.predict(fr)
     assert pred.names[0] == "predict" and pred.ncol == 4
 

@@ -427,11 +427,11 @@ def test_fix_import_insertion_precedes_mid_prelude_use():
     src = ('"""Doc."""\n'
            "import os\n"
            "\n"
-           'cache = os.environ.get("H2O_TPU_TEST_CACHE")\n'
+           'cache = os.environ.get("H2O_TPU_KEY_STRICT")\n'
            "\n"
            "import json\n")
     fixed = fix_source(src, "h2o_tpu/models/new.py")
-    assert 'knobs.raw("H2O_TPU_TEST_CACHE")' in fixed
+    assert 'knobs.raw("H2O_TPU_KEY_STRICT")' in fixed
     compile(fixed, "<fixed>", "exec")
     knobs_at = fixed.splitlines().index("from h2o_tpu.utils import knobs")
     use_at = next(i for i, ln in enumerate(fixed.splitlines())

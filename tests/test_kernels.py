@@ -387,30 +387,65 @@ def test_tree_phase_sample_records_backend_tagged_spans():
 # cold start: compile-cache wiring + AOT train step + compilemeter hits
 # ---------------------------------------------------------------------------
 class TestColdStart:
-    def test_ensure_is_knob_gated_and_idempotent(self, tmp_path,
-                                                 monkeypatch):
+    @pytest.fixture()
+    def cache_rule(self, monkeypatch):
+        """compile_cache with ``jax.config.update`` replaced by a recorder
+        (no global jax state leaks out of the test) and the once-per-process
+        latch cleared."""
+        import jax
+
         from h2o_tpu.utils import compile_cache
 
+        updates: dict = {}
+        monkeypatch.setattr(jax.config, "update",
+                            lambda k, v: updates.__setitem__(k, v))
         monkeypatch.setattr(compile_cache, "_ENSURED", False)
         monkeypatch.setattr(compile_cache, "_LOC", None)
-        monkeypatch.setenv("H2O_TPU_COMPILE_CACHE", "0")
-        assert compile_cache.ensure() is None
-        # idempotent: later calls return the frozen first answer
-        monkeypatch.setenv("H2O_TPU_COMPILE_CACHE", str(tmp_path / "x"))
-        assert compile_cache.ensure() is None
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        return compile_cache, updates, jax
 
-    def test_enable_uses_explicit_dir_on_cpu(self, tmp_path, monkeypatch):
-        from h2o_tpu.utils import compile_cache
-
-        loc = str(tmp_path / "xla_cache")
-        monkeypatch.setenv("H2O_TPU_COMPILE_CACHE", loc)
-        assert compile_cache.enable() == loc
+    def test_env_dir_wins_and_no_dir_is_set_in_code(self, cache_rule,
+                                                    tmp_path, monkeypatch):
+        compile_cache, updates, _jax = cache_rule
+        loc = str(tmp_path / "placed")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", loc)
+        assert compile_cache.ensure() == loc   # honoured on CPU too
         assert os.path.isdir(loc)
+        assert "jax_compilation_cache_dir" not in updates
+        # idempotent: later calls return the frozen first answer
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "y"))
+        assert compile_cache.ensure() == loc
+
+    def test_unset_on_cpu_is_off(self, cache_rule):
+        compile_cache, updates, _jax = cache_rule
+        assert compile_cache.ensure() is None
+        assert updates == {}
+
+    def test_unset_on_accelerator_is_the_checkout_dir(self, cache_rule,
+                                                      tmp_path, monkeypatch):
+        compile_cache, updates, jax = cache_rule
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        # ONE fixed default: beside the package, no home, temp, pid or time
+        assert compile_cache.DEFAULT_DIR == os.path.join(repo, ".xla_cache")
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(compile_cache, "DEFAULT_DIR",
+                            str(tmp_path / ".xla_cache"))
+        assert compile_cache.ensure() == str(tmp_path / ".xla_cache")
+        assert updates["jax_compilation_cache_dir"] == \
+            str(tmp_path / ".xla_cache")
+
+    def test_unusable_dir_is_an_error(self, cache_rule, tmp_path,
+                                      monkeypatch):
+        compile_cache, _updates, _jax = cache_rule
+        (tmp_path / "file").write_text("not a directory")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                           str(tmp_path / "file" / "cache"))
+        with pytest.raises(OSError):
+            compile_cache.ensure()
 
     def test_train_arms_the_cache(self, monkeypatch):
         """model_base.train calls compile_cache.ensure() before the first
-        dispatch — the knob-gated wiring the cold_start bench leg relies
-        on."""
+        dispatch — any process that trains gets the placed cache."""
         from h2o_tpu.models.gbm import GBM, GBMParameters
         from h2o_tpu.utils import compile_cache
 

@@ -57,59 +57,63 @@ def test_touch_order_is_lru_not_creation_order(tight_budget):
 
 
 class _FakeDev:
-    def __init__(self, kind):
-        self.device_kind = kind
+    def __init__(self, stats):
+        self._stats = stats
+
+    def memory_stats(self):
+        return self._stats
 
 
 @pytest.fixture()
-def _unresolved_hw(monkeypatch):
-    """Blind the memory_stats route and clear the cached hardware lookup so
-    each test resolves the device_kind table fresh."""
+def _fake_hw(monkeypatch):
+    """Swap the local devices for fakes and clear the cached hardware
+    lookup, so each test resolves the HBM budget fresh from the
+    ``memory_stats()`` it chooses."""
     import jax
 
     from h2o_tpu.backend import memory
 
     monkeypatch.delenv("H2O_TPU_HBM_LIMIT_BYTES", raising=False)
-    monkeypatch.setattr(memory, "hbm_stats", lambda: None)
     monkeypatch.setattr(memory, "_HW_BYTES", memory._UNRESOLVED)
     # fresh Cleaner: hbm_budget_bytes subtracts tracked resident bytes, and
     # vecs from other tests must not bleed into the budget assertions
     monkeypatch.setattr(memory, "CLEANER", memory.Cleaner())
-    yield memory, monkeypatch, jax
+
+    def install(*stats, backend="tpu"):
+        monkeypatch.setattr(jax, "local_devices",
+                            lambda: [_FakeDev(s) for s in stats])
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+
+    yield memory, monkeypatch, install
 
 
-@pytest.mark.parametrize("kind,gib", [
-    ("TPU v5p", 95), ("TPU v5 lite", 16), ("TPU v6 lite", 32),
-    ("TPU v4", 32), ("TPU v3", 16)])
-def test_device_kind_hbm_table(_unresolved_hw, kind, gib):
-    memory, monkeypatch, jax = _unresolved_hw
-    monkeypatch.setattr(jax, "devices", lambda: [_FakeDev(kind)])
-    assert memory.device_hbm_bytes() == gib << 30
-    assert memory.hbm_budget_bytes() == int((gib << 30) * 0.85)
+def test_hbm_bytes_are_what_memory_stats_reports(_fake_hw):
+    memory, _mp, install = _fake_hw
+    gib16 = 16 << 30
+    install({"bytes_limit": gib16, "bytes_in_use": 1 << 30},
+            {"bytes_limit": gib16, "bytes_in_use": 5 << 30})
+    # the FULLEST local device is the one a per-chip budget has to fit
+    assert memory.hbm_stats()["bytes_in_use"] == 5 << 30
+    assert memory.device_hbm_bytes() == gib16
+    assert memory.hbm_budget_bytes() == int(gib16 * 0.85)
+    assert memory.Cleaner().limit_bytes() == int(gib16 * 0.85)
 
 
-def test_cleaner_budget_derives_from_device_kind(_unresolved_hw):
-    """A v5p-class chip must not spill at the old hardcoded v5e budget when
-    the transport hides memory_stats (ADVICE r5)."""
-    memory, monkeypatch, jax = _unresolved_hw
-    monkeypatch.setattr(jax, "devices", lambda: [_FakeDev("TPU v5p")])
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    c = memory.Cleaner()
-    assert c.limit_bytes() == int((95 << 30) * 0.85)
+@pytest.mark.parametrize("stats", [None, {"bytes_in_use": 0}])
+def test_tpu_without_bytes_limit_is_an_error(_fake_hw, stats):
+    """The hardware is asked, not assumed: no device_kind table, no 16 GiB
+    last resort."""
+    memory, _mp, install = _fake_hw
+    install(stats)
+    with pytest.raises(RuntimeError, match="bytes_limit"):
+        memory.hbm_budget_bytes()
+    with pytest.raises(RuntimeError, match="bytes_limit"):
+        memory.Cleaner().limit_bytes()
 
 
-def test_cleaner_budget_unknown_tpu_kind_keeps_16gib_last_resort(
-        _unresolved_hw):
-    memory, monkeypatch, jax = _unresolved_hw
-    monkeypatch.setattr(jax, "devices", lambda: [_FakeDev("TPU v99")])
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    c = memory.Cleaner()
-    assert c.limit_bytes() == int(16 * (1 << 30) * 0.85)
-
-
-def test_hbm_budget_env_pin_and_cpu_none(_unresolved_hw):
-    memory, monkeypatch, jax = _unresolved_hw
-    monkeypatch.setattr(jax, "devices", lambda: [_FakeDev("cpu")])
+def test_hbm_budget_env_pin_and_cpu_none(_fake_hw):
+    memory, monkeypatch, install = _fake_hw
+    install(None, backend="cpu")
     assert memory.hbm_budget_bytes() is None  # planners fall back
     monkeypatch.setenv("H2O_TPU_HBM_LIMIT_BYTES", "123456")
     assert memory.hbm_budget_bytes() == 123456
@@ -120,11 +124,11 @@ def test_hbm_budget_env_pin_and_cpu_none(_unresolved_hw):
     assert memory.Cleaner().limit_bytes() is None
 
 
-def test_hbm_budget_is_live_minus_resident(_unresolved_hw):
+def test_hbm_budget_is_live_minus_resident(_fake_hw):
     """Planners must see physical headroom MINUS what already sits in HBM —
     a 14 GB resident frame on a v5e leaves ~nothing for intermediates."""
-    memory, monkeypatch, jax = _unresolved_hw
-    monkeypatch.setattr(jax, "devices", lambda: [_FakeDev("TPU v5 lite")])
+    memory, _mp, install = _fake_hw
+    install({"bytes_limit": 16 << 30, "bytes_in_use": 0})
 
     class _Obj:  # weakref-able stand-in for a device-resident Vec
         pass
@@ -134,5 +138,7 @@ def test_hbm_budget_is_live_minus_resident(_unresolved_hw):
     v = _Obj()
     memory.CLEANER.track(v, 4 << 30)
     assert memory.hbm_budget_bytes() == full - (4 << 30)
-    memory.CLEANER.track(v, 20 << 30)  # over-committed: floor at 1/16 HBM
+    # 13 GiB resident (still under the Cleaner's own 13.6 GiB sweep
+    # threshold) leaves 0.6 GiB of headroom: the planner floor, 1/16 HBM
+    memory.CLEANER.track(v, 9 << 30)
     assert memory.hbm_budget_bytes() == (16 << 30) >> 4

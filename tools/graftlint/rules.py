@@ -3,10 +3,10 @@
 Each rule cites the incident that motivated it (PR numbers refer to
 CHANGES.md entries):
 
-1. direct-shard-map      — PR 1: the seed suite was 100% import-broken on
-   jax 0.4.x because `shard_map` moved between jax versions; the
-   version-bridged shim in `h2o_tpu/parallel/mesh.py` is the ONLY sanctioned
-   import point (ROADMAP "jax version skew" item).
+1. direct-shard-map      — PR 1: the seed suite was 100% import-broken
+   because `shard_map` moved between jax versions; `h2o_tpu/parallel/
+   mesh.py` is the ONLY sanctioned import point, so the next move is a
+   one-line fix.
 2. pspec-concat          — PR 1: on jax 0.4.x `PartitionSpec.__add__`
    returns a plain tuple, which shard_map rejects; specs must be built in
    one constructor call (`parallel/mrtask.py` carries the war story).
@@ -106,7 +106,7 @@ def _norm_func(node: ast.Call, ctx: FileContext) -> str | None:
 class DirectShardMap(Rule):
     id = "direct-shard-map"
     doc = ("shard_map imported/used outside h2o_tpu/parallel/mesh.py — "
-           "route through the version-bridged shim (jax 0.4.x skew)")
+           "import it from the repo's single import point")
 
     def check(self, tree, ctx):
         if ctx.relpath == MESH_PATH:
@@ -114,8 +114,8 @@ class DirectShardMap(Rule):
         out = []
         spans: list[tuple] = []  # flagged attribute-chain spans
         msg = ("direct jax shard_map use — import it from "
-               "h2o_tpu.parallel.mesh (the version-bridged shim; "
-               "ROADMAP 'jax version skew')")
+               "h2o_tpu.parallel.mesh (the repo's single import "
+               "point)")
         for node in ast.walk(tree):
             if isinstance(node, ast.ImportFrom):
                 mod = node.module or ""
