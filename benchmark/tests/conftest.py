@@ -1,0 +1,15 @@
+"""The benchmark's own tests run on the CPU, one device, no chip, no socket
+beyond the in-process server's loopback port that ``h2o.init`` picks."""
+
+import os
+import sys
+
+os.environ["JAX_PLATFORMS"] = "cpu"
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+import jax  # noqa: E402
+
+jax.config.update("jax_platforms", "cpu")
