@@ -150,9 +150,9 @@ def bench_airlines(nrow: int, ntrees: int) -> dict:
     Since PR 12 this is also the pipelined-training scoreboard: the leg
     trains the pipelined default (H2O_TPU_PIPELINE=1) cold + warm, then
     the synchronous oracle (=0) warm, and records the speedup, the
-    forest/prediction BIT-parity flag, the warm run's uncached compile
-    count, and the sampled ``gbm.pipeline.overlap_ratio`` gauge —
-    acceptance: parity true, >= 1.25x, 0 uncached steady-state compiles."""
+    forest/prediction BIT-parity flag and the warm run's uncached compile
+    count — acceptance: parity true, >= 1.25x, 0 uncached steady-state
+    compiles."""
     import gc as _gc
 
     import jax
@@ -206,8 +206,6 @@ def bench_airlines(nrow: int, ntrees: int) -> dict:
     parity = parity and bool(np.array_equal(
         np.asarray(model.score0(Xs)), np.asarray(sync_model.score0(Xs))))
     del Xs
-    overlap = telemetry.snapshot().get("gbm.pipeline.overlap_ratio",
-                                       {}).get("value")
     auc = model.output.training_metrics.auc
     stats = hbm_stats() or {}
     out = {"wall_s": round(wall, 3), "wall_cold_s": round(wall_cold, 3),
@@ -215,7 +213,6 @@ def bench_airlines(nrow: int, ntrees: int) -> dict:
            "pipeline_speedup_x": round(wall_sync / max(wall, 1e-9), 3),
            "forest_parity": parity,
            "uncached_compiles_warm": uncached,
-           "overlap_ratio": overlap,
            "train_auc": round(float(auc), 4),
            "rows": nrow, "gen_s": gen_s, "h2d_s": h2d_s,
            "cleaner_spills": CLEANER.spills,

@@ -2273,12 +2273,18 @@ class H2OEstimator:
 
     def train(self, x=None, y=None, training_frame: H2OFrame | None = None,
               validation_frame: H2OFrame | None = None, **kw):
+        from ..utils import telemetry
+
         body = _train_body(dict(self._params), x, y, training_frame,
                            validation_frame, kw)
-        job = connection().request("POST", f"/3/ModelBuilders/{self.algo}",
-                                   data=body)
-        done = _poll_job(job)
-        self._model = get_model(done["dest"]["name"])
+        # the job's root span on the client: `_send` attaches it as
+        # `traceparent`, so the server's rest.request and train.* spans
+        # carry this trace id (an enclosing caller span stays the root)
+        with telemetry.span("client.train", algo=self.algo):
+            job = connection().request(
+                "POST", f"/3/ModelBuilders/{self.algo}", data=body)
+            done = _poll_job(job)
+            self._model = get_model(done["dest"]["name"])
         return self
 
     # delegate model accessors

@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ...utils import telemetry
 from . import hist_backend, interpret_mode, pow2_block_rows
 
 #: transient-cell budget per block: blocks sized so the (rb, P) weighted
@@ -126,6 +127,7 @@ def _pallas_gram(X, W, z, rb):
     return out[0], None
 
 
+@telemetry.scope("glm.gram")
 def gram_accumulate(X, W, z=None, *, block: int | None = None,
                     backend: str | None = None):
     """(G, b) = (XᵀWX, XᵀWz) in one blocked pass; ``b`` is None when ``z``

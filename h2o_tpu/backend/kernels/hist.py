@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ...utils import telemetry
 from . import hist_backend, interpret_mode, pow2_block_rows
 
 
@@ -105,6 +106,7 @@ def _group_contrib(xgs, l, vv, groups, n_lv: int, na_global: int,
 # ---------------------------------------------------------------------------
 # xla backend — blocked lax.scan (the oracle)
 # ---------------------------------------------------------------------------
+@telemetry.scope("gbm.hist")
 def _xla_flat(Xb, lc, vv, n_lv, nbins_tot, rb):
     Rl, F = Xb.shape
     V = vv.shape[1]
@@ -121,6 +123,7 @@ def _xla_flat(Xb, lc, vv, n_lv, nbins_tot, rb):
     return hist
 
 
+@telemetry.scope("gbm.hist")
 def _xla_grouped(xgs, lc, vv, groups, n_lv, na_global, rb):
     Rl = lc.shape[0]
     V = vv.shape[1]
@@ -231,6 +234,7 @@ def level_hist_one_group(xg, lc, vv, *, Bg: int, mode: str, n_lv: int,
     return fn([xg], lc, vv, groups1, n_lv, nbins_tot - 1, rb)[0]
 
 
+@telemetry.scope("gbm.hist")
 def streamed_route_hist(Xb, node, vals, route_fn, *, offset: int, n_lv: int,
                         nbins_tot: int, block: int, groups=None):
     """Fused route→accumulate single pass — the double-buffered column-block
@@ -263,7 +267,10 @@ def streamed_route_hist(Xb, node, vals, route_fn, *, offset: int, n_lv: int,
     def body(accs, blk):
         xb, nd, v = blk
         if route_fn is not None:
-            nd = route_fn(xb, nd)
+            # the innermost scope names an operation: routing inside the
+            # fused stream reads gbm.route, the accumulate gbm.hist
+            with telemetry.scope("gbm.route"):
+                nd = route_fn(xb, nd)
         local = nd - offset
         active = (local >= 0) & (local < n_lv)
         lc = jnp.clip(local, 0, n_lv - 1)

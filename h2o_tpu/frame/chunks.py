@@ -48,6 +48,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..parallel import mesh as meshmod
+from ..utils import telemetry
 from .vec import Rollups, T_CAT, T_NUM, Vec
 
 #: code-space caps: the top code of each width is the NA sentinel
@@ -441,6 +442,7 @@ def compress_frame(fr):
 # BinnedView — device-resident int8/int16 binned training matrix
 # ---------------------------------------------------------------------------
 @jax.jit
+@telemetry.scope("gbm.bin")
 def _stack_codes(*cols):
     return jnp.stack(cols, axis=1)
 

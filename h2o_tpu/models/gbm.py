@@ -37,8 +37,7 @@ from .model_base import Model, ModelBuilder, ModelOutput, Parameters, make_metri
 from .tree.binning import (bin_matrix, compute_bin_edges,
                            compute_bin_edges_cols)
 from .tree.engine import (TreeConfig, make_train_fn, plan_hist_groups,
-                          predict_forest, psum_payload_bytes,
-                          sample_pipeline_phases, sample_tree_phases)
+                          predict_forest, psum_payload_bytes)
 
 #: last build's training-matrix accounting (mode, per-matrix bytes) — the
 #: bench binned-storage leg and the chunk-store tests read this to put the
@@ -51,36 +50,6 @@ LAST_TRAIN_MATRIX_BYTES: dict = {}
 #: lower+compile (and with a warmed persistent compile cache that cost is
 #: a disk replay)
 _AOT_STEP_CACHE: dict = {}
-
-
-#: kernels backends whose phase profile this process already sampled —
-#: tests clear it to force a fresh sample
-_PHASE_SAMPLED: set = set()
-
-
-def _phase_sample_due() -> bool:
-    from ..backend.kernels import hist_backend
-
-    bk = hist_backend()
-    if bk in _PHASE_SAMPLED:
-        return False
-    _PHASE_SAMPLED.add(bk)
-    return True
-
-
-#: processes that already sampled the pipelined-stage profile (overlap
-#: ratio gauge) — tests clear it to force a fresh sample
-_PIPE_SAMPLED: set = set()
-
-
-def _pipe_sample_due() -> bool:
-    from ..backend.kernels import hist_backend
-
-    bk = hist_backend()
-    if bk in _PIPE_SAMPLED:
-        return False
-    _PIPE_SAMPLED.add(bk)
-    return True
 
 
 def _aot_train_step(train_fn, args, key_base):
@@ -523,6 +492,7 @@ class GBM(ModelBuilder):
         dist = self._distribution(category)
         K = len(resp_domain) if category == "Multinomial" else 1
 
+        from ..utils import telemetry
         from ..utils.knobs import get_bool
 
         use_binned = not need_raw and get_bool("H2O_TPU_BINNED_STORE")
@@ -544,11 +514,15 @@ class GBM(ModelBuilder):
         if use_binned:
             X = None
             feat_vecs = [fr.vec(n) for n in names]
-            edges_np = compute_bin_edges_cols(feat_vecs, is_cat, p.nbins,
-                                              **bin_kw)
         else:
             X = fr.as_matrix(names)
-            edges_np = compute_bin_edges(X, is_cat, p.nbins, **bin_kw)
+        # the quantile sketch, until the edges are on the host (the copy out
+        # drains it)
+        with telemetry.span("train.gbm.sketch"):
+            edges_np = (
+                compute_bin_edges_cols(feat_vecs, is_cat, p.nbins, **bin_kw)
+                if use_binned
+                else compute_bin_edges(X, is_cat, p.nbins, **bin_kw))
         mesh = default_mesh()
         edges = put_replicated(np.nan_to_num(edges_np, nan=np.inf), mesh)
         mono_np = np.zeros(len(names), dtype=np.float32)
@@ -567,15 +541,20 @@ class GBM(ModelBuilder):
         imat = put_replicated(imat_np, mesh)
         edge_ok = put_replicated(~np.isnan(edges_np), mesh)
         binned_view = None
-        if use_binned:
-            # device-resident coded training matrix, packed column-by-column
-            # (Cleaner-tracked; the engine upcasts blocks in-scan)
-            from ..frame.chunks import BinnedView
+        # HOST wall of the coded-matrix build: no sync is added here, so
+        # where the build does not drain by itself the device's side is the
+        # scope gbm.bin in a capture
+        with telemetry.span("train.gbm.binned_view"):
+            if use_binned:
+                # device-resident coded training matrix, packed column-by-
+                # column (Cleaner-tracked; the engine upcasts blocks in-scan)
+                from ..frame.chunks import BinnedView
 
-            binned_view = BinnedView.build(feat_vecs, edges_np, names=names)
-            Xb = binned_view.matrix
-        else:
-            Xb = bin_matrix(X, put_replicated(edges_np, mesh))
+                binned_view = BinnedView.build(feat_vecs, edges_np,
+                                               names=names)
+                Xb = binned_view.matrix
+            else:
+                Xb = bin_matrix(X, put_replicated(edges_np, mesh))
         plen = Xb.shape[0]
         global LAST_TRAIN_MATRIX_BYTES
         LAST_TRAIN_MATRIX_BYTES = {
@@ -951,41 +930,6 @@ class GBM(ModelBuilder):
             with telemetry.span("train.gbm.chunk",
                                 metric="train.chunk.seconds",
                                 chunk=ci, trees=int(len(keys))):
-                if (ci == start_ci and K == 1 and telemetry.enabled()
-                        and _phase_sample_due()):
-                    # sampled in-boundary phase profile (hist/split/route/
-                    # leaf + the train.hist.kernel backend-tagged wall):
-                    # nested under this chunk span — the fused program
-                    # exposes no phases of its own. Once per process per
-                    # kernels backend (the sample dispatches real device
-                    # work; paying it per job would tax every small train)
-                    try:
-                        g_s, h_s = grad_fn(y_k, f, w)
-                        sample_tree_phases(
-                            Xb, jnp.stack([w, g_s, h_s], axis=1),
-                            edge_ok, cfg,
-                            iscat=s.iscat_dev if cfg.use_sets else None,
-                            nedges=s.nedges_dev if cfg.use_sets else None)
-                    except Exception as e:  # instrumentation must never
-                        from ..utils.log import warn  # kill a training job
-
-                        warn(f"tree phase sample skipped ({e!r})")
-                if (ci == start_ci and K == 1 and telemetry.enabled()
-                        and cfg.pipeline and _pipe_sample_due()):
-                    # pipelined-stage profile: h2d / local-accum /
-                    # psum-wait / split walls + the overlap-ratio gauge
-                    # (how much of the h2d+collective wall the pipeline
-                    # hides) — once per process, same rationale as above
-                    try:
-                        g_s, h_s = grad_fn(y_k, f, w)
-                        sample_pipeline_phases(
-                            Xb, jnp.stack([w, g_s, h_s], axis=1), cfg,
-                            mesh)
-                    except Exception as e:
-                        from ..utils.log import warn
-
-                        warn(f"pipeline phase sample skipped ({e!r})")
-
                 def _dispatch(cj, f_in):
                     nonlocal train_step
                     import contextlib as _ctx
@@ -1054,34 +998,39 @@ class GBM(ModelBuilder):
                     # recovery, export, stopping — out of this mode.)
                     ahead = _dispatch(ci + 1, f)
                     f = None
-                oob_sum = osum if oob_sum is None else oob_sum + osum
-                oob_cnt = ocnt if oob_cnt is None else oob_cnt + ocnt
-                parts.append(trees)
-                ntrees_done = sum(t[0].shape[0] for t in parts)
-                # DRF scores OOB throughout (history + early stopping), so
-                # the stopping signal is honest, not in-bag memorization;
-                # OOB spans only this build's trees, hence the checkpoint
-                # gate below
-                m = None
-                if self.drf_mode and p.sample_rate < 1.0 and n_prior == 0:
-                    m = self._oob_metrics(category, oob_sum, oob_cnt, y,
-                                          ymask,
-                                          w if p.weights_column else None,
-                                          output.response_domain)
-                    if m is not None:
-                        m.description = "Reported on OOB data"
-                if m is None:
-                    m = make_metrics(category, s.ym,
-                                     mraw if mraw is not None else
-                                     _metrics_raw(category, dist, f,
-                                                  self.drf_mode,
-                                                  ntrees_done),
-                                     None if p.weights_column is None else w,
-                                     auc_type=p.auc_type,
-                                     domain=output.response_domain)
-                history.append({"timestamp": _t.time(),
-                                "number_of_trees": ntrees_done,
-                                "training_metrics": m})
+                # boundary scoring, from the step's outputs in hand to the
+                # history row: reading the metrics back is the host's wait
+                # for the chunk itself (and, dispatched ahead, for nothing
+                # else), so this span names gaps and backs no metric
+                with telemetry.span("train.gbm.score"):
+                    oob_sum = osum if oob_sum is None else oob_sum + osum
+                    oob_cnt = ocnt if oob_cnt is None else oob_cnt + ocnt
+                    parts.append(trees)
+                    ntrees_done = sum(t[0].shape[0] for t in parts)
+                    # DRF scores OOB throughout (history + early stopping), so
+                    # the stopping signal is honest, not in-bag memorization;
+                    # OOB spans only this build's trees, hence the checkpoint
+                    # gate below
+                    m = None
+                    if self.drf_mode and p.sample_rate < 1.0 and n_prior == 0:
+                        m = self._oob_metrics(category, oob_sum, oob_cnt, y,
+                                              ymask,
+                                              w if p.weights_column else None,
+                                              output.response_domain)
+                        if m is not None:
+                            m.description = "Reported on OOB data"
+                    if m is None:
+                        m = make_metrics(category, s.ym,
+                                         mraw if mraw is not None else
+                                         _metrics_raw(category, dist, f,
+                                                      self.drf_mode,
+                                                      ntrees_done),
+                                         None if p.weights_column is None else w,
+                                         auc_type=p.auc_type,
+                                         domain=output.response_domain)
+                    history.append({"timestamp": _t.time(),
+                                    "number_of_trees": ntrees_done,
+                                    "training_metrics": m})
                 job.update(len(keys) / max(n_new, 1))
                 if p.export_checkpoints_dir:
                     self._export_snapshot(p, output, parts, f0, dist, cfg,

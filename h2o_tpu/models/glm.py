@@ -33,6 +33,7 @@ from jax.sharding import PartitionSpec as _P
 from ..backend.jobs import Job
 from ..frame.frame import Frame
 from ..frame.vec import Vec
+from ..utils import telemetry
 from .datainfo import DataInfo
 from .model_base import Model, ModelBuilder, ModelOutput, Parameters, make_metrics
 
@@ -229,15 +230,20 @@ def _make_irls_kernel(family: Family):
     from ..backend.kernels import gram as gram_kernels
     from ..parallel.mesh import ROWS, default_mesh, n_row_shards, shard_map
 
+    # device scopes (telemetry.SCOPES) split the step in a capture; the
+    # function keeps its name: the XLA module `jit__core` is read by name
     def _core(X, y, w, beta, offset):
-        eta = X @ beta + offset
-        mu = family.linkinv(eta)
-        d = family.dmu_deta(eta)
-        V = family.variance(mu)
-        W = w * d * d / jnp.maximum(V, 1e-10)
-        z = eta - offset + (y - mu) / jnp.where(jnp.abs(d) < 1e-10, 1e-10, d)
-        G, b = gram_kernels.gram_accumulate(X, W, z)
-        dev = jnp.sum(family.deviance(y, mu, w))
+        with telemetry.scope("glm.eta"):
+            eta = X @ beta + offset
+            mu = family.linkinv(eta)
+            d = family.dmu_deta(eta)
+            V = family.variance(mu)
+            W = w * d * d / jnp.maximum(V, 1e-10)
+            z = eta - offset + (y - mu) / jnp.where(jnp.abs(d) < 1e-10,
+                                                    1e-10, d)
+        G, b = gram_kernels.gram_accumulate(X, W, z)   # scope glm.gram
+        with telemetry.scope("glm.deviance"):
+            dev = jnp.sum(family.deviance(y, mu, w))
         return G, b, dev, jnp.sum(w)
 
     from ..utils import programs
@@ -288,8 +294,10 @@ def _make_dev_kernel(family: Family):
 
     @jax.jit
     def dev_eval(X, y, w, beta, offset):
-        mu = family.linkinv(X @ beta + offset)
-        return jnp.sum(family.deviance(y, mu, w))
+        with telemetry.scope("glm.eta"):
+            mu = family.linkinv(X @ beta + offset)
+        with telemetry.scope("glm.deviance"):
+            return jnp.sum(family.deviance(y, mu, w))
 
     return dev_eval
 
@@ -1222,16 +1230,19 @@ class GLM(ModelBuilder):
             return self._build_multinomial(job, names, y_dev, resp_domain)
         family = self._family(category)
 
-        dinfo = DataInfo.make(fr, names, standardize=p.standardize,
-                              missing_values_handling=p.missing_values_handling)
-        X, okrow = dinfo.expand(fr)
-        X, y_dev, pad_cols = _shard_cols(X, y_dev, p.feature_parallelism)
-        y = jnp.nan_to_num(y_dev)
-        w = (~jnp.isnan(y_dev)).astype(jnp.float32) * okrow.astype(jnp.float32)
-        if p.weights_column:
-            w = w * jnp.nan_to_num(fr.vec(p.weights_column).data)
-        offset = (jnp.nan_to_num(fr.vec(p.offset_column).data)
-                  if p.offset_column else jnp.zeros_like(y))
+        with telemetry.span("train.glm.design"):
+            dinfo = DataInfo.make(
+                fr, names, standardize=p.standardize,
+                missing_values_handling=p.missing_values_handling)
+            X, okrow = dinfo.expand(fr)
+            X, y_dev, pad_cols = _shard_cols(X, y_dev, p.feature_parallelism)
+            y = jnp.nan_to_num(y_dev)
+            w = ((~jnp.isnan(y_dev)).astype(jnp.float32)
+                 * okrow.astype(jnp.float32))
+            if p.weights_column:
+                w = w * jnp.nan_to_num(fr.vec(p.weights_column).data)
+            offset = (jnp.nan_to_num(fr.vec(p.offset_column).data)
+                      if p.offset_column else jnp.zeros_like(y))
 
         self._bounds = _beta_bounds(p.beta_constraints, dinfo,
                                     pad_cols=pad_cols)
@@ -1250,57 +1261,59 @@ class GLM(ModelBuilder):
         output.model_category = category
         model = GLMModel(p, output, dinfo, beta, family)
         model.interaction_spec = self._interaction_spec
-        raw = model.score0(X)
-        ym = jnp.where(w > 0, y, jnp.nan)
-        m = make_metrics(category, ym, raw, w if p.weights_column else None,
-                         auc_type=p.auc_type, domain=output.response_domain)
-        m.residual_deviance = float(dev)
-        m.null_deviance = float(nulldev)
-        rank = int(np.sum(np.abs(np.asarray(beta)) > 1e-12))
-        m.aic = float(dev + 2 * rank)
-        m.residual_degrees_of_freedom = int(neff) - rank
-        m.null_degrees_of_freedom = int(neff) - 1
-        output.training_metrics = m
-        output.scoring_history = [{"iterations": iters, "lambda": lambda_used,
-                                   "deviance": float(dev)}]
-        output.variable_importances = self._varimp_from_beta(dinfo, beta)
-        if getattr(self, "_lincon", None) is not None:
-            # `GLMModel.output._linear_constraint_states` analog: per
-            # constraint, its value at the solution and whether it holds
-            from ..utils.twodimtable import TwoDimTable
+        # final scoring and metrics, through dispersion and p-values
+        with telemetry.span("train.glm.metrics"):
+            raw = model.score0(X)
+            ym = jnp.where(w > 0, y, jnp.nan)
+            m = make_metrics(category, ym, raw, w if p.weights_column else None,
+                             auc_type=p.auc_type, domain=output.response_domain)
+            m.residual_deviance = float(dev)
+            m.null_deviance = float(nulldev)
+            rank = int(np.sum(np.abs(np.asarray(beta)) > 1e-12))
+            m.aic = float(dev + 2 * rank)
+            m.residual_degrees_of_freedom = int(neff) - rank
+            m.null_degrees_of_freedom = int(neff) - 1
+            output.training_metrics = m
+            output.scoring_history = [{"iterations": iters, "lambda": lambda_used,
+                                       "deviance": float(dev)}]
+            output.variable_importances = self._varimp_from_beta(dinfo, beta)
+            if getattr(self, "_lincon", None) is not None:
+                # `GLMModel.output._linear_constraint_states` analog: per
+                # constraint, its value at the solution and whether it holds
+                from ..utils.twodimtable import TwoDimTable
 
-            Aeq, ceq, Ain, cin = self._lincon
-            if Aeq.shape[1] != len(beta):
-                # feature_parallelism stripped the pad columns from beta;
-                # drop the matching (all-zero) constraint columns
-                keep = list(range(dinfo.ncols_expanded)) + [Aeq.shape[1] - 1]
-                Aeq, Ain = Aeq[:, keep], Ain[:, keep]
-            rows_t = []
-            for i in range(len(ceq)):
-                val = float(Aeq[i] @ beta + ceq[i])
-                rows_t.append([f"equality_{i}", "Equal", val,
-                               bool(abs(val) < 1e-5)])
-            for i in range(len(cin)):
-                val = float(Ain[i] @ beta + cin[i])
-                rows_t.append([f"lessthanequal_{i}", "LessThanEqual", val,
-                               bool(val < 1e-5)])
-            output.linear_constraints_table = TwoDimTable(
-                table_header="Linear Constraints", description="",
-                col_header=["constraint", "type", "value",
-                            "condition_satisfied"],
-                col_types=["string", "string", "double", "string"],
-                cell_values=rows_t)
-        if family.name in ("gaussian", "gamma", "tweedie", "negativebinomial",
-                           "quasibinomial"):
-            mu = raw if raw.ndim == 1 else raw[:, -1]
-            model.dispersion_estimated = _estimate_dispersion(
-                p, family, ym, mu, np.asarray(w), float(dev), float(neff),
-                len(beta))
-            if getattr(family, "estimated_p", None) is not None:
-                model.tweedie_variance_power_estimated = family.estimated_p
-        if p.compute_p_values:
-            self._compute_p_values(model, X, y, w, offset, family, beta,
-                                   float(dev), float(neff))
+                Aeq, ceq, Ain, cin = self._lincon
+                if Aeq.shape[1] != len(beta):
+                    # feature_parallelism stripped the pad columns from beta;
+                    # drop the matching (all-zero) constraint columns
+                    keep = list(range(dinfo.ncols_expanded)) + [Aeq.shape[1] - 1]
+                    Aeq, Ain = Aeq[:, keep], Ain[:, keep]
+                rows_t = []
+                for i in range(len(ceq)):
+                    val = float(Aeq[i] @ beta + ceq[i])
+                    rows_t.append([f"equality_{i}", "Equal", val,
+                                   bool(abs(val) < 1e-5)])
+                for i in range(len(cin)):
+                    val = float(Ain[i] @ beta + cin[i])
+                    rows_t.append([f"lessthanequal_{i}", "LessThanEqual", val,
+                                   bool(val < 1e-5)])
+                output.linear_constraints_table = TwoDimTable(
+                    table_header="Linear Constraints", description="",
+                    col_header=["constraint", "type", "value",
+                                "condition_satisfied"],
+                    col_types=["string", "string", "double", "string"],
+                    cell_values=rows_t)
+            if family.name in ("gaussian", "gamma", "tweedie", "negativebinomial",
+                               "quasibinomial"):
+                mu = raw if raw.ndim == 1 else raw[:, -1]
+                model.dispersion_estimated = _estimate_dispersion(
+                    p, family, ym, mu, np.asarray(w), float(dev), float(neff),
+                    len(beta))
+                if getattr(family, "estimated_p", None) is not None:
+                    model.tweedie_variance_power_estimated = family.estimated_p
+            if p.compute_p_values:
+                self._compute_p_values(model, X, y, w, offset, family, beta,
+                                       float(dev), float(neff))
         if p.validation_frame is not None:
             output.validation_metrics = model.model_performance(p.validation_frame)
         return model
@@ -1367,8 +1380,9 @@ class GLM(ModelBuilder):
         P = X.shape[1]
         step = _make_irls_kernel(family)
         alpha = p.alpha if p.alpha is not None else 0.5
-        ones = jnp.ones((X.shape[0], 1), jnp.float32)
-        Xi = jnp.concatenate([X, ones], axis=1)  # intercept column last
+        with telemetry.span("train.glm.design"):
+            ones = jnp.ones((X.shape[0], 1), jnp.float32)
+            Xi = jnp.concatenate([X, ones], axis=1)  # intercept column last
         free = np.zeros(P + 1, dtype=bool)
         free[-1] = True
 
@@ -1382,8 +1396,10 @@ class GLM(ModelBuilder):
         neff = float(jnp.sum(w))
 
         if p.lambda_search:
-            G0, b_, _, _ = step(Xi, y, w, jnp.asarray(beta, jnp.float32), offset)
-            grad0 = np.abs(np.asarray(b_) - np.asarray(G0) @ beta)[:-1]
+            with telemetry.span("train.glm.gram"):
+                G0, b_, _, _ = step(Xi, y, w, jnp.asarray(beta, jnp.float32),
+                                    offset)
+                grad0 = np.abs(np.asarray(b_) - np.asarray(G0) @ beta)[:-1]
             lmax = float(grad0.max()) / max(alpha, 1e-3) / max(neff, 1.0)
             lambdas = np.geomspace(lmax, lmax * p.lambda_min_ratio, p.nlambdas)
         else:
@@ -1436,51 +1452,57 @@ class GLM(ModelBuilder):
             for it in range(max(p.max_iterations, 1)):
                 if it and job.time_exceeded():
                     break
-                G, b, dev, _ = step(Xi, y, w, jnp.asarray(beta, jnp.float32), offset)
+                # dispatch, the wait for the Gram and its copy out
+                # (the copy drains the step)
+                with telemetry.span("train.glm.gram"):
+                    G, b, dev, _ = step(
+                        Xi, y, w, jnp.asarray(beta, jnp.float32), offset)
+                    Gn = np.asarray(G, np.float64)
+                    bn = np.asarray(b, np.float64)
                 iters_total += 1
-                Gn, bn = np.asarray(G, np.float64), np.asarray(b, np.float64)
-                lincon = getattr(self, "_lincon", None)
-                if lincon is not None:
-                    # exact active-set QP on the normal equations; box
-                    # bounds / non_negative fold into the inequality rows
-                    # (a post-hoc clip would break the linear constraints)
-                    Aeq, ceq, Ain, cin = lincon
-                    rows_in = [(Ain, cin)]
-                    P1 = len(beta)
-                    if p.non_negative:
-                        E = -np.eye(P1)[: P1 - 1]
-                        rows_in.append((E, np.zeros(P1 - 1)))
-                    if getattr(self, "_bounds", None) is not None:
+                with telemetry.span("train.glm.solve"):
+                    lincon = getattr(self, "_lincon", None)
+                    if lincon is not None:
+                        # exact active-set QP on the normal equations; box
+                        # bounds / non_negative fold into the inequality rows
+                        # (a post-hoc clip would break the linear constraints)
+                        Aeq, ceq, Ain, cin = lincon
+                        rows_in = [(Ain, cin)]
+                        P1 = len(beta)
+                        if p.non_negative:
+                            E = -np.eye(P1)[: P1 - 1]
+                            rows_in.append((E, np.zeros(P1 - 1)))
+                        if getattr(self, "_bounds", None) is not None:
+                            lo, hi = self._bounds
+                            for j in range(P1):
+                                if np.isfinite(hi[j]):
+                                    e = np.zeros(P1)
+                                    e[j] = 1.0
+                                    rows_in.append((e[None, :],
+                                                    np.array([-hi[j]])))
+                                if np.isfinite(lo[j]):
+                                    e = np.zeros(P1)
+                                    e[j] = -1.0
+                                    rows_in.append((e[None, :],
+                                                    np.array([lo[j]])))
+                        Ain_all = np.vstack([r[0] for r in rows_in])
+                        cin_all = np.concatenate([r[1] for r in rows_in])
+                        beta_new = _constrained_qp(Gn + l2 * np.eye(len(beta)),
+                                                   bn, Aeq, ceq, Ain_all,
+                                                   cin_all)
+                    elif use_cod:
+                        beta_new = _cod_solve(Gn, bn, l1, l2, free, beta,
+                                              p.beta_epsilon, cod_lo, cod_hi)
+                    else:
+                        beta_new = _admm_solve(Gn, bn, l1, l2, free,
+                                               state=admm_state)
+                    if lincon is None and p.non_negative:
+                        nb = beta_new[:-1]
+                        beta_new[:-1] = np.clip(nb, 0, None)
+                    if lincon is None \
+                            and getattr(self, "_bounds", None) is not None:
                         lo, hi = self._bounds
-                        for j in range(P1):
-                            if np.isfinite(hi[j]):
-                                e = np.zeros(P1)
-                                e[j] = 1.0
-                                rows_in.append((e[None, :],
-                                                np.array([-hi[j]])))
-                            if np.isfinite(lo[j]):
-                                e = np.zeros(P1)
-                                e[j] = -1.0
-                                rows_in.append((e[None, :],
-                                                np.array([lo[j]])))
-                    Ain_all = np.vstack([r[0] for r in rows_in])
-                    cin_all = np.concatenate([r[1] for r in rows_in])
-                    beta_new = _constrained_qp(Gn + l2 * np.eye(len(beta)),
-                                               bn, Aeq, ceq, Ain_all,
-                                               cin_all)
-                elif use_cod:
-                    beta_new = _cod_solve(Gn, bn, l1, l2, free, beta,
-                                          p.beta_epsilon, cod_lo, cod_hi)
-                else:
-                    beta_new = _admm_solve(Gn, bn, l1, l2, free,
-                                           state=admm_state)
-                if lincon is None and p.non_negative:
-                    nb = beta_new[:-1]
-                    beta_new[:-1] = np.clip(nb, 0, None)
-                if lincon is None \
-                        and getattr(self, "_bounds", None) is not None:
-                    lo, hi = self._bounds
-                    beta_new = np.clip(beta_new, lo, hi)
+                        beta_new = np.clip(beta_new, lo, hi)
                 # convergence vs the INCOMING beta, first iteration
                 # included: a warm-started lambda whose solution has not
                 # moved converges in ONE step — the glmnet warm-path
@@ -1496,16 +1518,16 @@ class GLM(ModelBuilder):
                 # at the post-solve beta, instead of discovering the
                 # plateau one full Gram pass later: same epsilon, same
                 # criterion, measured one iteration earlier and ~P× cheaper
-                dev_new = float(dev_probe(Xi, y, w,
-                                          jnp.asarray(beta, jnp.float32),
-                                          offset))
+                with telemetry.span("train.glm.probe"):
+                    dev_new = float(dev_probe(
+                        Xi, y, w, jnp.asarray(beta, jnp.float32), offset))
                 dev_final = dev_new
                 if abs(float(dev) - dev_new) < p.objective_epsilon * abs(nulldev):
                     break
             if dev_final is None:
-                dev_final = float(dev_probe(Xi, y, w,
-                                            jnp.asarray(beta, jnp.float32),
-                                            offset))
+                with telemetry.span("train.glm.probe"):
+                    dev_final = float(dev_probe(
+                        Xi, y, w, jnp.asarray(beta, jnp.float32), offset))
             dev = dev_final
             best = (beta.copy(), float(lam), dev)
             if (p.lambda_search and getattr(p, "early_stopping", True)

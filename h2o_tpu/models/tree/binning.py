@@ -26,6 +26,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ...parallel.mesh import ROWS, default_mesh, n_row_shards, shard_map
+from ...utils import telemetry
 
 _UNSET = object()  # "resolve the budget live" sentinel; an explicit None
                    # means "no accelerator budget" and plans at the
@@ -70,6 +71,7 @@ def _sketch_plan(R: int, F: int, nb: int,
     return rb, Fb
 
 
+@telemetry.scope("gbm.sketch")
 def _sketch_core(X, qs, nb: int = 1024, rb: int = 1024, axis=None):
     """(nq, F) per-column quantiles via a TWO-PASS histogram sketch, all on
     device over ALL rows.
@@ -437,6 +439,7 @@ def compute_bin_edges_cols(cols, is_cat: np.ndarray, nbins: int,
 
 
 @jax.jit
+@telemetry.scope("gbm.bin")
 def bin_matrix(X: jax.Array, edges: jax.Array) -> jax.Array:
     """Map raw values to bin indices: bin = #edges < x; NA -> nbins (NA bucket).
 
@@ -456,6 +459,7 @@ def bin_matrix(X: jax.Array, edges: jax.Array) -> jax.Array:
 
 
 @functools.partial(jax.jit, static_argnames=("dtype",))
+@telemetry.scope("gbm.bin")
 def bin_column(x: jax.Array, erow: jax.Array, dtype=jnp.int32) -> jax.Array:
     """One column of `bin_matrix`: (plen,) raw values + that feature's
     NaN-padded edge row -> bin codes in ``dtype`` (the BinnedView packer).

@@ -42,6 +42,7 @@ from jax.sharding import PartitionSpec as P
 from ...backend import kernels
 from ...backend.kernels import hist as hist_kernels
 from ...parallel.mesh import ROWS, default_mesh, shard_map
+from ...utils import telemetry
 
 
 @dataclass(frozen=True)
@@ -230,6 +231,15 @@ def plan_hist_groups(nedges, B_hist: int, block_rows: int,
 # ---------------------------------------------------------------------------
 # Histogram build (the ScoreBuildHistogram2 analog) — runs inside shard_map.
 # ---------------------------------------------------------------------------
+def _psum_hist(hist):
+    """The level histogram's cross-shard reduction, under its own device
+    scope (a capture on several chips tells the collective from the
+    accumulation it follows)."""
+    with telemetry.scope("gbm.psum"):
+        return jax.lax.psum(hist, ROWS)
+
+
+@telemetry.scope("gbm.hist")
 def _build_level_hist(Xb, node, vals, offset, n_lv, nbins_tot, block,
                       groups=None, async_psum=False):
     """Accumulate hist (F, n_lv, nbins_tot, V) for nodes [offset, offset+n_lv).
@@ -267,7 +277,7 @@ def _build_level_hist(Xb, node, vals, offset, n_lv, nbins_tot, block,
     if groups is None:
         hist = hist_kernels.level_hist_blocks(
             Xb, lc, v, n_lv=n_lv, nbins_tot=nbins_tot, block=block)
-        return jax.lax.psum(hist, ROWS)
+        return _psum_hist(hist)
 
     groups = _norm_groups(groups)
     if async_psum:
@@ -276,12 +286,12 @@ def _build_level_hist(Xb, node, vals, offset, n_lv, nbins_tot, block,
         # on a real ICI the collective for bucket g overlaps bucket g+1's
         # local accumulation. Values are bit-equal to the joint scan (same
         # per-block contributions, same block order, same per-group psum).
-        hists = [jax.lax.psum(hist_kernels.level_hist_one_group(
+        hists = [_psum_hist(hist_kernels.level_hist_one_group(
             Xb[:, list(idxs)], lc, v, Bg=Bg, mode=mode, n_lv=n_lv,
-            nbins_tot=nbins_tot, block=block), ROWS)
+            nbins_tot=nbins_tot, block=block))
             for idxs, Bg, mode in groups]
     else:
-        hists = [jax.lax.psum(hg, ROWS)
+        hists = [_psum_hist(hg)
                  for hg in hist_kernels.level_hist_blocks(
                      Xb, lc, v, n_lv=n_lv, nbins_tot=nbins_tot, block=block,
                      groups=groups)]
@@ -291,6 +301,7 @@ def _build_level_hist(Xb, node, vals, offset, n_lv, nbins_tot, block,
                                 vals.shape[1])
 
 
+@telemetry.scope("gbm.hist")
 def _scatter_group_hists(hists, groups, F, n_lv, nbins_tot, V):
     """Per-group accumulators back into the global (F, n_lv, B, V) layout,
     each group's NA slot (its LAST bin) restored to the global NA bucket.
@@ -343,6 +354,7 @@ def _route_rows_gather(xb_blk, node_blk, route_args, cfg: "TreeConfig"):
                      node_blk)
 
 
+@telemetry.scope("gbm.route")
 def _route_all(Xb, node, route_args, cfg: "TreeConfig"):
     """Blocked standalone routing pass (gather formulation) — the pipelined
     path's final route after the last level's splits, and the route half
@@ -402,7 +414,7 @@ def _pipelined_level_hist(Xb, node, vals3, route_args, offset, n_lv,
         (h,), node = hist_kernels.streamed_route_hist(
             Xb, node, vals3, route_fn, offset=offset, n_lv=n_lv,
             nbins_tot=nbins_tot, block=cfg.block_rows)
-        return jax.lax.psum(h, ROWS), node
+        return _psum_hist(h), node
 
     if cfg.async_psum:
         # stream = route + lead bucket; its psum issues while the later
@@ -410,7 +422,7 @@ def _pipelined_level_hist(Xb, node, vals3, route_args, offset, n_lv,
         (h0,), node = hist_kernels.streamed_route_hist(
             Xb, node, vals3, route_fn, offset=offset, n_lv=n_lv,
             nbins_tot=nbins_tot, block=cfg.block_rows, groups=groups[:1])
-        hists = [jax.lax.psum(h0, ROWS)]
+        hists = [_psum_hist(h0)]
         local = node - offset
         active = (local >= 0) & (local < n_lv)
         lc = jnp.clip(local, 0, n_lv - 1)
@@ -419,16 +431,17 @@ def _pipelined_level_hist(Xb, node, vals3, route_args, offset, n_lv,
             hg = hist_kernels.level_hist_one_group(
                 Xb[:, list(idxs)], lc, v, Bg=Bg, mode=mode, n_lv=n_lv,
                 nbins_tot=nbins_tot, block=cfg.block_rows)
-            hists.append(jax.lax.psum(hg, ROWS))
+            hists.append(_psum_hist(hg))
     else:
         hs, node = hist_kernels.streamed_route_hist(
             Xb, node, vals3, route_fn, offset=offset, n_lv=n_lv,
             nbins_tot=nbins_tot, block=cfg.block_rows, groups=groups)
-        hists = [jax.lax.psum(h, ROWS) for h in hs]
+        hists = [_psum_hist(h) for h in hs]
     return _scatter_group_hists(hists, groups, F, n_lv, nbins_tot,
                                 vals3.shape[1]), node
 
 
+@telemetry.scope("gbm.leaf")
 def _leaf_quantile_vals(resid, w, node, n_nodes, q, block, qbins=256):
     """Per-node q-quantile of the residuals, distributed: (node, bin) weight
     histograms over a linear residual grid (one-hot einsums riding the MXU
@@ -485,6 +498,7 @@ def _leaf_quantile_vals(resid, w, node, n_nodes, q, block, qbins=256):
     return jnp.where(tot > 0, val, 0.0)
 
 
+@telemetry.scope("gbm.leaf")
 def _node_totals(node, vals, n_nodes, block):
     """Per-node channel totals (n_nodes, V) via the same blocked one-hot scan."""
     Rl = node.shape[0]
@@ -533,6 +547,7 @@ def _level_col_mask(lkey, F, n_lv, cfg: "TreeConfig", tree_cols,
 # ---------------------------------------------------------------------------
 # Split finding (DTree.DecidedNode analog), vectorized on device.
 # ---------------------------------------------------------------------------
+@telemetry.scope("gbm.split")
 def _find_splits(hist, colmask, edge_ok, cfg: TreeConfig, mono=None,
                  iscat=None, nedges=None):
     """hist: (F, n_lv, B, 3). Returns per-node best (gain, feat, bin, nan_left,
@@ -824,19 +839,22 @@ def _grow_tree(Xb, g, h, w, edges, edge_ok, colkey, cfg: TreeConfig,
                              2 * node_blk + 1 + go_right.astype(jnp.int32),
                              node_blk)
 
-        if use_sets or Xb.dtype.itemsize < 4:
-            # blocked: the (rows, nbins) bin one-hot lives per block, never
-            # materializing an (Rl, nbins) intermediate at wide nbins_cats —
-            # and for int8/int16 binned views the f32 cast feeding the
-            # routing matmul stays block-sized instead of re-materializing a
-            # raw-matrix-sized (Rl, F) f32 intermediate
-            rb_ = _block_rows(Rl, cfg.block_rows)
-            _, node_b = jax.lax.scan(
-                lambda c, blk: (c, _route(*blk)), None,
-                (Xb.reshape(Rl // rb_, rb_, F), node.reshape(Rl // rb_, rb_)))
-            node = node_b.reshape(Rl)
-        else:
-            node = _route(Xb, node)
+        with telemetry.scope("gbm.route"):
+            if use_sets or Xb.dtype.itemsize < 4:
+                # blocked: the (rows, nbins) bin one-hot lives per block,
+                # never materializing an (Rl, nbins) intermediate at wide
+                # nbins_cats — and for int8/int16 binned views the f32 cast
+                # feeding the routing matmul stays block-sized instead of
+                # re-materializing a raw-matrix-sized (Rl, F) f32
+                # intermediate
+                rb_ = _block_rows(Rl, cfg.block_rows)
+                _, node_b = jax.lax.scan(
+                    lambda c, blk: (c, _route(*blk)), None,
+                    (Xb.reshape(Rl // rb_, rb_, F),
+                     node.reshape(Rl // rb_, rb_)))
+                node = node_b.reshape(Rl)
+            else:
+                node = _route(Xb, node)
 
     if cfg.pipeline and route_args is not None:
         # the last level's routing was deferred — apply it so leaf/stop
@@ -867,10 +885,11 @@ def _grow_tree(Xb, g, h, w, edges, edge_ok, colkey, cfg: TreeConfig,
                                     w if w_full is None else w_full,
                                     jnp.zeros_like(node), 1,
                                     cfg.huber_leaf_alpha, cfg.block_rows)[0]
-        med_row = _onehot_pick(jax.nn.one_hot(node, N, dtype=jnp.float32),
-                               med)
-        d = resid - med_row
-        clipped = jnp.sign(d) * jnp.minimum(jnp.abs(d), delta)
+        with telemetry.scope("gbm.leaf"):
+            med_row = _onehot_pick(
+                jax.nn.one_hot(node, N, dtype=jnp.float32), med)
+            d = resid - med_row
+            clipped = jnp.sign(d) * jnp.minimum(jnp.abs(d), delta)
         tot2 = _node_totals(node, (w * clipped)[:, None], N, cfg.block_rows)
         # per-node weight sums already live in tot[:, 0]
         gamma = jnp.where(tot[:, 0] > 0,
@@ -985,7 +1004,8 @@ def make_train_fn(cfg: TreeConfig, grad_fn: Callable, mesh=None,
                      ).astype(jnp.float32)
             else:
                 s = jnp.ones(w.shape[-1:], jnp.float32)
-            g, h = grad_fn(y, f, w)
+            with telemetry.scope("gbm.grad"):
+                g, h = grad_fn(y, f, w)
 
             def scale_leaves(vlk):
                 # annealed rate first, THEN the cap: the reference clips
@@ -997,6 +1017,7 @@ def make_train_fn(cfg: TreeConfig, grad_fn: Callable, mesh=None,
                 return vlk
             # leaf-value broadcast rides the MXU too (vl[node] is a per-row
             # dynamic gather otherwise — see the routing comment in _grow_tree)
+            @telemetry.scope("gbm.leaf")
             def leaf_delta(vlk, nodek):
                 if cfg.pipeline:
                     # the pipelined program accepts the gather (exact: a
@@ -1057,7 +1078,8 @@ def make_train_fn(cfg: TreeConfig, grad_fn: Callable, mesh=None,
                 ft, th, nl, vl, ga, cd, node = grow(g, h, ckeys)
                 vl = scale_leaves(vl)
                 delta = jax.vmap(leaf_delta)(vl, node)
-            f = f + delta
+            with telemetry.scope("gbm.leaf"):
+                f = f + delta
             # OOB accumulation (`DRF.java` OOB scoring): rows outside this
             # tree's bag collect its raw output; two (R,)-adds per tree
             oob = 1.0 - s
@@ -1072,7 +1094,9 @@ def make_train_fn(cfg: TreeConfig, grad_fn: Callable, mesh=None,
             # raw predictions come out while the final margin is still
             # resident — the chunk loop never redispatches a standalone
             # margin→score0 program per scoring interval
-            return f, osum, ocnt, trees, score_fn(f, ntd[0])
+            with telemetry.scope("gbm.score"):
+                mraw = score_fn(f, ntd[0])
+            return f, osum, ocnt, trees, mraw
         return f, osum, ocnt, trees
 
     fspec = P(ROWS) if K == 1 else P(None, ROWS)
@@ -1101,178 +1125,6 @@ def make_train_fn(cfg: TreeConfig, grad_fn: Callable, mesh=None,
     if full_key is not None:
         _TRAIN_FN_CACHE[full_key] = jitted
     return jitted
-
-
-# ---------------------------------------------------------------------------
-# Sampled in-boundary phase profile (the PR 6 telemetry residual).
-# ---------------------------------------------------------------------------
-def sample_tree_phases(Xb, vals3, edge_ok, cfg: TreeConfig,
-                       iscat=None, nedges=None):
-    """Measure one representative hist → split → route → leaf sequence and
-    land it inside the GBM tree boundary's telemetry.
-
-    The production loop is ONE fused XLA program (jit(shard_map(scan over
-    trees))) — per-phase walls inside it are not host-observable, so this
-    replays the first level's work as four standalone drained dispatches
-    and records them as a ``train.gbm.phases`` span (phases ``hist`` /
-    ``split`` / ``route`` / ``leaf``) nested under the chunk span, with
-    the histogram wall observed into the ``train.hist.kernel`` histogram
-    and the kernels backend (pallas/xla) on the span detail. One sample
-    per training job (gbm.py gates on the first chunk); collectives are
-    excluded — the accumulations run shard-local exactly as the kernels
-    layer executes them, which is the wall the ROADMAP item steers by.
-    Also aggregated as a ``gbm.tree.level`` task profile so `/3/Profiler`
-    serves the phase split next to the MRTask anatomy."""
-    from ...utils import telemetry
-    from ...utils.profile import task_profile
-
-    Rl, F = Xb.shape
-    B = cfg.nbins + 1
-    groups = _norm_groups(cfg.hist_groups) if cfg.hist_groups else None
-    backend = kernels.hist_backend()
-    node = jnp.zeros((Rl,), jnp.int32)
-    na_global = B - 1
-
-    with telemetry.span("train.gbm.phases", backend=backend,
-                        sampled=True) as sp, \
-            task_profile("gbm.tree.level") as prof:
-        with sp.phase("hist"), prof.phase("hist"):
-            if groups is None:
-                hist = hist_kernels.level_hist_blocks(
-                    Xb, node, vals3, n_lv=1, nbins_tot=B,
-                    block=cfg.block_rows)
-            else:
-                hgs = hist_kernels.level_hist_blocks(
-                    Xb, node, vals3, n_lv=1, nbins_tot=B,
-                    block=cfg.block_rows, groups=groups)
-                # shard-local scatter-back (the psum is a mesh concern the
-                # sample deliberately excludes)
-                hist = jnp.zeros((F, 1, B, vals3.shape[1]), jnp.float32)
-                for (idxs, Bg, _mode), hg in zip(groups, hgs):
-                    ia = jnp.asarray(idxs)
-                    hist = hist.at[ia, :, :Bg - 1, :].set(hg[:, :, :Bg - 1, :])
-                    hist = hist.at[ia, :, na_global, :].set(hg[:, :, Bg - 1, :])
-            jax.block_until_ready(hist)
-        telemetry.observe("train.hist.kernel", sp.phases["hist"])
-
-        use_sets = cfg.use_sets and iscat is not None
-        with sp.phase("split"), prof.phase("split"):
-            colmask = jnp.ones((F, 1), dtype=jnp.bool_)
-            out = _find_splits(hist[..., :3], colmask, edge_ok, cfg,
-                               iscat=iscat if use_sets else None,
-                               nedges=nedges if use_sets else None)
-            jax.block_until_ready([o for o in out if o is not None])
-        _gain, bf, bb, bnal, _Wt, _vL, _vR, _catd, _isset = out
-
-        with sp.phase("route"), prof.phase("route"):
-            # one block of the level-0 routing matmuls (the per-block work
-            # the scan repeats; cfg.nbins >= 255 forces f32 like _grow_tree)
-            rb = _block_rows(Rl, cfg.block_rows)
-            prec = (jax.lax.Precision.HIGHEST if cfg.nbins >= 255
-                    else jax.lax.Precision.DEFAULT)
-            S = jax.nn.one_hot(bf, F, dtype=jnp.float32)
-            xbs = jnp.dot(Xb[:rb].astype(jnp.float32), S.T, precision=prec,
-                          preferred_element_type=jnp.float32)
-            rb_val = xbs[:, 0]
-            go_right = jnp.where(rb_val == cfg.nbins, ~bnal[0],
-                                 rb_val > bb[0].astype(jnp.float32))
-            routed = 1 + go_right.astype(jnp.int32)
-            jax.block_until_ready(routed)
-
-        with sp.phase("leaf"), prof.phase("leaf"):
-            # shard-local per-node totals (the _node_totals body sans psum)
-            n_oh = jax.nn.one_hot(node[:rb], cfg.n_nodes, dtype=jnp.float32)
-            tot = jnp.einsum("rn,rv->nv", n_oh, vals3[:rb])
-            jax.block_until_ready(tot)
-    return sp.phases
-
-
-def sample_pipeline_phases(Xb, vals3, cfg: TreeConfig, mesh=None):
-    """Measure one representative pipelined-level stage sequence — h2d /
-    local-accum / psum-wait / split — and how much of the H2D + collective
-    wall the pipeline actually hides.
-
-    Like `sample_tree_phases`, the production loop is one fused program, so
-    this replays level 0's stages as standalone dispatches inside a
-    ``train.gbm.pipeline`` span: ``h2d`` stages one column block onto the
-    mesh (the double-buffer's stream-in), ``local-accum`` drains the
-    shard-local histogram, ``psum-wait`` drains a psum of the same payload
-    across the ``rows`` axis, ``split`` drains `_find_splits`. A second,
-    UNdrained replay then dispatches h2d→accum→psum back to back and the
-    difference — sequential wall minus pipelined wall — over the h2d+psum
-    wall is recorded as the ``gbm.pipeline.overlap_ratio`` gauge (clipped
-    to [0, 1]; ~0 on a single-shard CPU mesh where both hidden stages are
-    already negligible, which is itself the honest record). One sample per
-    process (gbm.py gates); the bench sidecar picks the gauge out of the
-    telemetry delta."""
-    import time as _time
-
-    from ...parallel.mesh import put_row_sharded
-    from ...utils import telemetry
-
-    mesh = mesh or default_mesh()
-    Rl, F = Xb.shape
-    B = cfg.nbins + 1
-    groups = _norm_groups(cfg.hist_groups) if cfg.hist_groups else None
-    idxs = list(groups[0][0]) if groups else list(range(F))
-    Bg = groups[0][1] if groups else B
-    mode = groups[0][2] if groups else "onehot"
-    host_blk = np.asarray(Xb[:, idxs])      # the host-side coded block
-    node = jnp.zeros((Rl,), jnp.int32)
-
-    def _accum(xg, lc, vv):
-        return hist_kernels.level_hist_one_group(
-            xg, lc, vv, Bg=Bg, mode=mode, n_lv=1, nbins_tot=Bg,
-            block=cfg.block_rows)
-
-    from ...utils import programs
-
-    # the kernels-layer face of the program cost registry: the sampled
-    # level-hist accumulation is the one standalone dispatch of the hist
-    # kernel (the production loop fuses it into the train program), so its
-    # cost/memory analyses stand in for the kernel backend in /3/Programs
-    accum = programs.tracked(
-        "kernel.hist.level_group",
-        jax.jit(shard_map(
-            _accum, mesh=mesh,
-            in_specs=(P(ROWS, None), P(ROWS), P(ROWS, None)),
-            out_specs=P(), check_vma=False)),
-        "kernel", backend=kernels.hist_backend(), mode=mode, nbins=Bg)
-    psum_fn = jax.jit(shard_map(
-        lambda h: jax.lax.psum(h, ROWS), mesh=mesh, in_specs=P(),
-        out_specs=P(), check_vma=False))
-
-    with telemetry.span("train.gbm.pipeline",
-                        groups=0 if groups is None else len(groups)) as sp:
-        with sp.phase("h2d"):
-            staged = put_row_sharded(host_blk, mesh)
-            jax.block_until_ready(staged)
-        with sp.phase("local-accum"):
-            hloc = accum(staged, node, vals3)
-            jax.block_until_ready(hloc)
-        with sp.phase("psum-wait"):
-            hred = psum_fn(hloc)
-            jax.block_until_ready(hred)
-        with sp.phase("split"):
-            colmask = jnp.ones((F, 1), dtype=jnp.bool_)
-            hist = jnp.zeros((F, 1, B, 3), jnp.float32)
-            out = _find_splits(hist, colmask,
-                               jnp.ones((F, cfg.nbins - 1), jnp.bool_), cfg)
-            jax.block_until_ready([o for o in out if o is not None])
-        # pipelined replay: dispatch-ahead, one drain at the end — what the
-        # sequential walls above paid in h2d+psum, minus what this still
-        # pays, is the hidden fraction
-        t0 = _time.perf_counter()
-        staged2 = put_row_sharded(host_blk, mesh)
-        hred2 = psum_fn(accum(staged2, node, vals3))
-        jax.block_until_ready(hred2)
-        piped = _time.perf_counter() - t0
-        seq = sp.phases["h2d"] + sp.phases["local-accum"] + sp.phases["psum-wait"]
-        hidden_wall = max(sp.phases["h2d"] + sp.phases["psum-wait"], 1e-9)
-        ratio = min(max((seq - piped) / hidden_wall, 0.0), 1.0)
-        sp.attrs["overlap_ratio"] = round(ratio, 4)
-    telemetry.set_gauge("gbm.pipeline.overlap_ratio", ratio)
-    return ratio
 
 
 # ---------------------------------------------------------------------------

@@ -75,16 +75,21 @@ def _listener(name: str, secs: float, **kw) -> None:
         from . import telemetry, timeline
 
         telemetry.inc("xla.compile.count")
-        timeline.record("compile", "backend_compile",
-                        secs=round(float(secs), 4))
+        # jax passes the compiled function's name with the event. A
+        # persistent-cache replay fires its cache_hits event INSIDE
+        # compile_or_get_cached, i.e. BEFORE this duration event on the
+        # same thread — a pending hit pairs them: the event says it was a
+        # replay, and replays (zero XLA wall) never raise under a
+        # steady-state scope. Zeroed, not decremented: a hit whose
+        # duration event never came (an exception between the two) is
+        # spent on the next event and cannot pile up
+        cached = getattr(_STEADY, "hits", 0) > 0
+        _STEADY.hits = 0
+        timeline.record("compile", str(kw.get("fun_name") or "backend_compile"),
+                        secs=round(float(secs), 4), cached=cached)
         stack = _steady_stack()
         if stack:
-            # a persistent-cache replay fires its cache_hits event INSIDE
-            # compile_or_get_cached, i.e. BEFORE this duration event on
-            # the same thread — consuming one pending hit pairs them, so
-            # replays (zero XLA wall) never raise
-            if getattr(_STEADY, "hits", 0) > 0:
-                _STEADY.hits -= 1
+            if cached:
                 return
             from . import sanitizer
 
@@ -99,8 +104,7 @@ def _event_listener(name: str, **kw) -> None:
     if name == _CACHE_HIT_EVENT:
         with _lock:
             _cache_hits += 1
-        if _steady_stack():
-            _STEADY.hits = getattr(_STEADY, "hits", 0) + 1
+        _STEADY.hits = getattr(_STEADY, "hits", 0) + 1
 
 
 def install() -> None:

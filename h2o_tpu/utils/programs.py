@@ -16,9 +16,7 @@ points registers here under a STABLE program id:
 - ``models/glm.py _make_irls_kernel`` — the GLM IRLS step (jit and
   shard_map shapes), via :func:`tracked`;
 - ``serving/scorer.py CompiledScorer.warmup`` — one entry per bucket
-  executable;
-- ``models/tree/engine.py`` phase samples — the standalone kernels-layer
-  replays.
+  executable.
 
 Each record pairs the STATIC cost (``cost_analysis()``: flops, bytes
 accessed; ``memory_analysis()``: argument/output/temp/generated-code
@@ -54,7 +52,7 @@ import time
 import weakref
 from collections import deque
 
-from . import telemetry
+from . import compilemeter, telemetry
 
 #: measured dispatch walls kept per program (host seconds)
 _WALL_WINDOW = 128
@@ -78,7 +76,7 @@ class ProgramRecord:
     def __init__(self, pid, kind, name, labels, flops, bytes_accessed,
                  memory):
         self.pid = pid
-        self.kind = kind            # "train" | "dispatch" | "serving" | "kernel"
+        self.kind = kind            # "train" | "dispatch" | "serving"
         self.name = name
         self.labels = labels
         self.flops = flops
@@ -230,8 +228,16 @@ class Tracked:
         ent = self._compiled.get(key)
         if ent is None:
             # a compile error surfaces HERE, once — the jitted twin would
-            # hand the same program to the same compiler and fail again
-            ent = self._jitted.lower(*args).compile()
+            # hand the same program to the same compiler and fail again.
+            # The load has a span of its own (train.program.load, ...): a
+            # program re-jitted per job pays it per job, replayed from the
+            # persistent cache or not, and `compiles`/`uncached` say which
+            with telemetry.span(f"{self.kind}.program.load",
+                                program=self.name) as sp:
+                with compilemeter.scoped() as sc:
+                    ent = self._jitted.lower(*args).compile()
+                sp.attrs["compiles"] = sc.compiles
+                sp.attrs["uncached"] = sc.uncached
             sig = tuple((s[0], s[1]) for s in key
                         if isinstance(s, tuple) and len(s) == 3)
             self._pids[key] = register_compiled(

@@ -31,6 +31,11 @@ ALSO appended to a per-process chrome-tracing file
 (``trace_<pid>.trace.json``) loadable in Perfetto / chrome://tracing, so
 a whole training run can be opened in a trace viewer.
 
+Device scopes (``scope("gbm.route")``): the declared names of ``SCOPES``
+put on the level program's and the IRLS step's XLA ops with
+``jax.named_scope`` — the device-trace half of the span names above.
+`tools/trace_scopes.py` sums a capture's device seconds by them.
+
 Causality does not stop at process or thread boundaries:
 
 - **wire propagation** — trace ids are 32-hex (W3C trace-context shaped);
@@ -173,17 +178,6 @@ _counter("train.checkpoint.count",
 _histogram("train.checkpoint.seconds",
            "wall per auto-recovery checkpoint write (the preemption "
            "insurance premium, measured)")
-_histogram("train.hist.kernel",
-           "drained wall of one sampled level-histogram accumulation "
-           "(backend/kernels/hist.py), observed once per GBM/DRF training "
-           "job from the in-boundary phase sample; the backend "
-           "(pallas/xla) rides the train.gbm.phases span/timeline detail")
-_gauge("gbm.pipeline.overlap_ratio",
-       "fraction of the h2d + collective wall the pipelined GBM level "
-       "program hides under local accumulation, from the once-per-process "
-       "train.gbm.pipeline stage sample (engine.sample_pipeline_phases); "
-       "~0 on a single-shard CPU mesh where both hidden stages are "
-       "already negligible")
 _histogram("train.compile.seconds",
            "drained wall of the AOT lower+compile of the tree train step "
            "at build setup (near-zero when the persistent compile cache "
@@ -359,14 +353,51 @@ def _lookup(name: str) -> Metric:
             f"unregistered-metric enforces the same statically)") from None
 
 
+#: device scopes — the level program's and the Gram step's phases as they
+#: appear in a device trace (``jax.named_scope`` lands in the ``op_name``
+#: metadata of the XLA ops traced under it, so any capture of the REAL
+#: program splits device time by these names; nothing runs when no capture
+#: does). Scopes nest: an op's scope is the INNERMOST declared name on its
+#: path. Ops the compiler makes itself (layout copies, copy loops) carry no
+#: metadata and read "unscoped". Declared once here, like METRICS:
+#: :func:`scope` raises on any other name.
+SCOPES: tuple[str, ...] = (
+    "gbm.sketch",    # binning._sketch_core: the quantile sketch's passes
+    "gbm.bin",       # binning.bin_matrix / bin_column, the BinnedView coding
+    "gbm.grad",      # grad_fn per tree (make_train_fn.spmd)
+    "gbm.route",     # row routing off a level's splits (all formulations)
+    "gbm.hist",      # level-histogram accumulation (kernels/hist.py)
+    "gbm.psum",      # the level histogram's cross-shard reduction
+    "gbm.split",     # engine._find_splits
+    "gbm.leaf",      # per-node totals, quantile leaves, leaf values
+    "gbm.score",     # fused boundary scoring inside the chunk step
+    "glm.eta",       # link, weights, working response of the IRLS step
+    "glm.gram",      # kernels/gram.gram_accumulate
+    "glm.deviance",  # the family deviance (IRLS step and probe)
+)
+
+
+def scope(name: str):
+    """``jax.named_scope`` for a DECLARED device scope (KeyError otherwise —
+    the registry's own discipline). Metadata only: the traced values, the
+    XLA module's name and the compile-cache key do not change."""
+    if name not in SCOPES:
+        raise KeyError(
+            f"undeclared device scope {name!r} — declare it in SCOPES "
+            f"(h2o_tpu/utils/telemetry.py)")
+    import jax
+
+    return jax.named_scope(name)
+
+
 def _enabled() -> bool:
     return knobs.get_bool("H2O_TPU_METRICS_ENABLED")
 
 
 def enabled() -> bool:
     """Public master-switch read — gates optional instrumentation work
-    whose COST exists even when the emits are skipped (e.g. the GBM
-    sampled phase profile dispatches real device work)."""
+    whose COST exists even when the emits are skipped (slow-trace capture,
+    SLO windows)."""
     return _enabled()
 
 

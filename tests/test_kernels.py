@@ -354,36 +354,6 @@ def test_rulefit_covers_support_matches_membership():
 
 
 # ---------------------------------------------------------------------------
-# telemetry: the in-boundary phase sample
-# ---------------------------------------------------------------------------
-def test_tree_phase_sample_records_backend_tagged_spans():
-    from h2o_tpu.models import gbm as gbm_mod
-    from h2o_tpu.utils import telemetry, timeline
-
-    gbm_mod._PHASE_SAMPLED.clear()
-    before = telemetry.snapshot()["train.hist.kernel"]["count"]
-    fr = _higgs_like(4000, seed=13)
-    self_train = gbm_mod.GBM(gbm_mod.GBMParameters(
-        training_frame=fr, response_column="y", ntrees=4, max_depth=3,
-        seed=1)).train_model()
-    assert self_train is not None
-    after = telemetry.snapshot()["train.hist.kernel"]
-    assert after["count"] == before + 1
-    spans = [e for e in timeline.snapshot()
-             if e.get("what") == "train.gbm.phases"]
-    assert spans, "no train.gbm.phases span in the timeline"
-    detail = spans[-1]
-    assert detail.get("backend") in ("pallas", "xla")
-    for ph in ("hist_s", "split_s", "route_s", "leaf_s"):
-        assert ph in detail, (ph, detail)
-    # second train in the same process: sampled once per backend only
-    gbm_mod.GBM(gbm_mod.GBMParameters(
-        training_frame=fr, response_column="y", ntrees=4, max_depth=3,
-        seed=1)).train_model()
-    assert telemetry.snapshot()["train.hist.kernel"]["count"] == before + 1
-
-
-# ---------------------------------------------------------------------------
 # cold start: compile-cache wiring + AOT train step + compilemeter hits
 # ---------------------------------------------------------------------------
 class TestColdStart:

@@ -13,9 +13,7 @@ comparison on a one-device mesh.
 - GOSS is deterministic under the train seed, changes under a different
   seed, holds holdout AUC inside the band, and validates its knob;
 - an in-flight pipelined dispatch killed by the `mrtask.dispatch`
-  failpoint fails TYPED (no hang) and re-runs clean to the oracle forest;
-- the pipelined-stage sampler returns a sane overlap ratio and lands the
-  `gbm.pipeline.overlap_ratio` gauge.
+  failpoint fails TYPED (no hang) and re-runs clean to the oracle forest.
 """
 
 import os
@@ -28,12 +26,9 @@ import jax.numpy as jnp
 
 from h2o_tpu.frame.frame import Frame
 from h2o_tpu.frame.vec import T_CAT, Vec
-from h2o_tpu.models import gbm as gbm_mod
 from h2o_tpu.models.gbm import GBM, GBMParameters
-from h2o_tpu.models.tree import engine
 from h2o_tpu.parallel import mesh as meshmod
 from h2o_tpu.utils import failpoints as fp
-from h2o_tpu.utils import telemetry
 
 pytestmark = pytest.mark.pipeline
 
@@ -304,35 +299,6 @@ def test_knob_armed_recovery_disables_dispatch_ahead(monkeypatch, tmp_path):
     oracle = _train(fr, monkeypatch, pipeline="0", async_psum="0",
                     interval=2, ntrees=6)
     assert _forest_equal(oracle, m)
-
-
-# ---------------------------------------------------------------------------
-# Telemetry: pipelined-stage sample + overlap gauge
-# ---------------------------------------------------------------------------
-def test_pipeline_stage_sample_and_gauge(monkeypatch):
-    fr = _frame()
-    m = _train(fr, monkeypatch, pipeline="1", ntrees=2)
-    Xb = jnp.asarray(np.stack(
-        [np.clip(_C1, 0, 15), np.clip(_C2, 0, 4),
-         np.digitize(_X1, np.linspace(-2, 2, 15)),
-         np.digitize(_X2, np.linspace(-2, 2, 15))], axis=1)
-        .astype(np.int32))
-    vals3 = jnp.asarray(_RNG.normal(size=(_N, 3)).astype(np.float32))
-    ratio = engine.sample_pipeline_phases(Xb, vals3, m.cfg)
-    assert 0.0 <= ratio <= 1.0
-    snap = telemetry.snapshot()
-    assert snap["gbm.pipeline.overlap_ratio"]["value"] == pytest.approx(
-        ratio)
-
-
-def test_pipe_sample_emitted_once_per_process(monkeypatch):
-    gbm_mod._PIPE_SAMPLED.clear()
-    fr = _frame(rows=slice(0, 1024))
-    _train(fr, monkeypatch, pipeline="1", ntrees=2)
-    assert gbm_mod._PIPE_SAMPLED           # sampled on this build
-    before = telemetry.snapshot()
-    _train(fr, monkeypatch, pipeline="1", ntrees=2)
-    assert gbm_mod._PIPE_SAMPLED           # still marked — no re-sample
 
 
 # ---------------------------------------------------------------------------

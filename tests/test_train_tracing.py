@@ -1,0 +1,287 @@
+"""What a training job names about itself (ISSUE 26).
+
+- Device scopes: every name declared in ``telemetry.SCOPES`` reaches the
+  compiled HLO of the program it belongs to (the GBM train step with the
+  pipelined level program on and off, the sketch, the coding, the IRLS
+  step, the deviance probe) as a path element of an ``op_name``; an
+  undeclared name raises.
+- Host spans: a GBM and a GLM train record the spans inside ``train.gbm``
+  / ``train.glm`` with the right parent, one trace id, and children that
+  fit inside their parent; a lambda path records the same parts; the
+  IRLS step's load is a ``train.program.load`` span.
+- ``compile`` timeline events carry the compiled function's name and
+  whether the program came from the persistent cache.
+- A train through the client is one trace from ``client.train`` down.
+
+CPU mesh, small frames: names, parents and counts, never a time.
+"""
+
+import re
+import socket
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from h2o_tpu.frame.frame import Frame
+from h2o_tpu.frame.vec import T_CAT, Vec
+from h2o_tpu.utils import compilemeter, telemetry, timeline
+
+_N = 4096
+_F = 4
+
+
+def _frame(n=_N):
+    rng = np.random.default_rng(26)
+    X = rng.normal(size=(n, _F)).astype(np.float32)
+    y = ((X[:, 0] - X[:, 1] + rng.normal(scale=0.5, size=n)) > 0
+         ).astype(np.float32)
+    fr = Frame([f"x{i}" for i in range(_F)],
+               [Vec.from_numpy(X[:, i]) for i in range(_F)])
+    fr.add("y", Vec.from_numpy(y, type=T_CAT, domain=["n", "p"]))
+    return fr
+
+
+def _train_gbm(fr, **kw):
+    from h2o_tpu.models.gbm import GBM, GBMParameters
+
+    return GBM(GBMParameters(
+        training_frame=fr, response_column="y", ntrees=4, max_depth=3,
+        seed=1, score_tree_interval=2, **kw)).train_model()
+
+
+def _train_glm(fr, **kw):
+    from h2o_tpu.models.glm import GLM, GLMParameters
+
+    kw.setdefault("lambda_", 0.0)
+    return GLM(GLMParameters(training_frame=fr, response_column="y",
+                             family="binomial", **kw)).train_model()
+
+
+def _events_of(run):
+    seq0 = timeline.total_recorded()
+    run()
+    return timeline.snapshot(since=seq0)
+
+
+def _spans(events, name):
+    return [e for e in events if e["kind"] == "span" and e["what"] == name]
+
+
+# ---------------------------------------------------------------------------
+# (a) device scopes in the compiled programs
+# ---------------------------------------------------------------------------
+def _gbm_step_text(pipeline: str) -> str:
+    """HLO text of the train step a small GBM job compiled."""
+    from h2o_tpu.models import gbm as gbm_mod
+
+    mp = pytest.MonkeyPatch()
+    try:
+        mp.setenv("H2O_TPU_PIPELINE", pipeline)
+        gbm_mod._AOT_STEP_CACHE.clear()
+        _train_gbm(_frame())
+        (compiled,) = gbm_mod._AOT_STEP_CACHE.values()
+        return compiled.as_text()
+    finally:
+        gbm_mod._AOT_STEP_CACHE.clear()
+        mp.undo()
+
+
+def _glm_texts():
+    from h2o_tpu.models import glm as glm_mod
+
+    fam = glm_mod.BinomialF()
+    rng = np.random.default_rng(3)
+    X = jnp.asarray(rng.normal(size=(1024, 5)).astype(np.float32))
+    y = jnp.asarray((rng.random(1024) > 0.5).astype(np.float32))
+    w, off = jnp.ones(1024, jnp.float32), jnp.zeros(1024, jnp.float32)
+    beta = jnp.zeros(5, jnp.float32)
+    step = glm_mod._make_irls_kernel(fam)
+    # under an enclosing trace the tracked wrapper steps aside: the step
+    # lowers as the job dispatches it (shard_map on this 8-device mesh)
+    irls = jax.jit(lambda *a: step(*a)).lower(X, y, w, beta, off)
+    probe = glm_mod._make_dev_kernel(fam).lower(X, y, w, beta, off)
+    return irls.compile().as_text(), probe.compile().as_text()
+
+
+def _binning_texts():
+    from h2o_tpu.models.tree import binning
+
+    X = jnp.zeros((2048, 3), jnp.float32)
+    sketch = binning._hist_quantile_rows.lower(
+        X, (0.25, 0.5, 0.75), nb=64, rb=256)
+    col = binning.bin_column.lower(X[:, 0], jnp.zeros(7, jnp.float32),
+                                   dtype=jnp.int8)
+    mat = binning.bin_matrix.lower(X, jnp.zeros((3, 7), jnp.float32))
+    return (sketch.compile().as_text(), col.compile().as_text(),
+            mat.compile().as_text())
+
+
+@pytest.fixture(scope="module")
+def hlo():
+    irls, probe = _glm_texts()
+    sketch, bin_col, bin_mat = _binning_texts()
+    return {"gbm_step_pipelined": _gbm_step_text("1"),
+            "gbm_step_synchronous": _gbm_step_text("0"),
+            "sketch": sketch, "bin_column": bin_col, "bin_matrix": bin_mat,
+            "irls_step": irls, "deviance_probe": probe}
+
+
+_LEVEL = ("gbm.grad", "gbm.route", "gbm.hist", "gbm.psum", "gbm.split",
+          "gbm.leaf")
+#: (program, scope): where each declared scope has to arrive
+_SCOPE_CASES = (
+    [("gbm_step_pipelined", s) for s in _LEVEL + ("gbm.score",)]
+    + [("gbm_step_synchronous", s) for s in _LEVEL]
+    + [("sketch", "gbm.sketch"), ("bin_column", "gbm.bin"),
+       ("bin_matrix", "gbm.bin")]
+    + [("irls_step", s) for s in ("glm.eta", "glm.gram", "glm.deviance")]
+    + [("deviance_probe", s) for s in ("glm.eta", "glm.deviance")])
+
+
+def test_every_declared_scope_has_a_case():
+    assert {s for _, s in _SCOPE_CASES} == set(telemetry.SCOPES)
+
+
+@pytest.mark.parametrize("program,scope", _SCOPE_CASES)
+def test_scope_reaches_the_compiled_program(hlo, program, scope):
+    paths = set(re.findall(r'op_name="([^"]*)"', hlo[program]))
+    assert any(scope in p.split("/") for p in paths), (
+        f"{scope} is in no op_name of {program}")
+
+
+def test_scopes_rename_no_program():
+    """``irls_program_s`` reads the XLA module ``jit__core`` by name: on one
+    device the IRLS step still compiles as ``jit(_core)``."""
+    from h2o_tpu.parallel import mesh as meshmod
+
+    with meshmod.use_mesh(meshmod.make_mesh(devices=jax.devices()[:1])):
+        fr = _frame()
+        events = _events_of(lambda: _train_glm(fr))
+    assert "jit(_core)" in {e["what"] for e in events
+                            if e["kind"] == "compile"}
+
+
+def test_undeclared_scope_raises():
+    with pytest.raises(KeyError, match="nope"):
+        telemetry.scope("nope")
+
+
+# ---------------------------------------------------------------------------
+# (b) host spans: presence, parents, one trace, children inside the parent
+# ---------------------------------------------------------------------------
+#: algo -> {span: the span its parent has to be}
+_TREE = {
+    "gbm": {"train.gbm.sketch": "train.gbm",
+            "train.gbm.binned_view": "train.gbm",
+            "train.gbm.chunk": "train.gbm",
+            "train.gbm.score": "train.gbm.chunk"},
+    "glm": {"train.glm.design": "train.glm", "train.glm.gram": "train.glm",
+            "train.glm.solve": "train.glm", "train.glm.probe": "train.glm",
+            "train.glm.metrics": "train.glm",
+            "train.program.load": "train.glm.gram"},
+}
+# a lambda search has the one recording path: the same parts on the ring
+_TREE["glm_search"] = _TREE["glm"]
+_TRAIN = {"gbm": _train_gbm, "glm": _train_glm,
+          "glm_search": lambda fr: _train_glm(
+              fr, lambda_=None, lambda_search=True, nlambdas=6)}
+
+
+@pytest.mark.parametrize("algo", sorted(_TREE))
+def test_train_records_the_span_tree(algo):
+    fr = _frame()
+    events = _events_of(lambda: _TRAIN[algo](fr))
+    (root,) = _spans(events, f"train.{algo[:3]}")
+    by_id = {e["span"]: e for e in events if e["kind"] == "span"}
+    for name, parent in _TREE[algo].items():
+        got = _spans(events, name)
+        assert got, f"no {name} span"
+        for e in got:
+            assert e["trace"] == root["trace"], name
+            assert by_id[e["parent"]]["what"] == parent, (name, e)
+    kids: dict = {}
+    for e in by_id.values():
+        if e.get("parent") in by_id:
+            kids[e["parent"]] = kids.get(e["parent"], 0) + e["dur_us"]
+    for sid, total in kids.items():
+        assert total <= by_id[sid]["dur_us"], by_id[sid]["what"]
+
+
+# ---------------------------------------------------------------------------
+# (c) the program load and the compile events
+# ---------------------------------------------------------------------------
+def test_glm_job_records_its_program_load_and_named_compiles():
+    fr = _frame()
+    events = _events_of(lambda: _train_glm(fr))
+    (load,) = _spans(events, "train.program.load")
+    assert load["program"].startswith("train.glm.irls.binomial")
+    assert load["compiles"] >= 1 and load["uncached"] == load["compiles"]
+    compiles = [e for e in events if e["kind"] == "compile"]
+    assert compiles
+    for e in compiles:
+        assert e["what"] != "backend_compile" and e["cached"] is False
+    assert any("dev_eval" in e["what"] for e in compiles)
+
+
+def test_compile_event_says_when_it_was_a_cache_replay():
+    """jax fires the cache-hit event before the duration event of the same
+    program on the same thread: the pair is one ``cached`` compile event."""
+    compilemeter.install()
+    seq0 = timeline.total_recorded()
+    compilemeter._event_listener(compilemeter._CACHE_HIT_EVENT)
+    compilemeter._listener(compilemeter._COMPILE_EVENT, 0.25,
+                           fun_name="jit(replayed)")
+    compilemeter._listener(compilemeter._COMPILE_EVENT, 0.5,
+                           fun_name="jit(built)")
+    compilemeter._listener(compilemeter._COMPILE_EVENT, 0.5)
+    # hits whose duration events never came are spent on ONE event
+    compilemeter._event_listener(compilemeter._CACHE_HIT_EVENT)
+    compilemeter._event_listener(compilemeter._CACHE_HIT_EVENT)
+    compilemeter._listener(compilemeter._COMPILE_EVENT, 0.25,
+                           fun_name="jit(stale)")
+    compilemeter._listener(compilemeter._COMPILE_EVENT, 0.5,
+                           fun_name="jit(next)")
+    got = [(e["what"], e["cached"])
+           for e in timeline.snapshot(kind="compile", since=seq0)]
+    assert got == [("jit(replayed)", True), ("jit(built)", False),
+                   ("backend_compile", False), ("jit(stale)", True),
+                   ("jit(next)", False)]
+
+
+# ---------------------------------------------------------------------------
+# (d) one trace from the client down
+# ---------------------------------------------------------------------------
+def _free_port() -> int:
+    with socket.socket() as s:
+        s.bind(("127.0.0.1", 0))
+        return s.getsockname()[1]
+
+
+def test_client_train_roots_the_jobs_trace(monkeypatch, tmp_path):
+    import h2o_tpu.api as h2o
+    from h2o_tpu.backend.kvstore import STORE
+
+    # rest.request stays off the ring (a request-rate span): the
+    # per-process trace file sees every span
+    monkeypatch.setenv("H2O_TPU_TRACE_DIR", str(tmp_path))
+    h2o.init(port=_free_port())
+    try:
+        fr = _frame(2048)
+        STORE.put_keyed(fr)
+        est = h2o.H2OGeneralizedLinearEstimator(family="binomial", lambda_=0.0)
+        events = _events_of(lambda: est.train(
+            x=[f"x{i}" for i in range(_F)], y="y",
+            training_frame=h2o.get_frame(fr.key)))
+    finally:
+        h2o.shutdown()
+    (client,) = _spans(events, "client.train")
+    assert "parent" not in client                      # the root
+    for name in ("train.glm", "train.glm.gram", "train.program.load"):
+        got = _spans(events, name)
+        assert got and all(e["trace"] == client["trace"] for e in got), name
+    traced = {}
+    for e in telemetry.read_trace(telemetry.trace_path()):
+        traced.setdefault(e["name"], set()).add(e["args"]["trace"])
+    assert client["trace"] in traced["rest.request"]

@@ -120,8 +120,7 @@ assert rec["uncached_compiles_warm"] == 0, \
 print(json.dumps({"bench_smoke": "ok",
                   "wall_s": rec["wall_s"],
                   "wall_sync_s": rec["wall_sync_s"],
-                  "pipeline_speedup_x": rec["pipeline_speedup_x"],
-                  "overlap_ratio": rec["overlap_ratio"]}))
+                  "pipeline_speedup_x": rec["pipeline_speedup_x"]}))
 EOF
         bench_rc=$?
     fi

@@ -1,0 +1,99 @@
+"""Device seconds per declared scope (``telemetry.SCOPES``) in a capture:
+``python tools/trace_scopes.py <capture dir or .xplane.pb>``.
+
+An op's scope is the innermost declared name on the ``jax.named_scope`` path
+in its metadata. The trace keeps the path in a stat of the event or of its
+event-metadata entry, which ``ProfileData`` does not expose, so the file is
+read as plain protobuf fields (tsl's xplane.proto). Prints, per device, seconds
+by scope and which stat held the path in how many events (None: none).
+Imports four names of ``benchmark.trace_reduce``: keep them stable."""
+import os
+import sys
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from benchmark.trace_reduce import (DEVICE_PLANE, OPS_LINE,  # noqa: E402
+                                    find_xplane, is_container)
+from h2o_tpu.utils.telemetry import SCOPES  # noqa: E402
+
+
+def _varint(buf, i):
+    v, shift = buf[i] & 0x7F, 7
+    while buf[i] >= 0x80:
+        i += 1
+        v |= (buf[i] & 0x7F) << shift
+        shift += 7
+    return v, i + 1
+
+
+def _fields(buf):
+    """(field number, int | memoryview) of a message; fixed-width skipped."""
+    i = 0
+    while i < len(buf):
+        key, i = _varint(buf, i)
+        if key & 7 == 0:
+            v, i = _varint(buf, i)
+            yield key >> 3, v
+        elif key & 7 == 2:
+            n, i = _varint(buf, i)
+            yield key >> 3, buf[i:i + n]
+            i += n
+        else:
+            i += 8 if key & 7 == 1 else 4
+
+
+def reduce_plane(plane) -> tuple[dict, dict]:
+    """({scope: seconds}, {stat name: events}) over one XPlane's XLA ops. An
+    XStat's text is its str_value (5) or its ref_value (7) into the names."""
+    names, emeta, secs, held = {}, {}, {}, {}
+    plane = list(_fields(plane))
+    for num, v in plane:
+        if num in (4, 5):                   # map entries: key 1, message 2
+            body = list(_fields(dict(_fields(v))[2]))
+            ident, name = dict(body).get(1, 0), dict(body).get(2, b"")
+            if num == 5:
+                names[ident] = str(name, "utf8")
+            else:                           # XEventMetadata: its stats are 5
+                emeta[ident] = (str(name, "utf8", "replace"),
+                                [x for n, x in body if n == 5])
+    for line in (list(_fields(v)) for n, v in plane if n == 3):
+        if str(dict(line).get(2, b""), "utf8") != OPS_LINE:
+            continue
+        for ev in (list(_fields(v)) for n, v in line if n == 4):
+            d = {n: v for n, v in ev if n != 4}     # XEvent: id 1, ps 3
+            name, mstats = emeta.get(d.get(1, 0), ("", []))
+            if is_container(name):
+                continue
+            scope, stat = "unscoped", None
+            for f in (dict(_fields(m))
+                      for m in [v for n, v in ev if n == 4] + mstats):
+                text = (str(f[5], "utf8", "replace") if 5 in f
+                        else names.get(f.get(7), ""))
+                hit = [p for p in text.split("/") if p in SCOPES]
+                if hit:
+                    scope, stat = hit[-1], names.get(f.get(1), "?")
+                    break
+            secs[scope] = secs.get(scope, 0.0) + d.get(3, 0) / 1e12
+            held[stat] = held.get(stat, 0) + 1
+    return secs, held
+
+
+def main(path: str) -> None:
+    xp = path if path.endswith(".pb") else find_xplane(path)
+    with open(xp or sys.exit(f"no .xplane.pb under {path}"), "rb") as f:
+        space = memoryview(f.read())
+    for plane in (v for n, v in _fields(space) if n == 1):
+        name = str(dict(_fields(plane)).get(2, b""), "utf8")
+        if name.startswith(DEVICE_PLANE):
+            secs, held = reduce_plane(plane)
+            total = sum(secs.values()) or 1.0
+            print(f"{name}: {total:.4f} s of XLA ops; scope held in {held}")
+            if set(held) == {None}:     # the cache key leaves out op metadata
+                print("  NO scope: executables replayed from a cache written "
+                      "before the scopes? Empty JAX_COMPILATION_CACHE_DIR")
+            for k, v in sorted(secs.items(), key=lambda kv: -kv[1]):
+                print(f"  {k:12s} {v:9.4f} s {100 * v / total:5.1f}%")
+
+
+if __name__ == "__main__":
+    main(sys.argv[1])
