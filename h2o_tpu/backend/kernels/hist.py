@@ -237,22 +237,25 @@ def level_hist_one_group(xg, lc, vv, *, Bg: int, mode: str, n_lv: int,
 @telemetry.scope("gbm.hist")
 def streamed_route_hist(Xb, node, vals, route_fn, *, offset: int, n_lv: int,
                         nbins_tot: int, block: int, groups=None):
-    """Fused route→accumulate single pass — the double-buffered column-block
-    stream of the pipelined level program (``H2O_TPU_PIPELINE``).
+    """Fused route→accumulate single pass — the column-block stream of the
+    pipelined level program (``H2O_TPU_PIPELINE``).
 
     The synchronous level program walks the row blocks TWICE per level: once
     to route rows off the previous level's splits, once to accumulate the
-    new level's histogram. This pass decodes each (rb, F) block once:
-    ``route_fn`` (the previous level's routing, closure from the engine)
-    advances the block's node ids, the level window localizes them, and the
-    block's histogram contribution accumulates immediately — while the scan
-    machinery is already streaming the NEXT block's codes in (XLA pipelines
-    the decode/upcast of block i+1 against block i's contraction; on TPU
-    the Mosaic grid does the same with VMEM DMA double-buffering). Returns
-    ``(hists, node)`` with ``hists`` a tuple of per-group accumulators (one
-    flat accumulator when ``groups`` is None) and ``node`` the advanced
-    (Rl,) ids. No collectives — the caller psums, exactly like
-    `level_hist_blocks`.
+    new level's histogram. This pass decodes each (rb, F) block once (one
+    upcast of the int8/int16 codes, shared by both halves): ``route_fn``
+    (the previous level's routing, `engine._route_rows` closed over its
+    splits) advances the block's node ids, the level window localizes
+    them, and the block's histogram contribution accumulates immediately.
+    Returns ``(hists, node)`` with ``hists`` a tuple of per-group
+    accumulators (one flat accumulator when ``groups`` is None) and
+    ``node`` the advanced (Rl,) ids. No collectives — the caller psums,
+    exactly like `level_hist_blocks`.
+
+    The routing half is selects over the block's F codes and the level's
+    nodes, a small fraction of the one-hot and contraction the histogram
+    half does on the same block (PERF.md section 6, PR 27, has the chip's
+    milliseconds).
 
     Bit-parity with the two-pass shape is by construction: routing is
     integer/boolean work (any formulation that picks the same children is
@@ -266,6 +269,7 @@ def streamed_route_hist(Xb, node, vals, route_fn, *, offset: int, n_lv: int,
 
     def body(accs, blk):
         xb, nd, v = blk
+        xb = xb.astype(jnp.int32)   # the block's one decode, for both halves
         if route_fn is not None:
             # the innermost scope names an operation: routing inside the
             # fused stream reads gbm.route, the accumulate gbm.hist

@@ -106,15 +106,18 @@ class TreeConfig:
                                    # (H2O_TPU_PIPELINE): route(L-1) fuses
                                    # into level L's histogram pass (one
                                    # streamed decode per block instead of
-                                   # two), node-localized routing reads ride
-                                   # integer gathers instead of one-hot
-                                   # matmuls, and the carried margin is
-                                   # donated across chunk dispatches.
-                                   # Bit-equal to the synchronous oracle
-                                   # (pipeline=False) by construction —
-                                   # routing is integer/boolean work and
-                                   # every float accumulation keeps the
-                                   # oracle's per-block math and order.
+                                   # two), a row reads its node's split and
+                                   # its code at the split feature through
+                                   # integer selects over the level's nodes
+                                   # and the block's F codes (`_route_rows`)
+                                   # instead of one-hot matmuls, and the
+                                   # carried margin is donated across chunk
+                                   # dispatches. Bit-equal to the
+                                   # synchronous oracle (pipeline=False) by
+                                   # construction — routing is integer/
+                                   # boolean work and every float
+                                   # accumulation keeps the oracle's
+                                   # per-block math and order.
     async_psum: bool = False       # overlapped per-level reduction
                                    # (H2O_TPU_ASYNC_PSUM): each hist
                                    # group's psum is issued as soon as its
@@ -320,25 +323,31 @@ def _scatter_group_hists(hists, groups, F, n_lv, nbins_tot, V):
 # ---------------------------------------------------------------------------
 # Pipelined level program (H2O_TPU_PIPELINE) — fused route→hist streaming.
 # ---------------------------------------------------------------------------
-def _route_rows_gather(xb_blk, node_blk, route_args, cfg: "TreeConfig"):
-    """One block's routing off the previous level's splits, formulated as
-    integer gathers. Routing is integer/boolean work end to end — the
-    row's code at its node's split feature, the cut comparison, the set-
-    split direction-table read — so this produces node ids BIT-identical
-    to the one-hot-matmul `_route` in `_grow_tree` (which exists because
-    per-row gathers are slow on the TPU's serial gather path; the
-    pipelined program accepts them to keep each streamed block's decode
-    single-pass, and the real-TPU tradeoff is a ROADMAP campaign item)."""
+def _route_rows(xb_blk, node_blk, route_args, cfg: "TreeConfig"):
+    """One block's routing off the previous level's splits, inside the
+    streamed level pass, with no data-dependent addressing: a per-row
+    gather runs on the TPU's serial gather path (10.4 ns a row on a v5e,
+    78% of a HIGGS GBM job's device time; PERF.md, PR 27). Both per-row
+    reads are SELECTS instead — a boolean one-hot of the row's index keeps
+    the one survivor, a reduce brings it out. The node's split parameters
+    come through the (rb, n_lv) node mask, and the row's code at the split
+    feature through an (rb, F) feature mask: 1/B of the one-hot the
+    histogram half builds from the same decoded block, whatever F, n_lv
+    and the code dtype are.
+    Routing is integer/boolean work end to end (int32 for int8, int16 and
+    int32 codes alike), so the node ids are BIT-identical to the
+    one-hot-matmul `_route` in `_grow_tree` (the synchronous oracle)."""
     bf, bb, bnal, do_split, catd_lv, isset, offset, n_lv = route_args
     local = node_blk - offset
     active = (local >= 0) & (local < n_lv)
     lc = jnp.clip(local, 0, n_lv - 1)
-    bf_r = jnp.take(bf, lc)                                       # (rb,)
-    xv = jnp.take_along_axis(xb_blk, bf_r[:, None], axis=1)[:, 0]
-    xv = xv.astype(jnp.int32)
-    row_bb = jnp.take(bb, lc)
-    row_nal = jnp.take(bnal, lc)
-    row_split = jnp.take(do_split, lc) & active
+    at_node = jax.nn.one_hot(lc, n_lv, dtype=jnp.bool_)           # (rb, n_lv)
+    bf_r = jnp.sum(jnp.where(at_node, bf[None, :], 0), axis=1)    # (rb,)
+    row_bb = jnp.sum(jnp.where(at_node, bb[None, :], 0), axis=1)
+    row_nal = jnp.any(at_node & bnal[None, :], axis=1)
+    row_split = jnp.any(at_node & do_split[None, :], axis=1) & active
+    at_feat = jax.nn.one_hot(bf_r, xb_blk.shape[1], dtype=jnp.bool_)
+    xv = jnp.sum(jnp.where(at_feat, xb_blk.astype(jnp.int32), 0), axis=1)
     num_right = xv > row_bb
     if catd_lv is not None:
         # set-split direction read: the node's direction row at the row's
@@ -356,14 +365,14 @@ def _route_rows_gather(xb_blk, node_blk, route_args, cfg: "TreeConfig"):
 
 @telemetry.scope("gbm.route")
 def _route_all(Xb, node, route_args, cfg: "TreeConfig"):
-    """Blocked standalone routing pass (gather formulation) — the pipelined
-    path's final route after the last level's splits, and the route half
-    when the fused stream does not apply (GOSS rows, pallas backend)."""
+    """Blocked standalone routing pass (`_route_rows` per block) — the
+    pipelined path's final route after the last level's splits, and the
+    route half when the fused stream does not apply (GOSS rows, pallas
+    backend)."""
     Rl, F = Xb.shape
     rb = _block_rows(Rl, cfg.block_rows)
     _, node_b = jax.lax.scan(
-        lambda c, blk: (c, _route_rows_gather(blk[0], blk[1], route_args,
-                                              cfg)),
+        lambda c, blk: (c, _route_rows(blk[0], blk[1], route_args, cfg)),
         None, (Xb.reshape(Rl // rb, rb, F), node.reshape(Rl // rb, rb)))
     return node_b.reshape(Rl)
 
@@ -408,8 +417,7 @@ def _pipelined_level_hist(Xb, node, vals3, route_args, offset, n_lv,
         return hist, node
 
     route_fn = (None if route_args is None
-                else lambda xb, nd: _route_rows_gather(xb, nd, route_args,
-                                                       cfg))
+                else lambda xb, nd: _route_rows(xb, nd, route_args, cfg))
     if groups is None:
         (h,), node = hist_kernels.streamed_route_hist(
             Xb, node, vals3, route_fn, offset=offset, n_lv=n_lv,
@@ -1020,9 +1028,10 @@ def make_train_fn(cfg: TreeConfig, grad_fn: Callable, mesh=None,
             @telemetry.scope("gbm.leaf")
             def leaf_delta(vlk, nodek):
                 if cfg.pipeline:
-                    # the pipelined program accepts the gather (exact: a
-                    # gather IS the element) — same real-TPU tradeoff note
-                    # as _route_rows_gather
+                    # the pipelined program accepts this gather (exact: a
+                    # gather IS the element): one read a row a tree from
+                    # an n_nodes table — since PR 27 the per-row gather
+                    # left in the program (PERF.md section 7, no. 14)
                     return jnp.take(vlk, nodek)
                 # leaf values are real f32 — hi/lo split keeps the carried
                 # residuals f32-grade without Precision.HIGHEST's fusion cost

@@ -107,10 +107,11 @@ _knob("H2O_TPU_HIST_SEG_WIDTH", "int", 8,
       "pallas hist kernel (backend/kernels/hist.py)")
 _knob("H2O_TPU_PIPELINE", "bool", True,
       "async pipelined GBM/DRF level program: route->hist fused into one "
-      "streamed pass per row block, gather-formulated routing, cadence "
-      "scoring fused into the chunk step, donated margin carry. "
-      "BIT-equal to the synchronous oracle; 0 reverts to the two-pass "
-      "level program (models/tree/engine.py)")
+      "streamed pass per row block, routing by integer selects over the "
+      "block's codes (no per-row gather), cadence scoring fused into the "
+      "chunk step, donated margin carry. BIT-equal to the synchronous "
+      "oracle; 0 reverts to the two-pass one-hot-matmul level program "
+      "(models/tree/engine.py)")
 _knob("H2O_TPU_ASYNC_PSUM", "bool", True,
       "overlapped per-level histogram reduction: each width bucket's ICI "
       "psum is issued before the next bucket's local scan so the "

@@ -11,6 +11,7 @@ them, F=28, nbins=20, depth 5, int8 codes, P=29 for the GLM design.
 """
 
 import dataclasses
+import re
 import time
 
 import jax
@@ -66,10 +67,12 @@ def test_large_frames_pad_to_a_multiple_of_eight_row_blocks(v5e):
             assert per_shard % block == 0 and (per_shard // block) % 8 == 0
 
 
-def test_default_train_step_compiles_for_v5e_2x2(v5e, monkeypatch):
+@pytest.fixture(scope="module")
+def default_train_step(v5e):
     """The chunk step `GBM._train` builds under default settings — pipelined
-    level program, fused cadence score, donated margin — lowered for four
-    v5e chips."""
+    level program, fused cadence score, donated margin — lowered and compiled
+    for four v5e chips, once for every test that reads it: ``(lowered,
+    compiled, seconds the compile took)``."""
     from h2o_tpu.frame.frame import Frame
     from h2o_tpu.models import gbm as gbm_mod
     from h2o_tpu.models.distributions import get_distribution
@@ -77,47 +80,73 @@ def test_default_train_step_compiles_for_v5e_2x2(v5e, monkeypatch):
     from h2o_tpu.parallel.mesh import padded_len
     from h2o_tpu.utils.knobs import get_bool
 
-    monkeypatch.delenv("H2O_TPU_HIST_KERNEL", raising=False)
-    mesh = make_mesh(v5e)
-    R = padded_len(11_000_000, mesh)
-    tiny = Frame.from_dict({"a": np.arange(8, dtype=np.float32),
-                            "y": np.arange(8, dtype=np.float32) % 2})
-    b = gbm_mod.GBM(gbm_mod.GBMParameters(
-        training_frame=tiny, response_column="y", ntrees=20, max_depth=5,
-        nbins=NBINS, seed=42, score_tree_interval=INTERVAL))
-    dist = get_distribution("bernoulli")
-    cfg = b._tree_config(1, nbins=NBINS)
-    groups, blk = plan_hist_groups(
-        np.full(F, NBINS - 1, np.int32), cfg.nbins + 1, cfg.block_rows,
-        budget_bytes=12 << 30, n_lv_max=16, nvals=3)
-    cfg = dataclasses.replace(
-        cfg, ntrees=INTERVAL, block_rows=blk, hist_groups=groups,
-        pipeline=get_bool("H2O_TPU_PIPELINE"),
-        async_psum=get_bool("H2O_TPU_ASYNC_PSUM"), fused_score=True)
-    train_fn = make_train_fn(
-        cfg, b._make_grad_fn(dist, 1), mesh,
-        score_fn=gbm_mod._metrics_raw_fn("Binomial", dist, False),
-        score_spec=P(ROWS, None), donate=True)
-    row = lambda dt: _spec(mesh, (R,), dt, P(ROWS))  # noqa: E731
-    lowered = train_fn.lower(
-        _spec(mesh, (R, F), jnp.int8, P(ROWS, None)),     # binned codes
-        row(jnp.float32), row(jnp.float32), row(jnp.float32),  # y, w, f
-        _spec(mesh, (F, NBINS - 1), jnp.float32),         # edges
-        _spec(mesh, (F, NBINS - 1), jnp.bool_),           # edge_ok
-        _spec(mesh, (INTERVAL, 2), jnp.uint32),           # keys
-        _spec(mesh, (INTERVAL,), jnp.float32),            # rates
-        _spec(mesh, (F,), jnp.float32),                   # mono
-        _spec(mesh, (F, F), jnp.bool_),                   # imat
-        _spec(mesh, (F,), jnp.bool_),                     # iscat
-        _spec(mesh, (F,), jnp.int32),                     # nedges
-        _spec(mesh, (), jnp.float32))                     # trees done
+    with pytest.MonkeyPatch.context() as mp:
+        mp.delenv("H2O_TPU_HIST_KERNEL", raising=False)
+        mesh = make_mesh(v5e)
+        R = padded_len(11_000_000, mesh)
+        tiny = Frame.from_dict({"a": np.arange(8, dtype=np.float32),
+                                "y": np.arange(8, dtype=np.float32) % 2})
+        b = gbm_mod.GBM(gbm_mod.GBMParameters(
+            training_frame=tiny, response_column="y", ntrees=20, max_depth=5,
+            nbins=NBINS, seed=42, score_tree_interval=INTERVAL))
+        dist = get_distribution("bernoulli")
+        cfg = b._tree_config(1, nbins=NBINS)
+        groups, blk = plan_hist_groups(
+            np.full(F, NBINS - 1, np.int32), cfg.nbins + 1, cfg.block_rows,
+            budget_bytes=12 << 30, n_lv_max=16, nvals=3)
+        cfg = dataclasses.replace(
+            cfg, ntrees=INTERVAL, block_rows=blk, hist_groups=groups,
+            pipeline=get_bool("H2O_TPU_PIPELINE"),
+            async_psum=get_bool("H2O_TPU_ASYNC_PSUM"), fused_score=True)
+        train_fn = make_train_fn(
+            cfg, b._make_grad_fn(dist, 1), mesh,
+            score_fn=gbm_mod._metrics_raw_fn("Binomial", dist, False),
+            score_spec=P(ROWS, None), donate=True)
+        row = lambda dt: _spec(mesh, (R,), dt, P(ROWS))  # noqa: E731
+        lowered = train_fn.lower(
+            _spec(mesh, (R, F), jnp.int8, P(ROWS, None)),     # binned codes
+            row(jnp.float32), row(jnp.float32), row(jnp.float32),  # y, w, f
+            _spec(mesh, (F, NBINS - 1), jnp.float32),         # edges
+            _spec(mesh, (F, NBINS - 1), jnp.bool_),           # edge_ok
+            _spec(mesh, (INTERVAL, 2), jnp.uint32),           # keys
+            _spec(mesh, (INTERVAL,), jnp.float32),            # rates
+            _spec(mesh, (F,), jnp.float32),                   # mono
+            _spec(mesh, (F, F), jnp.bool_),                   # imat
+            _spec(mesh, (F,), jnp.bool_),                     # iscat
+            _spec(mesh, (F,), jnp.int32),                     # nedges
+            _spec(mesh, (), jnp.float32))                     # trees done
+        t0 = time.time()
+        compiled = lowered.compile()
+        return lowered, compiled, time.time() - t0
+
+
+def test_default_train_step_compiles_for_v5e_2x2(default_train_step):
+    lowered, compiled, secs = default_train_step
     # nothing default settings reach is a Mosaic kernel today; when one is,
     # this flips and chip_smoke.py asserts the custom call instead
     assert "tpu_custom_call" not in lowered.as_text()
-    t0 = time.time()
-    assert lowered.compile() is not None
+    assert compiled is not None
     # ~10 s on this sandbox's host; the block-count pathology above is 200+
-    assert time.time() - t0 < 90
+    assert secs < 90
+
+
+def test_default_train_step_has_no_gather_on_the_code_block(
+        default_train_step):
+    """Routing reads a row's code at its node's split feature by a select
+    over the block's 28 codes. A per-row gather there is the TPU's serial
+    gather path: 10.4 ns a row, 78% of a HIGGS GBM job's device time
+    (PERF.md, PR 27). The optimised program must hold no ``gather`` whose
+    operand is the ``s8[..., 28]`` code block, inside a fusion or out."""
+    hlo = default_train_step[1].as_text()
+    # optimised HLO names a gather's operands without their shapes: resolve
+    # each through the line that defines it
+    shape_of = dict(re.findall(r"^\s*(?:ROOT )?(%[\w.\-]+) = (\S+)", hlo, re.M))
+    codes = re.compile(r"s8\[[\d,]*\b28\]")
+    assert any(codes.match(sh) for sh in shape_of.values())   # the block is there
+    gathers = re.findall(r"^.* gather\((%[\w.\-]+),.*$", hlo, re.M)
+    assert gathers                      # split search, leaf values: small tables
+    bad = [op for op in gathers if codes.match(shape_of[op])]
+    assert not bad, [(op, shape_of[op]) for op in bad]
 
 
 def test_default_gram_compiles_for_v5e_at_higgs_rows(v5e, monkeypatch):
