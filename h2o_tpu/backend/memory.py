@@ -84,11 +84,13 @@ def device_hbm_bytes() -> int | None:
 
 
 def hbm_budget_bytes() -> int | None:
-    """LIVE HBM planning budget for compute intermediates: 85% of physical
-    (the Cleaner's headroom) minus the bytes the Cleaner currently tracks as
-    device-resident and minus outstanding serving reservations
-    (:func:`reserve_bytes`), floored at 1/16 of physical so planners always
-    get a workable (if small) budget under pressure.
+    """LIVE HBM planning budget for compute intermediates, PER DEVICE: 85%
+    of one device's physical HBM (the Cleaner's headroom) minus the bytes
+    the Cleaner currently tracks as resident on the FULLEST device (a
+    row-sharded frame debits each chip its own slice, not the total over
+    the mesh; one chip reads as before) and minus outstanding serving
+    reservations (:func:`reserve_bytes`), floored at 1/16 of physical so
+    planners always get a workable (if small) budget under pressure.
     ``H2O_TPU_HBM_LIMIT_BYTES`` pins the PRE-reservation value exactly (no
     residency adjustment — tests mock budgets with it); None when no
     accelerator budget is resolvable (planners fall back to their own
@@ -99,8 +101,8 @@ def hbm_budget_bytes() -> int | None:
     hw = device_hbm_bytes()
     if not hw:
         return None
-    return max(int(hw * 0.85) - CLEANER.tracked_bytes() - reserved_bytes(),
-               hw >> 4)
+    return max(int(hw * 0.85) - CLEANER.fullest_device_bytes()
+               - reserved_bytes(), hw >> 4)
 
 
 # ---------------------------------------------------------------------------
@@ -315,6 +317,13 @@ class Cleaner:
         nbytes on every device; a row-sharded one ~1/n_shards each)."""
         with self._lock:
             return {d: b for d, b in self._dev_live.items() if b > 0}
+
+    def fullest_device_bytes(self) -> int:
+        """Live tracked bytes on the device that holds the most: what a
+        per-device budget is debited (host-resident payloads hold no HBM)."""
+        with self._lock:
+            return max((b for d, b in self._dev_live.items() if d != "host"),
+                       default=0)
 
     def device_peak_bytes(self) -> dict:
         """Process-lifetime per-device residency peaks (the per-chip HBM

@@ -36,8 +36,9 @@ from .distributions import Bernoulli, Gaussian, get_distribution
 from .model_base import Model, ModelBuilder, ModelOutput, Parameters, make_metrics
 from .tree.binning import (bin_matrix, compute_bin_edges,
                            compute_bin_edges_cols)
-from .tree.engine import (TreeConfig, make_train_fn, plan_hist_groups,
-                          predict_forest, psum_payload_bytes)
+from .tree.engine import (TreeConfig, hist_psum_bytes, make_train_fn,
+                          plan_hist_groups, predict_forest,
+                          psum_payload_bytes)
 
 #: last build's training-matrix accounting (mode, per-matrix bytes) — the
 #: bench binned-storage leg and the chunk-store tests read this to put the
@@ -556,6 +557,12 @@ class GBM(ModelBuilder):
             else:
                 Xb = bin_matrix(X, put_replicated(edges_np, mesh))
         plen = Xb.shape[0]
+        # how the job's rows lie on the mesh, on the job's root span
+        # (train.<algo>; a CV fold's builder has none)
+        root = getattr(self, "_train_span", None)
+        if root is not None:
+            root.attrs["row_shards"] = n_row_shards(mesh)
+            root.attrs["rows_per_shard"] = plen // n_row_shards(mesh)
         global LAST_TRAIN_MATRIX_BYTES
         LAST_TRAIN_MATRIX_BYTES = {
             "mode": "binned" if use_binned else "stacked_f32",
@@ -912,6 +919,11 @@ class GBM(ModelBuilder):
         # chunk plans declare it: a ragged tail chunk legitimately
         # compiles its own shape on first dispatch.
         uniform_chunks = len({len(k) for k, _ in chunks}) <= 1
+        # bytes of level histogram one tree iteration hands to the psum,
+        # from shapes (one shard reduces nothing): the counter
+        # train.gbm.psum_bytes adds them at each chunk dispatch
+        psum_tree = (K * hist_psum_bytes(cfg, len(names))
+                     if n_row_shards(mesh) > 1 else 0)
         steady = [False]
         for ci in range(start_ci, len(chunks)):
             keys, rates = chunks[ci]
@@ -936,6 +948,9 @@ class GBM(ModelBuilder):
 
                     from ..utils import compilemeter, sanitizer
                     args = _step_args(cj, f_in)
+                    if psum_tree:
+                        telemetry.inc("train.gbm.psum_bytes",
+                                      int(chunks[cj][0].shape[0]) * psum_tree)
                     use_aot = (train_step is not None
                                and chunks[cj][0].shape[0]
                                == len(chunks[0][0]))

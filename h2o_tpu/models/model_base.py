@@ -465,7 +465,7 @@ class ModelBuilder:
             with telemetry.device_profile(f"train.{self.algo_name}"), \
                     telemetry.span(f"train.{self.algo_name}",
                                    algo=self.algo_name,
-                                   job=str(self.job.key)):
+                                   job=str(self.job.key)) as self._train_span:
                 # arm auto-recovery BEFORE the encoding swap: the persisted
                 # params/frames must be the ORIGINAL inputs so a resumed
                 # process replays the (deterministic) encoding itself

@@ -47,11 +47,21 @@ def _pow2_block(R: int, want: int) -> int:
 _DEFAULT_SKETCH_BUDGET = 4 << 30
 
 
+def _rows_per_device(R: int) -> int:
+    """Rows of an R-row frame that ONE device holds when its rows split
+    evenly over several shards (fewer than R: `_sketch_block` then goes
+    through `shard_map`), else R. The budget is one device's, so the
+    sketch is planned against one device's rows: 44M rows over four chips
+    plan like 11M rows on one."""
+    ns = n_row_shards()
+    return R // ns if ns > 1 and R % ns == 0 else R
+
+
 def _sketch_plan(R: int, F: int, nb: int,
                  budget_bytes: int | None) -> tuple[int, int]:
     """Pick (rb, Fb) — row-block and feature-block sizes — for the quantile
     sketch from a live HBM budget, so the sketch scales to any (R, F) by
-    construction.
+    construction. ``R`` is the rows ONE device holds (`_rows_per_device`).
 
     Peak f32 footprint the sketch ADDS on top of the caller's (R, F) matrix:
     the (R, Fb) column block it slices out (≤ budget/4), the per-scan-step
@@ -178,10 +188,8 @@ def _sketch_block(X, qs, nb: int, rb: int):
     (Fb, nb) histograms psum. Left to GSPMD, the scan over row blocks of a
     row-sharded matrix compiled on a four-chip v5e to an all-gather of the
     WHOLE matrix inside every one of its 2 x R/rb iterations (PERF.md)."""
-    mesh = default_mesh()
-    ns = n_row_shards(mesh)
-    if ns > 1 and X.shape[0] % ns == 0:
-        return _sharded_sketch(mesh, tuple(qs), nb, rb)(X)
+    if _rows_per_device(X.shape[0]) < X.shape[0]:
+        return _sharded_sketch(default_mesh(), tuple(qs), nb, rb)(X)
     return _hist_quantile_rows(X, tuple(qs), nb=nb, rb=rb)
 
 
@@ -199,7 +207,7 @@ def hist_quantile_sketch(X, qs, nb: int = 1024,
 
         budget_bytes = hbm_budget_bytes()
     R, F = X.shape
-    rb, Fb = _sketch_plan(R, F, nb, budget_bytes)
+    rb, Fb = _sketch_plan(_rows_per_device(R), F, nb, budget_bytes)
     if Fb >= F:
         return np.asarray(_sketch_block(X, qs, nb, rb))
     out = np.empty((len(qs), F), np.float32)
@@ -238,7 +246,7 @@ def hist_quantile_sketch_cols(cols, qs, nb: int = 1024,
     cols = list(cols)
     F = len(cols)
     R = _col_plen(cols[0])
-    rb, Fb = _sketch_plan(R, F, nb, budget_bytes)
+    rb, Fb = _sketch_plan(_rows_per_device(R), F, nb, budget_bytes)
     out = np.empty((len(qs), F), np.float32)
     for f0 in range(0, F, Fb):
         blk = jnp.stack([_coldata(c) for c in cols[f0:f0 + Fb]], axis=1)
@@ -413,7 +421,7 @@ def compute_bin_edges_cols(cols, is_cat: np.ndarray, nbins: int,
     if F == 0:
         return np.zeros((0, max(nbins - 1, 0)), np.float32)
     R = _col_plen(cols[0])
-    _, Fb = _sketch_plan(R, F, 1024, budget_bytes)
+    _, Fb = _sketch_plan(_rows_per_device(R), F, 1024, budget_bytes)
     col_min = np.empty(F, np.float32)
     col_max = np.empty(F, np.float32)
     exact = None

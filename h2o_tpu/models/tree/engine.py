@@ -925,20 +925,27 @@ def _grow_tree(Xb, g, h, w, edges, edge_ok, colkey, cfg: TreeConfig,
     return feat, thr, nanL, val, garr, catd, node
 
 
-def psum_payload_bytes(cfg: TreeConfig, F: int, nvals: int = 3) -> int:
-    """Bytes ONE tree's ICI reductions move per shard: the per-level
-    histogram psums (per-group when ``cfg.hist_groups`` is set — the wire
-    carries Σ F_g·B_g cells instead of the padded F·B_max) plus the final
-    per-node totals psum. Pure accounting off the static config — the
-    bench ``sharded`` leg records it next to the per-shard matrix bytes so
-    the compute-vs-wire tradeoff of a shard count is on the record."""
+def hist_psum_bytes(cfg: TreeConfig, F: int, nvals: int = 3) -> int:
+    """Bytes of level histogram ONE tree hands to `_psum_hist` per shard:
+    every level's whole f32 (F, n_lv, B, nvals) accumulator (per group
+    when ``cfg.hist_groups`` is set — the wire carries Σ F_g·B_g cells
+    instead of the padded F·B_max). From the static config alone: the
+    counter ``train.gbm.psum_bytes`` adds it at chunk dispatch."""
     B = cfg.nbins + 1
     groups = _norm_groups(cfg.hist_groups) if cfg.hist_groups else None
     cells_per_lv = (F * B if groups is None
                     else sum(len(idxs) * Bg for idxs, Bg, _ in groups))
-    hist_cells = sum((2 ** level) * cells_per_lv
-                     for level in range(cfg.max_depth))
-    return (hist_cells + cfg.n_nodes) * nvals * 4
+    return sum((2 ** level) * cells_per_lv
+               for level in range(cfg.max_depth)) * nvals * 4
+
+
+def psum_payload_bytes(cfg: TreeConfig, F: int, nvals: int = 3) -> int:
+    """Bytes ONE tree's ICI reductions move per shard: the per-level
+    histogram psums (`hist_psum_bytes`) plus the final per-node totals
+    psum. Pure accounting off the static config — the bench ``sharded``
+    leg records it next to the per-shard matrix bytes so the
+    compute-vs-wire tradeoff of a shard count is on the record."""
+    return hist_psum_bytes(cfg, F, nvals) + cfg.n_nodes * nvals * 4
 
 
 _TRAIN_FN_CACHE: dict = {}

@@ -167,6 +167,9 @@ _histogram("train.seconds",
            "drained train wall per job (the run_time_ms source — "
            "block_until_ready runs before the clock is read)")
 _counter("train.chunk.count", "GBM/DRF boosting-chunk iterations")
+_counter("train.gbm.psum_bytes",
+         "bytes of level histogram handed to the cross-shard psum, a shard, "
+         "counted from shapes at chunk dispatch (0 on one row shard)")
 _histogram("train.chunk.seconds",
            "wall per boosting chunk (train_fn dispatch + scoring + "
            "history, the score_tree_interval boundary)")

@@ -6,8 +6,10 @@ COMPILE for it. These run (never skip) on the CPU sandbox — they are the tests
 that would have caught a default kernel Mosaic refuses (PR 9's Pallas
 histogram had only ever run under ``interpret=True``).
 
-Shapes are the HIGGS configuration's: 11,000,000 rows as `padded_len` pads
-them, F=28, nbins=20, depth 5, int8 codes, P=29 for the GLM design.
+Shapes are the HIGGS configurations': 11,000,000 rows a chip as `padded_len`
+pads them (44,000,000 over the four chips of the train step, the
+deployment of the cell ``higgs_gbm_train_4chip``), F=28, nbins=20, depth 5,
+int8 codes, P=29 for the GLM design.
 """
 
 import dataclasses
@@ -27,6 +29,7 @@ from h2o_tpu.parallel.mesh import ROWS, make_mesh
 
 F, NBINS, INTERVAL = 28, 20, 10
 HIGGS_PLEN = 11_010_048          # padded_len(11_000_000) on one device
+HIGGS_4CHIP_ROWS = 44_000_000    # four times HIGGS: 11M rows a chip
 
 
 @pytest.fixture(scope="module")
@@ -71,8 +74,9 @@ def test_large_frames_pad_to_a_multiple_of_eight_row_blocks(v5e):
 def default_train_step(v5e):
     """The chunk step `GBM._train` builds under default settings — pipelined
     level program, fused cadence score, donated margin — lowered and compiled
-    for four v5e chips, once for every test that reads it: ``(lowered,
-    compiled, seconds the compile took)``."""
+    for four v5e chips at the four-chip deployment's rows (44M: each chip
+    scans what the one-chip cell's chip scans), once for every test that
+    reads it: ``(lowered, compiled, seconds the compile took)``."""
     from h2o_tpu.frame.frame import Frame
     from h2o_tpu.models import gbm as gbm_mod
     from h2o_tpu.models.distributions import get_distribution
@@ -83,7 +87,8 @@ def default_train_step(v5e):
     with pytest.MonkeyPatch.context() as mp:
         mp.delenv("H2O_TPU_HIST_KERNEL", raising=False)
         mesh = make_mesh(v5e)
-        R = padded_len(11_000_000, mesh)
+        R = padded_len(HIGGS_4CHIP_ROWS, mesh)
+        assert R == 4 * HIGGS_PLEN
         tiny = Frame.from_dict({"a": np.arange(8, dtype=np.float32),
                                 "y": np.arange(8, dtype=np.float32) % 2})
         b = gbm_mod.GBM(gbm_mod.GBMParameters(
@@ -147,6 +152,29 @@ def test_default_train_step_has_no_gather_on_the_code_block(
     assert gathers                      # split search, leaf values: small tables
     bad = [op for op in gathers if codes.match(shape_of[op])]
     assert not bad, [(op, shape_of[op]) for op in bad]
+
+
+def test_default_train_step_moves_no_rows_between_chips(default_train_step):
+    """Across four chips the train step reduces histograms and node totals,
+    never rows. Left to GSPMD, a scan over row blocks of a row-sharded array
+    compiled to an all-gather of the whole array in every iteration (PR 21:
+    the GBM phase 288 s instead of 53 s), which no one-chip cell shows. The
+    optimised four-chip program holds no ``all-gather`` (nor an all-to-all
+    or a collective-permute), and no ``all-reduce`` whose result has a
+    dimension the size of a shard's rows or of one of its row blocks."""
+    hlo = default_train_step[1].as_text()
+    colls = re.findall(
+        r"^\s*(?:ROOT )?%?[\w.\-]+ = (.*?) (all-gather|all-reduce|all-to-all|"
+        r"collective-permute|reduce-scatter)(?:-start)?\(", hlo, re.M)
+    assert colls, "a four-chip step with no collective reduces nothing"
+    assert {k for _, k in colls} == {"all-reduce"}, sorted(
+        {(k, sh[:60]) for sh, k in colls if k != "all-reduce"})
+    row_sized = {HIGGS_PLEN, HIGGS_PLEN // 8192, 8192, 4096, 2048, 1024, 512}
+    for shapes, _ in colls:
+        for dims in re.findall(r"\[([\d,]*)\]", shapes):
+            assert not row_sized & {int(d) for d in dims.split(",") if d}, shapes
+    # the level histograms are among them: f32[28, n_lv, 21, 3]
+    assert any(re.search(r"f32\[28,16,21,3\]", sh) for sh, _ in colls)
 
 
 def test_default_gram_compiles_for_v5e_at_higgs_rows(v5e, monkeypatch):

@@ -124,21 +124,81 @@ def test_hbm_budget_env_pin_and_cpu_none(_fake_hw):
     assert memory.Cleaner().limit_bytes() is None
 
 
+class _Resident:
+    """Weakref-able stand-in for a device-resident Vec: its ``_data`` has
+    the one thing the Cleaner's per-device ledger walks, the shards."""
+
+    def __init__(self, **per_device):
+        import types
+
+        self._data = types.SimpleNamespace(addressable_shards=[
+            types.SimpleNamespace(device=d, data=np.empty(b >> 20, "V1048576"))
+            for d, b in per_device.items()])
+        self.nbytes = sum(per_device.values())
+
+
 def test_hbm_budget_is_live_minus_resident(_fake_hw):
     """Planners must see physical headroom MINUS what already sits in HBM —
     a 14 GB resident frame on a v5e leaves ~nothing for intermediates."""
     memory, _mp, install = _fake_hw
     install({"bytes_limit": 16 << 30, "bytes_in_use": 0})
 
-    class _Obj:  # weakref-able stand-in for a device-resident Vec
-        pass
-
     full = int((16 << 30) * 0.85)
     assert memory.hbm_budget_bytes() == full
-    v = _Obj()
-    memory.CLEANER.track(v, 4 << 30)
+    v = _Resident(chip0=4 << 30)
+    memory.CLEANER.track(v, v.nbytes)
     assert memory.hbm_budget_bytes() == full - (4 << 30)
     # 13 GiB resident (still under the Cleaner's own 13.6 GiB sweep
     # threshold) leaves 0.6 GiB of headroom: the planner floor, 1/16 HBM
-    memory.CLEANER.track(v, 9 << 30)
+    u = _Resident(chip0=9 << 30)
+    memory.CLEANER.track(u, u.nbytes)
     assert memory.hbm_budget_bytes() == (16 << 30) >> 4
+
+
+def test_hbm_budget_debits_the_fullest_device_not_the_mesh_total(_fake_hw):
+    """The limit is ONE device's: a frame row-sharded over four chips takes
+    a quarter of its bytes from each chip's budget, a replicated table all
+    of them from every chip, and host-resident payloads nothing."""
+    memory, _mp, install = _fake_hw
+    gib = 1 << 30
+    install(*[{"bytes_limit": 16 * gib, "bytes_in_use": 0}] * 4)
+    full = int(16 * gib * 0.85)
+    frame = _Resident(chip0=gib, chip1=gib, chip2=gib, chip3=gib)
+    memory.CLEANER.track(frame, frame.nbytes)
+    assert memory.CLEANER.tracked_bytes() == 4 * gib
+    assert memory.hbm_budget_bytes() == full - gib
+    table = _Resident(chip0=gib >> 1, chip1=gib >> 1, chip2=gib >> 1,
+                      chip3=gib >> 1)
+    skewed = _Resident(chip2=2 * gib)
+    host = _Resident(host=2 * gib)   # total 10 GiB: under the sweep's limit
+    for r in (table, skewed, host):
+        memory.CLEANER.track(r, r.nbytes)
+    assert memory.CLEANER.fullest_device_bytes() == 3 * gib + (gib >> 1)
+    assert memory.hbm_budget_bytes() == full - 3 * gib - (gib >> 1)
+    del skewed
+    import gc
+
+    gc.collect()
+    assert memory.hbm_budget_bytes() == full - gib - (gib >> 1)
+
+
+def test_hbm_budget_of_a_row_sharded_vec_is_one_shards_bytes(_fake_hw):
+    """A real column on the four-shard CPU mesh: the budget falls by the
+    bytes ONE device holds; on a one-shard mesh by all of them."""
+    import jax
+
+    from h2o_tpu.parallel import mesh as meshmod
+
+    memory, _mp, install = _fake_hw
+    devs = jax.devices()
+    install(*[{"bytes_limit": 16 << 30, "bytes_in_use": 0}] * 4)
+    full = memory.hbm_budget_bytes()
+    col = np.arange(1 << 16, dtype=np.float32)
+    with meshmod.use_mesh(meshmod.make_mesh(devices=devs[:4])):
+        v = Vec.from_numpy(col)
+        assert len(v.data.sharding.device_set) == 4
+        assert full - memory.hbm_budget_bytes() == col.nbytes // 4
+    with meshmod.use_mesh(meshmod.make_mesh(devices=devs[:1])):
+        w = Vec.from_numpy(col)
+        assert full - memory.hbm_budget_bytes() == col.nbytes // 4 + col.nbytes
+    del v, w

@@ -73,18 +73,24 @@ def _spans(events, name):
 # (a) device scopes in the compiled programs
 # ---------------------------------------------------------------------------
 def _gbm_step_text(pipeline: str) -> str:
-    """HLO text of the train step a small GBM job compiled."""
+    """HLO text of the train step a small GBM job compiled: THIS job's
+    entry of the process-wide step cache, by its own key (the level program
+    asked for, this frame's coded matrix). Under ``-n 6 --dist load`` the
+    process may also be serving another worker's REST jobs (``h2o.init``
+    at a module's fixed port connects to whichever worker bound it first),
+    and their steps land in the same cache while this one trains."""
     from h2o_tpu.models import gbm as gbm_mod
 
     mp = pytest.MonkeyPatch()
     try:
         mp.setenv("H2O_TPU_PIPELINE", pipeline)
-        gbm_mod._AOT_STEP_CACHE.clear()
         _train_gbm(_frame())
-        (compiled,) = gbm_mod._AOT_STEP_CACHE.values()
+        (compiled,) = [
+            c for ((cfg, *_), sig), c in list(gbm_mod._AOT_STEP_CACHE.items())
+            if cfg.pipeline == (pipeline == "1")
+            and sig[0] == ((_N, _F), "int8")]
         return compiled.as_text()
     finally:
-        gbm_mod._AOT_STEP_CACHE.clear()
         mp.undo()
 
 

@@ -5,7 +5,9 @@ An op's scope is the innermost declared name on the ``jax.named_scope`` path
 in its metadata. The trace keeps the path in a stat of the event or of its
 event-metadata entry, which ``ProfileData`` does not expose, so the file is
 read as plain protobuf fields (tsl's xplane.proto). Prints, per device, seconds
-by scope and which stat held the path in how many events (None: none).
+by scope and which stat held the path in how many events (None: none); for a
+capture of several chips also the mean plane, with each scope's least and
+most over the chips.
 Imports four names of ``benchmark.trace_reduce``: keep them stable."""
 import os
 import sys
@@ -78,21 +80,37 @@ def reduce_plane(plane) -> tuple[dict, dict]:
     return secs, held
 
 
+def _table(secs: dict, spread: dict | None = None) -> None:
+    total = sum(secs.values()) or 1.0
+    for k, v in sorted(secs.items(), key=lambda kv: -kv[1]):
+        lo_hi = "" if spread is None else "  (%.4f .. %.4f)" % spread[k]
+        print(f"  {k:12s} {v:9.4f} s {100 * v / total:5.1f}%{lo_hi}")
+
+
 def main(path: str) -> None:
     xp = path if path.endswith(".pb") else find_xplane(path)
     with open(xp or sys.exit(f"no .xplane.pb under {path}"), "rb") as f:
         space = memoryview(f.read())
+    planes = []
     for plane in (v for n, v in _fields(space) if n == 1):
         name = str(dict(_fields(plane)).get(2, b""), "utf8")
         if name.startswith(DEVICE_PLANE):
             secs, held = reduce_plane(plane)
-            total = sum(secs.values()) or 1.0
-            print(f"{name}: {total:.4f} s of XLA ops; scope held in {held}")
+            planes.append(secs)
+            print(f"{name}: {sum(secs.values()):.4f} s of XLA ops; "
+                  f"scope held in {held}")
             if set(held) == {None}:     # the cache key leaves out op metadata
                 print("  NO scope: executables replayed from a cache written "
                       "before the scopes? Empty JAX_COMPILATION_CACHE_DIR")
-            for k, v in sorted(secs.items(), key=lambda kv: -kv[1]):
-                print(f"  {k:12s} {v:9.4f} s {100 * v / total:5.1f}%")
+            _table(secs)
+    if len(planes) > 1:
+        # several chips: the mean plane, and each scope's least and most
+        # over the chips (the straggler a collective waits for)
+        scopes = sorted({k for p in planes for k in p})
+        per = {k: [p.get(k, 0.0) for p in planes] for k in scopes}
+        print(f"mean of {len(planes)} device planes (least .. most):")
+        _table({k: sum(v) / len(v) for k, v in per.items()},
+               {k: (min(v), max(v)) for k, v in per.items()})
 
 
 if __name__ == "__main__":
