@@ -17,6 +17,16 @@ contract):
   standard no-gather idiom, now fused into a single kernel instead of a
   chain of HLO ops with HBM-visible intermediates.
 
+The block contraction has ONE free dimension a side: the node one-hot and
+the V channel values are folded into a 2-D (rb, n_lv * V) operand
+(`_node_fold`), contracted with the (rb, F, B) one-hot of the codes as
+``rk,rfb->kfb`` (`_fold_contract`), and every scan and kernel carries its
+accumulator in that (n_lv * V, F, B) shape; `_unfold` makes the
+(F, n_lv, B, V) layout once a level from the small result. With node and
+channel as two free dimensions the TPU compiler lowered the channels to a
+padded 3-tap convolution window and the level-4 pass took twice the time
+(PERF.md section 6, PR 29; tests/test_chip_compile.py pins the lowering).
+
 Width-bucketed ``groups`` (engine.plan_hist_groups) are first-class: the
 per-group column gather is hoisted out of the block loop (Pallas kernels
 cannot close over constant index arrays, and the narrow coded gather is
@@ -40,29 +50,56 @@ from . import hist_backend, interpret_mode, pow2_block_rows
 # ---------------------------------------------------------------------------
 # shared per-block contributions — the ONE definition both backends execute
 # ---------------------------------------------------------------------------
-def _node_outer(l, vv, n_lv: int):
-    """(rb, n_lv, V) per-row channel values routed to the row's node slot —
-    an outer product against the node one-hot (exact: one 1.0 per row)."""
-    n_oh = jax.nn.one_hot(l, n_lv, dtype=jnp.float32)
-    return jnp.einsum("rn,rv->rnv", n_oh, vv)
+def _node_fold(l, vv, n_lv: int):
+    """(rb, n_lv * V) per-row channel values in the row's node columns,
+    zero elsewhere: column ``v * n_lv + n`` holds ``vv[:, v]`` where the row
+    sits in node ``n``. One masked copy of the node one-hot per channel,
+    concatenated — exact (a value or 0.0), and it fuses into the
+    contraction that reads it (PERF.md section 6, PR 29)."""
+    at_node = jax.nn.one_hot(l, n_lv, dtype=jnp.bool_)            # (rb, n_lv)
+    return jnp.concatenate(
+        [jnp.where(at_node, vv[:, v:v + 1], 0.0)
+         for v in range(vv.shape[1])], axis=1)
+
+
+def _fold_contract(a2, b_oh):
+    """The block's one-hot contracted over its rows with the folded node
+    values: (k, F, B), k = n_lv * V — one free dimension a side (the module
+    docstring says what two cost)."""
+    return jnp.einsum("rk,rfb->kfb", a2, b_oh)
+
+
+def _unfold(h, n_lv: int):
+    """A (k, F, B) accumulator in the (F, n_lv, B, V) layout the psum, the
+    split search and the grouped scatter-back read — once a level, on the
+    small result, never per block."""
+    k, F, B = h.shape
+    return h.reshape(k // n_lv, n_lv, F, B).transpose(2, 1, 3, 0)
+
+
+def _folded(mode: str, segsum_ok: bool) -> bool:
+    """Whether a width bucket accumulates in the contraction's own (k, Fg,
+    Bg) shape: every bucket but a ``segsum`` one that may segment-sum."""
+    return not (mode == "segsum" and segsum_ok)
 
 
 def _flat_contrib(xb, l, vv, n_lv: int, nbins_tot: int):
-    """One row block's (F, n_lv, B, V) contribution, flat bin space."""
+    """One row block's (n_lv * V, F, B) contribution, flat bin space."""
     # int8/int16 binned views upcast HERE, one block at a time in VMEM /
     # in-scan: the accumulate below always sees int32 (graftlint
     # narrow-int-accumulate pins the hazard), while HBM keeps 1-2 B/cell.
     xb = xb.astype(jnp.int32)
-    a = _node_outer(l, vv, n_lv)
     b_oh = jax.nn.one_hot(xb, nbins_tot, dtype=jnp.float32)   # (rb, F, B)
-    return jnp.einsum("rnv,rfb->fnbv", a, b_oh)
+    return _fold_contract(_node_fold(l, vv, n_lv), b_oh)
 
 
-def _one_group_contrib(xg, a, l, vv, Bg: int, mode: str, n_lv: int,
+def _one_group_contrib(xg, a2, l, vv, Bg: int, mode: str, n_lv: int,
                        na_global: int, segsum_ok: bool = True):
-    """One width bucket's (Fg, n_lv, Bg, V) block contribution. ``xg`` is
-    the group's already-gathered code block; the group NA bucket is its
-    last slot (global NA remaps here, scatter-back restores it).
+    """One width bucket's block contribution: (n_lv * V, Fg, Bg) from the
+    one-hot contraction, (Fg, n_lv, Bg, V) from the segment-sum (`_folded`
+    says which). ``xg`` is the group's already-gathered code block; the
+    group NA bucket is its last slot (global NA remaps here, scatter-back
+    restores it).
 
     ``segsum_ok`` gates the segment-sum formulation: the xla path and the
     INTERPRETED pallas path use it (and stay bit-equal to each other), but
@@ -74,7 +111,7 @@ def _one_group_contrib(xg, a, l, vv, Bg: int, mode: str, n_lv: int,
     xg = xg.astype(jnp.int32)
     Fg = xg.shape[1]
     xg = jnp.where(xg == na_global, Bg - 1, xg)
-    if mode == "segsum" and segsum_ok:
+    if not _folded(mode, segsum_ok):
         # narrow-bin path: at Bg ≪ the 128-lane MXU tile the one-hot
         # matmul is degenerate (mostly-padding tiles); a flat segment-sum
         # over (feature, node, bin) keys accumulates the same cells with
@@ -91,16 +128,29 @@ def _one_group_contrib(xg, a, l, vv, Bg: int, mode: str, n_lv: int,
             num_segments=Fg * n_lv * Bg)
         return h.reshape(Fg, n_lv, Bg, vv.shape[1])
     b_oh = jax.nn.one_hot(xg, Bg, dtype=jnp.float32)
-    return jnp.einsum("rnv,rfb->fnbv", a, b_oh)
+    return _fold_contract(a2, b_oh)
 
 
 def _group_contrib(xgs, l, vv, groups, n_lv: int, na_global: int,
                    segsum_ok: bool = True):
-    a = _node_outer(l, vv, n_lv)   # shared across onehot groups — exact
+    a2 = _node_fold(l, vv, n_lv)   # shared across onehot groups — exact
     return tuple(
-        _one_group_contrib(xg, a, l, vv, Bg, mode, n_lv, na_global,
+        _one_group_contrib(xg, a2, l, vv, Bg, mode, n_lv, na_global,
                            segsum_ok=segsum_ok)
         for xg, (_idxs, Bg, mode) in zip(xgs, groups))
+
+
+def _group_acc_shapes(groups, n_lv: int, V: int, segsum_ok: bool = True):
+    """Each width bucket's accumulator shape, as `_one_group_contrib`
+    returns it."""
+    return tuple((n_lv * V, len(idxs), Bg) if _folded(mode, segsum_ok)
+                 else (len(idxs), n_lv, Bg, V)
+                 for idxs, Bg, mode in groups)
+
+
+def _unfold_groups(hs, groups, n_lv: int, segsum_ok: bool = True):
+    return tuple(_unfold(h, n_lv) if _folded(mode, segsum_ok) else h
+                 for h, (_idxs, _Bg, mode) in zip(hs, groups))
 
 
 # ---------------------------------------------------------------------------
@@ -116,11 +166,11 @@ def _xla_flat(Xb, lc, vv, n_lv, nbins_tot, rb):
         xb, l, v = blk
         return acc + _flat_contrib(xb, l, v, n_lv, nbins_tot), None
 
-    init = jnp.zeros((F, n_lv, nbins_tot, V), dtype=jnp.float32)
+    init = jnp.zeros((n_lv * V, F, nbins_tot), dtype=jnp.float32)
     hist, _ = jax.lax.scan(body, init, (Xb.reshape(nblk, rb, F),
                                         lc.reshape(nblk, rb),
                                         vv.reshape(nblk, rb, V)))
-    return hist
+    return _unfold(hist, n_lv)
 
 
 @telemetry.scope("gbm.hist")
@@ -135,11 +185,11 @@ def _xla_grouped(xgs, lc, vv, groups, n_lv, na_global, rb):
         cs = _group_contrib(xg, l, v, groups, n_lv, na_global)
         return tuple(a + c for a, c in zip(accs, cs)), None
 
-    init = tuple(jnp.zeros((len(idxs), n_lv, Bg, V), jnp.float32)
-                 for idxs, Bg, _mode in groups)
+    init = tuple(jnp.zeros(shp, jnp.float32)
+                 for shp in _group_acc_shapes(groups, n_lv, V))
     hists, _ = jax.lax.scan(body, init, (lc.reshape(nblk, rb),
                                          vv.reshape(nblk, rb, V), *xgs_r))
-    return hists
+    return _unfold_groups(hists, groups, n_lv)
 
 
 # ---------------------------------------------------------------------------
@@ -167,18 +217,19 @@ def _pallas_flat(Xb, lc, vv, n_lv, nbins_tot, rb):
         _accum_out(out_ref, _flat_contrib(xb_ref[...], l_ref[..., 0],
                                           v_ref[...], n_lv, nbins_tot))
 
-    return pl.pallas_call(
+    hist = pl.pallas_call(
         kernel,
         grid=(nblk,),
         in_specs=[pl.BlockSpec((rb, F), lambda i: (i, 0)),
                   pl.BlockSpec((rb, 1), lambda i: (i, 0)),
                   pl.BlockSpec((rb, V), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((F, n_lv, nbins_tot, V),
-                               lambda i: (0, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((F, n_lv, nbins_tot, V),
+        out_specs=pl.BlockSpec((n_lv * V, F, nbins_tot),
+                               lambda i: (0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_lv * V, F, nbins_tot),
                                        jnp.float32),
         interpret=interpret_mode(),
     )(Xb, lc[:, None], vv)
+    return _unfold(hist, n_lv)
 
 
 def _pallas_grouped(xgs, lc, vv, groups, n_lv, na_global, rb):
@@ -186,31 +237,32 @@ def _pallas_grouped(xgs, lc, vv, groups, n_lv, na_global, rb):
     V = vv.shape[1]
     nblk = Rl // rb
     ng = len(groups)
-    interp = interpret_mode()
-    shapes = tuple(jax.ShapeDtypeStruct((len(idxs), n_lv, Bg, V),
-                                        jnp.float32)
-                   for idxs, Bg, _mode in groups)
+    interp = interpret_mode()   # segsum only interpreted: Mosaic has no scatter
+    shapes = tuple(jax.ShapeDtypeStruct(shp, jnp.float32)
+                   for shp in _group_acc_shapes(groups, n_lv, V, interp))
 
     def kernel(l_ref, v_ref, *refs):
         xg_refs, out_refs = refs[:ng], refs[ng:]
         cs = _group_contrib([x[...] for x in xg_refs], l_ref[..., 0],
                             v_ref[...], groups, n_lv, na_global,
-                            segsum_ok=interp)  # no scatter through Mosaic
+                            segsum_ok=interp)
         for o, c in zip(out_refs, cs):
             _accum_out(o, c)
 
-    return pl.pallas_call(
+    hists = pl.pallas_call(
         kernel,
         grid=(nblk,),
         in_specs=[pl.BlockSpec((rb, 1), lambda i: (i, 0)),
                   pl.BlockSpec((rb, V), lambda i: (i, 0))]
                  + [pl.BlockSpec((rb, xg.shape[1]), lambda i: (i, 0))
                     for xg in xgs],
-        out_specs=tuple(pl.BlockSpec(s.shape, lambda i: (0, 0, 0, 0))
+        out_specs=tuple(pl.BlockSpec(s.shape, lambda i, nd=len(s.shape):
+                                     (0,) * nd)
                         for s in shapes),
         out_shape=shapes,
-        interpret=interpret_mode(),
+        interpret=interp,
     )(lc[:, None], vv, *xgs)
+    return _unfold_groups(hists, groups, n_lv, interp)
 
 
 # ---------------------------------------------------------------------------
@@ -225,8 +277,8 @@ def level_hist_one_group(xg, lc, vv, *, Bg: int, mode: str, n_lv: int,
     next bucket's local accumulation. Bit-parity with the joint-scan path
     is by construction: the per-block contribution is the same
     `_one_group_contrib` over the same block contents in the same ascending
-    block order (the shared node outer product is recomputed per scan but
-    is an exact outer product — identical values either way)."""
+    block order (the shared folded node operand is recomputed per scan but
+    is exact, a value or 0.0 — identical either way)."""
     rb = pow2_block_rows(lc.shape[0], block)
     bk = backend or hist_backend()
     groups1 = ((tuple(range(xg.shape[1])), Bg, mode),)
@@ -260,7 +312,9 @@ def streamed_route_hist(Xb, node, vals, route_fn, *, offset: int, n_lv: int,
     Bit-parity with the two-pass shape is by construction: routing is
     integer/boolean work (any formulation that picks the same children is
     exact), and the histogram contributions are the same `_flat_contrib` /
-    `_group_contrib` over the same block contents in the same block order.
+    `_group_contrib` over the same block contents in the same block order,
+    carried in the contraction's own (n_lv * V, F, B) shape and laid out as
+    (F, n_lv, B, V) after the scan, as `_xla_flat` / `_xla_grouped` do.
     ``route_fn=None`` (level 0) skips the routing half."""
     Rl = Xb.shape[0]
     V = vals.shape[1]
@@ -286,15 +340,15 @@ def streamed_route_hist(Xb, node, vals, route_fn, *, offset: int, n_lv: int,
             cs = _group_contrib(xgs, lc, vz, groups, n_lv, nbins_tot - 1)
         return tuple(a + c for a, c in zip(accs, cs)), nd
 
-    if groups is None:
-        init = (jnp.zeros((Xb.shape[1], n_lv, nbins_tot, V), jnp.float32),)
-    else:
-        init = tuple(jnp.zeros((len(idxs), n_lv, Bg, V), jnp.float32)
-                     for idxs, Bg, _mode in groups)
+    # the flat accumulator is one one-hot bucket over all F columns
+    buckets = groups or ((range(Xb.shape[1]), nbins_tot, "onehot"),)
     accs, node_b = jax.lax.scan(
-        body, init, (Xb.reshape(nblk, rb, Xb.shape[1]),
-                     node.reshape(nblk, rb),
-                     vals.reshape(nblk, rb, V)))
+        body, tuple(jnp.zeros(shp, jnp.float32)
+                    for shp in _group_acc_shapes(buckets, n_lv, V)),
+        (Xb.reshape(nblk, rb, Xb.shape[1]),
+         node.reshape(nblk, rb),
+         vals.reshape(nblk, rb, V)))
+    accs = _unfold_groups(accs, buckets, n_lv)
     return accs, node_b.reshape(Rl)
 
 

@@ -154,6 +154,32 @@ def test_default_train_step_has_no_gather_on_the_code_block(
     assert not bad, [(op, shape_of[op]) for op in bad]
 
 
+def test_level_histograms_fold_node_and_statistic_into_one_dimension(
+        default_train_step):
+    """The level histogram contracts the block's one-hot with the node-routed
+    statistics folded into ONE free dimension (``rk,rfb->kfb``, k = n_lv * V).
+    With two (``rnv,rfb->fnbv``) the TPU compiler made the three statistics a
+    convolution window padded by two on each side (``size=1x3 pad=0_0x2_2``)
+    and the MXU's output width n_lv alone: 63,904 estimated cycles a block
+    over a tree's five levels (7,498 + 9,713 + 13,413 + 11,264 + 22,016)
+    against 36,995 folded. ``estimated_cycles`` is the compiler's own figure
+    in each fusion's ``backend_config`` (jax 0.9.0 / libtpu 0.0.34); times the
+    26,880 blocks of a 20-tree HIGGS job at 1.5 GHz it matched the chip to
+    three digits at levels 3 and 4 and within 20% at the others (PERF.md
+    section 7 no. 19), so it ranks formulations before a chip is used."""
+    hlo = default_train_step[1].as_text()
+    cycles = [int(c) for op, c in re.findall(
+        r'^.* fusion\(.*op_name="([^"]*)".*"estimated_cycles":"(\d+)"',
+        hlo, re.M) if "gbm.hist" in op and "dot_general" in op]
+    assert len(cycles) == 5, cycles              # one contraction a level
+    assert sum(cycles) < 45_000, cycles
+    windows = [w for w, op in re.findall(
+        r'^.* convolution\(.*window=\{([^}]*)\}.*op_name="([^"]*)"', hlo, re.M)
+        if "gbm.hist" in op]
+    assert len(windows) == 5, windows
+    assert not [w for w in windows if re.search(r"pad=0_0x\d+_\d+", w)], windows
+
+
 def test_default_train_step_moves_no_rows_between_chips(default_train_step):
     """Across four chips the train step reduces histograms and node totals,
     never rows. Left to GSPMD, a scan over row blocks of a row-sharded array
