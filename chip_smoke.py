@@ -103,7 +103,7 @@ def _environment(jax) -> None:
 
     import jaxlib
 
-    from h2o_tpu.backend import kernels, native
+    from h2o_tpu.backend import native
     from h2o_tpu.utils import compile_cache
 
     devs = jax.devices()
@@ -122,7 +122,6 @@ def _environment(jax) -> None:
             os.environ.get("JAX_COMPILATION_CACHE_DIR"),
         "cache_entries_at_start":
             len(os.listdir(cache_dir)) if cache_dir else 0,
-        "hist_backend": kernels.hist_backend(),
         "native_sort": "native" if native.lib() is not None else "numpy",
     }
     for k, v in env.items():

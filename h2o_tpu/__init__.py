@@ -1,6 +1,6 @@
 """h2o_tpu — a TPU-native distributed ML platform with the capabilities of H2O-3.
 
-From-scratch JAX/XLA/Pallas design (see SURVEY.md for the blueprint): frames are
+From-scratch JAX/XLA design (see SURVEY.md for the blueprint): frames are
 row-sharded JAX arrays over a device mesh, the MRTask compute driver is
 shard_map + XLA collectives, and algorithms (GBM/DRF, GLM, KMeans, PCA, ...) run
 their hot loops on the MXU.

@@ -1,54 +1,44 @@
-"""Fused level-histogram kernel — the ScoreBuildHistogram2 inner loop.
+"""Level-histogram accumulation — the ScoreBuildHistogram2 inner loop.
 
 One call accumulates, per shard, the (F, n_lv, B, V) channel histograms of
-one tree level from the chunk store's int8/int16 bin codes. Two backends
-share the per-block math (see the package docstring for the parity
-contract):
-
-- ``xla``: ``lax.scan`` over row blocks — the pre-kernels production path.
-- ``pallas``: ONE ``pl.pallas_call`` with the grid over row blocks; each
-  step DMAs a (rb, F) code block + (rb,) node ids + (rb, V) channel values
-  into VMEM, upcasts the sub-int32 codes there (the PR 2 discipline — the
-  narrow dtype exists only as an HBM storage format), and adds the block's
-  contribution into the VMEM-resident accumulator. The GPU tree-boosting
-  kernels (Booster / XGBoost gpu_hist) do exactly this with shared-memory
-  atomics; TPUs have no scatter unit, so the in-VMEM accumulate is
-  expressed as compare-mask contractions riding the MXU — the engine's
-  standard no-gather idiom, now fused into a single kernel instead of a
-  chain of HLO ops with HBM-visible intermediates.
+one tree level from the chunk store's int8/int16 bin codes: a ``lax.scan``
+over row blocks, each step upcasting its (rb, F) code block (the PR 2
+discipline — the narrow dtype exists only as an HBM storage format) and
+adding the block's contribution into the carried accumulator. The GPU
+tree-boosting kernels (Booster / XGBoost gpu_hist) do this with
+shared-memory atomics; TPUs have no scatter unit, so the accumulate is
+expressed as compare-mask contractions riding the MXU — the engine's
+standard no-gather idiom.
 
 The block contraction has ONE free dimension a side: the node one-hot and
 the V channel values are folded into a 2-D (rb, n_lv * V) operand
 (`_node_fold`), contracted with the (rb, F, B) one-hot of the codes as
-``rk,rfb->kfb`` (`_fold_contract`), and every scan and kernel carries its
-accumulator in that (n_lv * V, F, B) shape; `_unfold` makes the
-(F, n_lv, B, V) layout once a level from the small result. With node and
-channel as two free dimensions the TPU compiler lowered the channels to a
-padded 3-tap convolution window and the level-4 pass took twice the time
-(PERF.md section 6, PR 29; tests/test_chip_compile.py pins the lowering).
+``rk,rfb->kfb`` (`_fold_contract`), and every scan carries its accumulator
+in that (n_lv * V, F, B) shape; `_unfold` makes the (F, n_lv, B, V) layout
+once a level from the small result. With node and channel as two free
+dimensions the TPU compiler lowered the channels to a padded 3-tap
+convolution window and the level-4 pass took twice the time (PERF.md
+section 6, PR 29; tests/test_chip_compile.py pins the lowering).
 
 Width-bucketed ``groups`` (engine.plan_hist_groups) are first-class: the
-per-group column gather is hoisted out of the block loop (Pallas kernels
-cannot close over constant index arrays, and the narrow coded gather is
-cheap), each group accumulates at its own width, and ``mode="segsum"``
-groups keep their segment-sum formulation on the xla path while the kernel
-uses the same op under interpret — parity pinned either way. The caller
-(engine._build_level_hist) owns the psum and the grouped scatter-back;
-nothing in here touches a mesh axis.
+per-group column gather is hoisted out of the block loop (the narrow coded
+gather is cheap), each group accumulates at its own width, and
+``mode="segsum"`` groups accumulate by segment-sum instead of the one-hot
+contraction. The caller (engine._build_level_hist) owns the psum and the
+grouped scatter-back; nothing in here touches a mesh axis.
 """
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
 from ...utils import telemetry
-from . import hist_backend, interpret_mode, pow2_block_rows
+from . import pow2_block_rows
 
 
 # ---------------------------------------------------------------------------
-# shared per-block contributions — the ONE definition both backends execute
+# per-block contributions — the ONE definition every scan below executes
 # ---------------------------------------------------------------------------
 def _node_fold(l, vv, n_lv: int):
     """(rb, n_lv * V) per-row channel values in the row's node columns,
@@ -77,16 +67,16 @@ def _unfold(h, n_lv: int):
     return h.reshape(k // n_lv, n_lv, F, B).transpose(2, 1, 3, 0)
 
 
-def _folded(mode: str, segsum_ok: bool) -> bool:
+def _folded(mode: str) -> bool:
     """Whether a width bucket accumulates in the contraction's own (k, Fg,
-    Bg) shape: every bucket but a ``segsum`` one that may segment-sum."""
-    return not (mode == "segsum" and segsum_ok)
+    Bg) shape: every bucket but a ``segsum`` one."""
+    return mode != "segsum"
 
 
 def _flat_contrib(xb, l, vv, n_lv: int, nbins_tot: int):
     """One row block's (n_lv * V, F, B) contribution, flat bin space."""
-    # int8/int16 binned views upcast HERE, one block at a time in VMEM /
-    # in-scan: the accumulate below always sees int32 (graftlint
+    # int8/int16 binned views upcast HERE, one block at a time in-scan:
+    # the accumulate below always sees int32 (graftlint
     # narrow-int-accumulate pins the hazard), while HBM keeps 1-2 B/cell.
     xb = xb.astype(jnp.int32)
     b_oh = jax.nn.one_hot(xb, nbins_tot, dtype=jnp.float32)   # (rb, F, B)
@@ -94,31 +84,22 @@ def _flat_contrib(xb, l, vv, n_lv: int, nbins_tot: int):
 
 
 def _one_group_contrib(xg, a2, l, vv, Bg: int, mode: str, n_lv: int,
-                       na_global: int, segsum_ok: bool = True):
+                       na_global: int):
     """One width bucket's block contribution: (n_lv * V, Fg, Bg) from the
     one-hot contraction, (Fg, n_lv, Bg, V) from the segment-sum (`_folded`
     says which). ``xg`` is the group's already-gathered code block; the
     group NA bucket is its last slot (global NA remaps here, scatter-back
-    restores it).
-
-    ``segsum_ok`` gates the segment-sum formulation: the xla path and the
-    INTERPRETED pallas path use it (and stay bit-equal to each other), but
-    a Mosaic-COMPILED kernel body must not — segment_sum is a scatter, and
-    the TPU has no scatter unit to lower it onto, so on-chip the narrow
-    groups fall back to the compare-mask contraction (value-equivalent;
-    on-chip parity vs the on-chip xla path is the ROADMAP's real-v5e
-    measurement)."""
+    restores it)."""
     xg = xg.astype(jnp.int32)
     Fg = xg.shape[1]
     xg = jnp.where(xg == na_global, Bg - 1, xg)
-    if not _folded(mode, segsum_ok):
+    if not _folded(mode):
         # narrow-bin path: at Bg ≪ the 128-lane MXU tile the one-hot
         # matmul is degenerate (mostly-padding tiles); a flat segment-sum
         # over (feature, node, bin) keys accumulates the same cells with
         # no one-hot at all (and in pure f32 adds — the matmul path rounds
         # each contribution through bf16 on TPU, so this path is the
-        # *more* exact of the two). broadcasted_iota, not arange: a Pallas
-        # kernel body may not close over constant arrays.
+        # *more* exact of the two).
         fi = jax.lax.broadcasted_iota(jnp.int32, (1, Fg), 1)
         seg = (fi * n_lv + l[:, None]) * Bg + xg              # (rb, Fg)
         data = jnp.broadcast_to(vv[:, None, :],
@@ -131,33 +112,31 @@ def _one_group_contrib(xg, a2, l, vv, Bg: int, mode: str, n_lv: int,
     return _fold_contract(a2, b_oh)
 
 
-def _group_contrib(xgs, l, vv, groups, n_lv: int, na_global: int,
-                   segsum_ok: bool = True):
+def _group_contrib(xgs, l, vv, groups, n_lv: int, na_global: int):
     a2 = _node_fold(l, vv, n_lv)   # shared across onehot groups — exact
     return tuple(
-        _one_group_contrib(xg, a2, l, vv, Bg, mode, n_lv, na_global,
-                           segsum_ok=segsum_ok)
+        _one_group_contrib(xg, a2, l, vv, Bg, mode, n_lv, na_global)
         for xg, (_idxs, Bg, mode) in zip(xgs, groups))
 
 
-def _group_acc_shapes(groups, n_lv: int, V: int, segsum_ok: bool = True):
+def _group_acc_shapes(groups, n_lv: int, V: int):
     """Each width bucket's accumulator shape, as `_one_group_contrib`
     returns it."""
-    return tuple((n_lv * V, len(idxs), Bg) if _folded(mode, segsum_ok)
+    return tuple((n_lv * V, len(idxs), Bg) if _folded(mode)
                  else (len(idxs), n_lv, Bg, V)
                  for idxs, Bg, mode in groups)
 
 
-def _unfold_groups(hs, groups, n_lv: int, segsum_ok: bool = True):
-    return tuple(_unfold(h, n_lv) if _folded(mode, segsum_ok) else h
+def _unfold_groups(hs, groups, n_lv: int):
+    return tuple(_unfold(h, n_lv) if _folded(mode) else h
                  for h, (_idxs, _Bg, mode) in zip(hs, groups))
 
 
 # ---------------------------------------------------------------------------
-# xla backend — blocked lax.scan (the oracle)
+# the blocked lax.scan over row blocks
 # ---------------------------------------------------------------------------
 @telemetry.scope("gbm.hist")
-def _xla_flat(Xb, lc, vv, n_lv, nbins_tot, rb):
+def _scan_flat(Xb, lc, vv, n_lv, nbins_tot, rb):
     Rl, F = Xb.shape
     V = vv.shape[1]
     nblk = Rl // rb
@@ -174,7 +153,7 @@ def _xla_flat(Xb, lc, vv, n_lv, nbins_tot, rb):
 
 
 @telemetry.scope("gbm.hist")
-def _xla_grouped(xgs, lc, vv, groups, n_lv, na_global, rb):
+def _scan_grouped(xgs, lc, vv, groups, n_lv, na_global, rb):
     Rl = lc.shape[0]
     V = vv.shape[1]
     nblk = Rl // rb
@@ -193,84 +172,10 @@ def _xla_grouped(xgs, lc, vv, groups, n_lv, na_global, rb):
 
 
 # ---------------------------------------------------------------------------
-# pallas backend — one fused kernel, grid over row blocks
-# ---------------------------------------------------------------------------
-def _accum_out(out_ref, contrib):
-    """Zero-on-first-step accumulate into a grid-revisited VMEM output."""
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _():
-        out_ref[...] = contrib
-
-    @pl.when(i != 0)
-    def _():
-        out_ref[...] = out_ref[...] + contrib
-
-
-def _pallas_flat(Xb, lc, vv, n_lv, nbins_tot, rb):
-    Rl, F = Xb.shape
-    V = vv.shape[1]
-    nblk = Rl // rb
-
-    def kernel(xb_ref, l_ref, v_ref, out_ref):
-        _accum_out(out_ref, _flat_contrib(xb_ref[...], l_ref[..., 0],
-                                          v_ref[...], n_lv, nbins_tot))
-
-    hist = pl.pallas_call(
-        kernel,
-        grid=(nblk,),
-        in_specs=[pl.BlockSpec((rb, F), lambda i: (i, 0)),
-                  pl.BlockSpec((rb, 1), lambda i: (i, 0)),
-                  pl.BlockSpec((rb, V), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((n_lv * V, F, nbins_tot),
-                               lambda i: (0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_lv * V, F, nbins_tot),
-                                       jnp.float32),
-        interpret=interpret_mode(),
-    )(Xb, lc[:, None], vv)
-    return _unfold(hist, n_lv)
-
-
-def _pallas_grouped(xgs, lc, vv, groups, n_lv, na_global, rb):
-    Rl = lc.shape[0]
-    V = vv.shape[1]
-    nblk = Rl // rb
-    ng = len(groups)
-    interp = interpret_mode()   # segsum only interpreted: Mosaic has no scatter
-    shapes = tuple(jax.ShapeDtypeStruct(shp, jnp.float32)
-                   for shp in _group_acc_shapes(groups, n_lv, V, interp))
-
-    def kernel(l_ref, v_ref, *refs):
-        xg_refs, out_refs = refs[:ng], refs[ng:]
-        cs = _group_contrib([x[...] for x in xg_refs], l_ref[..., 0],
-                            v_ref[...], groups, n_lv, na_global,
-                            segsum_ok=interp)
-        for o, c in zip(out_refs, cs):
-            _accum_out(o, c)
-
-    hists = pl.pallas_call(
-        kernel,
-        grid=(nblk,),
-        in_specs=[pl.BlockSpec((rb, 1), lambda i: (i, 0)),
-                  pl.BlockSpec((rb, V), lambda i: (i, 0))]
-                 + [pl.BlockSpec((rb, xg.shape[1]), lambda i: (i, 0))
-                    for xg in xgs],
-        out_specs=tuple(pl.BlockSpec(s.shape, lambda i, nd=len(s.shape):
-                                     (0,) * nd)
-                        for s in shapes),
-        out_shape=shapes,
-        interpret=interp,
-    )(lc[:, None], vv, *xgs)
-    return _unfold_groups(hists, groups, n_lv, interp)
-
-
-# ---------------------------------------------------------------------------
 # public entry — what engine._build_level_hist calls
 # ---------------------------------------------------------------------------
 def level_hist_one_group(xg, lc, vv, *, Bg: int, mode: str, n_lv: int,
-                         nbins_tot: int, block: int,
-                         backend: str | None = None):
+                         nbins_tot: int, block: int):
     """ONE width bucket accumulated in its own scan — the async-psum shape:
     the caller issues this group's psum immediately after, BEFORE tracing
     the next group's scan, so on a real ICI the collective overlaps the
@@ -280,10 +185,8 @@ def level_hist_one_group(xg, lc, vv, *, Bg: int, mode: str, n_lv: int,
     block order (the shared folded node operand is recomputed per scan but
     is exact, a value or 0.0 — identical either way)."""
     rb = pow2_block_rows(lc.shape[0], block)
-    bk = backend or hist_backend()
     groups1 = ((tuple(range(xg.shape[1])), Bg, mode),)
-    fn = _pallas_grouped if bk == "pallas" else _xla_grouped
-    return fn([xg], lc, vv, groups1, n_lv, nbins_tot - 1, rb)[0]
+    return _scan_grouped([xg], lc, vv, groups1, n_lv, nbins_tot - 1, rb)[0]
 
 
 @telemetry.scope("gbm.hist")
@@ -314,7 +217,7 @@ def streamed_route_hist(Xb, node, vals, route_fn, *, offset: int, n_lv: int,
     exact), and the histogram contributions are the same `_flat_contrib` /
     `_group_contrib` over the same block contents in the same block order,
     carried in the contraction's own (n_lv * V, F, B) shape and laid out as
-    (F, n_lv, B, V) after the scan, as `_xla_flat` / `_xla_grouped` do.
+    (F, n_lv, B, V) after the scan, as `_scan_flat` / `_scan_grouped` do.
     ``route_fn=None`` (level 0) skips the routing half."""
     Rl = Xb.shape[0]
     V = vals.shape[1]
@@ -353,7 +256,7 @@ def streamed_route_hist(Xb, node, vals, route_fn, *, offset: int, n_lv: int,
 
 
 def level_hist_blocks(Xb, lc, vv, *, n_lv: int, nbins_tot: int, block: int,
-                      groups=None, backend: str | None = None):
+                      groups=None):
     """Per-shard level-histogram accumulation over row blocks.
 
     ``Xb`` (Rl, F) int8/int16/int32 bin codes; ``lc`` (Rl,) int32 LOCAL
@@ -364,15 +267,10 @@ def level_hist_blocks(Xb, lc, vv, *, n_lv: int, nbins_tot: int, block: int,
     bucket in its LAST slot. No collectives — the caller psums.
     """
     rb = pow2_block_rows(Xb.shape[0], block)
-    bk = backend or hist_backend()
     if groups is None:
-        fn = _pallas_flat if bk == "pallas" else _xla_flat
-        return fn(Xb, lc, vv, n_lv, nbins_tot, rb)
-    na_global = nbins_tot - 1
+        return _scan_flat(Xb, lc, vv, n_lv, nbins_tot, rb)
     # the per-group column gather hoists out of the block loop: values are
-    # identical either way (int codes gather exactly), the Pallas kernel
-    # cannot close over the constant index arrays, and the gathered narrow
-    # views total exactly Xb's bytes
+    # identical either way (int codes gather exactly), and the gathered
+    # narrow views total exactly Xb's bytes
     xgs = [Xb[:, list(idxs)] for idxs, _Bg, _mode in groups]
-    fn = _pallas_grouped if bk == "pallas" else _xla_grouped
-    return fn(xgs, lc, vv, groups, n_lv, na_global, rb)
+    return _scan_grouped(xgs, lc, vv, groups, n_lv, nbins_tot - 1, rb)

@@ -622,12 +622,10 @@ class GBM(ModelBuilder):
             cfg = dataclasses.replace(cfg, huber_leaf_alpha=p.huber_alpha)
         # async pipelined training knobs (ISSUE 12): the pipelined level
         # program and the overlapped reduction are BIT-equal to the
-        # synchronous oracle, so they default on; GOSS changes the forest
-        # (it is a sampler) and defaults off
+        # synchronous oracle, so they default on
         cfg = dataclasses.replace(
             cfg, pipeline=get_bool("H2O_TPU_PIPELINE"),
-            async_psum=get_bool("H2O_TPU_ASYNC_PSUM"),
-            goss=self._goss_config(K))
+            async_psum=get_bool("H2O_TPU_ASYNC_PSUM"))
         # the cache key must pin everything grad_fn's behavior depends on;
         # custom distribution UDFs bypass the cache entirely (an id()-based
         # key could alias a new UDF at a recycled address after GC)
@@ -651,39 +649,6 @@ class GBM(ModelBuilder):
             f0=f0, grad_fn=grad_fn, cfg=cfg, grad_key=grad_key, y_k=y_k,
             f=f, iscat_dev=iscat_dev, nedges_dev=nedges_dev,
             nedges_np=nedges_np, binned_view=binned_view)
-
-    def _goss_config(self, K: int):
-        """Parse H2O_TPU_GOSS into cfg.goss — (a, b) fractions, or None.
-
-        A malformed spec fails loudly (the knobs discipline); a valid spec
-        on an ineligible build (multinomial's per-class gradients, DRF's
-        bagging-not-boosting, quantile/huber's full-row residual leaves)
-        logs and trains unsampled rather than failing a job over a global
-        env knob."""
-        from ..utils.knobs import get_str
-
-        raw = (get_str("H2O_TPU_GOSS") or "").strip()
-        if not raw:
-            return None
-        try:
-            a_s, b_s = raw.split(",")
-            a, b = float(a_s), float(b_s)
-        except ValueError:
-            raise ValueError(f"H2O_TPU_GOSS={raw!r} — expected two "
-                             f"fractions 'a,b' (e.g. 0.2,0.1)")
-        if not (0.0 <= a and 0.0 < b and a + b <= 1.0):
-            raise ValueError(f"H2O_TPU_GOSS={raw!r} — need a >= 0, b > 0 "
-                             f"and a + b <= 1")
-        if (K > 1 or self.drf_mode
-                or getattr(self.params, "distribution", None) in
-                ("laplace", "quantile", "huber")):
-            from ..utils.log import info
-
-            info("H2O_TPU_GOSS set but this build is ineligible "
-                 "(multinomial / DRF / quantile-family leaves) — training "
-                 "with full rows")
-            return None
-        return (a, b)
 
     def build_impl(self, job: Job) -> GBMModel:
         rs = self._take_resume_state()
@@ -858,10 +823,8 @@ class GBM(ModelBuilder):
         # persistent-cache replay is measured at one attributable site
         train_step = None
         if chunks and grad_key is not None:
-            from ..backend.kernels import hist_backend
-
             aot_key = (dataclasses.replace(cfg, ntrees=interval), grad_key,
-                       id(mesh), hist_backend(), donate_f)
+                       id(mesh), donate_f)
             # a compile error surfaces HERE, once: the jitted twin would
             # hand the same program to the same compiler and fail again
             train_step = _aot_train_step(

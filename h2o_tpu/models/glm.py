@@ -210,11 +210,10 @@ def _row_shardable(X, mesh) -> bool:
 def _make_irls_kernel(family: Family):
     """One GLMIterationTask: (X, y, w, beta, offset) -> (Gram, XWz, dev, neff).
 
-    X is row-sharded; the Gram/XWz accumulation routes through the fused
+    X is row-sharded; the Gram/XWz accumulation routes through the
     kernels layer (`backend/kernels/gram.py`): XᵀWX and XᵀWz accumulate in
-    ONE pass over row blocks — the (R, P) weighted design never
-    materializes — executed as the blocked-scan oracle or the fused Pallas
-    kernel per ``H2O_TPU_HIST_KERNEL``.
+    ONE blocked scan over row blocks — the (R, P) weighted design never
+    materializes.
 
     Dispatch is the DrJAX MapReduce shape on a multi-shard mesh: the whole
     step runs inside ``mesh.shard_map`` over the ``rows`` axis — each

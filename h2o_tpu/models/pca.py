@@ -58,28 +58,21 @@ def _transform_info(transform: str):
     return demean, descale
 
 
-_GRAM_KERNEL_CACHE: dict = {}
-
-
-def _gram_kernel(X, wmask):
-    """Masked Gram through the fused kernels layer: ``wmask`` is a 0/1 row
-    mask (w² == w), so the single-application weighted Gram Xᵀdiag(w)X
-    equals the historic (X·w)ᵀ(X·w) — accumulated in one blocked pass
+def kernel(X, wmask):
+    """Masked Gram through the kernels layer: ``wmask`` is a 0/1 row mask
+    (w² == w), so the single-application weighted Gram Xᵀdiag(w)X equals
+    the historic (X·w)ᵀ(X·w) — accumulated in one blocked pass
     (backend/kernels/gram.py) with the (R, P) masked copy never
-    materialized. The jit cache is keyed on the resolved kernels backend
-    (gram_accumulate reads the H2O_TPU_HIST_KERNEL knob at trace time — a
-    module-level @jax.jit would freeze whichever backend traced first)."""
-    from ..backend.kernels import gram as gram_kernels, hist_backend
+    materialized."""
+    from ..backend.kernels import gram as gram_kernels
 
-    bk = hist_backend()
-    fn = _GRAM_KERNEL_CACHE.get(bk)
-    if fn is None:
-        def kernel(X, wmask, _bk=bk):
-            G, _ = gram_kernels.gram_accumulate(X, wmask, backend=_bk)
-            return G, jnp.sum(wmask)
+    G, _ = gram_kernels.gram_accumulate(X, wmask)
+    return G, jnp.sum(wmask)
 
-        fn = _GRAM_KERNEL_CACHE.setdefault(bk, jax.jit(kernel))
-    return fn(X, wmask)
+
+#: jitted under the function name the compile cache knows the program by
+#: (XLA module ``jit_kernel``)
+_gram_kernel = jax.jit(kernel)
 
 
 def _gram_svd(X, wmask, k):

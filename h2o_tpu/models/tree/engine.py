@@ -137,25 +137,13 @@ class TreeConfig:
                                    # signature (extra ntrees-done scalar
                                    # arg + extra output) — see
                                    # make_train_fn.
-    goss: tuple | None = None      # (a, b) GOSS-style gradient-based row
-                                   # sampling: per shard, the top-a
-                                   # fraction of rows by |gradient| plus a
-                                   # uniform b fraction of the rest (their
-                                   # channels amplified by (1-a)/b) feed
-                                   # the histogram and leaf accumulations;
-                                   # routing and the carried margin still
-                                   # cover every row. Deterministic under
-                                   # the train seed (keys fold from the
-                                   # per-tree row key). None = off.
-
     @property
     def n_nodes(self) -> int:
         return 2 ** (self.max_depth + 1) - 1
 
 
-#: the row-block sizer now lives with the kernels layer (both backends of
-#: every blocked accumulation share it); this alias keeps the engine's
-#: historic call sites
+#: the row-block sizer lives with the kernels layer (every blocked
+#: accumulation shares it); this alias keeps the engine's call sites
 _block_rows = kernels.pow2_block_rows
 
 
@@ -265,9 +253,8 @@ def _build_level_hist(Xb, node, vals, offset, n_lv, nbins_tot, block,
     stays at ``nbins_tot - 1``. `plan_hist_groups` builds the partition.
 
     The blocked accumulation itself lives in `backend/kernels/hist.py`
-    (one shared per-block math, executed either as the historic lax.scan
-    or as a fused Pallas kernel per ``H2O_TPU_HIST_KERNEL``); this
-    function keeps the mesh concerns — node localization, the per-group
+    (one per-block math under a blocked ``lax.scan``); this function keeps
+    the mesh concerns — node localization, the per-group
     psum, and the scatter-back into the global bin layout.
     """
     F = Xb.shape[1]
@@ -366,9 +353,7 @@ def _route_rows(xb_blk, node_blk, route_args, cfg: "TreeConfig"):
 @telemetry.scope("gbm.route")
 def _route_all(Xb, node, route_args, cfg: "TreeConfig"):
     """Blocked standalone routing pass (`_route_rows` per block) — the
-    pipelined path's final route after the last level's splits, and the
-    route half when the fused stream does not apply (GOSS rows, pallas
-    backend)."""
+    pipelined path's final route after the last level's splits."""
     Rl, F = Xb.shape
     rb = _block_rows(Rl, cfg.block_rows)
     _, node_b = jax.lax.scan(
@@ -378,43 +363,22 @@ def _route_all(Xb, node, route_args, cfg: "TreeConfig"):
 
 
 def _pipelined_level_hist(Xb, node, vals3, route_args, offset, n_lv,
-                          nbins_tot, cfg: "TreeConfig", goss_ctx=None):
+                          nbins_tot, cfg: "TreeConfig"):
     """One pipelined level: advance ``node`` off the previous level's
     splits and accumulate this level's histogram, returning ``(hist,
     node)`` with ``hist`` already psummed and scattered back into the
     global (F, n_lv, B, V) layout — the drop-in replacement for the
     synchronous route-then-`_build_level_hist` pair.
 
-    Default shape: ONE streamed pass per level (`kernels.hist.
-    streamed_route_hist`) — each row block is decoded once, routed, and
-    accumulated while the next block streams in. With ``cfg.async_psum``
-    and a grouped plan, the stream carries the routing plus the FIRST
-    width bucket and issues its psum before the remaining buckets' scans
-    are traced (collective overlaps local accumulation); with async off,
-    all buckets ride the single stream and psum after (the PR 10 shape).
-    GOSS rows (``goss_ctx``) and the pallas backend split the pass back
-    into route + hist halves — the histogram then runs over the sampled
-    row set / inside the Mosaic kernel respectively."""
-    from ...backend import kernels
-
+    ONE streamed pass per level (`kernels.hist.streamed_route_hist`) —
+    each row block is decoded once, routed, and accumulated while the next
+    block streams in. With ``cfg.async_psum`` and a grouped plan, the
+    stream carries the routing plus the FIRST width bucket and issues its
+    psum before the remaining buckets' scans are traced (collective
+    overlaps local accumulation); with async off, all buckets ride the
+    single stream and psum after (the PR 10 shape)."""
     F = Xb.shape[1]
     groups = _norm_groups(cfg.hist_groups) if cfg.hist_groups else None
-
-    if goss_ctx is not None or kernels.hist_backend() == "pallas":
-        if route_args is not None:
-            node = _route_all(Xb, node, route_args, cfg)
-        if goss_ctx is not None:
-            Xb_s, take, vals_s = goss_ctx
-            hist = _build_level_hist(Xb_s, jnp.take(node, take), vals_s,
-                                     offset, n_lv, nbins_tot,
-                                     cfg.block_rows, groups=cfg.hist_groups,
-                                     async_psum=cfg.async_psum)
-        else:
-            hist = _build_level_hist(Xb, node, vals3, offset, n_lv,
-                                     nbins_tot, cfg.block_rows,
-                                     groups=cfg.hist_groups,
-                                     async_psum=cfg.async_psum)
-        return hist, node
 
     route_fn = (None if route_args is None
                 else lambda xb, nd: _route_rows(xb, nd, route_args, cfg))
@@ -689,7 +653,7 @@ def _find_splits(hist, colmask, edge_ok, cfg: TreeConfig, mono=None,
 # ---------------------------------------------------------------------------
 def _grow_tree(Xb, g, h, w, edges, edge_ok, colkey, cfg: TreeConfig,
                mono=None, imat=None, resid=None, w_full=None,
-               iscat=None, nedges=None, goss_ctx=None):
+               iscat=None, nedges=None):
     """Returns (feat (N,), thr (N,), nanL (N,), val (N,), gain (N,),
     catd (N, nb|1), node (Rl,)).
 
@@ -735,14 +699,7 @@ def _grow_tree(Xb, g, h, w, edges, edge_ok, colkey, cfg: TreeConfig,
         offset = n_lv - 1
         if cfg.pipeline:
             hist, node = _pipelined_level_hist(Xb, node, vals3, route_args,
-                                               offset, n_lv, B, cfg,
-                                               goss_ctx=goss_ctx)
-        elif goss_ctx is not None:
-            Xb_s, take, vals_s = goss_ctx
-            hist = _build_level_hist(Xb_s, jnp.take(node, take), vals_s,
-                                     offset, n_lv, B, cfg.block_rows,
-                                     groups=cfg.hist_groups,
-                                     async_psum=cfg.async_psum)
+                                               offset, n_lv, B, cfg)
         else:
             hist = _build_level_hist(Xb, node, vals3, offset, n_lv, B,
                                      cfg.block_rows, groups=cfg.hist_groups,
@@ -871,15 +828,7 @@ def _grow_tree(Xb, g, h, w, edges, edge_ok, colkey, cfg: TreeConfig,
 
     # Leaf/stop-node values from one final per-node accumulation (covers both
     # max-depth leaves and early-stopped internal nodes).
-    if goss_ctx is not None:
-        # GOSS leaf stats come from the sampled rows with the standard
-        # amplification weights (LightGBM's estimator) — the same channel
-        # sums the split search consumed
-        _Xb_s, take_g, vals_s = goss_ctx
-        tot = _node_totals(jnp.take(node, take_g), vals_s, N,
-                           cfg.block_rows)
-    else:
-        tot = _node_totals(node, vals3, N, cfg.block_rows)
+    tot = _node_totals(node, vals3, N, cfg.block_rows)
     scale = 1.0 if cfg.drf_mode else cfg.learn_rate
     if cfg.huber_leaf_alpha is not None and resid is not None:
         # huber hybrid gamma (`GBM.java:685`): per-leaf median, then the
@@ -989,13 +938,9 @@ def make_train_fn(cfg: TreeConfig, grad_fn: Callable, mesh=None,
     donation pins cover it at runtime.
     """
     mesh = mesh or default_mesh()
-    # the kernels backend is resolved at TRACE time (kernels.hist_backend
-    # reads the H2O_TPU_HIST_KERNEL knob), so a cached program compiled
-    # under one backend must never serve a process that flipped the knob
     full_key = None
     if cache_key is not None:
-        full_key = (cfg, cache_key, id(mesh), kernels.hist_backend(),
-                    donate)
+        full_key = (cfg, cache_key, id(mesh), donate)
         hit = _TRAIN_FN_CACHE.get(full_key)
         if hit is not None:
             return hit
@@ -1049,38 +994,10 @@ def make_train_fn(cfg: TreeConfig, grad_fn: Callable, mesh=None,
                 resid = ((y - f) if (cfg.leaf_quantile is not None or
                                      cfg.huber_leaf_alpha is not None)
                          else None)
-                goss_ctx = None
-                if cfg.goss is not None:
-                    # GOSS-style sampling (`PAPERS.md: XGBoost gpu_hist` /
-                    # LightGBM GOSS): per shard, keep the top-a rows by
-                    # |gradient| plus a uniform b of the rest, the latter
-                    # amplified by (1-a)/b; histogram and leaf passes then
-                    # touch ~(a+b)·R rows while routing/margins stay full.
-                    # Static shapes: the sample size is padded to a 256
-                    # multiple, pad slots carry zero weight.
-                    a_frac, b_frac = cfg.goss
-                    Rl = w.shape[-1]
-                    na = int(round(a_frac * Rl))
-                    n_sel = max(min(na + int(round(b_frac * Rl)), Rl), 1)
-                    n_pad = min(-(-n_sel // 256) * 256, Rl)
-                    gk = jax.random.fold_in(rowkey, 101)
-                    ag = jnp.abs(g * s)
-                    rank = jnp.argsort(jnp.argsort(-ag, stable=True),
-                                       stable=True)
-                    topmask = rank < na
-                    prio = jnp.where(topmask, -1.0,
-                                     jax.random.uniform(gk, (Rl,)))
-                    take = jnp.argsort(prio, stable=True)[:n_pad]
-                    amp = jnp.where(jnp.take(topmask, take), 1.0,
-                                    (1.0 - a_frac) / b_frac)
-                    amp = amp * (jnp.arange(n_pad) < n_sel)
-                    vals_s = (jnp.take(jnp.stack([w * s, g * s, h * s], 1),
-                                       take, axis=0) * amp[:, None])
-                    goss_ctx = (jnp.take(Xb, take, axis=0), take, vals_s)
                 ft, th, nl, vl, ga, cd, node = _grow_tree(
                     Xb, g * s, h * s, w * s, edges, edge_ok, key, cfg,
                     mono_arg, imat_arg, resid, w_full=w,
-                    iscat=iscat_arg, nedges=nedges_arg, goss_ctx=goss_ctx)
+                    iscat=iscat_arg, nedges=nedges_arg)
                 vl = scale_leaves(vl)
                 delta = leaf_delta(vl, node)
             else:

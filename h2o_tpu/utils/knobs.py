@@ -102,9 +102,8 @@ _knob("H2O_TPU_EXACT_BIN_ROWS", "int", 16384,
       "rows at or below which tree binning may use exact small-data cuts")
 _knob("H2O_TPU_HIST_SEG_WIDTH", "int", 8,
       "bin widths at/below this accumulate via segment-sum instead of the "
-      "one-hot matmul in the histogram scan (0 disables the path); also "
-      "bounds the widest VMEM accumulator slab a narrow group hands the "
-      "pallas hist kernel (backend/kernels/hist.py)")
+      "one-hot matmul in the histogram scan (0 disables the path; "
+      "backend/kernels/hist.py)")
 _knob("H2O_TPU_PIPELINE", "bool", True,
       "async pipelined GBM/DRF level program: route->hist fused into one "
       "streamed pass per row block, routing by integer selects over the "
@@ -117,18 +116,6 @@ _knob("H2O_TPU_ASYNC_PSUM", "bool", True,
       "psum is issued before the next bucket's local scan so the "
       "collective hides under compute; 0 reverts to the PR 10 shape "
       "(one joint scan, psums after). Bit-equal either way")
-_knob("H2O_TPU_GOSS", "str", "",
-      "GOSS-style gradient-based row sampling for GBM, 'a,b' fractions "
-      "(e.g. 0.2,0.1): per shard the top-a rows by |gradient| plus a "
-      "uniform b of the rest (amplified by (1-a)/b) feed the histogram "
-      "and leaf passes. Deterministic under the train seed; changes the "
-      "forest (a sampler, not an oracle-parity mode); empty = off")
-_knob("H2O_TPU_HIST_KERNEL", "str", "auto",
-      "kernels-layer backend for the level-histogram and Gram "
-      "accumulations (backend/kernels/): 'xla' = the blocked lax.scan "
-      "(what 'auto' resolves to on every backend), 'pallas' = fused "
-      "pl.pallas_call — interpreted off-TPU; on TPU it goes to Mosaic and "
-      "a refused kernel raises, nothing substitutes the scan")
 _knob("H2O_TPU_CLEAR_CACHES_EVERY", "int", 64,
       "drop live XLA executables every N models (long-server hygiene; "
       "0 = never)")

@@ -35,6 +35,21 @@ def cloud():
     yield
 
 
+@pytest.fixture(scope="session")
+def worker_port():
+    """``worker_port(base)``: a test module's fixed port, moved by 1000 for
+    each xdist worker (``PYTEST_XDIST_WORKER`` = gw0, gw1, ...). Under
+    ``-n 6 --dist load`` the tests of one module are dealt to several
+    workers, and ``h2o.init(port=N)`` connects to whatever listens at N
+    before it boots a server of its own: with one port a module, the second
+    worker's REST calls (and its ``h2o.shutdown()``) land in the first
+    worker's process. The modules' bases all lie in 54555-54990, so a step
+    of 1000 keeps every (module, worker) pair apart."""
+    worker = os.environ.get("PYTEST_XDIST_WORKER", "")
+    step = 1000 * int(worker[2:]) if worker[2:].isdigit() else 0
+    return lambda base: base + step
+
+
 @pytest.fixture(autouse=True, scope="module")
 def _bound_compile_state():
     """Full-suite stability: hundreds of XLA CPU compilations in one process
@@ -114,8 +129,8 @@ def pytest_configure(config):
                    "tracing, /3/Metrics + /3/Timeline surface (pytest -m "
                    "telemetry, utils/telemetry.py)")
     config.addinivalue_line(
-        "markers", "kernels: Pallas histogram/Gram kernels vs the XLA "
-                   "oracle — bit-parity suite + cold-start compile cache "
+        "markers", "kernels: blocked-scan histogram/Gram accumulations vs "
+                   "float64 references + cold-start compile cache "
                    "(pytest -m kernels, h2o_tpu/backend/kernels/)")
     config.addinivalue_line(
         "markers", "sharded: multi-chip sharded frames — sharded-vs-"
@@ -125,7 +140,7 @@ def pytest_configure(config):
     config.addinivalue_line(
         "markers", "pipeline: async pipelined GBM training — pipelined-"
                    "vs-synchronous bit parity across the knob matrix, "
-                   "GOSS sampling, donated-margin chunk dispatch "
+                   "donated-margin chunk dispatch "
                    "(pytest -m pipeline, tests/test_pipeline.py)")
     config.addinivalue_line(
         "markers", "fleetobs: fleet observability plane — program cost "

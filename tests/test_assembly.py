@@ -10,8 +10,8 @@ from h2o_tpu.api.assembly import (H2OAssembly, H2OBinaryOp, H2OColOp,
 
 
 @pytest.fixture(scope="module")
-def cloud():
-    conn = h2o.init(port=54700)
+def cloud(worker_port):
+    conn = h2o.init(port=worker_port(54700))
     yield conn
     try:
         h2o.shutdown()

@@ -730,10 +730,10 @@ class TestHealth:
 # /3/Timeline?since, wire propagation through a real socket
 # ---------------------------------------------------------------------------
 @pytest.fixture(scope="module")
-def cloud():
+def cloud(worker_port):
     import h2o_tpu.api as h2o
 
-    conn = h2o.init(port=54791)
+    conn = h2o.init(port=worker_port(54791))
     yield conn
     try:
         h2o.shutdown()

@@ -3,8 +3,8 @@
 ``jax.experimental.topologies`` gives a compile-only TPU v5e target from the
 installed libtpu, without a chip: what default settings select on a TPU must
 COMPILE for it. These run (never skip) on the CPU sandbox — they are the tests
-that would have caught a default kernel Mosaic refuses (PR 9's Pallas
-histogram had only ever run under ``interpret=True``).
+that catch a default program the TPU compiler refuses, or compiles into
+something no chip should run, before a chip is used.
 
 Shapes are the HIGGS configurations': 11,000,000 rows a chip as `padded_len`
 pads them (44,000,000 over the four chips of the train step, the
@@ -23,8 +23,7 @@ import pytest
 from jax.experimental import topologies
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from h2o_tpu.backend import kernels
-from h2o_tpu.backend.kernels import gram, hist
+from h2o_tpu.backend.kernels import gram
 from h2o_tpu.parallel.mesh import ROWS, make_mesh
 
 F, NBINS, INTERVAL = 28, 20, 10
@@ -41,19 +40,6 @@ def v5e():
 def _spec(mesh, shape, dtype, pspec=P()):
     return jax.ShapeDtypeStruct(shape, dtype,
                                 sharding=NamedSharding(mesh, pspec))
-
-
-def test_auto_resolves_to_xla_on_every_backend(monkeypatch):
-    monkeypatch.delenv("H2O_TPU_HIST_KERNEL", raising=False)
-    assert kernels.hist_backend() == "xla"
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert kernels.hist_backend() == "xla"
-    assert kernels.interpret_mode() is False   # never interpreted on TPU
-    monkeypatch.setenv("H2O_TPU_HIST_KERNEL", "pallas")
-    assert kernels.hist_backend() == "pallas"  # an explicit request only
-    monkeypatch.setenv("H2O_TPU_HIST_KERNEL", "mosaic")
-    with pytest.raises(ValueError):
-        kernels.hist_backend()
 
 
 def test_large_frames_pad_to_a_multiple_of_eight_row_blocks(v5e):
@@ -84,51 +70,49 @@ def default_train_step(v5e):
     from h2o_tpu.parallel.mesh import padded_len
     from h2o_tpu.utils.knobs import get_bool
 
-    with pytest.MonkeyPatch.context() as mp:
-        mp.delenv("H2O_TPU_HIST_KERNEL", raising=False)
-        mesh = make_mesh(v5e)
-        R = padded_len(HIGGS_4CHIP_ROWS, mesh)
-        assert R == 4 * HIGGS_PLEN
-        tiny = Frame.from_dict({"a": np.arange(8, dtype=np.float32),
-                                "y": np.arange(8, dtype=np.float32) % 2})
-        b = gbm_mod.GBM(gbm_mod.GBMParameters(
-            training_frame=tiny, response_column="y", ntrees=20, max_depth=5,
-            nbins=NBINS, seed=42, score_tree_interval=INTERVAL))
-        dist = get_distribution("bernoulli")
-        cfg = b._tree_config(1, nbins=NBINS)
-        groups, blk = plan_hist_groups(
-            np.full(F, NBINS - 1, np.int32), cfg.nbins + 1, cfg.block_rows,
-            budget_bytes=12 << 30, n_lv_max=16, nvals=3)
-        cfg = dataclasses.replace(
-            cfg, ntrees=INTERVAL, block_rows=blk, hist_groups=groups,
-            pipeline=get_bool("H2O_TPU_PIPELINE"),
-            async_psum=get_bool("H2O_TPU_ASYNC_PSUM"), fused_score=True)
-        train_fn = make_train_fn(
-            cfg, b._make_grad_fn(dist, 1), mesh,
-            score_fn=gbm_mod._metrics_raw_fn("Binomial", dist, False),
-            score_spec=P(ROWS, None), donate=True)
-        row = lambda dt: _spec(mesh, (R,), dt, P(ROWS))  # noqa: E731
-        lowered = train_fn.lower(
-            _spec(mesh, (R, F), jnp.int8, P(ROWS, None)),     # binned codes
-            row(jnp.float32), row(jnp.float32), row(jnp.float32),  # y, w, f
-            _spec(mesh, (F, NBINS - 1), jnp.float32),         # edges
-            _spec(mesh, (F, NBINS - 1), jnp.bool_),           # edge_ok
-            _spec(mesh, (INTERVAL, 2), jnp.uint32),           # keys
-            _spec(mesh, (INTERVAL,), jnp.float32),            # rates
-            _spec(mesh, (F,), jnp.float32),                   # mono
-            _spec(mesh, (F, F), jnp.bool_),                   # imat
-            _spec(mesh, (F,), jnp.bool_),                     # iscat
-            _spec(mesh, (F,), jnp.int32),                     # nedges
-            _spec(mesh, (), jnp.float32))                     # trees done
-        t0 = time.time()
-        compiled = lowered.compile()
-        return lowered, compiled, time.time() - t0
+    mesh = make_mesh(v5e)
+    R = padded_len(HIGGS_4CHIP_ROWS, mesh)
+    assert R == 4 * HIGGS_PLEN
+    tiny = Frame.from_dict({"a": np.arange(8, dtype=np.float32),
+                            "y": np.arange(8, dtype=np.float32) % 2})
+    b = gbm_mod.GBM(gbm_mod.GBMParameters(
+        training_frame=tiny, response_column="y", ntrees=20, max_depth=5,
+        nbins=NBINS, seed=42, score_tree_interval=INTERVAL))
+    dist = get_distribution("bernoulli")
+    cfg = b._tree_config(1, nbins=NBINS)
+    groups, blk = plan_hist_groups(
+        np.full(F, NBINS - 1, np.int32), cfg.nbins + 1, cfg.block_rows,
+        budget_bytes=12 << 30, n_lv_max=16, nvals=3)
+    cfg = dataclasses.replace(
+        cfg, ntrees=INTERVAL, block_rows=blk, hist_groups=groups,
+        pipeline=get_bool("H2O_TPU_PIPELINE"),
+        async_psum=get_bool("H2O_TPU_ASYNC_PSUM"), fused_score=True)
+    train_fn = make_train_fn(
+        cfg, b._make_grad_fn(dist, 1), mesh,
+        score_fn=gbm_mod._metrics_raw_fn("Binomial", dist, False),
+        score_spec=P(ROWS, None), donate=True)
+    row = lambda dt: _spec(mesh, (R,), dt, P(ROWS))  # noqa: E731
+    lowered = train_fn.lower(
+        _spec(mesh, (R, F), jnp.int8, P(ROWS, None)),     # binned codes
+        row(jnp.float32), row(jnp.float32), row(jnp.float32),  # y, w, f
+        _spec(mesh, (F, NBINS - 1), jnp.float32),         # edges
+        _spec(mesh, (F, NBINS - 1), jnp.bool_),           # edge_ok
+        _spec(mesh, (INTERVAL, 2), jnp.uint32),           # keys
+        _spec(mesh, (INTERVAL,), jnp.float32),            # rates
+        _spec(mesh, (F,), jnp.float32),                   # mono
+        _spec(mesh, (F, F), jnp.bool_),                   # imat
+        _spec(mesh, (F,), jnp.bool_),                     # iscat
+        _spec(mesh, (F,), jnp.int32),                     # nedges
+        _spec(mesh, (), jnp.float32))                     # trees done
+    t0 = time.time()
+    compiled = lowered.compile()
+    return lowered, compiled, time.time() - t0
 
 
 def test_default_train_step_compiles_for_v5e_2x2(default_train_step):
     lowered, compiled, secs = default_train_step
-    # nothing default settings reach is a Mosaic kernel today; when one is,
-    # this flips and chip_smoke.py asserts the custom call instead
+    # the program holds no hand-written kernel (ROADMAP D2): every
+    # accumulation is a scan the TPU compiler takes as it is
     assert "tpu_custom_call" not in lowered.as_text()
     assert compiled is not None
     # ~10 s on this sandbox's host; the block-count pathology above is 200+
@@ -203,29 +187,11 @@ def test_default_train_step_moves_no_rows_between_chips(default_train_step):
     assert any(re.search(r"f32\[28,16,21,3\]", sh) for sh, _ in colls)
 
 
-def test_default_gram_compiles_for_v5e_at_higgs_rows(v5e, monkeypatch):
+def test_default_gram_compiles_for_v5e_at_higgs_rows(v5e):
     """`gram_accumulate` at the block it really picks for 11M x 29 (ten
     1,101,005-row blocks under the 2^25-cell budget)."""
-    monkeypatch.delenv("H2O_TPU_HIST_KERNEL", raising=False)
     mesh = make_mesh(v5e[:1])
     vec = _spec(mesh, (HIGGS_PLEN,), jnp.float32)
     compiled = jax.jit(lambda X, W, z: gram.gram_accumulate(X, W, z)).lower(
         _spec(mesh, (HIGGS_PLEN, F + 1), jnp.float32), vec, vec).compile()
     assert compiled is not None
-
-
-def test_explicit_pallas_on_tpu_raises_the_compilers_error(v5e, monkeypatch):
-    """``H2O_TPU_HIST_KERNEL=pallas`` on a TPU goes to Mosaic, and Mosaic's
-    refusal surfaces — nothing catches it and carries on with the scan.
-    (ROADMAP S2 owns writing a kernel Mosaic takes; this pins the refusal
-    until then.)"""
-    monkeypatch.setattr(hist, "interpret_mode", lambda: False)
-    mesh = make_mesh(v5e[:1])
-    R = 65_536
-    fn = jax.jit(lambda X, l, v: hist.level_hist_blocks(
-        X, l, v, n_lv=1, nbins_tot=NBINS + 1, block=8192, backend="pallas"))
-    with pytest.raises(Exception) as ei:
-        fn.lower(_spec(mesh, (R, F), jnp.int8),
-                 _spec(mesh, (R,), jnp.int32),
-                 _spec(mesh, (R, 3), jnp.float32)).compile()
-    assert not isinstance(ei.value, AssertionError)

@@ -13,8 +13,8 @@ PORT = 54761
 
 
 @pytest.fixture(scope="module")
-def fr():
-    h2o.init(port=PORT)
+def fr(worker_port):
+    h2o.init(port=worker_port(PORT))
     rng = np.random.default_rng(5)
     df = pd.DataFrame({"x1": rng.normal(size=300),
                        "x2": rng.normal(size=300)})

@@ -24,8 +24,8 @@ Figure = matplotlib.pyplot.Figure
 
 
 @pytest.fixture(scope="module")
-def cloud():
-    conn = h2o.init(port=54591)
+def cloud(worker_port):
+    conn = h2o.init(port=worker_port(54591))
     yield conn
     try:
         h2o.shutdown()

@@ -675,10 +675,10 @@ class TestOverheadWithPlane:
 # HTTP surface — /3/Programs, /3/Metrics?fleet=1, /3/Flight, capture
 # ---------------------------------------------------------------------------
 @pytest.fixture(scope="module")
-def cloud():
+def cloud(worker_port):
     import h2o_tpu.api as h2o
 
-    conn = h2o.init(port=54787)
+    conn = h2o.init(port=worker_port(54787))
     yield conn
     try:
         h2o.shutdown()

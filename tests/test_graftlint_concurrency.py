@@ -622,13 +622,13 @@ def test_ci_gate_script_exists_and_is_executable():
     assert "pytest" in text
 
 
-def test_rule_catalog_is_twenty_four():
+def test_rule_catalog_is_twenty_three():
     from tools.graftlint import DATAFLOW_RULES
 
     ids = ([cls.id for cls in ALL_RULES]
            + [cls.id for cls in PROJECT_RULES]
            + [cls.id for cls in DATAFLOW_RULES])
-    assert len(ids) == len(set(ids)) == 24
+    assert len(ids) == len(set(ids)) == 23
     assert {"unguarded-shared-field", "lock-order-cycle",
             "blocking-under-lock", "unjoined-thread",
             "unscoped-profiler-capture",

@@ -657,7 +657,7 @@ def test_rule_catalog_counts_all_three_passes():
     ids = ([cls.id for cls in ALL_RULES]
            + [cls.id for cls in PROJECT_RULES]
            + [cls.id for cls in DATAFLOW_RULES])
-    assert len(ids) == len(set(ids)) == 24
+    assert len(ids) == len(set(ids)) == 23
     assert {"host-transfer-in-hot-path", "mixed-sharding-combine",
             "recompile-hazard", "donate-across-calls"} <= set(ids)
 

@@ -72,15 +72,14 @@ def test_block_contribution_is_the_per_cell_sum(n_lv, V, F, B, dtype):
     _equal(hist._unfold(c, n_lv), _ref(codes, local, vals, n_lv, B))
 
 
-@pytest.mark.parametrize("backend", ["xla", "pallas"])
 @pytest.mark.parametrize("n_lv,V,F,B,dtype", [
     (1, 3, 28, 21, jnp.int8), (16, 4, 31, 65, jnp.int16),
     (64, 3, 1, 21, jnp.int32), (2, 4, 28, 65, jnp.int8)])
-def test_level_hist_blocks_flat(n_lv, V, F, B, dtype, backend):
+def test_level_hist_blocks_flat(n_lv, V, F, B, dtype):
     codes, local, vals = _inputs(2048, F, B, n_lv, V, dtype, seed=3)
     h = hist.level_hist_blocks(
         jnp.asarray(codes), jnp.asarray(local), jnp.asarray(vals),
-        n_lv=n_lv, nbins_tot=B, block=512, backend=backend)
+        n_lv=n_lv, nbins_tot=B, block=512)
     _equal(h, _ref(codes, local, vals, n_lv, B))
 
 
@@ -99,14 +98,13 @@ def _grouped_inputs(R, n_lv, V, dtype, seed, lo=0, hi=None):
     return codes, local, vals
 
 
-@pytest.mark.parametrize("backend", ["xla", "pallas"])
 @pytest.mark.parametrize("n_lv,V,dtype", [(1, 3, jnp.int8), (16, 4, jnp.int16),
                                           (64, 3, jnp.int32)])
-def test_level_hist_blocks_grouped_onehot_and_segsum(n_lv, V, dtype, backend):
+def test_level_hist_blocks_grouped_onehot_and_segsum(n_lv, V, dtype):
     codes, local, vals = _grouped_inputs(2048, n_lv, V, dtype, seed=5)
     hs = hist.level_hist_blocks(
         jnp.asarray(codes), jnp.asarray(local), jnp.asarray(vals),
-        n_lv=n_lv, nbins_tot=65, block=512, groups=GROUPS, backend=backend)
+        n_lv=n_lv, nbins_tot=65, block=512, groups=GROUPS)
     want = _ref_groups(codes, local, vals, n_lv, 65, GROUPS)
     assert len(hs) == len(want)
     for g, w in zip(hs, want):

@@ -1,8 +1,7 @@
 """Width-bucketed histogram accumulation (hist_groups) vs the flat one-hot
 path — grouped/segment-sum bit-equality over mixed widths on the virtual CPU
 mesh, the auto-tuner's engagement rules, and a full GBM train with the
-grouped path forced on/off (the ADVICE r5 medium finding; mirrors the
-retired test_pallas_hist.py pattern)."""
+grouped path forced on/off (the ADVICE r5 medium finding)."""
 
 import jax
 import numpy as np

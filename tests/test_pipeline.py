@@ -6,12 +6,10 @@ REAL psums on the 8-shard mesh; the single-shard pin re-runs the same
 comparison on a one-device mesh.
 
 - Pipelined forests AND predictions are BIT-equal to the synchronous
-  oracle across the knob matrix (pipeline × async-psum, GOSS off), on the
+  oracle across the knob matrix (pipeline × async-psum), on the
   8-shard mesh and single-shard, at the one-chunk and multi-chunk
   (fused cadence scoring + dispatch-ahead + donated margin) cadences;
 - the fused-scoring metric series is identical to the oracle's;
-- GOSS is deterministic under the train seed, changes under a different
-  seed, holds holdout AUC inside the band, and validates its knob;
 - an in-flight pipelined dispatch killed by the `mrtask.dispatch`
   failpoint fails TYPED (no hang) and re-runs clean to the oracle forest.
 """
@@ -61,14 +59,10 @@ def _frame(rows=slice(None), mesh=None):
     return fr
 
 
-def _train(fr, monkeypatch, pipeline, async_psum="1", goss=None,
-           interval=None, ntrees=8, seed=7, **kw):
+def _train(fr, monkeypatch, pipeline, async_psum="1", interval=None,
+           ntrees=8, seed=7, **kw):
     monkeypatch.setenv("H2O_TPU_PIPELINE", pipeline)
     monkeypatch.setenv("H2O_TPU_ASYNC_PSUM", async_psum)
-    if goss is None:
-        monkeypatch.delenv("H2O_TPU_GOSS", raising=False)
-    else:
-        monkeypatch.setenv("H2O_TPU_GOSS", goss)
     p = GBMParameters(training_frame=fr, response_column="y",
                       ntrees=ntrees, max_depth=4, nbins=16, seed=seed,
                       learn_rate=0.2,
@@ -89,7 +83,7 @@ def _preds_equal(a, b, fr):
 
 
 # ---------------------------------------------------------------------------
-# Bit parity: pipelined vs the synchronous oracle, knob matrix, GOSS off
+# Bit parity: pipelined vs the synchronous oracle, knob matrix
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("async_psum", ["0", "1"])
 def test_pipelined_bit_parity_8shard(monkeypatch, async_psum):
@@ -169,78 +163,6 @@ def test_multinomial_pipelined_parity(monkeypatch):
     oracle, m = tri("0"), tri("1")
     assert _forest_equal(oracle, m)
     assert _preds_equal(oracle, m, fr)
-
-
-# ---------------------------------------------------------------------------
-# GOSS sampling
-# ---------------------------------------------------------------------------
-def test_goss_deterministic_under_seed(monkeypatch):
-    fr = _frame()
-    a = _train(fr, monkeypatch, pipeline="1", goss="0.3,0.2", ntrees=6)
-    b = _train(fr, monkeypatch, pipeline="1", goss="0.3,0.2", ntrees=6)
-    assert _forest_equal(a, b)
-    assert _preds_equal(a, b, fr)
-
-
-def test_goss_seed_and_fraction_sensitivity(monkeypatch):
-    fr = _frame()
-    a = _train(fr, monkeypatch, pipeline="1", goss="0.3,0.2", ntrees=6)
-    b = _train(fr, monkeypatch, pipeline="1", goss="0.3,0.2", ntrees=6,
-               seed=8)
-    c = _train(fr, monkeypatch, pipeline="1", goss="0.5,0.3", ntrees=6)
-    assert not _forest_equal(a, b)   # different seed, different sample
-    assert not _forest_equal(a, c)   # different fractions, different rows
-
-
-def test_goss_works_in_sync_oracle_too(monkeypatch):
-    """GOSS is a sampler, orthogonal to the pipeline knob: the same seed
-    produces the same forest whether the level program is pipelined or
-    synchronous (selection happens before the hist pass either way)."""
-    fr = _frame()
-    a = _train(fr, monkeypatch, pipeline="0", goss="0.3,0.2", ntrees=6)
-    b = _train(fr, monkeypatch, pipeline="1", goss="0.3,0.2", ntrees=6)
-    assert _forest_equal(a, b)
-
-
-def test_goss_auc_band_airlines_width_smoke(monkeypatch):
-    """Holdout AUC with GOSS at (0.3, 0.2) stays inside the band of the
-    full-row forest — the 'fewer rows per hist pass at equal AUC' claim,
-    at airlines-width smoke shape (wide categorical + numerics)."""
-    tr = _frame(rows=slice(0, 3072))
-    va = _frame(rows=slice(3072, 4096))
-    full = _train(tr, monkeypatch, pipeline="1", ntrees=20)
-    goss = _train(tr, monkeypatch, pipeline="1", goss="0.3,0.2", ntrees=20)
-    auc_full = float(full.model_performance(va).auc)
-    auc_goss = float(goss.model_performance(va).auc)
-    assert abs(auc_full - auc_goss) < 0.04, (auc_full, auc_goss)
-
-
-def test_goss_knob_validation(monkeypatch):
-    fr = _frame(rows=slice(0, 512))
-    with pytest.raises(ValueError, match="H2O_TPU_GOSS"):
-        _train(fr, monkeypatch, pipeline="1", goss="0.9,0.5", ntrees=2)
-    with pytest.raises(ValueError, match="H2O_TPU_GOSS"):
-        _train(fr, monkeypatch, pipeline="1", goss="nope", ntrees=2)
-
-
-def test_goss_ineligible_build_trains_unsampled(monkeypatch):
-    """A global GOSS knob must not fail a multinomial job — it logs and
-    trains full-row (bit-equal to the GOSS-off forest)."""
-    y3 = (_C1 % 3).astype(np.float32)
-    fr = _frame()
-    fr.add("y3", Vec.from_numpy(y3, type=T_CAT, domain=["a", "b", "c"]))
-
-    def tri(goss):
-        if goss is None:
-            monkeypatch.delenv("H2O_TPU_GOSS", raising=False)
-        else:
-            monkeypatch.setenv("H2O_TPU_GOSS", goss)
-        monkeypatch.setenv("H2O_TPU_PIPELINE", "1")
-        p = GBMParameters(training_frame=fr, response_column="y3",
-                          ntrees=3, max_depth=3, nbins=16, seed=7)
-        return GBM(p).train_model()
-
-    assert _forest_equal(tri("0.3,0.2"), tri(None))
 
 
 # ---------------------------------------------------------------------------
@@ -324,8 +246,7 @@ def test_streamed_route_hist_matches_two_pass():
     lc = jnp.clip(local, 0, width - 1)
     v = jnp.where(active[:, None], vals, 0.0)
     want = hist_kernels.level_hist_blocks(Xb, lc, v, n_lv=width,
-                                          nbins_tot=B, block=256,
-                                          backend="xla")
+                                          nbins_tot=B, block=256)
     (got,), node_out = hist_kernels.streamed_route_hist(
         Xb, node, vals, fake_route, offset=offset, n_lv=width,
         nbins_tot=B, block=256)

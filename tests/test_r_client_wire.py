@@ -18,8 +18,8 @@ import h2o_tpu.api as h2o
 
 
 @pytest.fixture(scope="module")
-def cloud():
-    conn = h2o.init(port=54667)
+def cloud(worker_port):
+    conn = h2o.init(port=worker_port(54667))
     yield conn
     try:
         h2o.shutdown()
@@ -242,8 +242,8 @@ class TestRound4RSurface:
         # raw text route (the R client reads it with read.csv)
         import urllib.request
 
-        base = h2o.connection()._base if hasattr(h2o.connection(), "_base")             else None
-        url = (base or f"http://127.0.0.1:54667") +             f"/3/DownloadDataset?frame_id={frame_id}"
+        url = (h2o.connection().url
+               + f"/3/DownloadDataset?frame_id={frame_id}")
         with urllib.request.urlopen(url) as r:
             return r.read().decode()
 

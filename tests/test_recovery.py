@@ -359,10 +359,10 @@ def test_spill_failpoint_fires():
 # client retry against a LIVE flaky server (real socket, injected 429/503)
 # ---------------------------------------------------------------------------
 @pytest.fixture(scope="module")
-def cloud():
+def cloud(worker_port):
     import h2o_tpu.api.client as h2o
 
-    conn = h2o.init(port=54671)
+    conn = h2o.init(port=worker_port(54671))
     yield conn
     try:
         h2o.shutdown()

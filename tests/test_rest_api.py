@@ -13,8 +13,8 @@ from h2o_tpu.rapids.exec import Rapids, Session
 
 
 @pytest.fixture(scope="module")
-def cloud():
-    conn = h2o.init(port=54555)
+def cloud(worker_port):
+    conn = h2o.init(port=worker_port(54555))
     yield conn
     try:
         h2o.shutdown()

@@ -12,8 +12,8 @@ PORT = 54771
 
 
 @pytest.fixture(scope="module")
-def fr():
-    h2o.init(port=PORT)
+def fr(worker_port):
+    h2o.init(port=worker_port(PORT))
     rng = np.random.default_rng(2)
     df = pd.DataFrame({"a": rng.normal(size=200),
                        "b": rng.normal(size=200)})

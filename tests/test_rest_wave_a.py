@@ -19,8 +19,8 @@ PORT = 54773
 
 
 @pytest.fixture(scope="module")
-def fr():
-    h2o.init(port=PORT)
+def fr(worker_port):
+    h2o.init(port=worker_port(PORT))
     rng = np.random.default_rng(7)
     df = pd.DataFrame({
         "num": rng.normal(size=300),
@@ -36,11 +36,12 @@ def _req(method, path, body=None, params=None, **kw):
 
 # -- cloud / misc verbs ------------------------------------------------------
 
-def test_head_cloud(fr):
+def test_head_cloud(fr, worker_port):
     """HEAD /3/Cloud answers 200 with headers and an empty body — and a GET
     on the SAME keep-alive connection still gets its body (the handler
     instance persists across requests; the suppress-body flag must not)."""
-    conn = http.client.HTTPConnection("127.0.0.1", PORT, timeout=10)
+    conn = http.client.HTTPConnection("127.0.0.1", worker_port(PORT),
+                                      timeout=10)
     conn.request("HEAD", "/3/Cloud")
     resp = conn.getresponse()
     body = resp.read()

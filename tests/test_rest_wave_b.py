@@ -21,8 +21,8 @@ def _req(method, path, body=None, params=None, **kw):
 
 
 @pytest.fixture(scope="module")
-def setup():
-    h2o.init(port=PORT)
+def setup(worker_port):
+    h2o.init(port=worker_port(PORT))
     rng = np.random.default_rng(11)
     df = pd.DataFrame({
         "x1": rng.normal(size=400),

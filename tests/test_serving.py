@@ -403,8 +403,8 @@ def test_encode_rows_strict_raises(models, tmp_path):
 # REST + client surface
 # ---------------------------------------------------------------------------
 @pytest.fixture(scope="module")
-def cloud():
-    conn = h2o.init(port=54641)
+def cloud(worker_port):
+    conn = h2o.init(port=worker_port(54641))
     yield conn
     try:
         h2o.shutdown()

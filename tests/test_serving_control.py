@@ -516,8 +516,8 @@ def test_queue_full_isolation_across_models(runtime, models):
 # REST + client surface (routes, admission, control, pooled wire)
 # ---------------------------------------------------------------------------
 @pytest.fixture(scope="module")
-def cloud():
-    conn = h2o.init(port=54643)
+def cloud(worker_port):
+    conn = h2o.init(port=worker_port(54643))
     yield conn
     try:
         h2o.shutdown()

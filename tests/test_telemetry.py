@@ -412,10 +412,21 @@ class TestOverhead:
 # HTTP surface — /3/Metrics, /3/Timeline, /3/Logs, /3/Profiler
 # ---------------------------------------------------------------------------
 @pytest.fixture(scope="module")
-def cloud():
+def cloud(worker_port):
+    """This worker's own server, with one REST-driven train behind it: the
+    tests below read that train's metrics, spans and log lines, whichever
+    of them a worker is dealt first."""
     import h2o_tpu.api as h2o
+    import pandas as pd
 
-    conn = h2o.init(port=54772)
+    conn = h2o.init(port=worker_port(54772))
+    rng = np.random.default_rng(7)
+    df = pd.DataFrame({"x1": rng.normal(size=300),
+                       "x2": rng.normal(size=300)})
+    df["y"] = np.where(df.x1 > 0, "yes", "no")
+    m = h2o.H2OGradientBoostingEstimator(ntrees=4, max_depth=3, seed=1,
+                                         score_tree_interval=2)
+    m.train(y="y", training_frame=h2o.H2OFrame(df))
     yield conn
     try:
         h2o.shutdown()
@@ -427,17 +438,7 @@ class TestHTTPSurface:
     def test_metrics_json_over_http(self, cloud):
         import h2o_tpu.api as h2o
 
-        # drive a real train through REST so the registry is non-trivial
-        import pandas as pd
-
-        rng = np.random.default_rng(7)
-        df = pd.DataFrame({"x1": rng.normal(size=300),
-                           "x2": rng.normal(size=300)})
-        df["y"] = np.where(df.x1 > 0, "yes", "no")
-        fr = h2o.H2OFrame(df)
-        m = h2o.H2OGradientBoostingEstimator(ntrees=4, max_depth=3, seed=1,
-                                             score_tree_interval=2)
-        m.train(y="y", training_frame=fr)
+        # the fixture's REST-driven train makes the registry non-trivial
         payload = h2o.connection().request("GET", "/3/Metrics")
         mx = payload["metrics"]
         assert mx["train.count"]["value"] >= 1

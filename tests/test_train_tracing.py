@@ -75,7 +75,9 @@ def _spans(events, name):
 def _gbm_step_text(pipeline: str) -> str:
     """HLO text of the train step a small GBM job compiled: THIS job's
     entry of the process-wide step cache, by its own key (the level program
-    asked for, this frame's coded matrix). Under ``-n 6 --dist load`` the
+    asked for, this job's depth and chunk length, this frame's coded
+    matrix: tests/test_pipeline.py trains on the same shape in the same
+    process at depth 4). Under ``-n 6 --dist load`` the
     process may also be serving another worker's REST jobs (``h2o.init``
     at a module's fixed port connects to whichever worker bound it first),
     and their steps land in the same cache while this one trains."""
@@ -88,6 +90,7 @@ def _gbm_step_text(pipeline: str) -> str:
         (compiled,) = [
             c for ((cfg, *_), sig), c in list(gbm_mod._AOT_STEP_CACHE.items())
             if cfg.pipeline == (pipeline == "1")
+            and (cfg.max_depth, cfg.ntrees) == (3, 2)
             and sig[0] == ((_N, _F), "int8")]
         return compiled.as_text()
     finally:

@@ -24,8 +24,8 @@ PORT = 54741
 
 
 @pytest.fixture(scope="module")
-def conn():
-    h2o.init(port=PORT)
+def conn(worker_port):
+    h2o.init(port=worker_port(PORT))
     yield h2o.connection()
 
 
@@ -205,7 +205,7 @@ print("PREDS::" + json.dumps(preds))
 """
 
 
-def test_load_model_in_fresh_process(conn, tmp_path):
+def test_load_model_in_fresh_process(conn, tmp_path, worker_port):
     """train -> save_model -> FRESH server process -> load_model -> identical
     predictions, over HTTP only (the VERDICT #2 done-criterion)."""
     df = _df(seed=23)
@@ -220,7 +220,7 @@ def test_load_model_in_fresh_process(conn, tmp_path):
     env.setdefault("JAX_PLATFORMS", "cpu")
     out = subprocess.run(
         [sys.executable, "-c", _FRESH_SERVER, saved, str(csv),
-         str(PORT + 37)],
+         str(worker_port(PORT + 37))],
         capture_output=True, text=True, timeout=600, env=env,
         cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     assert out.returncode == 0, out.stderr[-3000:]

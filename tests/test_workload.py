@@ -423,17 +423,17 @@ def test_grid_runs_under_its_priority_lane():
 # REST surface: /3/Workload, 429 + Retry-After, per-tenant Prometheus
 # ---------------------------------------------------------------------------
 @pytest.fixture(scope="module")
-def srv():
+def srv(worker_port):
     from h2o_tpu.api.server import H2OServer
 
-    s = H2OServer(port=54944, name="workload-rest").start()
+    s = H2OServer(port=worker_port(54944), name="workload-rest").start()
     yield s
     s.stop()
 
 
-def _req(method, path, body=None, hdrs=None, port=54944):
+def _req(srv, method, path, body=None, hdrs=None):
     r = urllib.request.Request(
-        f"http://127.0.0.1:{port}{path}", method=method,
+        f"http://127.0.0.1:{srv.port}{path}", method=method,
         data=json.dumps(body).encode() if body is not None else None,
         headers={"Content-Type": "application/json", **(hdrs or {})})
     try:
@@ -444,18 +444,18 @@ def _req(method, path, body=None, hdrs=None, port=54944):
 
 
 def test_rest_workload_snapshot_and_configure(srv):
-    status, snap, _ = _req("GET", "/3/Workload")
+    status, snap, _ = _req(srv, "GET", "/3/Workload")
     assert status == 200
     assert snap["priorities"] == list(Job.PRIORITIES)
-    status, snap, _ = _req("POST", "/3/Workload",
+    status, snap, _ = _req(srv, "POST", "/3/Workload",
                            {"tenant": "acme", "weight": 2.5,
                             "quota_fraction": 0.25})
     assert status == 200
     assert snap["tenants"]["acme"]["weight"] == 2.5
     assert snap["tenants"]["acme"]["quota_fraction"] == 0.25
-    status, err, _ = _req("POST", "/3/Workload", {})
+    status, err, _ = _req(srv, "POST", "/3/Workload", {})
     assert status == 400
-    status, err, _ = _req("POST", "/3/Workload",
+    status, err, _ = _req(srv, "POST", "/3/Workload",
                           {"tenant": "acme", "weight": -1})
     assert status == 400
 
@@ -465,7 +465,7 @@ def test_rest_over_quota_build_is_429_with_retry_after(srv, monkeypatch):
     monkeypatch.setenv("H2O_TPU_WORKLOAD_QUOTA", "starved=0.000001")
     fr = _frame()
     status, payload, hdrs = _req(
-        "POST", "/3/ModelBuilders/gbm",
+        srv, "POST", "/3/ModelBuilders/gbm",
         {"training_frame": str(fr.key), "response_column": "y",
          "ntrees": 2, "seed": 1},
         hdrs={"X-H2O-TPU-Tenant": "starved"})
@@ -475,12 +475,12 @@ def test_rest_over_quota_build_is_429_with_retry_after(srv, monkeypatch):
     assert int(hdrs["Retry-After"]) >= 1
     # the same build WITHOUT the starved tenant header sails through
     status, job, _ = _req(
-        "POST", "/3/ModelBuilders/gbm",
+        srv, "POST", "/3/ModelBuilders/gbm",
         {"training_frame": str(fr.key), "response_column": "y",
          "ntrees": 2, "seed": 1})
     assert status == 200
     key = job["job"]["key"]["name"] if "job" in job else None
-    assert _wait(lambda: _req("GET", f"/3/Jobs/{key}")[1]
+    assert _wait(lambda: _req(srv, "GET", f"/3/Jobs/{key}")[1]
                  ["jobs"][0]["status"] == Job.DONE, timeout=60)
 
 
@@ -488,7 +488,7 @@ def test_rest_job_schema_carries_tenant_and_priority(srv):
     with tenants.request_scope("acme", "interactive"):
         m = GBM(_params(ntrees=2)).train_model()
     assert m is not None
-    status, payload, _ = _req("GET", "/3/Jobs")
+    status, payload, _ = _req(srv, "GET", "/3/Jobs")
     assert status == 200
     mine = [j for j in payload["jobs"] if j.get("tenant") == "acme"]
     assert mine and mine[-1]["priority"] == "interactive"
@@ -497,10 +497,10 @@ def test_rest_job_schema_carries_tenant_and_priority(srv):
 def test_per_tenant_prometheus_series(srv):
     with tenants.request_scope("prom-t"):
         workload.submit(Job("noop"), lambda: None)
-    status, _, _ = _req("GET", "/3/Workload")
+    status, _, _ = _req(srv, "GET", "/3/Workload")
     assert status == 200
     r = urllib.request.urlopen(
-        "http://127.0.0.1:54944/3/Metrics?format=prometheus")
+        f"http://127.0.0.1:{srv.port}/3/Metrics?format=prometheus")
     text = r.read().decode()
     assert 'h2o_tpu_tenant_running_jobs{tenant="prom-t"}' in text
     assert 'h2o_tpu_tenant_preemptions_total{tenant="prom-t"} 0' in text

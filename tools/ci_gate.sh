@@ -1,5 +1,5 @@
 #!/usr/bin/env bash
-# CI gate — graftlint (24 rules, baseline-gated) + the tier-1 pytest line,
+# CI gate — graftlint (23 rules, baseline-gated) + the tier-1 pytest line,
 # as ONE exit-coded command. Either failing fails the gate; both always
 # run so a single CI pass reports lint findings AND test failures.
 #

@@ -37,11 +37,6 @@ CHANGES.md entries):
    `utils/telemetry.py` accessors must be declared in its registry; an
    undeclared name raises at runtime (KeyError, the knobs contract) — this
    rule catches it before a hot path does.
-12. direct-pallas-call   — PR 9: `h2o_tpu/backend/kernels/` is the ONLY
-   sanctioned `pl.pallas_call` site (the direct-shard-map shape, applied
-   to kernels): a Pallas kernel grown elsewhere dodges the XLA-oracle
-   bit-parity contract, the interpret-mode routing off-TPU, and the
-   `H2O_TPU_HIST_KERNEL` backend switch.
 13. direct-device-put    — PR 10 (multi-chip sharded frames): mesh-sharded
    `jax.device_put` calls belong to `parallel/mesh.py`'s put_* helpers or
    the frame layer (`frame/vec.py`, `frame/chunks.py`). Placement policy —
@@ -88,8 +83,6 @@ from .core import (REPO_ROOT, FileContext, Rule, Violation, dotted_name,
 
 #: the one sanctioned shard_map definition site
 MESH_PATH = "h2o_tpu/parallel/mesh.py"
-#: the one sanctioned pallas_call site (the kernels layer)
-KERNELS_PATH = "h2o_tpu/backend/kernels/"
 KNOBS_PATH = "h2o_tpu/utils/knobs.py"
 FAILPOINTS_PATH = "h2o_tpu/utils/failpoints.py"
 TELEMETRY_PATH = "h2o_tpu/utils/telemetry.py"
@@ -141,54 +134,6 @@ class DirectShardMap(Rule):
                     if not any(s0 <= lo and hi <= s1 for s0, s1 in spans):
                         spans.append((lo, hi))
                         out.append(self.violation(ctx, node, msg))
-        return out
-
-
-class DirectPallasCall(Rule):
-    id = "direct-pallas-call"
-    doc = ("pallas imported/used outside h2o_tpu/backend/kernels/ — the "
-           "kernels layer is the only sanctioned pl.pallas_call site "
-           "(XLA-oracle parity + interpret routing)")
-
-    def check(self, tree, ctx):
-        if ctx.relpath.startswith(KERNELS_PATH):
-            return []
-        out = []
-        spans: list[tuple] = []
-        msg = ("direct pallas use — kernels live in h2o_tpu/backend/"
-               "kernels/ (the sanctioned pl.pallas_call site: XLA-oracle "
-               "bit parity, interpret-mode routing off-TPU, and the "
-               "H2O_TPU_HIST_KERNEL backend switch)")
-        for node in ast.walk(tree):
-            if isinstance(node, ast.ImportFrom):
-                mod = node.module or ""
-                names = {a.name for a in node.names}
-                if (mod.startswith("jax.experimental.pallas")
-                        or (mod == "jax.experimental" and "pallas" in names)):
-                    out.append(self.violation(ctx, node, msg))
-            elif isinstance(node, ast.Import):
-                if any(a.name.startswith("jax.experimental.pallas")
-                       for a in node.names):
-                    out.append(self.violation(ctx, node, msg))
-            elif isinstance(node, ast.Attribute):
-                dn = normalize(dotted_name(node), ctx.aliases)
-                if dn and ("experimental.pallas" in dn
-                           or dn.endswith(".pallas_call")):
-                    # outermost matching attribute chain only (the
-                    # direct-shard-map span discipline)
-                    lo = (node.lineno, node.col_offset)
-                    hi = (node.end_lineno, node.end_col_offset)
-                    if not any(s0 <= lo and hi <= s1 for s0, s1 in spans):
-                        spans.append((lo, hi))
-                        out.append(self.violation(ctx, node, msg))
-            elif (isinstance(node, ast.Call)
-                  and isinstance(node.func, ast.Name)):
-                # bare `pallas_call(...)` resolved through its import alias
-                # (an unimported local name of the same spelling is not
-                # pallas and stays clean)
-                dn = normalize(dotted_name(node.func), ctx.aliases)
-                if dn and "experimental.pallas" in dn:
-                    out.append(self.violation(ctx, node, msg))
         return out
 
 
@@ -1050,7 +995,7 @@ class UnscopedProfilerCapture(Rule):
                 dn = normalize(dotted_name(node), ctx.aliases)
                 if dn and self._is_capture(dn):
                     # outermost matching attribute chain only (the
-                    # direct-pallas-call span discipline)
+                    # direct-shard-map span discipline)
                     lo = (node.lineno, node.col_offset)
                     hi = (node.end_lineno, node.end_col_offset)
                     if not any(s0 <= lo and hi <= s1 for s0, s1 in spans):
@@ -1156,7 +1101,7 @@ class ThreadWithoutTraceContext(Rule):
         return out
 
 
-ALL_RULES = (DirectShardMap, DirectPallasCall, DirectDevicePut, PSpecConcat,
+ALL_RULES = (DirectShardMap, DirectDevicePut, PSpecConcat,
              NarrowIntAccumulate, UntrackedResident, TimingWithoutSync,
              HostSyncInTrace, NondeterminismInTrace, UnregisteredKnob,
              UnregisteredFailpoint, SwallowedRetryable, UnregisteredMetric,
