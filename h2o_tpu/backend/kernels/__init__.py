@@ -4,14 +4,18 @@ of the two accumulations every hot loop in this repo bottoms out in.
 - `hist`: the GBM/DRF level-histogram scan (bin codes → per-(feature, node,
   bin) channel sums), alone (`level_hist_blocks`, `level_hist_one_group`)
   and fused with the previous level's routing (`streamed_route_hist`).
-- `gram`: the GLM/PCA weighted Gram (XᵀWX + XᵀWz), `gram_accumulate`.
+- `gram`: the GLM/PCA weighted Gram (XᵀWX + XᵀWz), `gram_accumulate`, and
+  the row blocks it cuts a design into, `block_plan`.
 
-Each is a ``lax.scan`` over row blocks whose body adds one block's
-contribution (`hist._flat_contrib` / `hist._one_group_contrib` /
-`gram.block_contrib`, the ONE definition of a block's contribution) into a
-carried accumulator in ascending block order. It is the formulation that
-compiles for the TPU (tests/test_chip_compile.py) and the one the chip has
-run; callers own the mesh concerns (psum, scatter-back).
+Each is a loop over row blocks whose body adds one block's contribution
+(`hist._flat_contrib` / `hist._one_group_contrib` / `gram.block_contrib`,
+the ONE definition of a block's contribution) into a carried accumulator in
+ascending block order: a ``lax.scan`` over the coded blocks for the
+histograms, a ``lax.fori_loop`` that slices each block out of the design in
+place for the Gram (as ``xs`` of a scan the f32 design was copied whole and
+re-tiled a block). It is the formulation that compiles for the TPU
+(tests/test_chip_compile.py) and the one the chip has run; callers own the
+mesh concerns (psum, scatter-back).
 """
 
 from __future__ import annotations

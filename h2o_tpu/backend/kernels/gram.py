@@ -8,9 +8,14 @@ one-MRTask contract. Consumers: `glm._make_irls_kernel` (IRLS driver),
 `pca._gram_kernel` (GramSVD), and RuleFit's streaming IRLS shares
 `block_contrib` inside its design-building scan.
 
-Like kernels/hist.py it is a blocked ``lax.scan``: the (P, P) accumulator
-is the carry, blocks add in ascending order, and a design under the block
-budget runs as ONE block.
+The design is read where it lies: a loop over a block index whose body
+slices its rows out of X, W and z in place (`lax.dynamic_slice_in_dim`),
+the (P, P) accumulator the carry, blocks added in ascending order and the
+rows past the last full block as one static tail slice. No operand is
+padded, concatenated or reshaped: handed to ``lax.scan`` as ``xs`` the
+design was copied whole and every block re-tiled (0.37 s of copies around
+0.014 s of arithmetic at 11M x 29 on a v5e; PERF.md, PR 31). A design
+under the block budget runs as ONE block, the plain fused einsum.
 
 The W/z row vectors arrive precomputed (they are O(R) elementwise — the
 IRLS step builds them from eta in the same jitted program); the fusion
@@ -26,9 +31,14 @@ from ...utils import telemetry
 
 #: transient-cell budget per block: blocks sized so the (rb, P) weighted
 #: product stays ~128 MB of f32 (gemm-sized, never HBM-relevant); designs
-#: under the budget run as ONE block — i.e. exactly the historic fused
-#: einsum, byte-for-byte — and only frame-scale designs split
+#: under the budget run as ONE block, the plain fused einsum, and only
+#: frame-scale designs split
 _BLOCK_CELLS = 1 << 25
+
+#: rows a block is a multiple of: covers the TPU's T(1024) tiling of the
+#: (R,) vectors and the T(8,128) tiling of the design (rows in lanes), so
+#: a block's slice starts on a tile boundary and is read where it lies
+_LANE = 1024
 
 
 def block_contrib(xb, wb, zb):
@@ -44,25 +54,38 @@ def block_contrib(xb, wb, zb):
     return dG, XW.T @ zb
 
 
+def block_plan(R: int, P: int, block: int | None = None):
+    """``(nblk, rb, tail)`` for an (R, P) design: ``nblk`` full blocks of
+    ``rb`` rows, then ``tail`` rows, ``nblk * rb + tail == R``. Pure
+    arithmetic on the static shape; ``block`` overrides the budget's rows
+    a block (tests).
 
-def _scan_gram(X, W, z, rb):
-    R, P = X.shape
+    A design at or under the budget (or under one lane tile) is one block.
+    Otherwise ``rb`` is a multiple of ``_LANE`` at or under the budget (one
+    tile at least), and the block count is kept a multiple of 8 where there
+    are 8 or more: the TPU compiler's time on a blocked loop is linear in
+    the count otherwise (12 blocks compiled in 51.6 s against 1.0-1.5 s for
+    16; `parallel.mesh.padded_len` documents the same trap). ``rb`` is not
+    searched among R's divisors — a pow2-divisor fallback once produced
+    16-row blocks at R=50000, turning the gemm into 3125 dispatch-bound
+    slivers — so what the lane floor leaves over is the tail, under ``rb``
+    rows (under 8 one-tile blocks in the corner where no tile multiple
+    gives a count that is a multiple of 8)."""
+    cap = max(block or _BLOCK_CELLS // max(P, 1), 1)
+    if R <= max(cap, _LANE):
+        return 1, R, 0
+    n = -(-R // max(cap // _LANE * _LANE, _LANE))
+    while True:
+        if n >= 8:
+            n = -(-n // 8) * 8
+        rb = max(R // n // _LANE * _LANE, _LANE)
+        if R // rb == n or rb == _LANE:
+            break
+        n += 1
     nblk = R // rb
-    has_z = z is not None
-
-    def body(carry, blk):
-        G, b = carry
-        xb, wb, zb = blk if has_z else (*blk, None)
-        dG, db = block_contrib(xb, wb, zb)
-        return (G + dG, b + db if has_z else b), None
-
-    init = (jnp.zeros((P, P), jnp.float32),
-            jnp.zeros((P,), jnp.float32) if has_z else 0.0)
-    xs = (X.reshape(nblk, rb, P), W.reshape(nblk, rb))
-    if has_z:
-        xs = xs + (z.reshape(nblk, rb),)
-    (G, b), _ = jax.lax.scan(body, init, xs)
-    return G, (b if has_z else None)
+    if nblk >= 8:
+        nblk -= nblk % 8
+    return nblk, rb, R - nblk * rb
 
 
 @telemetry.scope("glm.gram")
@@ -72,23 +95,26 @@ def gram_accumulate(X, W, z=None, *, block: int | None = None):
     contraction applies W once, i.e. Xᵀ·diag(W)·X; mask callers rely on
     0²=0, 1²=1).
 
-    Blocks are balanced — nblk = ceil(R·P / cell budget), rb = ceil(R /
-    nblk) — and rows pad with zeros up to nblk·rb when R doesn't divide
-    (unlike the engine's power-of-two frame padding, GLM designs arrive at
-    arbitrary lengths; a pow2-divisor fallback once produced 16-row blocks
-    at R=50000, turning the gemm into 3125 dispatch-bound slivers).
-    Zero-weight zero-value rows contribute exact +0.0 products, so the
-    padded sum is bit-identical to the unpadded one; designs under the
-    budget run as a single block, which IS the historic fused einsum."""
+    Blocks are `block_plan`'s: each a slice of X, W and z at a lane-tile
+    offset, contributed in ascending order into the f32 (G, b) carry, the
+    tail rows last. The sums differ from another block size's only by the
+    order of addition inside a block."""
     R, P = X.shape
-    cells = block * P if block else _BLOCK_CELLS
-    nblk = max(1, -(-R * P // max(cells, 1)))
-    rb = -(-R // nblk)
-    pad = nblk * rb - R
-    if pad:
-        X = jnp.concatenate(
-            [X, jnp.zeros((pad, X.shape[1]), X.dtype)], axis=0)
-        W = jnp.concatenate([W, jnp.zeros((pad,), W.dtype)])
-        if z is not None:
-            z = jnp.concatenate([z, jnp.zeros((pad,), z.dtype)])
-    return _scan_gram(X, W, z, rb)
+    nblk, rb, tail = block_plan(R, P, block)
+    if nblk == 1 and not tail:
+        return block_contrib(X, W, z)
+
+    def add_block(acc, start, rows):
+        xb, wb, zb = (
+            None if a is None
+            else jax.lax.dynamic_slice_in_dim(a, start, rows, 0)
+            for a in (X, W, z))
+        return jax.tree.map(jnp.add, acc, block_contrib(xb, wb, zb))
+
+    acc = (jnp.zeros((P, P), jnp.float32),
+           None if z is None else jnp.zeros((P,), jnp.float32))
+    acc = jax.lax.fori_loop(
+        0, nblk, lambda i, acc: add_block(acc, i * rb, rb), acc)
+    if tail:
+        acc = add_block(acc, nblk * rb, tail)
+    return acc

@@ -207,13 +207,39 @@ def _row_shardable(X, mesh) -> bool:
     return True
 
 
+def _step_row_shards(X, w, offset, mesh) -> int:
+    """Row shards the IRLS step runs over: the mesh's when the design goes
+    through ``shard_map`` (rows divide, plain row vectors, `_row_shardable`),
+    else 1 — the jit/GSPMD program sees the whole design."""
+    from ..parallel.mesh import n_row_shards
+
+    ns = n_row_shards(mesh)
+    if (ns > 1 and X.shape[0] % ns == 0 and jnp.ndim(w) == 1
+            and jnp.ndim(offset) == 1 and _row_shardable(X, mesh)):
+        return ns
+    return 1
+
+
+def _gram_plan_attrs(X, w, offset) -> dict:
+    """``train.glm.gram``'s attributes: the row blocks `gram_accumulate`
+    cuts the design into inside the step (a shard's rows under
+    ``shard_map``), from `gram.block_plan` on the shapes alone."""
+    from ..backend.kernels import gram as gram_kernels
+    from ..parallel.mesh import default_mesh
+
+    ns = _step_row_shards(X, w, offset, default_mesh())
+    nblk, rb, tail = gram_kernels.block_plan(X.shape[0] // ns, X.shape[1])
+    return {"gram_blocks": nblk, "gram_block_rows": rb,
+            "gram_tail_rows": tail}
+
+
 def _make_irls_kernel(family: Family):
     """One GLMIterationTask: (X, y, w, beta, offset) -> (Gram, XWz, dev, neff).
 
     X is row-sharded; the Gram/XWz accumulation routes through the
     kernels layer (`backend/kernels/gram.py`): XᵀWX and XᵀWz accumulate in
-    ONE blocked scan over row blocks — the (R, P) weighted design never
-    materializes.
+    ONE pass over row blocks sliced out of the design in place — the (R, P)
+    weighted design never materializes.
 
     Dispatch is the DrJAX MapReduce shape on a multi-shard mesh: the whole
     step runs inside ``mesh.shard_map`` over the ``rows`` axis — each
@@ -224,10 +250,10 @@ def _make_irls_kernel(family: Family):
     (`_shard_cols`) and row counts that don't divide the shard count keep
     the jit/GSPMD fallback. Sharded-vs-single coefficients agree to
     reduction-order ulps (the psum combines per-shard partial Grams in a
-    different order than one device's sequential block scan) — pinned at
+    different order than one device's sequential block pass) — pinned at
     tolerance in tests/test_sharded_frames.py."""
     from ..backend.kernels import gram as gram_kernels
-    from ..parallel.mesh import ROWS, default_mesh, n_row_shards, shard_map
+    from ..parallel.mesh import ROWS, default_mesh, shard_map
 
     # device scopes (telemetry.SCOPES) split the step in a capture; the
     # function keeps its name: the XLA module `jit__core` is read by name
@@ -258,9 +284,8 @@ def _make_irls_kernel(family: Family):
 
     def step(X, y, w, beta, offset):
         mesh = default_mesh()
-        ns = n_row_shards(mesh)
-        if (ns > 1 and X.shape[0] % ns == 0 and jnp.ndim(w) == 1
-                and jnp.ndim(offset) == 1 and _row_shardable(X, mesh)):
+        ns = _step_row_shards(X, w, offset, mesh)
+        if ns > 1:
             prog = sharded.get(mesh)
             if prog is None:
                 def spmd(X, y, w, beta, offset):
@@ -1394,8 +1419,9 @@ class GLM(ModelBuilder):
         nulldev = float(jnp.sum(family.deviance(y, mu0, w)))
         neff = float(jnp.sum(w))
 
+        gram_plan = _gram_plan_attrs(Xi, w, offset)
         if p.lambda_search:
-            with telemetry.span("train.glm.gram"):
+            with telemetry.span("train.glm.gram", **gram_plan):
                 G0, b_, _, _ = step(Xi, y, w, jnp.asarray(beta, jnp.float32),
                                     offset)
                 grad0 = np.abs(np.asarray(b_) - np.asarray(G0) @ beta)[:-1]
@@ -1453,7 +1479,7 @@ class GLM(ModelBuilder):
                     break
                 # dispatch, the wait for the Gram and its copy out
                 # (the copy drains the step)
-                with telemetry.span("train.glm.gram"):
+                with telemetry.span("train.glm.gram", **gram_plan):
                     G, b, dev, _ = step(
                         Xi, y, w, jnp.asarray(beta, jnp.float32), offset)
                     Gn = np.asarray(G, np.float64)

@@ -187,11 +187,39 @@ def test_default_train_step_moves_no_rows_between_chips(default_train_step):
     assert any(re.search(r"f32\[28,16,21,3\]", sh) for sh, _ in colls)
 
 
-def test_default_gram_compiles_for_v5e_at_higgs_rows(v5e):
-    """`gram_accumulate` at the block it really picks for 11M x 29 (ten
-    1,101,005-row blocks under the 2^25-cell budget)."""
+@pytest.mark.parametrize("rows,has_z", [
+    (HIGGS_PLEN, True),            # the one-chip cell's design
+    (HIGGS_PLEN // 4, True),       # a shard of it on four chips: 2,752,512
+    (HIGGS_PLEN, False),           # PCA's Gram: a mask as weight, no z
+], ids=["higgs", "four_chip_shard", "no_response"])
+def test_default_gram_compiles_for_v5e_at_higgs_rows(v5e, rows, has_z):
+    """`gram_accumulate` at the plan it really picks for 11M x 29 (sixteen
+    688,128-row blocks; three of 917,504 for a four-chip shard) reads the
+    design where it lies: the optimised program holds no ``copy``, ``pad``,
+    ``concatenate`` or ``dynamic-update-slice`` with a row-sized dimension
+    and no temporary to speak of. Handed to ``lax.scan`` as ``xs`` in ten
+    blocks of 1,101,005 the same design was padded, copied whole and
+    re-tiled a block: 3.96 GB of temporaries, 0.37 s of copies around
+    0.014 s of arithmetic in every IRLS job of the chip (PERF.md, PR 31)."""
     mesh = make_mesh(v5e[:1])
-    vec = _spec(mesh, (HIGGS_PLEN,), jnp.float32)
-    compiled = jax.jit(lambda X, W, z: gram.gram_accumulate(X, W, z)).lower(
-        _spec(mesh, (HIGGS_PLEN, F + 1), jnp.float32), vec, vec).compile()
-    assert compiled is not None
+    vec = _spec(mesh, (rows,), jnp.float32)
+    X = _spec(mesh, (rows, F + 1), jnp.float32)
+    args = (X, vec, vec) if has_z else (X, vec)
+    lowered = jax.jit(lambda X, W, z=None: gram.gram_accumulate(
+        X, W, z)).lower(*args)
+    t0 = time.time()
+    compiled = lowered.compile()
+    secs = time.time() - t0
+    nblk, rb, tail = gram.block_plan(rows, F + 1)
+    assert nblk > 1 and rb % 1024 == 0      # the blocked path is compiled
+    moved = re.findall(
+        r"^\s*(?:ROOT )?%[\w.\-]+ = (.*?) (copy|copy-start|pad|concatenate|"
+        r"dynamic-update-slice)\(", compiled.as_text(), re.M)
+    assert moved        # the (29, 29) and (29,) results copied out at the end
+    row_sized = [(op, sh) for sh, op in moved
+                 for dims in re.findall(r"\[([\d,]+)\]", sh)
+                 if max(int(d) for d in dims.split(",")) >= 1024]
+    assert not row_sized, row_sized
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+    # about a second on this sandbox's host; 51.6 s at 12 blocks of a scan
+    assert secs < 30

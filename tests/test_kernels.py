@@ -9,6 +9,7 @@ cases on exactly representable statistics.
 """
 
 import os
+import re
 
 import numpy as np
 import pytest
@@ -121,10 +122,19 @@ class TestGramParity:
         (2048, 6, 3, None, True),
         (4096, 8, 1, None, True), (5000, 33, 1, None, True),
         (16384, 65, 1, None, True),
-        # R not a multiple of the block: several blocks, zero pad rows
+        # a block under one lane tile is one tile: four 1024-row blocks
+        # sliced in place, then the 904 rows left as the tail slice
         (5000, 17, 2, 999, True),
         # a 0/1 mask as the weight and no response (PCA's Gram)
-        (1024, 9, 4, None, False)])
+        (1024, 9, 4, None, False),
+        # a lane-aligned block with a ragged tail: 2 x 2048 + 904
+        (5000, 7, 5, 2048, True),
+        # R under one lane tile: one block whatever the budget
+        (700, 5, 6, 100, True),
+        # no response, blocks and a tail: 3 x 1024 + 928
+        (4000, 9, 7, 1024, False),
+        # an exact multiple with 8 blocks, no tail
+        (8192, 6, 8, 1024, True)])
     def test_gram_matches_per_row_mul_sum_reference(self, R, P, seed, block,
                                                     has_z):
         """The PR 4 last-ulp policy reference: G[p,q] accumulated by
@@ -151,9 +161,51 @@ class TestGramParity:
                                    atol=1e-3)
 
 
+@pytest.mark.parametrize("R,P,block,want", [
+    # the HIGGS design on one chip: 16 blocks, no tail
+    (11_010_048, 29, None, (16, 688_128, 0)),
+    # a four-chip shard of it
+    (2_752_512, 29, None, (3, 917_504, 0)),
+    # the shape that once made 16-row slivers: under the budget, one block
+    (50_000, 33, None, (1, 50_000, 0)),
+    (5_000, 17, 999, (4, 1024, 904)),
+    (7, 3, None, (1, 7, 0)),
+    # 11M unpadded rows: the lane floor leaves a tail under one block
+    (11_000_000, 29, None, (16, 687_104, 6_336)),
+    # 168 blocks would leave more than a block over: the count moves on
+    # to the next multiple of 8 whose lane-floored block leaves less
+    (11_000_000, 29, 65_536, (176, 62_464, 6_336)),
+    (11_010_048, 29, 65_536, (168, 65_536, 0)),
+])
+def test_gram_block_plan(R, P, block, want):
+    from h2o_tpu.backend.kernels.gram import _BLOCK_CELLS, _LANE, block_plan
+
+    nblk, rb, tail = got = block_plan(R, P, block)
+    assert got == want
+    assert nblk * rb + tail == R and 0 <= tail < rb
+    if nblk > 1 or tail:
+        assert rb % _LANE == 0
+        assert rb <= max(block or _BLOCK_CELLS // P, _LANE)
+    assert nblk < 8 or nblk % 8 == 0
+
+
+def test_gram_under_the_budget_is_one_contraction():
+    """A design under the block budget lowers to the plain fused einsum:
+    no loop, no slice, one contraction for G and one for b."""
+    X = jnp.zeros((50_000, 33), jnp.float32)
+    v = jnp.zeros((50_000,), jnp.float32)
+    text = jax.jit(gram.gram_accumulate).lower(X, v, v).as_text()
+    assert text.count("dot_general") == 2
+    assert "while" not in text and "dynamic_slice" not in text
+    blocked = jax.jit(lambda X, W, z: gram.gram_accumulate(
+        X, W, z, block=8192)).lower(X, v, v).as_text()
+    assert "while" in blocked and "dynamic_slice" in blocked
+    assert not re.search(r"\b(pad|concatenate|reshape)\b.*50000", blocked)
+
+
 def test_pow2_block_rows():
     assert pow2_block_rows(8192, 2048) == 2048
-    assert pow2_block_rows(50000, 16384) == 16  # why gram pads instead
+    assert pow2_block_rows(50000, 16384) == 16  # why gram takes no divisor
     assert pow2_block_rows(7, 4) == 1  # degenerate: only 1 divides
 
 

@@ -129,12 +129,27 @@ def _binning_texts():
 
 @pytest.fixture(scope="module")
 def hlo():
-    irls, probe = _glm_texts()
-    sketch, bin_col, bin_mat = _binning_texts()
-    return {"gbm_step_pipelined": _gbm_step_text("1"),
-            "gbm_step_synchronous": _gbm_step_text("0"),
-            "sketch": sketch, "bin_column": bin_col, "bin_matrix": bin_mat,
-            "irls_step": irls, "deviance_probe": probe}
+    """The programs' compiled texts, every one compiled HERE: an executable
+    replayed from a persistent compile cache carries no scope names (its
+    key leaves op metadata out; PERF.md section 7 no. 7(b)), so whatever
+    cache the environment or an earlier test of this process placed is off
+    while these compile."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        irls, probe = _glm_texts()
+        sketch, bin_col, bin_mat = _binning_texts()
+        return {"gbm_step_pipelined": _gbm_step_text("1"),
+                "gbm_step_synchronous": _gbm_step_text("0"),
+                "sketch": sketch, "bin_column": bin_col,
+                "bin_matrix": bin_mat,
+                "irls_step": irls, "deviance_probe": probe}
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was_on)
+        cc.reset_cache()
 
 
 _LEVEL = ("gbm.grad", "gbm.route", "gbm.hist", "gbm.psum", "gbm.split",
@@ -216,6 +231,29 @@ def test_train_records_the_span_tree(algo):
             kids[e["parent"]] = kids.get(e["parent"], 0) + e["dur_us"]
     for sid, total in kids.items():
         assert total <= by_id[sid]["dur_us"], by_id[sid]["what"]
+
+
+@pytest.mark.parametrize("algo", ["glm", "glm_search"])
+def test_glm_gram_spans_carry_the_block_plan(monkeypatch, algo):
+    """Every ``train.glm.gram`` span says how `gram_accumulate` cut the
+    design the step was handed (a shard's rows on this 8-device mesh):
+    `gram.block_plan` of that shape. The budget is lowered so that the
+    frame splits: three 1024-row blocks and a 768-row tail a shard."""
+    from h2o_tpu.backend.kernels import gram
+    from h2o_tpu.parallel import mesh as meshmod
+
+    P = _F + 1                                    # the intercept column
+    monkeypatch.setattr(gram, "_BLOCK_CELLS", 1024 * P)
+    n = 30_000
+    rows = meshmod.padded_len(n) // meshmod.n_row_shards()
+    want = gram.block_plan(rows, P)
+    assert want == (3, 1024, 768)
+    fr = _frame(n)
+    got = _spans(_events_of(lambda: _TRAIN[algo](fr)), "train.glm.gram")
+    assert got
+    for e in got:
+        assert (e["gram_blocks"], e["gram_block_rows"],
+                e["gram_tail_rows"]) == want, e
 
 
 # ---------------------------------------------------------------------------
