@@ -2306,7 +2306,28 @@ def _estimator(algo: str, clsname: str) -> type:
 H2OGradientBoostingEstimator = _estimator("gbm", "H2OGradientBoostingEstimator")
 H2ORandomForestEstimator = _estimator("drf", "H2ORandomForestEstimator")
 H2OXGBoostEstimator = _estimator("xgboost", "H2OXGBoostEstimator")
-H2OGeneralizedLinearEstimator = _estimator("glm", "H2OGeneralizedLinearEstimator")
+
+
+class H2OGeneralizedLinearEstimator(H2OEstimator):
+    algo = "glm"
+
+    @staticmethod
+    def getGLMRegularizationPath(model) -> dict:
+        """`H2OGeneralizedLinearEstimator.getGLMRegularizationPath`
+        (h2o-py): per lambda fitted, the coefficients (natural and
+        standardised scale, by name) and the explained deviance."""
+        x = connection().request("GET", "/3/GetGLMRegPath",
+                                 params={"model": _model_id_of(model)})
+        ns = x["coefficient_names"]
+        return {
+            "lambdas": x["lambdas"], "alphas": x["alphas"],
+            "explained_deviance_train": x["explained_deviance_train"],
+            "explained_deviance_valid": x["explained_deviance_valid"],
+            "coefficients": [dict(zip(ns, c)) for c in x["coefficients"]],
+            "coefficients_std": [dict(zip(ns, c))
+                                 for c in x["coefficients_std"]]}
+
+
 H2OGeneralizedAdditiveEstimator = _estimator("gam", "H2OGeneralizedAdditiveEstimator")
 H2ODeepLearningEstimator = _estimator("deeplearning", "H2ODeepLearningEstimator")
 H2OKMeansEstimator = _estimator("kmeans", "H2OKMeansEstimator")

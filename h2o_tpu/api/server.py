@@ -2002,6 +2002,30 @@ def route(server: H2OServer, method: str, parts: list[str], query: dict,
             "support": [float(r["support"]) for r in rows]})
         return 200, {"significant_rules_table": schemas.table_schema(t)}
 
+    if head == "GetGLMRegPath" and method == "GET":
+        # `hex/api/MakeGLMModelHandler.extractRegularizationPath`
+        # (`GLMRegularizationPathV3`): a model fitted without a search
+        # answers with its one lambda
+        m = STORE.get(p.get("model", ""))
+        if m is None:
+            return _err(404, f"model {p.get('model')} not found")
+        o = m.output
+        if getattr(o, "lambdas", None) is None:
+            return _err(400, f"{getattr(m, 'algo_name', '?')} model "
+                             f"{m.key} keeps no regularisation path")
+        alpha = m.params.alpha if m.params.alpha is not None else 0.5
+        return 200, {
+            "model": schemas.key_schema(m.key, "Key<Model>"),
+            "lambdas": [float(v) for v in o.lambdas],
+            "alphas": [float(alpha)] * len(o.lambdas),
+            "explained_deviance_train": [
+                float(v) for v in o.explained_deviance_train],
+            # the path is not scored on a validation frame
+            "explained_deviance_valid": None,
+            "coefficients": np.asarray(o.coefficients).tolist(),
+            "coefficients_std": np.asarray(o.coefficients_std).tolist(),
+            "coefficient_names": m.dinfo.expanded_names + ["Intercept"]}
+
     # -- tabulate / DCT / SQL import ----------------------------------------
     if head == "Tabulate" and method == "POST":
         # `water/api/TabulateHandler` → `water/util/Tabulate`
@@ -2815,6 +2839,9 @@ _ROUTES_DOC = [
         ("POST", "/3/FriedmansPopescusH",
          "Friedman-Popescu H interaction statistic"),
         ("POST", "/3/SignificantRules", "RuleFit rule-importance table"),
+        ("GET", "/3/GetGLMRegPath",
+         "a GLM's regularisation path: per lambda, coefficients and "
+         "explained deviance"),
         ("POST", "/99/Tabulate", "co-occurrence tabulation of two columns"),
         ("POST", "/99/DCTTransformer", "row-wise discrete cosine transform"),
         ("POST", "/99/ImportSQLTable", "import a SQL table (sqlite3)"),

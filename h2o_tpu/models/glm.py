@@ -22,6 +22,7 @@ via device one-hot cross-products + host Henderson/EM solve).
 
 from __future__ import annotations
 
+import contextlib
 import functools
 from dataclasses import dataclass, field
 
@@ -341,14 +342,15 @@ def _admm_solve(G, b, l1, l2, free: np.ndarray, rho=None, iters=500, tol=1e-6,
     convex, so the warm start changes only the iteration count, not the
     tolerance the returned solution satisfies."""
     P = G.shape[0]
+    ridge = np.diag(np.where(free, 0.0, l2))  # the intercept is not shrunk
     if l1 <= 0:
-        A = G + l2 * np.eye(P)
+        A = G + ridge
         A[np.diag_indices(P)] += 1e-8
         return np.linalg.solve(A, b)
     # rho on the Gram's own scale keeps the x-update well conditioned and the
     # soft threshold l1/rho small relative to coefficient magnitudes.
     rho = rho or max(float(np.mean(np.diag(G))), l1, 1e-3)
-    A = G + (l2 + rho) * np.eye(P)
+    A = G + ridge + rho * np.eye(P)
     # one inversion, then the x-update is a matvec: numpy's generic solve
     # re-factorizes every call (it cannot exploit triangularity), which made
     # the ADMM loop O(iters·P³) — RuleFit's ~600-rule Gram measured 170 s in
@@ -464,7 +466,8 @@ class GLMParameters(Parameters):
                                    # (reference default; `hex/glm/GLM.java`
                                    # _early_stop_search) — False forces the
                                    # full nlambdas path
-    nlambdas: int = 30
+    nlambdas: int = -1             # -1 (the reference's documented default):
+                                   # 100 when alpha > 0, else 30
     lambda_min_ratio: float = 1e-4
     standardize: bool = True
     intercept: bool = True
@@ -1272,10 +1275,12 @@ class GLM(ModelBuilder):
                                     pad_cols=pad_cols)
         self._lincon = _linear_constraint_system(p.linear_constraints, dinfo,
                                                  pad_cols=pad_cols)
-        beta, lambda_used, dev, nulldev, neff, iters = self._fit(
+        beta, lambda_used, dev, nulldev, neff, iters, path = self._fit(
             X, y, w, offset, family, job)
+        betas = np.stack([e[3] for e in path])
         if pad_cols:  # strip padding: coefficients (all ~0) and design cols
-            beta = np.concatenate([beta[:dinfo.ncols_expanded], beta[-1:]])
+            keep = np.r_[:dinfo.ncols_expanded, -1]
+            beta, betas = beta[keep], betas[:, keep]
             X = X[:, :dinfo.ncols_expanded]
 
         output = ModelOutput()
@@ -1283,6 +1288,17 @@ class GLM(ModelBuilder):
         output.domains = {n: fr.vec(n).domain for n in names}
         output.response_domain = list(resp_domain) if resp_domain else None
         output.model_category = category
+        # the regularisation path as fitted (`GLMModel.RegularizationPath`):
+        # one lambda without a search; the model returned is its last
+        output.lambdas = [e[0] for e in path]
+        output.explained_deviance_train = [
+            1.0 - e[1] / nulldev if nulldev else float("nan") for e in path]
+        output.path_iterations = [e[2] for e in path]
+        output.coefficients_std = betas
+        output.coefficients = np.stack(
+            [_destandardize(b, dinfo) for b in betas])
+        output.lambda_best = lambda_used
+        output.lambda_best_index = len(path) - 1
         model = GLMModel(p, output, dinfo, beta, family)
         model.interaction_spec = self._interaction_spec
         # final scoring and metrics, through dispersion and p-values
@@ -1426,27 +1442,19 @@ class GLM(ModelBuilder):
                                     offset)
                 grad0 = np.abs(np.asarray(b_) - np.asarray(G0) @ beta)[:-1]
             lmax = float(grad0.max()) / max(alpha, 1e-3) / max(neff, 1.0)
-            lambdas = np.geomspace(lmax, lmax * p.lambda_min_ratio, p.nlambdas)
+            nlambdas = p.nlambdas if p.nlambdas > 0 else (
+                100 if alpha > 0 else 30)
+            lambdas = np.geomspace(lmax, lmax * p.lambda_min_ratio, nlambdas)
         else:
             lambdas = [p.lambda_ if p.lambda_ is not None else 0.0]
 
-        if p.solver and p.solver.upper() in ("L_BFGS", "LBFGS"):
-            if getattr(self, "_bounds", None) is not None:
-                # reference restriction: L-BFGS has no projection step
-                # (`hex/glm/GLM.java` beta constraints require IRLSM/COD)
-                raise ValueError("beta_constraints are not supported with "
-                                 "solver=L_BFGS — use IRLSM or "
-                                 "COORDINATE_DESCENT")
-            # walk the full lambda path warm-started, like the IRLSM branch
-            iters_total = 0
-            result = None
-            for lam in lambdas:
-                job.check_cancelled()
-                result = self._fit_lbfgs(Xi, y, w, offset, family, beta,
-                                         float(lam), alpha, neff, nulldev, job)
-                beta = result[0]
-                iters_total += result[5]
-            return (*result[:5], iters_total)
+        lbfgs = bool(p.solver) and p.solver.upper() in ("L_BFGS", "LBFGS")
+        if lbfgs and getattr(self, "_bounds", None) is not None:
+            # reference restriction: L-BFGS has no projection step
+            # (`hex/glm/GLM.java` beta constraints require IRLSM/COD)
+            raise ValueError("beta_constraints are not supported with "
+                             "solver=L_BFGS — use IRLSM or "
+                             "COORDINATE_DESCENT")
 
         use_cod = bool(p.solver) and p.solver.upper() in (
             "COORDINATE_DESCENT", "COORDINATE_DESCENT_NAIVE")
@@ -1463,112 +1471,126 @@ class GLM(ModelBuilder):
                 cod_lo, cod_hi = np.maximum(cod_lo, lo_b), np.minimum(cod_hi, hi_b)
 
         dev_probe = _make_dev_kernel(family)
-        best = None
-        iters_total = 0
-        dev_path_prev = None
         admm_state: dict = {}  # (z, u) warm start across IRLS/path solves
-        for lam in lambdas:
-            job.check_cancelled()
-            if best is not None and job.time_exceeded():
-                break  # keep the best-so-far lambda (partial path)
-            l1 = alpha * lam * neff
-            l2 = (1 - alpha) * lam * neff
-            dev_final = None
-            for it in range(max(p.max_iterations, 1)):
-                if it and job.time_exceeded():
-                    break
-                # dispatch, the wait for the Gram and its copy out
-                # (the copy drains the step)
-                with telemetry.span("train.glm.gram", **gram_plan):
-                    G, b, dev, _ = step(
-                        Xi, y, w, jnp.asarray(beta, jnp.float32), offset)
-                    Gn = np.asarray(G, np.float64)
-                    bn = np.asarray(b, np.float64)
-                iters_total += 1
-                with telemetry.span("train.glm.solve"):
-                    lincon = getattr(self, "_lincon", None)
-                    if lincon is not None:
-                        # exact active-set QP on the normal equations; box
-                        # bounds / non_negative fold into the inequality rows
-                        # (a post-hoc clip would break the linear constraints)
-                        Aeq, ceq, Ain, cin = lincon
-                        rows_in = [(Ain, cin)]
-                        P1 = len(beta)
-                        if p.non_negative:
-                            E = -np.eye(P1)[: P1 - 1]
-                            rows_in.append((E, np.zeros(P1 - 1)))
-                        if getattr(self, "_bounds", None) is not None:
+
+        # the path: one entry a lambda fitted, each warm-started at the last
+        # (lambda, deviance, iterations, beta on the training scale); without
+        # a search it has the one lambda. A search stops early
+        # (`GLM.java` _early_stop_search, default-on like the reference)
+        # once an extra lambda stops buying deviance: the remaining path
+        # only densifies coefficients, and each skipped lambda costs 1+
+        # full Gram passes
+        path: list = []
+        stopped_early = False
+        with (telemetry.span("train.glm.path", lambdas_planned=len(lambdas))
+              if p.lambda_search else contextlib.nullcontext()) as path_span:
+            for lam in lambdas:
+                job.check_cancelled()
+                if path and job.time_exceeded():
+                    break  # keep the last lambda fitted (partial path)
+                lam = float(lam)
+                its, dev_final = 0, None
+                if lbfgs:  # its own solver at this lambda: no IRLS below
+                    beta, dev_final, its = self._fit_lbfgs(
+                        Xi, y, w, offset, family, beta, lam, alpha, neff,
+                        nulldev, job)
+                l1 = alpha * lam * neff
+                l2 = (1 - alpha) * lam * neff
+                for it in range(0 if lbfgs else max(p.max_iterations, 1)):
+                    if it and job.time_exceeded():
+                        break
+                    # dispatch, the wait for the Gram and its copy out
+                    # (the copy drains the step)
+                    with telemetry.span("train.glm.gram", **gram_plan):
+                        G, b, dev, _ = step(
+                            Xi, y, w, jnp.asarray(beta, jnp.float32), offset)
+                        Gn = np.asarray(G, np.float64)
+                        bn = np.asarray(b, np.float64)
+                    its += 1
+                    with telemetry.span("train.glm.solve"):
+                        lincon = getattr(self, "_lincon", None)
+                        if lincon is not None:
+                            # exact active-set QP on the normal equations; box
+                            # bounds / non_negative fold into the inequality rows
+                            # (a post-hoc clip would break the linear constraints)
+                            Aeq, ceq, Ain, cin = lincon
+                            rows_in = [(Ain, cin)]
+                            P1 = len(beta)
+                            if p.non_negative:
+                                E = -np.eye(P1)[: P1 - 1]
+                                rows_in.append((E, np.zeros(P1 - 1)))
+                            if getattr(self, "_bounds", None) is not None:
+                                lo, hi = self._bounds
+                                for j in range(P1):
+                                    if np.isfinite(hi[j]):
+                                        e = np.zeros(P1)
+                                        e[j] = 1.0
+                                        rows_in.append((e[None, :],
+                                                        np.array([-hi[j]])))
+                                    if np.isfinite(lo[j]):
+                                        e = np.zeros(P1)
+                                        e[j] = -1.0
+                                        rows_in.append((e[None, :],
+                                                        np.array([lo[j]])))
+                            Ain_all = np.vstack([r[0] for r in rows_in])
+                            cin_all = np.concatenate([r[1] for r in rows_in])
+                            beta_new = _constrained_qp(Gn + l2 * np.eye(len(beta)),
+                                                       bn, Aeq, ceq, Ain_all,
+                                                       cin_all)
+                        elif use_cod:
+                            beta_new = _cod_solve(Gn, bn, l1, l2, free, beta,
+                                                  p.beta_epsilon, cod_lo, cod_hi)
+                        else:
+                            beta_new = _admm_solve(Gn, bn, l1, l2, free,
+                                                   state=admm_state)
+                        if lincon is None and p.non_negative:
+                            nb = beta_new[:-1]
+                            beta_new[:-1] = np.clip(nb, 0, None)
+                        if lincon is None \
+                                and getattr(self, "_bounds", None) is not None:
                             lo, hi = self._bounds
-                            for j in range(P1):
-                                if np.isfinite(hi[j]):
-                                    e = np.zeros(P1)
-                                    e[j] = 1.0
-                                    rows_in.append((e[None, :],
-                                                    np.array([-hi[j]])))
-                                if np.isfinite(lo[j]):
-                                    e = np.zeros(P1)
-                                    e[j] = -1.0
-                                    rows_in.append((e[None, :],
-                                                    np.array([lo[j]])))
-                        Ain_all = np.vstack([r[0] for r in rows_in])
-                        cin_all = np.concatenate([r[1] for r in rows_in])
-                        beta_new = _constrained_qp(Gn + l2 * np.eye(len(beta)),
-                                                   bn, Aeq, ceq, Ain_all,
-                                                   cin_all)
-                    elif use_cod:
-                        beta_new = _cod_solve(Gn, bn, l1, l2, free, beta,
-                                              p.beta_epsilon, cod_lo, cod_hi)
-                    else:
-                        beta_new = _admm_solve(Gn, bn, l1, l2, free,
-                                               state=admm_state)
-                    if lincon is None and p.non_negative:
-                        nb = beta_new[:-1]
-                        beta_new[:-1] = np.clip(nb, 0, None)
-                    if lincon is None \
-                            and getattr(self, "_bounds", None) is not None:
-                        lo, hi = self._bounds
-                        beta_new = np.clip(beta_new, lo, hi)
-                # convergence vs the INCOMING beta, first iteration
-                # included: a warm-started lambda whose solution has not
-                # moved converges in ONE step — the glmnet warm-path
-                # economics RuleFit's streaming IRLS already rides (the
-                # historic `if it else np.inf` guard forced every lambda
-                # to pay at least two Gram passes)
-                diff = np.max(np.abs(beta_new - beta))
-                beta = beta_new
-                if diff < p.beta_epsilon:
-                    dev_final = None  # beta moved since `dev` — probe below
+                            beta_new = np.clip(beta_new, lo, hi)
+                    # convergence vs the INCOMING beta, first iteration
+                    # included: a warm-started lambda whose solution has not
+                    # moved converges in ONE step — the glmnet warm-path
+                    # economics RuleFit's streaming IRLS already rides (the
+                    # historic `if it else np.inf` guard forced every lambda
+                    # to pay at least two Gram passes)
+                    diff = np.max(np.abs(beta_new - beta))
+                    beta = beta_new
+                    if diff < p.beta_epsilon:
+                        dev_final = None  # beta moved since `dev` — probe below
+                        break
+                    # deviance-plateau check via the CHEAP probe (one matvec)
+                    # at the post-solve beta, instead of discovering the
+                    # plateau one full Gram pass later: same epsilon, same
+                    # criterion, measured one iteration earlier and ~P× cheaper
+                    with telemetry.span("train.glm.probe"):
+                        dev_new = float(dev_probe(
+                            Xi, y, w, jnp.asarray(beta, jnp.float32), offset))
+                    dev_final = dev_new
+                    if (abs(float(dev) - dev_new)
+                            < p.objective_epsilon * abs(nulldev)):
+                        break
+                if dev_final is None:
+                    with telemetry.span("train.glm.probe"):
+                        dev_final = float(dev_probe(
+                            Xi, y, w, jnp.asarray(beta, jnp.float32), offset))
+                path.append((lam, dev_final, its, beta.copy()))
+                if (p.lambda_search and p.early_stopping and len(path) > 1
+                        and path[-2][1] - dev_final < 1e-4 * abs(nulldev)):
+                    stopped_early = True
                     break
-                # deviance-plateau check via the CHEAP probe (one matvec)
-                # at the post-solve beta, instead of discovering the
-                # plateau one full Gram pass later: same epsilon, same
-                # criterion, measured one iteration earlier and ~P× cheaper
-                with telemetry.span("train.glm.probe"):
-                    dev_new = float(dev_probe(
-                        Xi, y, w, jnp.asarray(beta, jnp.float32), offset))
-                dev_final = dev_new
-                if abs(float(dev) - dev_new) < p.objective_epsilon * abs(nulldev):
-                    break
-            if dev_final is None:
-                with telemetry.span("train.glm.probe"):
-                    dev_final = float(dev_probe(
-                        Xi, y, w, jnp.asarray(beta, jnp.float32), offset))
-            dev = dev_final
-            best = (beta.copy(), float(lam), dev)
-            if (p.lambda_search and getattr(p, "early_stopping", True)
-                    and dev_path_prev is not None
-                    and dev_path_prev - dev < 1e-4 * abs(nulldev)):
-                # lambda-search early stop (`GLM.java` _early_stop_search,
-                # default-on like the reference): once an extra lambda
-                # stops buying deviance the remaining path only densifies
-                # coefficients — each skipped lambda costs 1+ full Gram
-                # passes. (On paths whose deviance keeps improving — the
-                # rulefit bench leg does — this never fires; its wins came
-                # from the probe + the reference epsilons instead.)
-                break
-            dev_path_prev = dev
-        beta, lam, dev = best
-        return beta, lam, dev, nulldev, neff, iters_total
+            iters_total = sum(e[2] for e in path)
+            if path_span is not None:
+                path_span.attrs.update(
+                    lambdas_fit=len(path), iterations=iters_total,
+                    active=int(np.count_nonzero(beta[:-1])),
+                    lambda_final=path[-1][0], stopped_early=stopped_early)
+                telemetry.inc("train.glm.path.lambdas", len(path))
+                telemetry.inc("train.glm.path.iterations", iters_total)
+        lam, dev = path[-1][:2]
+        return beta, lam, dev, nulldev, neff, iters_total, path
 
     def _fit_lbfgs(self, Xi, y, w, offset, family, beta0, lam, alpha, neff,
                    nulldev, job):
@@ -1623,7 +1645,7 @@ class GLM(ModelBuilder):
             prev = v
         mu = family.linkinv(Xi @ beta + offset)
         dev = float(jnp.sum(family.deviance(y, mu, w)))
-        return (np.asarray(beta, np.float64), lam, dev, nulldev, neff, iters)
+        return np.asarray(beta, np.float64), dev, iters
 
     def _build_ordinal(self, job, names, y_dev, resp_domain):
         """Ordinal (proportional-odds) regression — `hex/glm/GLM.java`'s

@@ -38,6 +38,9 @@ class RuleFitParameters(GLMParameters):
     min_rule_length: int = 3
     max_rule_length: int = 3
     max_num_rules: int = -1        # -1 = no cap (reference default)
+    nlambdas: int = 20             # the lasso path walks at most 20 lambdas
+                                   # (the GLM's own default, -1, resolves to
+                                   # 100 or 30)
     model_type: str = "rules_and_linear"  # rules_and_linear | rules | linear
     rule_generation_ntrees: int = 50
     beta_epsilon: float = 1e-4     # the reference GLM IRLSM default — the
@@ -613,8 +616,7 @@ class RuleFit(ModelBuilder):
                 if p.weights_column else jnp.ones((), jnp.float32))
         y, w, offset, _neff, _b0 = _stream_prelude(family)(
             y_dev, wcol, fr.nrow)
-        beta, _lam, _dev, _nulldev, _neff2, _iters = gb._fit(
-            Xd, y, w, offset, family, job)
+        beta = gb._fit(Xd, y, w, offset, family, job)[0]
         return np.asarray(beta, np.float64)
 
     def _fit_streaming(self, job, model, fr, y_dev, category) -> np.ndarray:

@@ -170,6 +170,12 @@ _counter("train.chunk.count", "GBM/DRF boosting-chunk iterations")
 _counter("train.gbm.psum_bytes",
          "bytes of level histogram handed to the cross-shard psum, a shard, "
          "counted from shapes at chunk dispatch (0 on one row shard)")
+_counter("train.glm.path.lambdas",
+         "lambdas a GLM lambda_search fitted before its path ended, added "
+         "once a job")
+_counter("train.glm.path.iterations",
+         "IRLS iterations (Gram passes) a GLM lambda_search spent on its "
+         "path, added once a job")
 _histogram("train.chunk.seconds",
            "wall per boosting chunk (train_fn dispatch + scoring + "
            "history, the score_tree_interval boundary)")

@@ -206,8 +206,15 @@ _TREE = {
             "train.glm.metrics": "train.glm",
             "train.program.load": "train.glm.gram"},
 }
-# a lambda search has the one recording path: the same parts on the ring
-_TREE["glm_search"] = _TREE["glm"]
+# a lambda search has the one recording path: the same parts on the ring,
+# the lambda loop's inside its one train.glm.path (the lambda_max pass
+# keeps its train.glm.gram under the job)
+_TREE["glm_search"] = {
+    "train.glm.design": "train.glm", "train.glm.metrics": "train.glm",
+    "train.glm.path": "train.glm",
+    "train.glm.gram": ("train.glm", "train.glm.path"),
+    "train.glm.solve": "train.glm.path", "train.glm.probe": "train.glm.path",
+    "train.program.load": "train.glm.gram"}
 _TRAIN = {"gbm": _train_gbm, "glm": _train_glm,
           "glm_search": lambda fr: _train_glm(
               fr, lambda_=None, lambda_search=True, nlambdas=6)}
@@ -224,13 +231,52 @@ def test_train_records_the_span_tree(algo):
         assert got, f"no {name} span"
         for e in got:
             assert e["trace"] == root["trace"], name
-            assert by_id[e["parent"]]["what"] == parent, (name, e)
+            assert by_id[e["parent"]]["what"] in (
+                parent if isinstance(parent, tuple) else (parent,)), (name, e)
     kids: dict = {}
     for e in by_id.values():
         if e.get("parent") in by_id:
             kids[e["parent"]] = kids.get(e["parent"], 0) + e["dur_us"]
     for sid, total in kids.items():
         assert total <= by_id[sid]["dur_us"], by_id[sid]["what"]
+
+
+@pytest.mark.parametrize("search", [True, False])
+def test_a_lambda_search_records_one_path_span_and_counts_it(search):
+    """ONE ``train.glm.path`` a ``lambda_search`` job, around the lambda
+    loop, with what the walk did; the two declared counters grow by it
+    once a job; the ring holds the parts' events plus that one. A job
+    without a search opens none and counts nothing."""
+    fr = _frame()
+    before = {k: telemetry.value(f"train.glm.path.{k}")
+              for k in ("lambdas", "iterations")}
+    events = _events_of(lambda: _TRAIN["glm_search" if search else "glm"](fr))
+    grew = {k: telemetry.value(f"train.glm.path.{k}") - v
+            for k, v in before.items()}
+    spans = [e for e in events
+             if e["kind"] == "span" and e["what"].startswith("train.")]
+    paths = _spans(events, "train.glm.path")
+    parts = [e for e in spans if e["what"] in (
+        "train.glm", "train.glm.design", "train.glm.gram", "train.glm.solve",
+        "train.glm.probe", "train.glm.metrics", "train.program.load")]
+    assert len(spans) == len(parts) + len(paths)
+    if not search:
+        assert paths == [] and grew == {"lambdas": 0, "iterations": 0}
+        return
+    (path,) = paths
+    assert path["lambdas_planned"] == 6
+    assert 1 <= path["lambdas_fit"] <= 6
+    assert path["stopped_early"] == (path["lambdas_fit"] < 6)
+    # every iteration is a .gram and a .solve inside the path; the
+    # lambda_max pass is the one .gram outside it
+    inside = [e for e in spans if e.get("parent") == path["span"]]
+    grams = [e for e in inside if e["what"] == "train.glm.gram"]
+    assert path["iterations"] == len(grams) == len(
+        [e for e in inside if e["what"] == "train.glm.solve"])
+    assert len(_spans(events, "train.glm.gram")) == len(grams) + 1
+    assert 0 <= path["active"] <= _F and path["lambda_final"] > 0
+    assert grew == {"lambdas": path["lambdas_fit"],
+                    "iterations": path["iterations"]}
 
 
 @pytest.mark.parametrize("algo", ["glm", "glm_search"])
