@@ -35,7 +35,7 @@ from ..parallel.mesh import (ROWS, default_mesh, n_row_shards,
 from .distributions import Bernoulli, Gaussian, get_distribution
 from .model_base import Model, ModelBuilder, ModelOutput, Parameters, make_metrics
 from .tree.binning import (bin_matrix, compute_bin_edges,
-                           compute_bin_edges_cols)
+                           compute_bin_edges_cols, sketch_span_attrs)
 from .tree.engine import (TreeConfig, hist_psum_bytes, make_train_fn,
                           plan_hist_groups, predict_forest,
                           psum_payload_bytes)
@@ -518,8 +518,9 @@ class GBM(ModelBuilder):
         else:
             X = fr.as_matrix(names)
         # the quantile sketch, until the edges are on the host (the copy out
-        # drains it)
-        with telemetry.span("train.gbm.sketch"):
+        # drains it); its attributes say which count contraction ran
+        with telemetry.span("train.gbm.sketch", **sketch_span_attrs(
+                y_dev.shape[0], len(names), p.histogram_type)):
             edges_np = (
                 compute_bin_edges_cols(feat_vecs, is_cat, p.nbins, **bin_kw)
                 if use_binned

@@ -57,32 +57,102 @@ def _rows_per_device(R: int) -> int:
     return R // ns if ns > 1 and R % ns == 0 else R
 
 
+#: rows of one step of the sketch's loop at the most: on a v5e a pass over
+#: 11M x 28 reads 20.0 ms at 8192, 18.4 at 32768, 18.1 at 65536 (PERF.md,
+#: PR 33); frames of a million rows and up are padded to a multiple of 65536
+#: a shard (`mesh.padded_len`), so this divides them
+_SKETCH_ROW_BLOCK = 32768
+
+
+def _sketch_digits(nb: int) -> tuple[int, int]:
+    """(d_hi, d_lo): the widths of the two digits a bin index splits into,
+    ``b = d_lo * hi + lo``. ``d_lo`` is the smallest power of two whose
+    square reaches nb and ``d_hi`` covers the rest: 32 x 32 at 1024 (and at
+    1000, 24 surplus cells that no index reaches), 16 x 16 at 256, 8 x 8 at
+    64."""
+    d_lo = 1 << (((nb - 1).bit_length() + 1) // 2)
+    return -(-nb // d_lo), d_lo
+
+
+def _sketch_tile_bytes(rb: int, Fb: int, nb: int) -> int:
+    """Bytes one loop step of `_sketch_hist` holds: two bf16 digit one-hots
+    and their f32 counts."""
+    d_hi, d_lo = _sketch_digits(nb)
+    return rb * Fb * (d_hi + d_lo) * 2 + Fb * d_hi * d_lo * 4
+
+
 def _sketch_plan(R: int, F: int, nb: int,
                  budget_bytes: int | None) -> tuple[int, int]:
     """Pick (rb, Fb) — row-block and feature-block sizes — for the quantile
     sketch from a live HBM budget, so the sketch scales to any (R, F) by
     construction. ``R`` is the rows ONE device holds (`_rows_per_device`).
 
-    Peak f32 footprint the sketch ADDS on top of the caller's (R, F) matrix:
-    the (R, Fb) column block it slices out (≤ budget/4), the per-scan-step
-    (rb, Fb, nb) one-hot (≤ budget/8), and the (F, nb)-sized accumulators /
-    quantile read-out (noise). At the airlines-116M×31 shape under a v5e
-    budget this yields Fb≈7, rb=1024 — ~3.3 GB of intermediates where the
-    unblocked sketch wanted the full 14 GB matrix reshaped at once."""
+    Peak footprint the sketch ADDS on top of the caller's (R, F) matrix:
+    the f32 (R, Fb) column block it slices out (≤ budget/4), and a loop
+    step's tile (≤ budget/8): the two bf16 digit one-hots, (rb, Fb, d_hi)
+    and (rb, Fb, d_lo), and the f32 (Fb, d_hi, d_lo) counts they contract
+    to. The quantile read-out is noise. At the airlines-116M×31 shape under
+    a v5e budget this yields Fb≈7, rb=32768 — 3.3 GB of column block and a
+    29 MB tile (the (rb, Fb, nb) f32 one-hot this replaced would be 940 MB
+    at that rb). ``rb`` starts at `_SKETCH_ROW_BLOCK` and halves only under
+    a budget that small, or down to the first power of two that holds all
+    R rows."""
     budget = budget_bytes or _DEFAULT_SKETCH_BUDGET
     col_cap = max(budget // 4, 1 << 20)
-    onehot_cap = max(budget // 8, 1 << 20)
+    tile_cap = max(budget // 8, 1 << 20)
     Fb = int(min(F, max(1, col_cap // (4 * max(R, 1)))))
-    rb = 1024
-    while rb > 64 and rb * Fb * nb * 4 > onehot_cap:
+    rb = _SKETCH_ROW_BLOCK
+    while rb > 64 and (rb // 2 >= R
+                       or _sketch_tile_bytes(rb, Fb, nb) > tile_cap):
         rb //= 2
-    while Fb > 1 and rb * Fb * nb * 4 > onehot_cap:
+    while Fb > 1 and _sketch_tile_bytes(rb, Fb, nb) > tile_cap:
         Fb = max(1, Fb // 2)
     return rb, Fb
 
 
+def _sketch_bins(x, lo, span, nb: int):
+    """Index of each value's bin among ``nb`` equal bins from ``lo`` (F,)
+    over ``span`` (F,): int32 in [0, nb - 1], values outside clipped into
+    the edge bins, NaN -> -1 (counted nowhere)."""
+    b = jnp.clip(((x - lo[None, :]) / span[None, :] * nb).astype(jnp.int32),
+                 0, nb - 1)
+    return jnp.where(jnp.isnan(x), -1, b)
+
+
+def _sketch_hist(X, lo, hi, nb: int, rb: int):
+    """(F, nb) f32 counts of `_sketch_bins` over all rows of X (a multiple of
+    ``rb`` rows), one loop step a row block, sliced out of X where it lies
+    (handed to ``lax.scan`` as ``xs`` the matrix was copied whole first: as
+    long again as the counting, and 2.6 GB of temporaries at 11M x 28). A
+    block's indices split into two digits (`_sketch_digits`), each digit
+    becomes a narrow bf16 one-hot, and ``rfa,rfb->fab`` contracts the two
+    over the block's rows on the MXU into f32: per feature a (d_hi, d_lo)
+    table whose cell (hi, lo) is bin ``d_lo * hi + lo``'s count. 0/1 is
+    exact in bf16 and the sums are f32, so every cell is the exact integer
+    an nb-wide one-hot summed over rows gives, for d_hi + d_lo compares a
+    value and not nb."""
+    R, F = X.shape
+    d_hi, d_lo = _sketch_digits(nb)
+    shift = d_lo.bit_length() - 1
+    span = jnp.maximum(hi - lo, 1e-30)
+
+    def body(i, acc):
+        xb = jax.lax.dynamic_slice_in_dim(X, i * rb, rb, axis=0)
+        b = _sketch_bins(xb, lo, span, nb)
+        # b = -1 has high digit -1: a zero one-hot row, so NaN adds nothing
+        oh_hi = jax.nn.one_hot(b >> shift, d_hi, dtype=jnp.bfloat16)
+        oh_lo = jax.nn.one_hot(b & (d_lo - 1), d_lo, dtype=jnp.bfloat16)
+        return acc + jnp.einsum("rfa,rfb->fab", oh_hi, oh_lo,
+                                preferred_element_type=jnp.float32)
+
+    h = jax.lax.fori_loop(0, R // rb, body,
+                          jnp.zeros((F, d_hi, d_lo), jnp.float32))
+    return h.reshape(F, d_hi * d_lo)[:, :nb]
+
+
 @telemetry.scope("gbm.sketch")
-def _sketch_core(X, qs, nb: int = 1024, rb: int = 1024, axis=None):
+def _sketch_core(X, qs, nb: int = 1024, rb: int = _SKETCH_ROW_BLOCK,
+                 axis=None):
     """(nq, F) per-column quantiles via a TWO-PASS histogram sketch, all on
     device over ALL rows.
 
@@ -96,10 +166,12 @@ def _sketch_core(X, qs, nb: int = 1024, rb: int = 1024, axis=None):
     COMPILE time alone (measured; structural, independent of size), which
     was the single largest item in the GBM cold-start wall. Histograms are
     one-hot einsums — the engine's bread-and-butter shape — and compile in
-    ~1 s. Pass 1 spans [min, max]; pass 2 re-bins inside the [0.1%, 99.9%]
-    bracket (outliers clip into edge bins but keep their cumulative mass,
-    the `_leaf_quantile_vals` trick), so each quantile is read at
-    (robust span)/nb resolution — far finer than the 20-bin edges it feeds.
+    ~1 s: `_sketch_hist` contracts two narrow digit one-hots of the bin
+    index over a row block's rows on the MXU. Pass 1 spans [min, max];
+    pass 2 re-bins inside the [0.1%, 99.9%] bracket (outliers clip into
+    edge bins but keep their cumulative mass, the `_leaf_quantile_vals`
+    trick), so each quantile is read at (robust span)/nb resolution — far
+    finer than the 20-bin edges it feeds.
 
     Row counts that don't divide ``rb`` are NaN-padded up to the next block
     boundary (NaN rows drop out of every count), so ``rb`` is a free memory
@@ -111,7 +183,6 @@ def _sketch_core(X, qs, nb: int = 1024, rb: int = 1024, axis=None):
     if pad:
         X = jnp.concatenate(
             [X, jnp.full((pad, F), jnp.nan, X.dtype)], axis=0)
-    nblk = (R + pad) // rb
     ok = ~jnp.isnan(X)
     nval = jnp.sum(ok, axis=0).astype(jnp.float32)
     cmin = jnp.nanmin(X, axis=0)
@@ -126,17 +197,7 @@ def _sketch_core(X, qs, nb: int = 1024, rb: int = 1024, axis=None):
         cmax = jnp.where(nval > 0, cmax, jnp.nan)
 
     def hist(lo, hi):
-        span = jnp.maximum(hi - lo, 1e-30)
-
-        def body(acc, xb):
-            b = jnp.clip(((xb - lo[None, :]) / span[None, :] * nb)
-                         .astype(jnp.int32), 0, nb - 1)
-            b = jnp.where(jnp.isnan(xb), -1, b)  # one_hot(-1) = zero row
-            oh = jax.nn.one_hot(b, nb, dtype=jnp.float32)   # (rb, F, nb)
-            return acc + jnp.sum(oh, axis=0), None
-
-        h, _ = jax.lax.scan(body, jnp.zeros((F, nb), jnp.float32),
-                            X.reshape(nblk, rb, F))
+        h = _sketch_hist(X, lo, hi, nb, rb)
         return h if axis is None else jax.lax.psum(h, axis)
 
     cum1 = jnp.cumsum(hist(cmin, cmax), axis=1)
@@ -197,11 +258,11 @@ def hist_quantile_sketch(X, qs, nb: int = 1024,
                          budget_bytes=_UNSET) -> np.ndarray:
     """Memory-bounded streaming driver for `_hist_quantile_rows`: columns go
     through the two-pass sketch in blocks of Fb, with (rb, Fb) planned from
-    the live HBM budget (`_sketch_plan`), so the per-step (rb, Fb, nb)
-    one-hot and the (nblk, rb, Fb) reshape never exceed memory at any
-    (R, F) — 116M×31 included. Each column's quantiles depend only on that
-    column, so blocking is exact, not an approximation. Returns the host
-    (nq, F) array (the only thing that crosses back)."""
+    the live HBM budget (`_sketch_plan`), so the (R, Fb) column block and
+    the per-step digit one-hots ((rb, Fb, d_hi) and (rb, Fb, d_lo)) never
+    exceed memory at any (R, F) — 116M×31 included. Each column's quantiles
+    depend only on that column, so blocking is exact, not an approximation.
+    Returns the host (nq, F) array (the only thing that crosses back)."""
     if budget_bytes is _UNSET:
         from ...backend.memory import hbm_budget_bytes
 
@@ -284,6 +345,10 @@ def _exact_bin_row_limit() -> int:
     return get_int("H2O_TPU_EXACT_BIN_ROWS")
 
 
+#: the histogram types whose cuts are read off the quantile sketch
+_QUANTILE_HT = ("auto", "quantilesglobal", "exact")
+
+
 def _validate_ht(histogram_type: str) -> str:
     ht = (histogram_type or "AUTO").lower()
     if ht not in ("auto", "quantilesglobal", "uniformadaptive", "random",
@@ -299,6 +364,25 @@ def _wants_exact(ht: str, R: int, nbins: int, nbins_top_level: int) -> bool:
     return (ht == "exact"
             or (R <= _exact_bin_row_limit() and nbins_top_level > nbins
                 and ht in ("auto", "quantilesglobal", "uniformadaptive")))
+
+
+def sketch_span_attrs(R: int, F: int, histogram_type: str = "AUTO") -> dict:
+    """``train.gbm.sketch``'s attributes: the plan the quantile sketch of an
+    (R, F) frame's edges runs under, from shapes and the live budget as
+    `hist_quantile_sketch` resolves it — the digit widths of the count
+    contraction, the rows of a loop step, the column blocks streamed and a
+    device's loop steps in one pass over one of them. Empty where the
+    histogram type reads no quantiles."""
+    if F == 0 or _validate_ht(histogram_type) not in _QUANTILE_HT:
+        return {}
+    from ...backend.memory import hbm_budget_bytes
+
+    nb = 1024                   # `hist_quantile_sketch`'s, which no caller sets
+    rows = _rows_per_device(R)
+    rb, Fb = _sketch_plan(rows, F, nb, hbm_budget_bytes())
+    return {"sketch_digits": "%dx%d" % _sketch_digits(nb),
+            "sketch_row_block": rb, "sketch_col_blocks": -(-F // Fb),
+            "sketch_scan_steps": -(-rows // rb)}
 
 
 def _edges_from_stats(F, is_cat, col_min, col_max, qrows, exact, ht,
@@ -392,7 +476,7 @@ def compute_bin_edges(X: jax.Array, is_cat: np.ndarray, nbins: int,
     qs = np.linspace(0, 1, nbins + 1)[1:-1]
     col_min, col_max = (np.asarray(v) for v in _col_minmax(Xj))
     qrows = None
-    if ht in ("auto", "quantilesglobal", "exact"):
+    if ht in _QUANTILE_HT:
         qrows = hist_quantile_sketch(Xj, tuple(qs))
     return _edges_from_stats(F, is_cat, col_min, col_max, qrows, exact, ht,
                              nbins, nbins_top_level, nbins_cats, seed)
@@ -439,7 +523,7 @@ def compute_bin_edges_cols(cols, is_cat: np.ndarray, nbins: int,
             exact[1][f0:f0 + Fb] = np.asarray(counts)
     qs = np.linspace(0, 1, nbins + 1)[1:-1]
     qrows = None
-    if ht in ("auto", "quantilesglobal", "exact"):
+    if ht in _QUANTILE_HT:
         qrows = hist_quantile_sketch_cols(cols, tuple(qs),
                                           budget_bytes=budget_bytes)
     return _edges_from_stats(F, is_cat, col_min, col_max, qrows, exact, ht,

@@ -164,6 +164,13 @@ def test_level_histograms_fold_node_and_statistic_into_one_dimension(
     assert not [w for w in windows if re.search(r"pad=0_0x\d+_\d+", w)], windows
 
 
+def _collectives(hlo: str) -> list[tuple[str, str]]:
+    """(result shapes, kind) of every collective in an optimised program."""
+    return re.findall(
+        r"^\s*(?:ROOT )?%?[\w.\-]+ = (.*?) (all-gather|all-reduce|all-to-all|"
+        r"collective-permute|reduce-scatter)(?:-start)?\(", hlo, re.M)
+
+
 def test_default_train_step_moves_no_rows_between_chips(default_train_step):
     """Across four chips the train step reduces histograms and node totals,
     never rows. Left to GSPMD, a scan over row blocks of a row-sharded array
@@ -173,9 +180,7 @@ def test_default_train_step_moves_no_rows_between_chips(default_train_step):
     or a collective-permute), and no ``all-reduce`` whose result has a
     dimension the size of a shard's rows or of one of its row blocks."""
     hlo = default_train_step[1].as_text()
-    colls = re.findall(
-        r"^\s*(?:ROOT )?%?[\w.\-]+ = (.*?) (all-gather|all-reduce|all-to-all|"
-        r"collective-permute|reduce-scatter)(?:-start)?\(", hlo, re.M)
+    colls = _collectives(hlo)
     assert colls, "a four-chip step with no collective reduces nothing"
     assert {k for _, k in colls} == {"all-reduce"}, sorted(
         {(k, sh[:60]) for sh, k in colls if k != "all-reduce"})
@@ -223,3 +228,87 @@ def test_default_gram_compiles_for_v5e_at_higgs_rows(v5e, rows, has_z):
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
     # about a second on this sandbox's host; 51.6 s at 12 blocks of a scan
     assert secs < 30
+
+
+# ---------------------------------------------------------------------------
+# the quantile sketch (binning._sketch_core) at the HIGGS cells' shapes
+# ---------------------------------------------------------------------------
+SKETCH_NB = 1024
+_QS = tuple(np.linspace(0, 1, NBINS + 1)[1:-1])
+
+
+def _while_bodies(hlo: str) -> list[str]:
+    """The text of every computation some ``while`` names as its body."""
+    comps = dict(re.findall(
+        r"^(?:ENTRY )?(%[\w.\-]+) \([^\n]*\) -> [^\n]*\{\n(.*?)^\}", hlo,
+        re.M | re.S))
+    return [comps[b] for b in sorted(set(re.findall(r"body=(%[\w.\-]+)", hlo)))]
+
+
+def _sketch_program(v5e, chips: int):
+    """(compiled text, rb): the sketch as `_sketch_block` dispatches it at
+    11,010,048 rows a chip and the `rb` `_sketch_plan` gives under a v5e
+    budget — `_hist_quantile_rows` on one chip, the `shard_map` form over
+    the 2x2."""
+    from h2o_tpu.models.tree import binning
+
+    rb, Fb = binning._sketch_plan(HIGGS_PLEN, F, SKETCH_NB,
+                                  int(16 * (1 << 30) * 0.85))
+    assert Fb == F and rb != SKETCH_NB
+    assert HIGGS_PLEN % (8 * rb) == 0               # block count: 8 divides
+    mesh = make_mesh(v5e[:chips])
+    X = _spec(mesh, (chips * HIGGS_PLEN, F), jnp.float32,
+              P(ROWS, None) if chips > 1 else P())
+    fn = (binning._sharded_sketch(mesh, _QS, SKETCH_NB, rb) if chips > 1
+          else jax.jit(lambda X: binning._hist_quantile_rows(
+              X, _QS, nb=SKETCH_NB, rb=rb)))
+    compiled = fn.lower(X).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+    return compiled.as_text(), rb
+
+
+@pytest.mark.parametrize("chips", [1, 4], ids=["one_chip", "shard_map_2x2"])
+def test_sketch_counts_are_a_digit_contraction_on_the_mxu(v5e, chips):
+    """Both passes of the sketch count a row block's 1024 bins as two
+    32-wide digit one-hots contracted over the block's rows
+    (``rfa,rfb->fab``), not as a ``(rb, 28, 1024)`` one-hot summed on the
+    VPU: 292,448 estimated cycles a 1024-row block for that sum alone
+    (285.6 a row; on the chip 0.2097 s a pass, the two longest operations of
+    every GBM job until PR 33). In each loop body nothing is 1024 wide, the
+    contraction is under scope ``gbm.sketch`` with no padded window, and the
+    body's estimated cycles a row stay under 1.3 times what the kept form
+    reads (75,520 a 32,768-row block, 2.30 a row; the chip 18.4 ms a pass,
+    PERF.md section 6). The blocks are sliced out of the matrix where it
+    lies: as ``xs`` of a scan it was copied whole first (2.64 GB of
+    temporaries, and as long again as the counting)."""
+    hlo, rb = _sketch_program(v5e, chips)
+    bodies = [b for b in _while_bodies(hlo) if "gbm.sketch" in b]
+    assert len(bodies) == 2, len(bodies)               # pass 1 and pass 2
+    for body in bodies:
+        shapes = re.findall(r"\b(?:f32|bf16|s32|s8|pred|u32)\[([\d,]+)\]", body)
+        assert shapes
+        # no (rb, 28, 1024) one-hot, nor any other 1024-wide value
+        assert not [d for d in shapes if str(SKETCH_NB) in d.split(",")]
+        assert re.search(
+            r'fusion\(.*op_name="[^"]*gbm\.sketch[^"]*dot_general', body)
+        cycles = sum(int(c) for c in re.findall(
+            r'"estimated_cycles":"(\d+)"', body))
+        assert 0 < cycles / rb < 1.3 * 2.30, cycles / rb
+    # the contraction itself (inside the body's fusion): one a pass, and the
+    # 28 features a batch, not a window the compiler had to pad (PR 29)
+    windows = [w for w, op in re.findall(
+        r'^.* convolution\(.*window=\{([^}]*)\}.*op_name="([^"]*)"', hlo, re.M)
+        if "gbm.sketch" in op]
+    assert len(windows) == 2, windows
+    assert not [w for w in windows if "pad=" in w], windows
+    colls = _collectives(hlo)
+    if chips == 1:
+        assert not colls
+        return
+    # four chips reduce counts, extrema and the two (28, 1024) histograms,
+    # never rows
+    assert {k for _, k in colls} == {"all-reduce"}, colls
+    sizes = [int(np.prod([int(d) for d in dims.split(",") if d]))
+             for shapes, _ in colls
+             for dims in re.findall(r"\[([\d,]*)\]", shapes)]
+    assert max(sizes) == F * SKETCH_NB and sizes.count(F * SKETCH_NB) == 2, colls

@@ -302,6 +302,34 @@ def test_glm_gram_spans_carry_the_block_plan(monkeypatch, algo):
                 e["gram_tail_rows"]) == want, e
 
 
+@pytest.mark.parametrize("histogram_type,n", [
+    ("AUTO", 30_000), ("QuantilesGlobal", 300_000), ("UniformAdaptive", _N)])
+def test_gbm_sketch_span_carries_the_sketch_plan(histogram_type, n):
+    """``train.gbm.sketch`` says which count contraction ran and in what
+    blocks: `binning._sketch_plan` of a shard's rows (8-device mesh) and
+    `_sketch_digits` of the sketch's 1024 bins. A histogram type that reads
+    no quantiles runs no sketch and says nothing."""
+    from h2o_tpu.backend.memory import hbm_budget_bytes
+    from h2o_tpu.models.tree import binning
+    from h2o_tpu.parallel import mesh as meshmod
+
+    fr = _frame(n)
+    (span,) = _spans(_events_of(
+        lambda: _train_gbm(fr, histogram_type=histogram_type)),
+        "train.gbm.sketch")
+    got = {k: v for k, v in span.items() if k.startswith("sketch_")}
+    if histogram_type == "UniformAdaptive":
+        assert got == {}
+        return
+    rows = meshmod.padded_len(n) // meshmod.n_row_shards()
+    rb, Fb = binning._sketch_plan(rows, _F, 1024, hbm_budget_bytes())
+    assert Fb == _F and rb == min(binning._SKETCH_ROW_BLOCK,
+                                  1 << (rows - 1).bit_length())
+    assert got == {"sketch_digits": "32x32", "sketch_row_block": rb,
+                   "sketch_col_blocks": 1, "sketch_scan_steps": -(-rows // rb)}
+    assert got["sketch_scan_steps"] == (1 if n == 30_000 else 2)
+
+
 # ---------------------------------------------------------------------------
 # (c) the program load and the compile events
 # ---------------------------------------------------------------------------
