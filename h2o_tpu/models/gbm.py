@@ -36,9 +36,9 @@ from .distributions import Bernoulli, Gaussian, get_distribution
 from .model_base import Model, ModelBuilder, ModelOutput, Parameters, make_metrics
 from .tree.binning import (bin_matrix, compute_bin_edges,
                            compute_bin_edges_cols, sketch_span_attrs)
-from .tree.engine import (TreeConfig, hist_psum_bytes, make_train_fn,
-                          plan_hist_groups, predict_forest,
-                          psum_payload_bytes)
+from .tree.engine import (TreeConfig, hist_onehot_cells, hist_plan_attrs,
+                          hist_psum_bytes, make_train_fn, plan_hist_groups,
+                          predict_forest, psum_payload_bytes)
 
 #: last build's training-matrix accounting (mode, per-matrix bytes) — the
 #: bench binned-storage leg and the chunk-store tests read this to put the
@@ -546,7 +546,7 @@ class GBM(ModelBuilder):
         # HOST wall of the coded-matrix build: no sync is added here, so
         # where the build does not drain by itself the device's side is the
         # scope gbm.bin in a capture
-        with telemetry.span("train.gbm.binned_view"):
+        with telemetry.span("train.gbm.binned_view") as bv_span:
             if use_binned:
                 # device-resident coded training matrix, packed column-by-
                 # column (Cleaner-tracked; the engine upcasts blocks in-scan)
@@ -557,6 +557,10 @@ class GBM(ModelBuilder):
                 Xb = binned_view.matrix
             else:
                 Xb = bin_matrix(X, put_replicated(edges_np, mesh))
+            # what a stored code costs: 1 or 2 bytes in the binned view
+            # (`BinnedView.code_dtype`), 4 on the stacked path
+            bv_span.attrs["code_bytes"] = int(Xb.dtype.itemsize)
+            bv_span.attrs["coded_gb"] = Xb.size * Xb.dtype.itemsize / 1e9
         plen = Xb.shape[0]
         # how the job's rows lie on the mesh, on the job's root span
         # (train.<algo>; a CV fold's builder has none)
@@ -888,6 +892,11 @@ class GBM(ModelBuilder):
         # train.gbm.psum_bytes adds them at each chunk dispatch
         psum_tree = (K * hist_psum_bytes(cfg, len(names))
                      if n_row_shards(mesh) > 1 else 0)
+        # the level histogram's plan, on every chunk span, and the one-hot
+        # cells a tree iteration's level passes generate over all rows
+        # (the counter train.gbm.hist_onehot_cells), both from shapes
+        hist_plan = hist_plan_attrs(cfg, Xb.shape[0] // n_row_shards(mesh))
+        onehot_tree = K * hist_onehot_cells(cfg, Xb.shape[0], len(names))
         steady = [False]
         for ci in range(start_ci, len(chunks)):
             keys, rates = chunks[ci]
@@ -905,7 +914,8 @@ class GBM(ModelBuilder):
             # metric values to host, so the wall is near-drained
             with telemetry.span("train.gbm.chunk",
                                 metric="train.chunk.seconds",
-                                chunk=ci, trees=int(len(keys))):
+                                chunk=ci, trees=int(len(keys)),
+                                **hist_plan):
                 def _dispatch(cj, f_in):
                     nonlocal train_step
                     import contextlib as _ctx
@@ -915,6 +925,8 @@ class GBM(ModelBuilder):
                     if psum_tree:
                         telemetry.inc("train.gbm.psum_bytes",
                                       int(chunks[cj][0].shape[0]) * psum_tree)
+                    telemetry.inc("train.gbm.hist_onehot_cells",
+                                  int(chunks[cj][0].shape[0]) * onehot_tree)
                     use_aot = (train_step is not None
                                and chunks[cj][0].shape[0]
                                == len(chunks[0][0]))

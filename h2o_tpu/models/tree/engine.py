@@ -51,6 +51,10 @@ class TreeConfig:
     max_depth: int = 5
     nbins: int = 20              # real-value bins; bin index nbins = NA bucket
     min_rows: float = 10.0
+    child_weight_hessian: bool = False  # `min_rows` bounds a child's HESSIAN
+                                 # sum (XGBoost's min_child_weight), not its
+                                 # row weight; set by `XGBoost._tree_config`
+                                 # alone (a static branch, like reg_alpha's)
     learn_rate: float = 0.1
     reg_lambda: float = 0.0      # Newton denominator regularizer (0 = H2O SE gain)
     reg_alpha: float = 0.0       # L1 on leaf values (xgboost-style soft threshold)
@@ -523,8 +527,9 @@ def _level_col_mask(lkey, F, n_lv, cfg: "TreeConfig", tree_cols,
 def _find_splits(hist, colmask, edge_ok, cfg: TreeConfig, mono=None,
                  iscat=None, nedges=None):
     """hist: (F, n_lv, B, 3). Returns per-node best (gain, feat, bin, nan_left,
-    node weight, left/right Newton values of the chosen split[, bin-direction
-    rows + set flags when cfg.use_sets]).
+    node weight (the node's hessian sum under ``cfg.child_weight_hessian``:
+    whichever `min_rows` bounds), left/right Newton values of the chosen
+    split[, bin-direction rows + set flags when cfg.use_sets]).
 
     Candidates: split at bin b (left = bins <= b), b in 0..nb-2, NA bucket sent
     left or right (`hex/tree/DHistogram.java` NA bucket; direction chosen by
@@ -595,7 +600,10 @@ def _find_splits(hist, colmask, edge_ok, cfg: TreeConfig, mono=None,
         gl_, gr_, gt_ = _soft(gl), _soft(gr), _soft(Gt)
         g = (gl_ * gl_ / (hl + lam + 1e-10) + gr_ * gr_ / (hr + lam + 1e-10)
              - (gt_ * gt_ / (Ht + lam + 1e-10))[None, :, None])
-        ok = (wl >= cfg.min_rows) & (wr >= cfg.min_rows)
+        if cfg.child_weight_hessian:
+            ok = (hl >= cfg.min_rows) & (hr >= cfg.min_rows)
+        else:
+            ok = (wl >= cfg.min_rows) & (wr >= cfg.min_rows)
         return jnp.where(ok, g, -jnp.inf)
 
     gain_nar = gain_of(cw, cg, ch)                      # NA right
@@ -626,8 +634,9 @@ def _find_splits(hist, colmask, edge_ok, cfg: TreeConfig, mono=None,
     bf = (best // per_f).astype(jnp.int32)
     bb = ((best % per_f) // 2).astype(jnp.int32)
     bnal = (best % 2).astype(jnp.bool_)
+    node_w = Ht if cfg.child_weight_hessian else Wt
     if rank is None:
-        return best_gain, bf, bb, bnal, Wt, best_vL, best_vR, None, None
+        return best_gain, bf, bb, bnal, node_w, best_vL, best_vR, None, None
     # Direction row per node over REAL bins (0 = left, 1 = right): for a set
     # split, bin b goes left iff its sorted rank is inside the chosen prefix;
     # empty bins follow the NA direction (a level unseen at this node is
@@ -645,7 +654,7 @@ def _find_splits(hist, colmask, edge_ok, cfg: TreeConfig, mono=None,
     catd_lv = jnp.where(isset[:, None], dir_c,
                         jnp.arange(nb)[None, :] > bb[:, None]
                         ).astype(jnp.float32)
-    return best_gain, bf, bb, bnal, Wt, best_vL, best_vR, catd_lv, isset
+    return best_gain, bf, bb, bnal, node_w, best_vL, best_vR, catd_lv, isset
 
 
 # ---------------------------------------------------------------------------
@@ -874,18 +883,43 @@ def _grow_tree(Xb, g, h, w, edges, edge_ok, colkey, cfg: TreeConfig,
     return feat, thr, nanL, val, garr, catd, node
 
 
+def _hist_cells_per_row(cfg: TreeConfig, F: int) -> int:
+    """(feature, bin) cells of ONE node's level histogram, which is also
+    the one-hot cells a row generates in one level pass: F x B flat, each
+    width bucket's own F_g x B_g under ``cfg.hist_groups``."""
+    groups = _norm_groups(cfg.hist_groups) if cfg.hist_groups else None
+    return (F * (cfg.nbins + 1) if groups is None
+            else sum(len(idxs) * Bg for idxs, Bg, _ in groups))
+
+
 def hist_psum_bytes(cfg: TreeConfig, F: int, nvals: int = 3) -> int:
     """Bytes of level histogram ONE tree hands to `_psum_hist` per shard:
     every level's whole f32 (F, n_lv, B, nvals) accumulator (per group
     when ``cfg.hist_groups`` is set — the wire carries Σ F_g·B_g cells
     instead of the padded F·B_max). From the static config alone: the
     counter ``train.gbm.psum_bytes`` adds it at chunk dispatch."""
-    B = cfg.nbins + 1
-    groups = _norm_groups(cfg.hist_groups) if cfg.hist_groups else None
-    cells_per_lv = (F * B if groups is None
-                    else sum(len(idxs) * Bg for idxs, Bg, _ in groups))
-    return sum((2 ** level) * cells_per_lv
+    return sum((2 ** level) * _hist_cells_per_row(cfg, F)
                for level in range(cfg.max_depth)) * nvals * 4
+
+
+def hist_plan_attrs(cfg: TreeConfig, rows: int) -> dict:
+    """``train.gbm.chunk``'s attributes: the plan the level histogram of a
+    shard's ``rows`` rows runs under, from shapes alone — the bins of the
+    one-hot (NA slot included), the rows of a scan step and the steps of
+    one level pass, the width buckets (0 where flat) and the widest
+    level's nodes."""
+    rb = _block_rows(rows, cfg.block_rows)
+    return {"hist_bins": cfg.nbins + 1, "hist_row_block": rb,
+            "hist_blocks": rows // rb,
+            "hist_groups": len(cfg.hist_groups or ()),
+            "n_lv_max": 2 ** max(cfg.max_depth - 1, 0)}
+
+
+def hist_onehot_cells(cfg: TreeConfig, rows: int, F: int) -> int:
+    """One-hot cells ONE tree's level passes generate over ``rows`` rows:
+    rows x (feature, bin) cells x levels. From shapes alone: the counter
+    ``train.gbm.hist_onehot_cells`` adds it at chunk dispatch."""
+    return rows * _hist_cells_per_row(cfg, F) * cfg.max_depth
 
 
 def psum_payload_bytes(cfg: TreeConfig, F: int, nvals: int = 3) -> int:

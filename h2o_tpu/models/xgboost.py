@@ -19,7 +19,13 @@ Supported param aliases (mirroring `XGBoostV3.XGBoostParametersV3`):
   one_drop/normalize_type incl. multinomial + checkpoint continuation, see
   `XGBoost._build_dart` | gblinear — the penalized linear model retargeted
   onto the GLM elastic-net path, see `XGBoost._build_gblinear`), tree_method
-  (ignored: always hist), backend (ignored: always TPU).
+  (auto | hist: the engine IS hist), grow_policy (depthwise), max_leaves (0:
+  no leaf cap) — any other value of the three raises at validation, it is
+  not ignored — and backend (ignored: always TPU).
+
+`min_child_weight` bounds a child's HESSIAN sum, as in XGBoost (the GBM's
+`min_rows` bounds its row weight): `XGBoost._tree_config` sets
+`TreeConfig.child_weight_hessian`.
 """
 
 from __future__ import annotations
@@ -33,8 +39,10 @@ from .gbm import GBM, GBMParameters
 class XGBoostParameters(GBMParameters):
     """Mirrors `hex/tree/xgboost/XGBoostModel.XGBoostParameters` field names.
 
-    xgboost-default field overrides (eta 0.3, min_child_weight 1, lambda 1)
-    are declared as dataclass defaults; the xgboost-native spellings below are
+    xgboost-default field overrides (the H2O-3 XGBoost documentation's:
+    eta 0.3, max_depth 6, min_child_weight 1, max_bins 256, lambda 1,
+    gamma 0) are declared as dataclass defaults, so that a builder with
+    nothing set IS the documented model; the xgboost-native spellings below are
     sentinel-valued aliases that, when set, overwrite their H2O-named twin and
     then reset to the sentinel — so ``dataclasses.replace`` (clone / grid
     search) re-running ``__post_init__`` is a no-op and an explicitly-passed
@@ -42,6 +50,9 @@ class XGBoostParameters(GBMParameters):
     """
 
     learn_rate: float = 0.3   # xgboost default eta
+    max_depth: int = 6        # xgboost default max_depth
+    nbins: int = 256          # xgboost default max_bins
+    min_split_improvement: float = 0.0  # xgboost default gamma
     min_rows: float = 1.0     # xgboost default min_child_weight
     reg_lambda: float = 1.0   # xgboost default lambda
     reg_alpha: float = 0.0
@@ -69,7 +80,9 @@ class XGBoostParameters(GBMParameters):
     max_bins: int = 0              # alias of nbins
     gamma: float = -1.0            # min split loss == min_split_improvement
     booster: str = "gbtree"
-    tree_method: str = "hist"
+    tree_method: str = "hist"      # auto | hist
+    grow_policy: str = "depthwise"  # the engine grows level by level
+    max_leaves: int = 0            # 0 = no cap (lossguide's knob)
     backend: str = "auto"
 
     def __post_init__(self):
@@ -98,10 +111,27 @@ class XGBoostParameters(GBMParameters):
 class XGBoost(GBM):
     algo_name = "xgboost"
 
+    def _validate(self):
+        super()._validate()
+        p = self.params
+        # what the level-wise histogram engine does not implement raises
+        # here; accepted and ignored, the model would not be the one asked
+        for name, ok in (("tree_method", ("auto", "hist")),
+                         ("grow_policy", ("depthwise",))):
+            if (getattr(p, name) or ok[0]).lower() not in ok:
+                raise ValueError(
+                    f"xgboost: {name}='{getattr(p, name)}' is not "
+                    f"implemented (supported: {', '.join(ok)})")
+        if p.max_leaves:
+            raise ValueError(
+                f"xgboost: max_leaves={p.max_leaves} is not implemented "
+                "(trees grow depthwise to max_depth; 0 = no leaf cap)")
+
     def _tree_config(self, K, nbins=None):
         import dataclasses
         cfg = super()._tree_config(K, nbins=nbins)
-        return dataclasses.replace(cfg, reg_alpha=self.params.reg_alpha)
+        return dataclasses.replace(cfg, reg_alpha=self.params.reg_alpha,
+                                   child_weight_hessian=True)
 
     def build_impl(self, job):
         booster = (self.params.booster or "gbtree").lower()

@@ -170,6 +170,10 @@ _counter("train.chunk.count", "GBM/DRF boosting-chunk iterations")
 _counter("train.gbm.psum_bytes",
          "bytes of level histogram handed to the cross-shard psum, a shard, "
          "counted from shapes at chunk dispatch (0 on one row shard)")
+_counter("train.gbm.hist_onehot_cells",
+         "one-hot cells the level histograms of a chunk's trees generate "
+         "(rows x features x bins with the NA slot x levels x trees), "
+         "counted from shapes at chunk dispatch")
 _counter("train.glm.path.lambdas",
          "lambdas a GLM lambda_search fitted before its path ended, added "
          "once a job")

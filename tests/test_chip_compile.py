@@ -9,7 +9,8 @@ something no chip should run, before a chip is used.
 Shapes are the HIGGS configurations': 11,000,000 rows a chip as `padded_len`
 pads them (44,000,000 over the four chips of the train step, the
 deployment of the cell ``higgs_gbm_train_4chip``), F=28, nbins=20, depth 5,
-int8 codes, P=29 for the GLM design.
+int8 codes, P=29 for the GLM design; and the XGBoost cell's step on one
+chip: 256 bins, depth 6, int16 codes.
 """
 
 import dataclasses
@@ -40,6 +41,23 @@ def v5e():
 def _spec(mesh, shape, dtype, pspec=P()):
     return jax.ShapeDtypeStruct(shape, dtype,
                                 sharding=NamedSharding(mesh, pspec))
+
+
+def _step_specs(mesh, R, code, nbins):
+    """The arguments of a fused-score chunk step (`make_train_fn`) over R
+    rows of ``code``-typed bins, as shapes on ``mesh``."""
+    row = lambda dt: _spec(mesh, (R,), dt, P(ROWS))  # noqa: E731
+    return (_spec(mesh, (R, F), code, P(ROWS, None)),         # binned codes
+            row(jnp.float32), row(jnp.float32), row(jnp.float32),  # y, w, f
+            _spec(mesh, (F, nbins - 1), jnp.float32),         # edges
+            _spec(mesh, (F, nbins - 1), jnp.bool_),           # edge_ok
+            _spec(mesh, (INTERVAL, 2), jnp.uint32),           # keys
+            _spec(mesh, (INTERVAL,), jnp.float32),            # rates
+            _spec(mesh, (F,), jnp.float32),                   # mono
+            _spec(mesh, (F, F), jnp.bool_),                   # imat
+            _spec(mesh, (F,), jnp.bool_),                     # iscat
+            _spec(mesh, (F,), jnp.int32),                     # nedges
+            _spec(mesh, (), jnp.float32))                     # trees done
 
 
 def test_large_frames_pad_to_a_multiple_of_eight_row_blocks(v5e):
@@ -91,19 +109,7 @@ def default_train_step(v5e):
         cfg, b._make_grad_fn(dist, 1), mesh,
         score_fn=gbm_mod._metrics_raw_fn("Binomial", dist, False),
         score_spec=P(ROWS, None), donate=True)
-    row = lambda dt: _spec(mesh, (R,), dt, P(ROWS))  # noqa: E731
-    lowered = train_fn.lower(
-        _spec(mesh, (R, F), jnp.int8, P(ROWS, None)),     # binned codes
-        row(jnp.float32), row(jnp.float32), row(jnp.float32),  # y, w, f
-        _spec(mesh, (F, NBINS - 1), jnp.float32),         # edges
-        _spec(mesh, (F, NBINS - 1), jnp.bool_),           # edge_ok
-        _spec(mesh, (INTERVAL, 2), jnp.uint32),           # keys
-        _spec(mesh, (INTERVAL,), jnp.float32),            # rates
-        _spec(mesh, (F,), jnp.float32),                   # mono
-        _spec(mesh, (F, F), jnp.bool_),                   # imat
-        _spec(mesh, (F,), jnp.bool_),                     # iscat
-        _spec(mesh, (F,), jnp.int32),                     # nedges
-        _spec(mesh, (), jnp.float32))                     # trees done
+    lowered = train_fn.lower(*_step_specs(mesh, R, jnp.int8, NBINS))
     t0 = time.time()
     compiled = lowered.compile()
     return lowered, compiled, time.time() - t0
@@ -162,6 +168,72 @@ def test_level_histograms_fold_node_and_statistic_into_one_dimension(
         if "gbm.hist" in op]
     assert len(windows) == 5, windows
     assert not [w for w in windows if re.search(r"pad=0_0x\d+_\d+", w)], windows
+
+
+@pytest.fixture(scope="module")
+def xgb_train_step(v5e):
+    """The chunk step `XGBoost` builds at its documented settings (the cell
+    ``higgs_xgb_train``: 256 bins, depth 6, lambda 1, hessian child weight,
+    int16 codes), lowered and compiled for ONE v5e chip at the cell's rows:
+    ``(lowered, compiled)``."""
+    from h2o_tpu.frame.chunks import BinnedView
+    from h2o_tpu.frame.frame import Frame
+    from h2o_tpu.models import gbm as gbm_mod
+    from h2o_tpu.models import xgboost as xgb_mod
+    from h2o_tpu.models.distributions import get_distribution
+    from h2o_tpu.models.tree.engine import make_train_fn, plan_hist_groups
+    from h2o_tpu.utils.knobs import get_bool
+
+    nb = 256
+    mesh = make_mesh(v5e[:1])
+    tiny = Frame.from_dict({"a": np.arange(8, dtype=np.float32),
+                            "y": np.arange(8, dtype=np.float32) % 2})
+    b = xgb_mod.XGBoost(xgb_mod.XGBoostParameters(
+        training_frame=tiny, response_column="y", ntrees=20, seed=42,
+        score_tree_interval=INTERVAL))
+    dist = get_distribution("bernoulli")
+    cfg = b._tree_config(1, nbins=nb)
+    assert (cfg.max_depth, cfg.nbins, cfg.reg_lambda, cfg.min_rows,
+            cfg.child_weight_hessian) == (6, nb, 1.0, 1.0, True)
+    groups, blk = plan_hist_groups(
+        np.full(F, nb - 1, np.int32), nb + 1, cfg.block_rows,
+        budget_bytes=12 << 30, n_lv_max=32, nvals=3)
+    assert (groups, blk) == (None, 8192)
+    cfg = dataclasses.replace(
+        cfg, ntrees=INTERVAL, block_rows=blk, hist_groups=groups,
+        pipeline=get_bool("H2O_TPU_PIPELINE"),
+        async_psum=get_bool("H2O_TPU_ASYNC_PSUM"), fused_score=True)
+    train_fn = make_train_fn(
+        cfg, b._make_grad_fn(dist, 1), mesh,
+        score_fn=gbm_mod._metrics_raw_fn("Binomial", dist, False),
+        score_spec=P(ROWS, None), donate=True)
+    code = BinnedView.code_dtype(nb + 1)
+    assert code == jnp.int16
+    lowered = train_fn.lower(*_step_specs(mesh, HIGGS_PLEN, code, nb))
+    return lowered, lowered.compile()
+
+
+def test_xgboost_step_compiles_for_one_v5e_chip_at_256_bins(xgb_train_step):
+    """What no GBM cell compiles: ``s16`` codes, a (8192, 28, 257) one-hot
+    and six levels. The TPU compiler takes it as it is (no hand-written
+    kernel), streams the cell's 1,344 row blocks, holds one ``gbm.hist``
+    contraction a level, reads no code by a per-row gather, and needs under
+    2 GB of temporaries beside its 0.84 GB of arguments (1.32 GB on jax
+    0.9.0 / libtpu 0.0.34; the GBM cells' step 0.66 GB)."""
+    lowered, compiled = xgb_train_step
+    assert "tpu_custom_call" not in lowered.as_text()
+    hlo = compiled.as_text()
+    assert re.search(r"s16\[1344,8192,28\]", hlo)        # the scanned codes
+    hist = [op for op in re.findall(
+        r'^.* fusion\(.*op_name="([^"]*)"', hlo, re.M)
+        if "gbm.hist" in op and "dot_general" in op]
+    assert len(hist) == 6, hist
+    shape_of = dict(re.findall(r"^\s*(?:ROOT )?(%[\w.\-]+) = (\S+)", hlo, re.M))
+    codes = re.compile(r"s(16|32)\[[\d,]*\b28\]")
+    gathers = re.findall(r"^.* gather\((%[\w.\-]+),.*$", hlo, re.M)
+    bad = [op for op in gathers if codes.match(shape_of[op])]
+    assert not bad, [(op, shape_of[op]) for op in bad]
+    assert compiled.memory_analysis().temp_size_in_bytes < 2e9
 
 
 def _collectives(hlo: str) -> list[tuple[str, str]]:

@@ -51,6 +51,14 @@ def _train_gbm(fr, **kw):
         seed=1, score_tree_interval=2, **kw)).train_model()
 
 
+def _train_xgboost(fr, **kw):
+    from h2o_tpu.models.xgboost import XGBoost, XGBoostParameters
+
+    return XGBoost(XGBoostParameters(
+        training_frame=fr, response_column="y", ntrees=4, max_depth=3,
+        seed=1, score_tree_interval=2, **kw)).train_model()
+
+
 def _train_glm(fr, **kw):
     from h2o_tpu.models.glm import GLM, GLMParameters
 
@@ -215,16 +223,23 @@ _TREE["glm_search"] = {
     "train.glm.gram": ("train.glm", "train.glm.path"),
     "train.glm.solve": "train.glm.path", "train.glm.probe": "train.glm.path",
     "train.program.load": "train.glm.gram"}
-_TRAIN = {"gbm": _train_gbm, "glm": _train_glm,
+# the XGBoost builder's job: the GBM's parts under ITS root, and no
+# train.gbm span (why the benchmark reads xgb_setup_s, not gbm_setup_s)
+_TREE["xgboost"] = {k: "train.xgboost" if v == "train.gbm" else v
+                    for k, v in _TREE["gbm"].items()}
+_TRAIN = {"gbm": _train_gbm, "glm": _train_glm, "xgboost": _train_xgboost,
           "glm_search": lambda fr: _train_glm(
               fr, lambda_=None, lambda_search=True, nlambdas=6)}
+_ROOT = {"xgboost": "train.xgboost"}
 
 
 @pytest.mark.parametrize("algo", sorted(_TREE))
 def test_train_records_the_span_tree(algo):
     fr = _frame()
     events = _events_of(lambda: _TRAIN[algo](fr))
-    (root,) = _spans(events, f"train.{algo[:3]}")
+    (root,) = _spans(events, _ROOT.get(algo, f"train.{algo[:3]}"))
+    if algo == "xgboost":
+        assert _spans(events, "train.gbm") == []
     by_id = {e["span"]: e for e in events if e["kind"] == "span"}
     for name, parent in _TREE[algo].items():
         got = _spans(events, name)
@@ -328,6 +343,35 @@ def test_gbm_sketch_span_carries_the_sketch_plan(histogram_type, n):
     assert got == {"sketch_digits": "32x32", "sketch_row_block": rb,
                    "sketch_col_blocks": 1, "sketch_scan_steps": -(-rows // rb)}
     assert got["sketch_scan_steps"] == (1 if n == 30_000 else 2)
+
+
+@pytest.mark.parametrize("algo,bins,code_bytes", [
+    ("gbm", 21, 1), ("xgboost", 257, 2)])
+def test_gbm_chunk_spans_carry_the_histogram_plan(algo, bins, code_bytes):
+    """``train.gbm.binned_view`` says what a stored code costs, every
+    ``train.gbm.chunk`` the plan of the level histogram (`engine.
+    hist_plan_attrs` of a shard's rows on this 8-device mesh), and the
+    counter ``train.gbm.hist_onehot_cells`` grows at each dispatch by rows
+    x features x bins (NA slot in) x levels x the chunk's trees: a GBM at
+    its 20 bins on int8 codes, the XGBoost builder at its 256 on int16."""
+    from h2o_tpu.parallel import mesh as meshmod
+
+    fr = _frame()
+    before = telemetry.value("train.gbm.hist_onehot_cells")
+    events = _events_of(lambda: _TRAIN[algo](fr))
+    (view,) = _spans(events, "train.gbm.binned_view")
+    assert view["code_bytes"] == code_bytes
+    assert view["coded_gb"] == pytest.approx(_N * _F * code_bytes / 1e9)
+    chunks = _spans(events, "train.gbm.chunk")
+    assert len(chunks) == 2
+    rows = _N // meshmod.n_row_shards()
+    for e in chunks:
+        assert {k: e[k] for k in ("hist_bins", "hist_row_block", "hist_blocks",
+                                  "hist_groups", "n_lv_max")} == {
+            "hist_bins": bins, "hist_row_block": rows, "hist_blocks": 1,
+            "hist_groups": 0, "n_lv_max": 4}, e
+    grew = telemetry.value("train.gbm.hist_onehot_cells") - before
+    assert grew == _N * _F * bins * 3 * 4      # depth 3, 4 trees
 
 
 # ---------------------------------------------------------------------------

@@ -174,8 +174,10 @@ def test_xgboost_dart_checkpoint_continuation():
     x = rng.normal(size=(n, 4)).astype(np.float32)
     y = (x[:, 0] - 0.7 * x[:, 1] + 0.1 * rng.normal(size=n)).astype(np.float32)
     fr = Frame.from_dict({f"x{i}": x[:, i] for i in range(4)} | {"y": y})
+    # 20 bins, named: what these forests were built at before the builder's
+    # default became XGBoost's 256 (continuation is not about the bins)
     kw = dict(training_frame=fr, response_column="y", max_depth=3, eta=0.3,
-              seed=7, booster="dart", rate_drop=0.3)
+              seed=7, booster="dart", rate_drop=0.3, nbins=20)
     m1 = XGBoost(XGBoostParameters(ntrees=8, **kw)).train_model()
     m2 = XGBoost(XGBoostParameters(ntrees=16, checkpoint=m1,
                                    **kw)).train_model()
@@ -189,7 +191,7 @@ def test_xgboost_dart_checkpoint_continuation():
     # checkpoint from a plain gbtree forest also continues
     g1 = XGBoost(XGBoostParameters(ntrees=6, training_frame=fr,
                                    response_column="y", max_depth=3,
-                                   eta=0.3, seed=7)).train_model()
+                                   eta=0.3, seed=7, nbins=20)).train_model()
     g2 = XGBoost(XGBoostParameters(ntrees=12, checkpoint=g1,
                                    **kw)).train_model()
     assert g2.ntrees == 12
@@ -208,7 +210,7 @@ def test_xgboost_dart_export_checkpoints(tmp_path):
     m = XGBoost(XGBoostParameters(training_frame=fr, response_column="y",
                                   booster="dart", rate_drop=0.3, ntrees=6,
                                   score_tree_interval=2, max_depth=3,
-                                  seed=3, export_checkpoints_dir=d)
+                                  nbins=20, seed=3, export_checkpoints_dir=d)
                 ).train_model()
     import os
 
