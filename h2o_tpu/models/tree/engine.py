@@ -166,6 +166,60 @@ def _onehot_pick(oh: jax.Array, v: jax.Array) -> jax.Array:
             + jnp.dot(oh, lo, preferred_element_type=jnp.float32))
 
 
+#: the form `_leaf_read` takes, as ``train.gbm.chunk`` names it
+LEAF_READ = "select_tree"
+
+
+def _leaf_tree_plan(n_nodes: int) -> tuple[int, int]:
+    """``(width, chunks)`` of `_leaf_read`'s select tree over an
+    ``n_nodes``-entry table, from the table's length alone: the tree spans
+    the next power of two up to 128 entries (one fused pass over the rows
+    at depth 5 and 6: 63 and 127 nodes), and deeper tables are read 128
+    entries a pass (depth 12, DRF's cap: 64 passes over 8,191 nodes)."""
+    width = min(1 << (n_nodes - 1).bit_length(), 128)
+    return width, -(-n_nodes // width)
+
+
+def _leaf_read(v: jax.Array, node: jax.Array) -> jax.Array:
+    """``v[node]``: a row's value out of a small f32 table, with no
+    data-dependent addressing — the element itself, bit for bit, as a
+    gather returns it.
+
+    `jnp.take` here was the TPU's serial gather path above 64 entries
+    (9.4 ns a row at 127: 2.06 s of a 12.1 s XGBoost job) and, at 64 and
+    under inside the step's loop body, the compiler's own expansion into 57
+    row-sized compare / select passes (0.21 s of a 1.9 s GBM job; PERF.md,
+    PR 35). This is the form the compiler finds for a bare 63-entry take,
+    written out: a tree of selects on the index's low bits, ``width - 1``
+    selects and ``log2(width)`` bit tests a row with the table's entries
+    as scalar operands, one elementwise fusion over the rows. A table
+    longer than the tree (`_leaf_tree_plan`) is read a chunk a loop step,
+    the chunk picked by the index's high bits.
+
+    A select moves bits, so every entry comes back as it is: -0.0, and a
+    non-finite entry too, which reaches the rows of its own node alone
+    (under a one-hot contraction ``0 * inf`` would make every row NaN). An
+    index outside ``[0, len(v))`` reads 0.0 where `jnp.take` fills NaN;
+    node ids never leave the table."""
+    n = v.shape[0]
+    width, chunks = _leaf_tree_plan(n)
+    k = width.bit_length() - 1
+    vp = jnp.pad(v, (0, chunks * width - n))
+    bits = [((node >> b) & 1) == 1 for b in range(k)]
+    hi = node >> k
+
+    def chunk(c, acc):
+        t = jax.lax.dynamic_slice_in_dim(vp, c * width, width)
+        vals = [t[j] for j in range(width)]
+        for bit in bits:
+            vals = [jnp.where(bit, vals[j + 1], vals[j])
+                    for j in range(0, len(vals), 2)]
+        return jnp.where(hi == c, vals[0], acc)
+
+    return jax.lax.fori_loop(0, chunks, chunk,
+                             jnp.zeros(node.shape, v.dtype))
+
+
 def _norm_groups(groups):
     """Normalize hist_groups entries to (idxs, width, mode): legacy 2-tuples
     (pre-mode persisted models) accumulate via the one-hot matmul."""
@@ -907,12 +961,14 @@ def hist_plan_attrs(cfg: TreeConfig, rows: int) -> dict:
     shard's ``rows`` rows runs under, from shapes alone — the bins of the
     one-hot (NA slot included), the rows of a scan step and the steps of
     one level pass, the width buckets (0 where flat) and the widest
-    level's nodes."""
+    level's nodes — and the leaf table's length with the form
+    `_leaf_read` reads it in."""
     rb = _block_rows(rows, cfg.block_rows)
     return {"hist_bins": cfg.nbins + 1, "hist_row_block": rb,
             "hist_blocks": rows // rb,
             "hist_groups": len(cfg.hist_groups or ()),
-            "n_lv_max": 2 ** max(cfg.max_depth - 1, 0)}
+            "n_lv_max": 2 ** max(cfg.max_depth - 1, 0),
+            "leaf_nodes": cfg.n_nodes, "leaf_read": LEAF_READ}
 
 
 def hist_onehot_cells(cfg: TreeConfig, rows: int, F: int) -> int:
@@ -1009,20 +1065,10 @@ def make_train_fn(cfg: TreeConfig, grad_fn: Callable, mesh=None,
                     vlk = jnp.clip(vlk, -cfg.max_abs_leafnode_pred,
                                    cfg.max_abs_leafnode_pred)
                 return vlk
-            # leaf-value broadcast rides the MXU too (vl[node] is a per-row
-            # dynamic gather otherwise — see the routing comment in _grow_tree)
+
             @telemetry.scope("gbm.leaf")
             def leaf_delta(vlk, nodek):
-                if cfg.pipeline:
-                    # the pipelined program accepts this gather (exact: a
-                    # gather IS the element): one read a row a tree from
-                    # an n_nodes table — since PR 27 the per-row gather
-                    # left in the program (PERF.md section 7, no. 14)
-                    return jnp.take(vlk, nodek)
-                # leaf values are real f32 — hi/lo split keeps the carried
-                # residuals f32-grade without Precision.HIGHEST's fusion cost
-                oh = jax.nn.one_hot(nodek, cfg.n_nodes, dtype=jnp.float32)
-                return _onehot_pick(oh, vlk)
+                return _leaf_read(vlk, nodek)
 
             if K == 1:
                 resid = ((y - f) if (cfg.leaf_quantile is not None or
@@ -1047,11 +1093,12 @@ def make_train_fn(cfg: TreeConfig, grad_fn: Callable, mesh=None,
                 delta = jax.vmap(leaf_delta)(vl, node)
             with telemetry.scope("gbm.leaf"):
                 f = f + delta
-            # OOB accumulation (`DRF.java` OOB scoring): rows outside this
-            # tree's bag collect its raw output; two (R,)-adds per tree
-            oob = 1.0 - s
-            osum = osum + delta * (oob if K == 1 else oob[None, :])
-            ocnt = ocnt + oob
+                # OOB accumulation (`DRF.java` OOB scoring): rows outside
+                # this tree's bag collect its raw output; two (R,)-adds per
+                # tree, in the fusion that reads the leaf values
+                oob = 1.0 - s
+                osum = osum + delta * (oob if K == 1 else oob[None, :])
+                ocnt = ocnt + oob
             return (f, osum, ocnt), (ft, th, nl, vl, ga, cd)
 
         init = (f, jnp.zeros_like(f), jnp.zeros(w.shape[-1:], jnp.float32))

@@ -236,6 +236,79 @@ def test_xgboost_step_compiles_for_one_v5e_chip_at_256_bins(xgb_train_step):
     assert compiled.memory_analysis().temp_size_in_bytes < 2e9
 
 
+def _computations(hlo: str) -> dict[str, str]:
+    """name -> body text of every computation of an optimised program."""
+    return dict(re.findall(
+        r"^(?:ENTRY )?(%[\w.\-]+) \([^\n]*\) -> [^\n]*\{\n(.*?)^\}", hlo,
+        re.M | re.S))
+
+
+def _depth12_leaf_read(v5e):
+    """``f + _leaf_read(v, node)`` alone at DRF's depth cap (12: 8,191
+    nodes) over one chip's HIGGS rows: ``(compiled, seconds)``."""
+    from h2o_tpu.models.tree.engine import _leaf_read
+
+    mesh = make_mesh(v5e[:1])
+    lowered = jax.jit(lambda v, node, f: f + _leaf_read(v, node)).lower(
+        _spec(mesh, (8191,), jnp.float32),
+        _spec(mesh, (HIGGS_PLEN,), jnp.int32),
+        _spec(mesh, (HIGGS_PLEN,), jnp.float32))
+    t0 = time.time()
+    compiled = lowered.compile()
+    return compiled, time.time() - t0
+
+
+@pytest.mark.parametrize("program,leaf_ops,temp_limit", [
+    ("default_train_step", 1, 1.0e9),    # 0.839 GB (0.841 before the read)
+    ("xgb_train_step", 1, 2e9),          # 1.3245 GB (1.3241)
+    ("depth12_leaf_read", 0, 64 << 20),  # 48.5 MB: seven bit masks of the rows
+], ids=["default_train_step", "xgb_train_step", "depth12_leaf_read"])
+def test_leaf_values_are_read_in_one_dense_pass(v5e, request, program,
+                                                leaf_ops, temp_limit):
+    """`leaf_delta` reads ``vl[node]`` as a select tree on the node id's
+    bits (`engine._leaf_read`): with the margin's update and the out-of-bag
+    sums it is ONE row-sized fusion under ``gbm.leaf`` in each compiled
+    step, and no ``gather`` has a row-sized result anywhere. As `jnp.take`
+    the 127-entry table of the XGBoost step was a serial gather (``fusion
+    f32[11010048] = fusion(f32[127], s32[11010048])``, 9.4 ns a row, 2.06 s
+    of a 12.1 s job) and the 63-entry table of the GBM step, inside the
+    step's loop body, 56 ``compare_reduce_fusion pred[11010048]``, a
+    ``compare_select_fusion`` and an add: 58 row-sized operations where
+    this counts 1, 3 and a row-sized gather in the XGBoost step (PERF.md,
+    PR 35). The third case is the read alone at depth 12, 8,191 nodes: a
+    64-step loop of 128-entry trees that compiles in seconds (1.3 s here)
+    and holds no row-by-``n_nodes`` temporary."""
+    if program == "depth12_leaf_read":
+        compiled, secs = _depth12_leaf_read(v5e)
+        assert secs < 30
+    else:
+        compiled = request.getfixturevalue(program)[1]
+    hlo = compiled.as_text()
+    fused = set(re.findall(r"calls=(%[\w.\-]+)", hlo))
+    gathers, leaf = [], []
+    for comp, body in _computations(hlo).items():
+        for line in body.splitlines():
+            m = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = (.*?) ([\w\-]+)\(", line)
+            if not m or str(HIGGS_PLEN) not in m.group(1):
+                continue
+            result, kind = m.groups()
+            if kind == "gather":
+                gathers.append(line.strip()[:120])
+            op = re.search(r'op_name="([^"]*)"', line)
+            if (comp not in fused and op and "gbm.leaf" in op.group(1)
+                    and "_node_totals" not in op.group(1)
+                    and kind not in ("get-tuple-element", "bitcast", "tuple",
+                                     "parameter")):
+                leaf.append((kind, result[:60]))
+    assert not gathers, gathers
+    assert len(leaf) == leaf_ops, leaf
+    assert compiled.memory_analysis().temp_size_in_bytes < temp_limit
+    if program == "depth12_leaf_read":
+        # the table is read 128 entries a loop step (64 steps by
+        # `_leaf_tree_plan`), never unrolled into 8,190 selects
+        assert len(re.findall(r" while\(", hlo)) == 1
+
+
 def _collectives(hlo: str) -> list[tuple[str, str]]:
     """(result shapes, kind) of every collective in an optimised program."""
     return re.findall(
@@ -311,9 +384,7 @@ _QS = tuple(np.linspace(0, 1, NBINS + 1)[1:-1])
 
 def _while_bodies(hlo: str) -> list[str]:
     """The text of every computation some ``while`` names as its body."""
-    comps = dict(re.findall(
-        r"^(?:ENTRY )?(%[\w.\-]+) \([^\n]*\) -> [^\n]*\{\n(.*?)^\}", hlo,
-        re.M | re.S))
+    comps = _computations(hlo)
     return [comps[b] for b in sorted(set(re.findall(r"body=(%[\w.\-]+)", hlo)))]
 
 
