@@ -937,8 +937,9 @@ def serving_control() -> dict:
 # ---------------------------------------------------------------------------
 def programs() -> dict:
     """`GET /3/Programs` → {program_id: record}: per compiled program,
-    XLA cost-model flops / bytes accessed, memory assignment, measured
-    dispatch walls and the roofline fraction (null off-TPU)."""
+    XLA cost-model flops / bytes accessed, memory assignment, its XLA
+    module's name, host dispatch walls (enqueue times) and the module's
+    device seconds from the last capture folded in (null before any)."""
     return connection().request("GET", "/3/Programs")["programs"]
 
 
@@ -954,7 +955,9 @@ def fleet_metrics(force: bool = False) -> dict:
 def profiler_capture(ms: int = 1000) -> str:
     """`POST /3/Profiler/capture?ms=N` — bounded live jax.profiler device
     capture on the server process; returns the capture directory (load it
-    in Perfetto / tensorboard-profile)."""
+    in Perfetto / tensorboard-profile). The server folds the capture's
+    device seconds by program into its registry first: `programs()` then
+    shows them per record."""
     return connection().request(
         "POST", f"/3/Profiler/capture?ms={int(ms)}")["dir"]
 

@@ -345,24 +345,26 @@ def program_schema(pid: str, rec: dict) -> dict:
     (the bench sidecar's program block uses the compact subset of these
     field names, so a consumer learns ONE schema)."""
     return {"program_id": pid, "kind": rec.get("kind"),
-            "name": rec.get("name"), "labels": _clean(rec.get("labels")),
+            "name": rec.get("name"), "module": rec.get("module"),
+            "labels": _clean(rec.get("labels")),
             "flops": _clean(rec.get("flops")),
             "bytes_accessed": _clean(rec.get("bytes_accessed")),
             "memory": _clean(rec.get("memory")),
             "dispatch_count": rec.get("dispatch_count"),
             "wall": _clean(rec.get("wall")),
-            "achieved_flops_per_s": _clean(rec.get(
-                "achieved_flops_per_s")),
-            "roofline_fraction": _clean(rec.get("roofline_fraction")),
+            "device": _clean(rec.get("device")),
             "registered_ms": rec.get("registered_ms")}
 
 
-def programs_schema(snapshot: dict, peak_flops) -> dict:
-    """The full `GET /3/Programs` payload."""
+def programs_schema(snapshot: dict, last_fold) -> dict:
+    """The full `GET /3/Programs` payload: the records, and what the last
+    capture folded in read by XLA module (`programs.fold_capture`: the
+    declared programs with and without a record, the undeclared by name;
+    null before any capture)."""
     return {"programs": {pid: program_schema(pid, rec)
                          for pid, rec in snapshot.items()},
             "count": len(snapshot),
-            "peak_flops_per_s": _clean(peak_flops)}
+            "capture": _clean(last_fold)}
 
 
 # ---------------------------------------------------------------------------

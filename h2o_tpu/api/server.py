@@ -2506,13 +2506,14 @@ def route(server: H2OServer, method: str, parts: list[str], query: dict,
     if head == "Programs":
         # the program cost registry (utils/programs.py): per compiled
         # program, XLA cost_analysis flops/bytes + memory_analysis
-        # figures, measured dispatch walls, achieved FLOP/s and the
-        # roofline fraction (null off-TPU — see README caveats)
+        # figures, the XLA module's name, host dispatch walls (enqueue
+        # times) and the module's device seconds from the last capture
+        # folded in (POST /3/Profiler/capture folds its own)
         from ..utils import programs as _programs
         from .schemas import programs_schema
 
         payload = programs_schema(_programs.snapshot(),
-                                  _programs.device_peak_flops())
+                                  _programs.last_fold())
         payload["ts_ms"] = int(time.time() * 1000)
         return 200, payload
     if head == "Flight":

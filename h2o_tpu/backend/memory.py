@@ -69,6 +69,20 @@ def hbm_stats() -> dict | None:
     return None
 
 
+def hbm_span_attrs() -> dict:
+    """``hbm_in_use_gb`` and ``hbm_peak_gb`` of the fullest device, now: what
+    a span that may set the HBM peak carries at its close (the sketch, the
+    coded view, the GLM's design, a job's root), so that a job's own spans
+    show the first one at whose close the peak stands and whether the job
+    before left memory behind. One ``memory_stats()`` call; {} where the
+    backend reports none (the CPU mesh)."""
+    stats = hbm_stats()
+    if not stats:
+        return {}
+    return {"hbm_in_use_gb": stats.get("bytes_in_use", 0) / 1e9,
+            "hbm_peak_gb": stats.get("peak_bytes_in_use", 0) / 1e9}
+
+
 _HW_BYTES = _UNRESOLVED  # cached device_hbm_bytes result
 
 

@@ -442,6 +442,7 @@ def compress_frame(fr):
 # BinnedView — device-resident int8/int16 binned training matrix
 # ---------------------------------------------------------------------------
 @jax.jit
+@telemetry.program("gbm_setup_stack")
 @telemetry.scope("gbm.bin")
 def _stack_codes(*cols):
     return jnp.stack(cols, axis=1)

@@ -27,11 +27,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..backend.jobs import Job
-from ..backend.memory import hbm_budget_bytes
+from ..backend.memory import hbm_budget_bytes, hbm_span_attrs
 from ..frame.frame import Frame
 from ..frame.vec import T_CAT, Vec
 from ..parallel.mesh import (ROWS, default_mesh, n_row_shards,
                              per_shard_nbytes, put_replicated, put_sharded)
+from ..utils import telemetry
 from .distributions import Bernoulli, Gaussian, get_distribution
 from .model_base import Model, ModelBuilder, ModelOutput, Parameters, make_metrics
 from .tree.binning import (bin_matrix, compute_bin_edges,
@@ -70,7 +71,7 @@ def _aot_train_step(train_fn, args, key_base):
     hit = _AOT_STEP_CACHE.get(key)
     if hit is not None:
         return hit
-    from ..utils import compilemeter, telemetry
+    from ..utils import compilemeter
 
     with telemetry.span("train.gbm.compile",
                         metric="train.compile.seconds") as sp:
@@ -86,7 +87,8 @@ def _aot_train_step(train_fn, args, key_base):
     from ..utils import programs
 
     programs.register_compiled("train.tree.step", compiled, "train",
-                               sig=sig, wall_metric="train.chunk.seconds")
+                               sig=sig, wall_metric="train.chunk.seconds",
+                               module=programs.module_of(train_fn))
     _AOT_STEP_CACHE[key] = compiled
     return compiled
 
@@ -486,63 +488,69 @@ class GBM(ModelBuilder):
         (checkpoint restarts, DART's dropped-tree evaluation)."""
         import types as _types
 
-        p = self.params
-        fr = p.training_frame
-        names = self.feature_names()
-        y_dev, category, resp_domain = self.response_info()
-        dist = self._distribution(category)
-        K = len(resp_domain) if category == "Multinomial" else 1
+        # train.gbm.prep names every stretch of the set-up that is neither
+        # the sketch nor the coded view (response and weights here, edges
+        # and constraints to the mesh, start margin and plan below): it
+        # opens more than once a job, a child of the job's root each time
+        with telemetry.span("train.gbm.prep"):
+            p = self.params
+            fr = p.training_frame
+            names = self.feature_names()
+            y_dev, category, resp_domain = self.response_info()
+            dist = self._distribution(category)
+            K = len(resp_domain) if category == "Multinomial" else 1
 
-        from ..utils import telemetry
-        from ..utils.knobs import get_bool
+            from ..utils.knobs import get_bool
 
-        use_binned = not need_raw and get_bool("H2O_TPU_BINNED_STORE")
-        is_cat = np.array([fr.vec(n).is_categorical() for n in names])
-        w_in = (jnp.nan_to_num(
-            Vec.from_numpy(np.nan_to_num(
-                fr.vec(p.weights_column).to_numpy())).data)
-            if p.weights_column else None)
-        # ONE compiled program for the y/w/mask prep — the per-op eager
-        # version paid a fixed compile+load per tiny program on a cold
-        # process
-        y, ymask, w, ym = _jit_prep(y_dev, w_in)
+            use_binned = not need_raw and get_bool("H2O_TPU_BINNED_STORE")
+            is_cat = np.array([fr.vec(n).is_categorical() for n in names])
+            w_in = (jnp.nan_to_num(
+                Vec.from_numpy(np.nan_to_num(
+                    fr.vec(p.weights_column).to_numpy())).data)
+                if p.weights_column else None)
+            # ONE compiled program for the y/w/mask prep — the per-op eager
+            # version paid a fixed compile+load per tiny program on a cold
+            # process
+            y, ymask, w, ym = _jit_prep(y_dev, w_in)
 
-        bin_kw = dict(
-            seed=p.seed if p.seed not in (-1, None) else 1234,
-            histogram_type=p.histogram_type,
-            nbins_top_level=int(getattr(p, "nbins_top_level", 1024) or 1024),
-            nbins_cats=int(getattr(p, "nbins_cats", 1024) or 1024))
-        if use_binned:
-            X = None
-            feat_vecs = [fr.vec(n) for n in names]
-        else:
-            X = fr.as_matrix(names)
+            bin_kw = dict(
+                seed=p.seed if p.seed not in (-1, None) else 1234,
+                histogram_type=p.histogram_type,
+                nbins_top_level=int(getattr(p, "nbins_top_level", 1024) or 1024),
+                nbins_cats=int(getattr(p, "nbins_cats", 1024) or 1024))
+            if use_binned:
+                X = None
+                feat_vecs = [fr.vec(n) for n in names]
+            else:
+                X = fr.as_matrix(names)
         # the quantile sketch, until the edges are on the host (the copy out
         # drains it); its attributes say which count contraction ran
         with telemetry.span("train.gbm.sketch", **sketch_span_attrs(
-                y_dev.shape[0], len(names), p.histogram_type)):
+                y_dev.shape[0], len(names), p.histogram_type)) as sk_span:
             edges_np = (
                 compute_bin_edges_cols(feat_vecs, is_cat, p.nbins, **bin_kw)
                 if use_binned
                 else compute_bin_edges(X, is_cat, p.nbins, **bin_kw))
-        mesh = default_mesh()
-        edges = put_replicated(np.nan_to_num(edges_np, nan=np.inf), mesh)
-        mono_np = np.zeros(len(names), dtype=np.float32)
-        for col, d in (getattr(p, "monotone_constraints", None) or {}).items():
-            if col not in names:
-                raise ValueError(f"monotone_constraints column '{col}' is not "
-                                 f"a feature")
-            if fr.vec(col).is_categorical():
-                raise ValueError(f"monotone_constraints on categorical column "
-                                 f"'{col}' (numeric only, as in the reference)")
-            mono_np[names.index(col)] = float(np.sign(d))
-        mono = put_replicated(mono_np, mesh)
-        imat_np = _interaction_matrix(names,
-                                      getattr(p, "interaction_constraints",
-                                              None))
-        imat = put_replicated(imat_np, mesh)
-        edge_ok = put_replicated(~np.isnan(edges_np), mesh)
-        binned_view = None
+            sk_span.attrs.update(hbm_span_attrs())
+        with telemetry.span("train.gbm.prep"):
+            mesh = default_mesh()
+            edges = put_replicated(np.nan_to_num(edges_np, nan=np.inf), mesh)
+            mono_np = np.zeros(len(names), dtype=np.float32)
+            for col, d in (getattr(p, "monotone_constraints", None) or {}).items():
+                if col not in names:
+                    raise ValueError(f"monotone_constraints column '{col}' is not "
+                                     f"a feature")
+                if fr.vec(col).is_categorical():
+                    raise ValueError(f"monotone_constraints on categorical column "
+                                     f"'{col}' (numeric only, as in the reference)")
+                mono_np[names.index(col)] = float(np.sign(d))
+            mono = put_replicated(mono_np, mesh)
+            imat_np = _interaction_matrix(names,
+                                          getattr(p, "interaction_constraints",
+                                                  None))
+            imat = put_replicated(imat_np, mesh)
+            edge_ok = put_replicated(~np.isnan(edges_np), mesh)
+            binned_view = None
         # HOST wall of the coded-matrix build: no sync is added here, so
         # where the build does not drain by itself the device's side is the
         # scope gbm.bin in a capture
@@ -561,91 +569,93 @@ class GBM(ModelBuilder):
             # (`BinnedView.code_dtype`), 4 on the stacked path
             bv_span.attrs["code_bytes"] = int(Xb.dtype.itemsize)
             bv_span.attrs["coded_gb"] = Xb.size * Xb.dtype.itemsize / 1e9
-        plen = Xb.shape[0]
-        # how the job's rows lie on the mesh, on the job's root span
-        # (train.<algo>; a CV fold's builder has none)
-        root = getattr(self, "_train_span", None)
-        if root is not None:
-            root.attrs["row_shards"] = n_row_shards(mesh)
-            root.attrs["rows_per_shard"] = plen // n_row_shards(mesh)
-        global LAST_TRAIN_MATRIX_BYTES
-        LAST_TRAIN_MATRIX_BYTES = {
-            "mode": "binned" if use_binned else "stacked_f32",
-            "raw_bytes": 0 if X is None else int(X.size * X.dtype.itemsize),
-            "binned_bytes": int(Xb.size * Xb.dtype.itemsize),
-            "binned_dtype": str(Xb.dtype),
-            "cells": int(plen * len(names)),
-            # multi-chip accounting: the LARGEST single-device slice of the
-            # training matrix (row-sharded ⇒ ~binned_bytes/n_shards; the
-            # per-chip HBM number the sharded bench leg steers by)
-            "per_shard_bytes": per_shard_nbytes(Xb),
-            "n_row_shards": n_row_shards(mesh),
-        }
+            bv_span.attrs.update(hbm_span_attrs())
+        with telemetry.span("train.gbm.prep"):
+            plen = Xb.shape[0]
+            # how the job's rows lie on the mesh, on the job's root span
+            # (train.<algo>; a CV fold's builder has none)
+            root = getattr(self, "_train_span", None)
+            if root is not None:
+                root.attrs["row_shards"] = n_row_shards(mesh)
+                root.attrs["rows_per_shard"] = plen // n_row_shards(mesh)
+            global LAST_TRAIN_MATRIX_BYTES
+            LAST_TRAIN_MATRIX_BYTES = {
+                "mode": "binned" if use_binned else "stacked_f32",
+                "raw_bytes": 0 if X is None else int(X.size * X.dtype.itemsize),
+                "binned_bytes": int(Xb.size * Xb.dtype.itemsize),
+                "binned_dtype": str(Xb.dtype),
+                "cells": int(plen * len(names)),
+                # multi-chip accounting: the LARGEST single-device slice of the
+                # training matrix (row-sharded ⇒ ~binned_bytes/n_shards; the
+                # per-chip HBM number the sharded bench leg steers by)
+                "per_shard_bytes": per_shard_nbytes(Xb),
+                "n_row_shards": n_row_shards(mesh),
+            }
 
-        # initial prediction (`hex/tree/gbm/GBM.java:265` init) — one
-        # compiled program per (drf, K, distribution) family
-        f0 = _jit_init_f(self.drf_mode, K, dist, y, w)
+            # initial prediction (`hex/tree/gbm/GBM.java:265` init) — one
+            # compiled program per (drf, K, distribution) family
+            f0 = _jit_init_f(self.drf_mode, K, dist, y, w)
 
-        grad_fn = self._make_grad_fn(dist, K)
-        # effective bin count follows the edge matrix: small-data exact
-        # binning and nbins_cats may widen it past p.nbins
-        cfg = self._tree_config(K, nbins=edges_np.shape[1] + 1)
-        # categorical SET splits (IcedBitSet analog) whenever categorical
-        # features exist; RuleFit's internal forests opt out (threshold-only
-        # rule language)
-        use_sets = bool(is_cat.any()) and getattr(self, "_use_set_splits",
-                                                  True)
-        nedges_np = (~np.isnan(edges_np)).sum(axis=1).astype(np.int32)
-        iscat_dev = put_replicated(is_cat, mesh)
-        nedges_dev = put_replicated(nedges_np, mesh)
-        # histogram accumulation plan: width-bucketed hist_groups (auto-tuned
-        # from the per-column bin counts) plus a row block fitted to the live
-        # HBM budget, so wide bin spaces (high-cardinality categoricals /
-        # exact binning) bound the per-block one-hot footprint by
-        # construction — see engine.plan_hist_groups
-        B_hist = cfg.nbins + 1
-        hist_groups, blk = plan_hist_groups(
-            nedges_np, B_hist, cfg.block_rows,
-            budget_bytes=hbm_budget_bytes(),
-            n_lv_max=2 ** max(cfg.max_depth - 1, 0), nvals=3)
-        cfg = dataclasses.replace(cfg, use_sets=use_sets, block_rows=blk,
-                                  hist_groups=hist_groups)
-        # per-tree ICI reduction payload (per-level hist psums + the node-
-        # totals psum) — static accounting the sharded bench leg records
-        LAST_TRAIN_MATRIX_BYTES["psum_bytes_per_tree"] = \
-            psum_payload_bytes(cfg, len(names))
-        if not self.drf_mode and K == 1 and dist.name in ("laplace",
-                                                          "quantile"):
-            # exact gamma leaves: median (laplace) / alpha-quantile of the
-            # in-leaf residuals replaces the Newton step (`GBM.java:730,814`)
+            grad_fn = self._make_grad_fn(dist, K)
+            # effective bin count follows the edge matrix: small-data exact
+            # binning and nbins_cats may widen it past p.nbins
+            cfg = self._tree_config(K, nbins=edges_np.shape[1] + 1)
+            # categorical SET splits (IcedBitSet analog) whenever categorical
+            # features exist; RuleFit's internal forests opt out (threshold-only
+            # rule language)
+            use_sets = bool(is_cat.any()) and getattr(self, "_use_set_splits",
+                                                      True)
+            nedges_np = (~np.isnan(edges_np)).sum(axis=1).astype(np.int32)
+            iscat_dev = put_replicated(is_cat, mesh)
+            nedges_dev = put_replicated(nedges_np, mesh)
+            # histogram accumulation plan: width-bucketed hist_groups (auto-tuned
+            # from the per-column bin counts) plus a row block fitted to the live
+            # HBM budget, so wide bin spaces (high-cardinality categoricals /
+            # exact binning) bound the per-block one-hot footprint by
+            # construction — see engine.plan_hist_groups
+            B_hist = cfg.nbins + 1
+            hist_groups, blk = plan_hist_groups(
+                nedges_np, B_hist, cfg.block_rows,
+                budget_bytes=hbm_budget_bytes(),
+                n_lv_max=2 ** max(cfg.max_depth - 1, 0), nvals=3)
+            cfg = dataclasses.replace(cfg, use_sets=use_sets, block_rows=blk,
+                                      hist_groups=hist_groups)
+            # per-tree ICI reduction payload (per-level hist psums + the node-
+            # totals psum) — static accounting the sharded bench leg records
+            LAST_TRAIN_MATRIX_BYTES["psum_bytes_per_tree"] = \
+                psum_payload_bytes(cfg, len(names))
+            if not self.drf_mode and K == 1 and dist.name in ("laplace",
+                                                              "quantile"):
+                # exact gamma leaves: median (laplace) / alpha-quantile of the
+                # in-leaf residuals replaces the Newton step (`GBM.java:730,814`)
+                cfg = dataclasses.replace(
+                    cfg, leaf_quantile=(0.5 if dist.name == "laplace"
+                                        else p.quantile_alpha))
+            elif not self.drf_mode and K == 1 and dist.name == "huber":
+                # hybrid gamma leaves (`GBM.java:685`); the split-search
+                # gradients still clip at unit delta (documented residue)
+                cfg = dataclasses.replace(cfg, huber_leaf_alpha=p.huber_alpha)
+            # async pipelined training knobs (ISSUE 12): the pipelined level
+            # program and the overlapped reduction are BIT-equal to the
+            # synchronous oracle, so they default on
             cfg = dataclasses.replace(
-                cfg, leaf_quantile=(0.5 if dist.name == "laplace"
-                                    else p.quantile_alpha))
-        elif not self.drf_mode and K == 1 and dist.name == "huber":
-            # hybrid gamma leaves (`GBM.java:685`); the split-search
-            # gradients still clip at unit delta (documented residue)
-            cfg = dataclasses.replace(cfg, huber_leaf_alpha=p.huber_alpha)
-        # async pipelined training knobs (ISSUE 12): the pipelined level
-        # program and the overlapped reduction are BIT-equal to the
-        # synchronous oracle, so they default on
-        cfg = dataclasses.replace(
-            cfg, pipeline=get_bool("H2O_TPU_PIPELINE"),
-            async_psum=get_bool("H2O_TPU_ASYNC_PSUM"))
-        # the cache key must pin everything grad_fn's behavior depends on;
-        # custom distribution UDFs bypass the cache entirely (an id()-based
-        # key could alias a new UDF at a recycled address after GC)
-        if p.custom_distribution_func is dist:
-            grad_key = None
-        else:
-            grad_key = (type(self).__name__, self.drf_mode, K, dist.name,
-                        p.tweedie_power, p.quantile_alpha, p.huber_alpha)
+                cfg, pipeline=get_bool("H2O_TPU_PIPELINE"),
+                async_psum=get_bool("H2O_TPU_ASYNC_PSUM"))
+            # the cache key must pin everything grad_fn's behavior depends on;
+            # custom distribution UDFs bypass the cache entirely (an id()-based
+            # key could alias a new UDF at a recycled address after GC)
+            if p.custom_distribution_func is dist:
+                grad_key = None
+            else:
+                grad_key = (type(self).__name__, self.drf_mode, K, dist.name,
+                            p.tweedie_power, p.quantile_alpha, p.huber_alpha)
 
-        if K > 1:
-            y_k = jnp.broadcast_to(y, (K, y.shape[0]))
-            f = jnp.broadcast_to(f0[:, None], (K, y.shape[0])).astype(jnp.float32)
-        else:
-            y_k = y
-            f = _jit_full_like(y, f0)
+            if K > 1:
+                y_k = jnp.broadcast_to(y, (K, y.shape[0]))
+                f = jnp.broadcast_to(f0[:, None], (K, y.shape[0])).astype(jnp.float32)
+            else:
+                y_k = y
+                f = _jit_full_like(y, f0)
         return _types.SimpleNamespace(
             p=p, fr=fr, names=names, category=category,
             resp_domain=resp_domain, dist=dist, K=K, X=X, is_cat=is_cat,
@@ -677,151 +687,155 @@ class GBM(ModelBuilder):
                      "the current bin grid — replaying over the stacked raw "
                      "matrix instead")
                 s = self._setup_build(need_raw=True)
-        p, fr, names = s.p, s.fr, s.names
-        category, resp_domain, dist, K = (s.category, s.resp_domain,
-                                          s.dist, s.K)
-        is_cat, w, y, ymask = s.is_cat, s.w, s.y, s.ymask
-        # the RAW stacked matrix (present only with BINNED_STORE=0 or the
-        # off-grid fallback above) is binning input / replay input only —
-        # drop it the moment nothing needs it: at airlines-116M scale it is
-        # ~4 GB of HBM the whole train would otherwise hold. (XGBoost's
-        # DART driver keeps its own s.X.)
-        X = s.X
-        if prior is None:
-            X = s.X = None
-        edges, mono, imat, edge_ok, Xb = (s.edges, s.mono, s.imat,
-                                          s.edge_ok, s.Xb)
-        mesh, f0, grad_fn, cfg, grad_key = (s.mesh, s.f0, s.grad_fn,
-                                            s.cfg, s.grad_key)
-        y_k, f = s.y_k, s.f
+        # the set-up's last stretch, train.gbm.prep as in _setup_build: keys,
+        # rates, the train function and its arguments, up to the step's
+        # load (train.gbm.compile where the executable is not in hand)
+        with telemetry.span("train.gbm.prep"):
+            p, fr, names = s.p, s.fr, s.names
+            category, resp_domain, dist, K = (s.category, s.resp_domain,
+                                              s.dist, s.K)
+            is_cat, w, y, ymask = s.is_cat, s.w, s.y, s.ymask
+            # the RAW stacked matrix (present only with BINNED_STORE=0 or the
+            # off-grid fallback above) is binning input / replay input only —
+            # drop it the moment nothing needs it: at airlines-116M scale it is
+            # ~4 GB of HBM the whole train would otherwise hold. (XGBoost's
+            # DART driver keeps its own s.X.)
+            X = s.X
+            if prior is None:
+                X = s.X = None
+            edges, mono, imat, edge_ok, Xb = (s.edges, s.mono, s.imat,
+                                              s.edge_ok, s.Xb)
+            mesh, f0, grad_fn, cfg, grad_key = (s.mesh, s.f0, s.grad_fn,
+                                                s.cfg, s.grad_key)
+            y_k, f = s.y_k, s.f
 
-        # checkpoint restart (`hex/tree/SharedTree.java:146,243,470`): resume
-        # the boosting sequence from a prior model's carried link predictions.
-        prior_parts = []
-        if prior is not None:
-            if p.ntrees <= prior.ntrees:
-                raise ValueError(
-                    f"checkpoint model already has {prior.ntrees} trees; "
-                    f"ntrees must exceed that (got {p.ntrees})")
-            # parameter-compatibility validation, up front (the reference
-            # validates before training, `SharedTree` checkpoint checks)
-            prior_mono = getattr(prior.params, "monotone_constraints", None) or {}
-            for fld, ours, theirs in (
-                    ("max_depth", p.max_depth, prior.cfg.max_depth),
-                    # cfg.nbins is the EFFECTIVE bin count (small-data exact
-                    # binning may widen it); the user contract is the param
-                    ("nbins", p.nbins,
-                     getattr(prior.params, "nbins", prior.cfg.nbins)),
-                    ("nbins_cats", getattr(p, "nbins_cats", 1024),
-                     getattr(prior.params, "nbins_cats", 1024)),
-                    ("nclasses", K, prior.cfg.nclass),
-                    ("drf_mode", self.drf_mode, prior.cfg.drf_mode),
-                    ("monotone_constraints",
-                     dict(getattr(p, "monotone_constraints", None) or {}),
-                     dict(prior_mono))):
-                if ours != theirs:
+            # checkpoint restart (`hex/tree/SharedTree.java:146,243,470`): resume
+            # the boosting sequence from a prior model's carried link predictions.
+            prior_parts = []
+            if prior is not None:
+                if p.ntrees <= prior.ntrees:
                     raise ValueError(
-                        f"checkpoint incompatible: {fld} differs "
-                        f"(checkpoint={theirs}, request={ours})")
-            # the stored params reference the prior by key, not by object —
-            # keeps binary export/import free of nested models/frames
-            p = self.params = dataclasses.replace(p, checkpoint=prior.key)
-            # continuation trees must speak the prior forest's split
-            # language: inherit its use_sets so pre-round-4 models (ordinal
-            # categorical splits) stay continuable, and a set-split prior
-            # keeps its routing tables live
-            prior_sets = bool(getattr(prior.cfg, "use_sets", False))
-            if cfg.use_sets != prior_sets:
-                cfg = dataclasses.replace(cfg, use_sets=prior_sets)
-            f0 = prior.f0
-            if prior_thr_codes is not None:  # binned replay — X never stacked
-                fprev = prior._raw_f_codes(Xb, prior_thr_codes,
-                                           s.edges_np.shape[1] + 1)
-            else:
-                fprev = prior._raw_f(X)  # includes f0, link scale
-            X = s.X = None  # replay done — release the raw matrix (if any)
-            f = fprev.T.astype(jnp.float32) if K > 1 else fprev.astype(jnp.float32)
-            if self.drf_mode:
-                # _raw_f averages DRF trees; the carried f is the raw sum
-                f = f * prior.ntrees
-            pf = prior.forest
-            prior_parts = [tuple(
-                pf[k] if k in pf else
-                jnp.zeros(pf["feat"].shape + (1,), jnp.float32)
-                for k in ("feat", "thr", "nanL", "val", "gain", "catd"))]
+                        f"checkpoint model already has {prior.ntrees} trees; "
+                        f"ntrees must exceed that (got {p.ntrees})")
+                # parameter-compatibility validation, up front (the reference
+                # validates before training, `SharedTree` checkpoint checks)
+                prior_mono = getattr(prior.params, "monotone_constraints", None) or {}
+                for fld, ours, theirs in (
+                        ("max_depth", p.max_depth, prior.cfg.max_depth),
+                        # cfg.nbins is the EFFECTIVE bin count (small-data exact
+                        # binning may widen it); the user contract is the param
+                        ("nbins", p.nbins,
+                         getattr(prior.params, "nbins", prior.cfg.nbins)),
+                        ("nbins_cats", getattr(p, "nbins_cats", 1024),
+                         getattr(prior.params, "nbins_cats", 1024)),
+                        ("nclasses", K, prior.cfg.nclass),
+                        ("drf_mode", self.drf_mode, prior.cfg.drf_mode),
+                        ("monotone_constraints",
+                         dict(getattr(p, "monotone_constraints", None) or {}),
+                         dict(prior_mono))):
+                    if ours != theirs:
+                        raise ValueError(
+                            f"checkpoint incompatible: {fld} differs "
+                            f"(checkpoint={theirs}, request={ours})")
+                # the stored params reference the prior by key, not by object —
+                # keeps binary export/import free of nested models/frames
+                p = self.params = dataclasses.replace(p, checkpoint=prior.key)
+                # continuation trees must speak the prior forest's split
+                # language: inherit its use_sets so pre-round-4 models (ordinal
+                # categorical splits) stay continuable, and a set-split prior
+                # keeps its routing tables live
+                prior_sets = bool(getattr(prior.cfg, "use_sets", False))
+                if cfg.use_sets != prior_sets:
+                    cfg = dataclasses.replace(cfg, use_sets=prior_sets)
+                f0 = prior.f0
+                if prior_thr_codes is not None:  # binned replay — X never stacked
+                    fprev = prior._raw_f_codes(Xb, prior_thr_codes,
+                                               s.edges_np.shape[1] + 1)
+                else:
+                    fprev = prior._raw_f(X)  # includes f0, link scale
+                X = s.X = None  # replay done — release the raw matrix (if any)
+                f = fprev.T.astype(jnp.float32) if K > 1 else fprev.astype(jnp.float32)
+                if self.drf_mode:
+                    # _raw_f averages DRF trees; the carried f is the raw sum
+                    f = f * prior.ntrees
+                pf = prior.forest
+                prior_parts = [tuple(
+                    pf[k] if k in pf else
+                    jnp.zeros(pf["feat"].shape + (1,), jnp.float32)
+                    for k in ("feat", "thr", "nanL", "val", "gain", "catd"))]
 
-        n_prior = prior.ntrees if prior else 0
-        if rs is not None:
-            # auto-recovery resume: the state carries everything the prior
-            # block would have derived (n_prior/f0/use_sets), so a resumed
-            # continuation never needs the prior model object back
-            n_prior = int(rs["n_prior"])
-            f0 = jnp.asarray(np.asarray(rs["f0"]))
-            if bool(rs["use_sets"]) != cfg.use_sets:
-                cfg = dataclasses.replace(cfg, use_sets=bool(rs["use_sets"]))
-        n_new = p.ntrees - n_prior
-        base_seed = p.seed if p.seed not in (-1, None) else 1234
-        all_keys = _jit_keys(base_seed, p.ntrees)[n_prior:]
-        # learn_rate_annealing: rate_i = annealing^i (GBM.java lr schedule);
-        # indices continue across chunks and checkpoint restarts. DRF has no
-        # learning rate at all — leaves are response means — so annealing is
-        # forced off there like learn_rate itself.
-        anneal = (1.0 if self.drf_mode
-                  else float(getattr(p, "learn_rate_annealing", 1.0) or 1.0))
-        all_rates = (anneal ** np.arange(n_prior, p.ntrees)
-                     ).astype(np.float32)
+            n_prior = prior.ntrees if prior else 0
+            if rs is not None:
+                # auto-recovery resume: the state carries everything the prior
+                # block would have derived (n_prior/f0/use_sets), so a resumed
+                # continuation never needs the prior model object back
+                n_prior = int(rs["n_prior"])
+                f0 = jnp.asarray(np.asarray(rs["f0"]))
+                if bool(rs["use_sets"]) != cfg.use_sets:
+                    cfg = dataclasses.replace(cfg, use_sets=bool(rs["use_sets"]))
+            n_new = p.ntrees - n_prior
+            base_seed = p.seed if p.seed not in (-1, None) else 1234
+            all_keys = _jit_keys(base_seed, p.ntrees)[n_prior:]
+            # learn_rate_annealing: rate_i = annealing^i (GBM.java lr schedule);
+            # indices continue across chunks and checkpoint restarts. DRF has no
+            # learning rate at all — leaves are response means — so annealing is
+            # forced off there like learn_rate itself.
+            anneal = (1.0 if self.drf_mode
+                      else float(getattr(p, "learn_rate_annealing", 1.0) or 1.0))
+            all_rates = (anneal ** np.arange(n_prior, p.ntrees)
+                         ).astype(np.float32)
 
-        interval = p.score_tree_interval or n_new
-        interval = min(interval, n_new)
-        chunks = [(all_keys[i:i + interval],
-                   jnp.asarray(all_rates[i:i + interval]))
-                  for i in range(0, n_new, interval)]
-        from jax.sharding import PartitionSpec as _Pspec
+            interval = p.score_tree_interval or n_new
+            interval = min(interval, n_new)
+            chunks = [(all_keys[i:i + interval],
+                       jnp.asarray(all_rates[i:i + interval]))
+                      for i in range(0, n_new, interval)]
+            from jax.sharding import PartitionSpec as _Pspec
 
-        # pipelined chunk dispatch (ISSUE 12): fold cadence scoring into
-        # the train step (the score0-layout raw predictions come out of
-        # the program that already holds the final margin), and donate the
-        # carried margin's buffer across chunk dispatches. Both ride
-        # cfg.pipeline; DRF keeps standalone scoring (its cadence metrics
-        # are the OOB path's, computed from the OOB accumulators).
-        fused_score = bool(cfg.pipeline) and not self.drf_mode
-        donate_f = bool(cfg.pipeline)
-        score_fn = score_spec = None
-        if fused_score:
-            cfg = dataclasses.replace(cfg, fused_score=True)
-            score_fn = _metrics_raw_fn(category, dist, self.drf_mode)
-            score_spec = (_Pspec(ROWS) if category == "Regression"
-                          else _Pspec(ROWS, None))
-        # trees done after each chunk (the fused score's traced nt scalar)
-        nd_after = []
-        run = n_prior
-        for keys_c, _rates_c in chunks:
-            run += int(keys_c.shape[0])
-            nd_after.append(run)
-        # The compiled program depends on the CHUNK length (the scan is over
-        # the per-chunk keys), never on the total tree count — keying the
-        # train-fn cache on the interval makes a 10-tree warm-up compile serve
-        # a 1000-tree run at the same scoring cadence.
-        train_fn = make_train_fn(dataclasses.replace(cfg, ntrees=interval),
-                                 grad_fn, mesh, cache_key=grad_key,
-                                 score_fn=score_fn, score_spec=score_spec,
-                                 donate=donate_f)
-        # pin the carried f to the trainer's OUTPUT sharding before the AOT
-        # lower: chunk 0's freshly-broadcast f can come back replicated
-        # (GSPMD's choice for a data-independent broadcast) while every
-        # later chunk carries the P(ROWS)-sharded train output — an AOT
-        # executable compiled for the former rejects the latter, and the
-        # whole job silently pays the jitted fallback on a multi-shard mesh
-        fspec = _Pspec(ROWS) if K == 1 else _Pspec(None, ROWS)
-        f = put_sharded(f, fspec, mesh)
-
-        def _step_args(ci, f_in):
-            keys_c, rates_c = chunks[ci]
-            args = (Xb, y_k, w, f_in, edges, edge_ok, keys_c, rates_c,
-                    mono, imat, s.iscat_dev, s.nedges_dev)
+            # pipelined chunk dispatch (ISSUE 12): fold cadence scoring into
+            # the train step (the score0-layout raw predictions come out of
+            # the program that already holds the final margin), and donate the
+            # carried margin's buffer across chunk dispatches. Both ride
+            # cfg.pipeline; DRF keeps standalone scoring (its cadence metrics
+            # are the OOB path's, computed from the OOB accumulators).
+            fused_score = bool(cfg.pipeline) and not self.drf_mode
+            donate_f = bool(cfg.pipeline)
+            score_fn = score_spec = None
             if fused_score:
-                args += (jnp.asarray(nd_after[ci], jnp.float32),)
-            return args
+                cfg = dataclasses.replace(cfg, fused_score=True)
+                score_fn = _metrics_raw_fn(category, dist, self.drf_mode)
+                score_spec = (_Pspec(ROWS) if category == "Regression"
+                              else _Pspec(ROWS, None))
+            # trees done after each chunk (the fused score's traced nt scalar)
+            nd_after = []
+            run = n_prior
+            for keys_c, _rates_c in chunks:
+                run += int(keys_c.shape[0])
+                nd_after.append(run)
+            # The compiled program depends on the CHUNK length (the scan is over
+            # the per-chunk keys), never on the total tree count — keying the
+            # train-fn cache on the interval makes a 10-tree warm-up compile serve
+            # a 1000-tree run at the same scoring cadence.
+            train_fn = make_train_fn(dataclasses.replace(cfg, ntrees=interval),
+                                     grad_fn, mesh, cache_key=grad_key,
+                                     score_fn=score_fn, score_spec=score_spec,
+                                     donate=donate_f)
+            # pin the carried f to the trainer's OUTPUT sharding before the AOT
+            # lower: chunk 0's freshly-broadcast f can come back replicated
+            # (GSPMD's choice for a data-independent broadcast) while every
+            # later chunk carries the P(ROWS)-sharded train output — an AOT
+            # executable compiled for the former rejects the latter, and the
+            # whole job silently pays the jitted fallback on a multi-shard mesh
+            fspec = _Pspec(ROWS) if K == 1 else _Pspec(None, ROWS)
+            f = put_sharded(f, fspec, mesh)
+
+            def _step_args(ci, f_in):
+                keys_c, rates_c = chunks[ci]
+                args = (Xb, y_k, w, f_in, edges, edge_ok, keys_c, rates_c,
+                        mono, imat, s.iscat_dev, s.nedges_dev)
+                if fused_score:
+                    args += (jnp.asarray(nd_after[ci], jnp.float32),)
+                return args
 
         # AOT lower+compile the uniform-chunk step NOW (build setup), so the
         # chunk loop dispatches a prebuilt executable and the compile wall /
@@ -868,8 +882,6 @@ class GBM(ModelBuilder):
                        else jnp.asarray(np.asarray(rs["oob_cnt"])))
             history = list(rs["history"])
             stop_metric_series = list(rs["stop_series"])
-        from ..utils import telemetry
-
         # dispatch-ahead engages when nothing at a boundary needs the
         # carried margin back on host: fused scoring supplies the metric
         # input, no early stopping / time budget / auto-recovery reads
@@ -1056,25 +1068,27 @@ class GBM(ModelBuilder):
             flightrec.maybe_drill()
             if self._should_stop(m, stop_metric_series):
                 break
-        output.scoring_history = history
-        # DRF training metrics are the OOB metrics from the chunk loop above;
-        # checkpoint continuations fall back to in-bag (prior trees' bags are
-        # not recoverable, and one new tree's OOB would misrepresent the
-        # whole forest)
-        output.training_metrics = history[-1]["training_metrics"]
+        # from the last chunk's end to the model in the store
+        with telemetry.span("train.gbm.finish"):
+            output.scoring_history = history
+            # DRF training metrics are the OOB metrics from the chunk loop above;
+            # checkpoint continuations fall back to in-bag (prior trees' bags are
+            # not recoverable, and one new tree's OOB would misrepresent the
+            # whole forest)
+            output.training_metrics = history[-1]["training_metrics"]
 
-        forest = _assemble_forest(parts)
-        # node covers for TreeSHAP are computed lazily on first
-        # predict_contributions call (GBMModel._ensure_covers) — the routing
-        # pass over all training rows is pure overhead for the common
-        # train→predict path
-        output.variable_importances = self._varimp(forest, names)
-        model = GBMModel(p, output, forest, f0, dist, cfg, is_cat,
-                         cat_nedges=s.nedges_np)
-        if getattr(p, "calibrate_model", False):
-            model.calib = self._fit_calibration(model, category)
-        if p.validation_frame is not None:
-            output.validation_metrics = model.model_performance(p.validation_frame)
+            forest = _assemble_forest(parts)
+            # node covers for TreeSHAP are computed lazily on first
+            # predict_contributions call (GBMModel._ensure_covers) — the routing
+            # pass over all training rows is pure overhead for the common
+            # train→predict path
+            output.variable_importances = self._varimp(forest, names)
+            model = GBMModel(p, output, forest, f0, dist, cfg, is_cat,
+                             cat_nedges=s.nedges_np)
+            if getattr(p, "calibrate_model", False):
+                model.calib = self._fit_calibration(model, category)
+            if p.validation_frame is not None:
+                output.validation_metrics = model.model_performance(p.validation_frame)
         return model
 
     def _oob_metrics(self, category, osum, ocnt, y, ymask, w, domain=None):
@@ -1224,11 +1238,13 @@ class GBM(ModelBuilder):
 
 
 @jax.jit
+@telemetry.program("gbm_setup_init")
 def _jit_full_like(y, f0):
     return jnp.full_like(y, f0, dtype=jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("n",))
+@telemetry.program("gbm_setup_keys")
 def _jit_keys(seed, n: int):
     """PRNGKey + split in one program (eagerly: 2 programs + a slice)."""
     return jax.random.split(jax.random.PRNGKey(seed), n)
@@ -1243,6 +1259,7 @@ def _jit_prep(y_dev, w_in):
     has_w = w_in is not None
     fn = _PREP_CACHE.get(has_w)
     if fn is None:
+        @telemetry.program("gbm_setup_prep")
         def prep(y_dev, w_in):
             y = jnp.nan_to_num(y_dev)
             ymask = ~jnp.isnan(y_dev)
@@ -1267,6 +1284,7 @@ def _jit_init_f(drf_mode, K, dist, y, w):
            getattr(dist, "power", None))
     fn = _INIT_F_CACHE.get(key) if builtin else None
     if fn is None:
+        @telemetry.program("gbm_setup_init")
         def init(y, w):
             if drf_mode:
                 return jnp.zeros((K,)) if K > 1 else jnp.array(0.0)
@@ -1283,6 +1301,7 @@ def _jit_init_f(drf_mode, K, dist, y, w):
 
 
 @jax.jit
+@telemetry.program("gbm_setup_stack")
 def _codes_to_f32(blk, na_code):
     """One replay block: int8/int16 bin codes -> f32 with the NA bucket
     restored to NaN (codes upcast to int32 first — the NA code can exceed
@@ -1386,6 +1405,7 @@ def _metrics_raw_fn(category, dist, drf_mode):
     (f, ntrees) — consumed by `_metrics_raw`'s standalone jitted program
     AND, under fused cadence scoring (cfg.fused_score), traced straight
     into the chunk train step so the margin never rematerializes."""
+    @telemetry.program("gbm_score_raw")
     def raw(f, nt):
         if category == "Regression":
             # DRF carries the SUM of per-tree leaf means; the

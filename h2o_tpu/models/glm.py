@@ -32,6 +32,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as _P
 
 from ..backend.jobs import Job
+from ..backend.memory import hbm_span_attrs
 from ..frame.frame import Frame
 from ..frame.vec import Vec
 from ..utils import telemetry
@@ -258,6 +259,7 @@ def _make_irls_kernel(family: Family):
 
     # device scopes (telemetry.SCOPES) split the step in a capture; the
     # function keeps its name: the XLA module `jit__core` is read by name
+    @telemetry.program("_core")
     def _core(X, y, w, beta, offset):
         with telemetry.scope("glm.eta"):
             eta = X @ beta + offset
@@ -289,6 +291,7 @@ def _make_irls_kernel(family: Family):
         if ns > 1:
             prog = sharded.get(mesh)
             if prog is None:
+                @telemetry.program("glm_irls_sharded")
                 def spmd(X, y, w, beta, offset):
                     out = _core(X, y, w, beta, offset)
                     return tuple(jax.lax.psum(o, ROWS) for o in out)
@@ -318,6 +321,7 @@ def _make_dev_kernel(family: Family):
     wall)."""
 
     @jax.jit
+    @telemetry.program("glm_probe")
     def dev_eval(X, y, w, beta, offset):
         with telemetry.scope("glm.eta"):
             mu = family.linkinv(X @ beta + offset)
@@ -1257,7 +1261,7 @@ class GLM(ModelBuilder):
             return self._build_multinomial(job, names, y_dev, resp_domain)
         family = self._family(category)
 
-        with telemetry.span("train.glm.design"):
+        with telemetry.span("train.glm.design") as design_span:
             dinfo = DataInfo.make(
                 fr, names, standardize=p.standardize,
                 missing_values_handling=p.missing_values_handling)
@@ -1270,6 +1274,7 @@ class GLM(ModelBuilder):
                 w = w * jnp.nan_to_num(fr.vec(p.weights_column).data)
             offset = (jnp.nan_to_num(fr.vec(p.offset_column).data)
                       if p.offset_column else jnp.zeros_like(y))
+            design_span.attrs.update(hbm_span_attrs())
 
         self._bounds = _beta_bounds(p.beta_constraints, dinfo,
                                     pad_cols=pad_cols)
@@ -1277,30 +1282,35 @@ class GLM(ModelBuilder):
                                                  pad_cols=pad_cols)
         beta, lambda_used, dev, nulldev, neff, iters, path = self._fit(
             X, y, w, offset, family, job)
-        betas = np.stack([e[3] for e in path])
-        if pad_cols:  # strip padding: coefficients (all ~0) and design cols
-            keep = np.r_[:dinfo.ncols_expanded, -1]
-            beta, betas = beta[keep], betas[:, keep]
-            X = X[:, :dinfo.ncols_expanded]
+        # from the fit's end to the model in the store
+        with telemetry.span("train.glm.finish"):
+            betas = np.stack([e[3] for e in path])
+            if pad_cols:  # strip padding: coefficients (all ~0), design cols
+                keep = np.r_[:dinfo.ncols_expanded, -1]
+                beta, betas = beta[keep], betas[:, keep]
+                X = X[:, :dinfo.ncols_expanded]
 
-        output = ModelOutput()
-        output.names = names
-        output.domains = {n: fr.vec(n).domain for n in names}
-        output.response_domain = list(resp_domain) if resp_domain else None
-        output.model_category = category
-        # the regularisation path as fitted (`GLMModel.RegularizationPath`):
-        # one lambda without a search; the model returned is its last
-        output.lambdas = [e[0] for e in path]
-        output.explained_deviance_train = [
-            1.0 - e[1] / nulldev if nulldev else float("nan") for e in path]
-        output.path_iterations = [e[2] for e in path]
-        output.coefficients_std = betas
-        output.coefficients = np.stack(
-            [_destandardize(b, dinfo) for b in betas])
-        output.lambda_best = lambda_used
-        output.lambda_best_index = len(path) - 1
-        model = GLMModel(p, output, dinfo, beta, family)
-        model.interaction_spec = self._interaction_spec
+            output = ModelOutput()
+            output.names = names
+            output.domains = {n: fr.vec(n).domain for n in names}
+            output.response_domain = (list(resp_domain) if resp_domain
+                                      else None)
+            output.model_category = category
+            # the regularisation path as fitted
+            # (`GLMModel.RegularizationPath`): one lambda without a search;
+            # the model returned is its last
+            output.lambdas = [e[0] for e in path]
+            output.explained_deviance_train = [
+                1.0 - e[1] / nulldev if nulldev else float("nan")
+                for e in path]
+            output.path_iterations = [e[2] for e in path]
+            output.coefficients_std = betas
+            output.coefficients = np.stack(
+                [_destandardize(b, dinfo) for b in betas])
+            output.lambda_best = lambda_used
+            output.lambda_best_index = len(path) - 1
+            model = GLMModel(p, output, dinfo, beta, family)
+            model.interaction_spec = self._interaction_spec
         # final scoring and metrics, through dispersion and p-values
         with telemetry.span("train.glm.metrics"):
             raw = model.score0(X)
@@ -1420,22 +1430,26 @@ class GLM(ModelBuilder):
         P = X.shape[1]
         step = _make_irls_kernel(family)
         alpha = p.alpha if p.alpha is not None else 0.5
-        with telemetry.span("train.glm.design"):
+        with telemetry.span("train.glm.design") as design_span:
             ones = jnp.ones((X.shape[0], 1), jnp.float32)
             Xi = jnp.concatenate([X, ones], axis=1)  # intercept column last
-        free = np.zeros(P + 1, dtype=bool)
-        free[-1] = True
+            design_span.attrs.update(hbm_span_attrs())
+        # start intercept, null deviance and neff: eager reductions, each
+        # read back with a sync, between the design and the first step
+        with telemetry.span("train.glm.start"):
+            free = np.zeros(P + 1, dtype=bool)
+            free[-1] = True
 
-        beta = np.zeros(P + 1, dtype=np.float64)
-        b0 = float(family.init_intercept(y, w))
-        beta[-1] = b0 if p.intercept else 0.0
+            beta = np.zeros(P + 1, dtype=np.float64)
+            b0 = float(family.init_intercept(y, w))
+            beta[-1] = b0 if p.intercept else 0.0
 
-        # null deviance
-        mu0 = family.linkinv(jnp.full_like(y, b0) + offset)
-        nulldev = float(jnp.sum(family.deviance(y, mu0, w)))
-        neff = float(jnp.sum(w))
+            # null deviance
+            mu0 = family.linkinv(jnp.full_like(y, b0) + offset)
+            nulldev = float(jnp.sum(family.deviance(y, mu0, w)))
+            neff = float(jnp.sum(w))
 
-        gram_plan = _gram_plan_attrs(Xi, w, offset)
+            gram_plan = _gram_plan_attrs(Xi, w, offset)
         if p.lambda_search:
             with telemetry.span("train.glm.gram", **gram_plan):
                 G0, b_, _, _ = step(Xi, y, w, jnp.asarray(beta, jnp.float32),

@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..utils import telemetry
+
 NBINS = 1024
 
 
@@ -29,6 +31,7 @@ NBINS = 1024
 # device kernels
 # ---------------------------------------------------------------------------
 @jax.jit
+@telemetry.program("metrics_regression")
 def _regression_kernel(y, pred, w):
     n = jnp.sum(w)
     err = pred - y
@@ -44,6 +47,7 @@ def _regression_kernel(y, pred, w):
 
 
 @jax.jit
+@telemetry.program("metrics_binomial")
 def _binomial_hist_kernel(y, p, w):
     """Per-bin {TP,FP} histogram over NBINS probability thresholds + logloss."""
     pc = jnp.clip(p, 1e-15, 1 - 1e-15)
@@ -60,6 +64,7 @@ def _binomial_hist_kernel(y, p, w):
 
 
 @jax.jit
+@telemetry.program("metrics_multinomial")
 def _multinomial_kernel(y, probs, w):
     """logloss + confusion matrix + hit-ratio table for K classes."""
     k = probs.shape[1]
@@ -333,6 +338,7 @@ def _gains_lift(pos, neg, npos, n, groups: int = 16):
 # approximation.
 # ---------------------------------------------------------------------------
 @functools.partial(jax.jit, static_argnames=("K",))
+@telemetry.program("metrics_mauc")
 def _mauc_kernel(y, probs, w, K):
     yi = y.astype(jnp.int32)
     W = jax.nn.one_hot(yi, K, dtype=jnp.float32) * w[:, None]    # (n, K)
@@ -497,6 +503,7 @@ def _weights(y, weights):
 
 
 @functools.partial(jax.jit, static_argnames=("kernel", "has_w"))
+@telemetry.program("metrics_fused")
 def _fused_metric_kernel(y, pred, weights, kernel, has_w):
     """NaN masking + weight prep + the metric kernel in ONE program —
     eagerly the prelude cost 4-5 tiny XLA programs per metrics family,

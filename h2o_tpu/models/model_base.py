@@ -34,6 +34,7 @@ import numpy as np
 
 from ..backend.jobs import Job
 from ..backend.kvstore import Keyed, STORE
+from ..backend.memory import hbm_span_attrs
 from ..frame.frame import Frame
 from ..frame.vec import T_CAT, Vec
 from .metrics import (make_binomial_metrics, make_multinomial_metrics,
@@ -493,6 +494,7 @@ class ModelBuilder:
 
                 jax.block_until_ready(device_arrays(model))
                 model.output.run_time_ms = int((time.time() - t0) * 1000)
+                self._train_span.attrs.update(hbm_span_attrs())
             telemetry.inc("train.count")
             # drained above, so this histogram is honest compute wall
             telemetry.observe("train.seconds",

@@ -150,6 +150,7 @@ def _sketch_hist(X, lo, hi, nb: int, rb: int):
     return h.reshape(F, d_hi * d_lo)[:, :nb]
 
 
+@telemetry.program("gbm_setup_sketch")
 @telemetry.scope("gbm.sketch")
 def _sketch_core(X, qs, nb: int = 1024, rb: int = _SKETCH_ROW_BLOCK,
                  axis=None):
@@ -316,11 +317,13 @@ def hist_quantile_sketch_cols(cols, qs, nb: int = 1024,
 
 
 @jax.jit
+@telemetry.program("gbm_setup_minmax")
 def _col_minmax(X):
     return jnp.nanmin(X, axis=0), jnp.nanmax(X, axis=0)
 
 
 @functools.partial(jax.jit, static_argnames=("cap",))
+@telemetry.program("gbm_setup_distinct")
 def _distinct_values(X, cap: int):
     """Per-column distinct values, on device: (cap, F) ascending and
     NaN-padded, plus the true (F,) distinct counts (which may exceed cap —
@@ -531,6 +534,7 @@ def compute_bin_edges_cols(cols, is_cat: np.ndarray, nbins: int,
 
 
 @jax.jit
+@telemetry.program("gbm_setup_bin")
 @telemetry.scope("gbm.bin")
 def bin_matrix(X: jax.Array, edges: jax.Array) -> jax.Array:
     """Map raw values to bin indices: bin = #edges < x; NA -> nbins (NA bucket).
@@ -551,6 +555,7 @@ def bin_matrix(X: jax.Array, edges: jax.Array) -> jax.Array:
 
 
 @functools.partial(jax.jit, static_argnames=("dtype",))
+@telemetry.program("gbm_setup_bin")
 @telemetry.scope("gbm.bin")
 def bin_column(x: jax.Array, erow: jax.Array, dtype=jnp.int32) -> jax.Array:
     """One column of `bin_matrix`: (plen,) raw values + that feature's

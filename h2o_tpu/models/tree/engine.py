@@ -1038,6 +1038,7 @@ def make_train_fn(cfg: TreeConfig, grad_fn: Callable, mesh=None,
 
     fused = cfg.fused_score and score_fn is not None
 
+    @telemetry.program("gbm_level")
     def spmd(Xb, y, w, f, edges, edge_ok, keys, rates, mono, imat, iscat,
              nedges, *ntd):
         mono_arg = mono if cfg.use_monotone else None

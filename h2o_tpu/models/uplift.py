@@ -36,6 +36,7 @@ from ..backend.jobs import Job
 from ..frame.frame import Frame
 from ..frame.vec import Vec
 from ..parallel.mesh import ROWS, default_mesh, put_replicated, shard_map
+from ..utils import telemetry
 from .drf import DRFParameters
 from .metrics import ModelMetrics
 from .model_base import Model, ModelBuilder, ModelOutput
@@ -178,6 +179,7 @@ def make_uplift_train_fn(cfg: TreeConfig, metric: str, mesh=None):
     mesh = mesh or default_mesh()
     div = _divergence(metric)
 
+    @telemetry.program("uplift_level")
     def spmd(Xb, y, treat, w, edges, edge_ok, keys):
         def tree_step(_, key):
             rowkey = jax.random.fold_in(key, jax.lax.axis_index(ROWS))

@@ -96,6 +96,7 @@ def _build_driver_program(map_fn, mesh: Mesh, nrow: int, reduce_key, avt,
         else dict(reduce_key)
     shard_rows = avt[0][0][0] // mesh.shape[ROWS]
 
+    @telemetry.program("mrtask_driver")
     def spmd(*cols):
         rows = _row_info(shard_rows, nrow)
         out = map_fn(cols, rows)

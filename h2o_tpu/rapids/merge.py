@@ -25,6 +25,7 @@ from ..frame.frame import Frame
 from ..frame.vec import T_STR, Vec
 from ..parallel import mesh as meshmod
 from ..parallel.mesh import ROWS, shard_map
+from ..utils import telemetry
 
 
 def sort(fr: Frame, by: list[str] | None = None, ascending: list[bool] | None = None) -> Frame:
@@ -259,6 +260,7 @@ def _sharded_expand_program(mesh, total: int, plen: int, n_l: int, n_r: int):
     shards = mesh.shape[ROWS]
     L = plen // shards
 
+    @telemetry.program("merge_expand")
     def spmd(l_cols, r_cols_s, lo, counts, cum):
         off = jax.lax.axis_index(ROWS).astype(jnp.int32) * L
         rowid = off + jnp.arange(L, dtype=jnp.int32)

@@ -185,6 +185,7 @@ class CompiledScorer(_BucketedScorer):
                     self._program_name, self._compiled[b], "serving",
                     sig=(((b, self.n_features), "float32"),),
                     wall_metric="serving.request.seconds",
+                    module=programs.module_of(self._jit),
                     model=self._model_key, bucket=b)
         self.warmup_compiles = compilemeter.count() - before
         return self.warmup_compiles

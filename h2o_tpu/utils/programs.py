@@ -20,14 +20,19 @@ points registers here under a STABLE program id:
 
 Each record pairs the STATIC cost (``cost_analysis()``: flops, bytes
 accessed; ``memory_analysis()``: argument/output/temp/generated-code
-bytes) with MEASURED dispatch walls (a bounded per-program ring fed by
-:class:`Tracked` dispatches or explicit :func:`note_wall` calls) to derive
-achieved-FLOPs and a roofline fraction per program. Caveat the README
-spells out: dispatch walls are HOST walls — async dispatch means they are
-an upper bound on queue-insert cost, not device compute, unless the caller
-drains (the tree chunk loop and bench legs do); and on the CPU mesh there
-is no meaningful peak-FLOPs figure, so ``roofline_fraction`` is ``null``
-off-TPU by design.
+bytes) with two measured sides, each named as what it is:
+
+- ``wall``: HOST dispatch walls (a bounded per-program ring fed by
+  :class:`Tracked` dispatches or explicit :func:`note_wall` calls).
+  Dispatch is asynchronous, so these are ENQUEUE times, not device compute
+  (unless the caller drains), and nothing is divided by them;
+- ``device``: seconds and executions of the record's XLA MODULE on the
+  device's own clock, folded in from a profiler capture by
+  :func:`fold_capture` (``POST /3/Profiler/capture`` folds its own); null
+  until a capture held the module. A record's ``module`` is the name jit
+  gave its program (``jit_<telemetry.PROGRAMS name>``), the same name the
+  ``XLA Modules`` line of a device trace and the ``compile`` timeline
+  events carry: records that share a module share its reading.
 
 :func:`tracked` wraps a jitted callable: the first dispatch per argument
 signature AOT-lowers and compiles (the SAME single compile the jit
@@ -46,7 +51,10 @@ telemetry provider hook), embedded per-leg in the bench sidecar
 
 from __future__ import annotations
 
+import glob
 import hashlib
+import os
+import re
 import threading
 import time
 import weakref
@@ -70,14 +78,17 @@ _TRACKED: "weakref.WeakSet" = weakref.WeakSet()
 
 
 class ProgramRecord:
-    __slots__ = ("pid", "kind", "name", "labels", "flops", "bytes_accessed",
-                 "memory", "registered_ms", "dispatch_count", "walls")
+    __slots__ = ("pid", "kind", "name", "module", "labels", "flops",
+                 "bytes_accessed", "memory", "registered_ms",
+                 "dispatch_count", "walls", "device")
 
-    def __init__(self, pid, kind, name, labels, flops, bytes_accessed,
-                 memory):
+    def __init__(self, pid, kind, name, module, labels, flops,
+                 bytes_accessed, memory):
         self.pid = pid
         self.kind = kind            # "train" | "dispatch" | "serving"
         self.name = name
+        self.module = module        # the XLA module's name, jit_<function>
+        self.device = None          # fold_capture's reading of that module
         self.labels = labels
         self.flops = flops
         self.bytes_accessed = bytes_accessed
@@ -140,12 +151,22 @@ def _stable_pid(kind: str, name: str, sig, labels: dict) -> str:
     return f"{base}#{h}"
 
 
+def module_of(jitted) -> str | None:
+    """The XLA module name jit gives ``jitted``'s program: ``jit_`` and the
+    function's ``__name__`` (a `telemetry.program` name on the hot path)."""
+    fn = getattr(jitted, "__name__", None)
+    return f"jit_{fn}" if fn else None
+
+
 def register_compiled(name: str, compiled, kind: str, sig=None,
-                      wall_metric: str | None = None, **labels) -> str:
+                      wall_metric: str | None = None,
+                      module: str | None = None, **labels) -> str:
     """Register one compiled executable's analyses; idempotent per id
     (re-registration refreshes the static figures, keeps the wall ring).
     ``wall_metric`` names the DECLARED telemetry histogram whose walls
-    already time this program's dispatches (the /3/Programs join)."""
+    already time this program's dispatches (the /3/Programs join);
+    ``module`` is the program's XLA module name (:func:`module_of` of the
+    jitted function it was lowered from)."""
     flops, nbytes = _cost_of(compiled)
     memory = _memory_of(compiled)
     if wall_metric is not None:
@@ -154,11 +175,12 @@ def register_compiled(name: str, compiled, kind: str, sig=None,
     with _LOCK:
         rec = _REGISTRY.get(pid)
         if rec is None:
-            rec = ProgramRecord(pid, kind, name, dict(labels), flops,
-                                nbytes, memory)
+            rec = ProgramRecord(pid, kind, name, module, dict(labels),
+                                flops, nbytes, memory)
             _REGISTRY[pid] = rec
         else:
             rec.flops, rec.bytes_accessed, rec.memory = flops, nbytes, memory
+            rec.module = module or rec.module
     telemetry.inc("programs.registered.count")
     return pid
 
@@ -241,7 +263,8 @@ class Tracked:
             sig = tuple((s[0], s[1]) for s in key
                         if isinstance(s, tuple) and len(s) == 3)
             self._pids[key] = register_compiled(
-                self.name, ent, self.kind, sig=sig, **self.labels)
+                self.name, ent, self.kind, sig=sig,
+                module=module_of(self._jitted), **self.labels)
             self._compiled[key] = ent
         if ent is False:
             return self._jitted(*args)
@@ -285,59 +308,124 @@ def clear_compiled() -> None:
         t.clear()
 
 
-def device_peak_flops() -> float | None:
-    """Per-chip peak dense-matmul FLOP/s for the roofline denominator —
-    bf16/MXU peaks from the published TPU specs (the units real-TPU
-    campaign numbers are quoted in). None off-TPU: a CPU mesh has no
-    honest single figure, so /3/Programs reports roofline as null there
-    (README caveats this explicitly)."""
-    try:
-        import jax
-
-        dev = jax.devices()[0]
-        if getattr(dev, "platform", "") != "tpu":
-            return None
-        kind = (getattr(dev, "device_kind", "") or "").lower()
-    except Exception:  # pragma: no cover — no backend yet
-        return None
-    table = {"v4": 275e12, "v5 lite": 197e12, "v5litepod": 197e12,
-             "v5e": 197e12, "v5p": 459e12, "v6 lite": 918e12,
-             "v6e": 918e12}
-    for k, v in table.items():
-        if k in kind:
-            return v
-    return None
-
-
 def snapshot() -> dict:
     """{pid: record} — the /3/Programs payload. Static cost + memory
-    figures, measured wall quantiles (telemetry's nearest-rank formula —
-    ONE quantile definition across /3/Metrics and /3/Programs), achieved
-    FLOP/s (flops / p50 wall) and the roofline fraction against
-    :func:`device_peak_flops`."""
-    peak = device_peak_flops()
+    figures, the XLA module's name, HOST dispatch-wall quantiles
+    (telemetry's nearest-rank formula — ONE quantile definition across
+    /3/Metrics and /3/Programs; enqueue times, see the module doc) and the
+    module's device seconds from the last capture folded in (None before
+    any)."""
     out: dict[str, dict] = {}
     with _LOCK:
         recs = list(_REGISTRY.values())
     for rec in recs:
         walls = list(rec.walls)
         pcts = telemetry._percentiles(walls)
-        p50 = pcts["p50"]
-        achieved = (rec.flops / p50) if (p50 and rec.flops) else None
         entry = {
-            "kind": rec.kind, "name": rec.name, "labels": rec.labels,
+            "kind": rec.kind, "name": rec.name, "module": rec.module,
+            "labels": rec.labels,
             "flops": rec.flops, "bytes_accessed": rec.bytes_accessed,
             "memory": rec.memory, "registered_ms": rec.registered_ms,
             "dispatch_count": rec.dispatch_count,
             "wall": {"count": len(walls),
-                     "p50_s": p50, "p95_s": pcts["p95"],
+                     "p50_s": pcts["p50"], "p95_s": pcts["p95"],
                      "total_s": round(sum(walls), 6) if walls else 0.0},
-            "achieved_flops_per_s": achieved,
-            "roofline_fraction": ((achieved / peak)
-                                  if (achieved and peak) else None),
+            "device": rec.device,
         }
         out[rec.pid] = entry
     return out
+
+
+# ---------------------------------------------------------------------------
+# the device's clock: a profiler capture folded into the registry
+# ---------------------------------------------------------------------------
+_DEVICE_PLANE = "/device:"
+_MODULES_LINE = "XLA Modules"
+_RUN_ID = re.compile(r"\(\d+\)$")
+
+#: the XLA module names of `telemetry.PROGRAMS`
+DECLARED_MODULES = frozenset(f"jit_{p}" for p in telemetry.PROGRAMS)
+
+#: the last capture folded in (fold_capture's return), for /3/Programs
+_LAST_FOLD: list = [None]
+
+
+def capture_modules(path: str) -> dict:
+    """{device plane: {module: [seconds, executions]}} of a capture (its
+    directory, or the ``.xplane.pb`` itself): the ``XLA Modules`` line of
+    every device plane that has one (a TPU chip's trace holds a second
+    ``/device:`` plane without: it is no chip), one event a program
+    execution, the run id jit appends to a module's name
+    (``jit_gbm_level(123)``) cut off. A program in flight when the capture
+    was told to stop has left its event in the captures read so far (the
+    profiler stops some tens of ms later): seen, not promised."""
+    from jax.profiler import ProfileData
+
+    if not path.endswith(".pb"):
+        hits = glob.glob(os.path.join(path, "**", "*.xplane.pb"),
+                         recursive=True)
+        if not hits:
+            raise FileNotFoundError(f"no .xplane.pb under {path}")
+        path = max(hits, key=os.path.getmtime)
+    planes: dict = {}
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith(_DEVICE_PLANE):
+            continue
+        for line in plane.lines:
+            if line.name != _MODULES_LINE:
+                continue
+            mods = planes.setdefault(plane.name, {})
+            for ev in line.events:
+                m = mods.setdefault(_RUN_ID.sub("", ev.name), [0.0, 0])
+                m[0] += ev.duration_ns / 1e9
+                m[1] += 1
+    return planes
+
+
+def fold_capture(path: str) -> dict:
+    """Fold a capture's device seconds by program into the registry. For
+    each XLA module of the capture: seconds and executions, averaged over
+    the device planes (a program under ``shard_map`` runs once on every
+    chip). A module that is a registry record's gets that reading as the
+    record's ``device`` block (records that share a module share it); one
+    named by `telemetry.PROGRAMS` without a record (the set-up's programs)
+    is listed under ``programs`` all the same; every other one (the eager
+    primitives, jit's own helpers) under ``undeclared``. Returns
+    {capture, planes, programs: {module: {seconds, executions, records}},
+    undeclared: {module: {seconds, executions}}}; :func:`last_fold` keeps
+    it for /3/Programs."""
+    planes = capture_modules(path)
+    n = max(len(planes), 1)
+    total: dict = {}
+    for mods in planes.values():
+        for name, (secs, runs) in mods.items():
+            t = total.setdefault(name, [0.0, 0])
+            t[0] += secs
+            t[1] += runs
+    out = {"capture": path, "planes": len(planes), "programs": {},
+           "undeclared": {}}
+    with _LOCK:
+        by_module: dict = {}
+        for rec in _REGISTRY.values():
+            by_module.setdefault(rec.module, []).append(rec)
+        for name, (secs, runs) in sorted(total.items(),
+                                         key=lambda kv: -kv[1][0]):
+            read = {"seconds": secs / n, "executions": runs / n}
+            recs = by_module.get(name, [])
+            for rec in recs:
+                rec.device = dict(read, capture=path)
+            if recs or name in DECLARED_MODULES:
+                out["programs"][name] = dict(
+                    read, records=sorted(r.pid for r in recs))
+            else:
+                out["undeclared"][name] = read
+        _LAST_FOLD[0] = out
+    return out
+
+
+def last_fold() -> dict | None:
+    """What the last :func:`fold_capture` returned (None before any)."""
+    return _LAST_FOLD[0]
 
 
 def ids() -> set:
@@ -398,3 +486,4 @@ def reset() -> None:
     clear_compiled()
     with _LOCK:
         _REGISTRY.clear()
+        _LAST_FOLD[0] = None

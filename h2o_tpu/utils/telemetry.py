@@ -403,6 +403,58 @@ def scope(name: str):
     return jax.named_scope(name)
 
 
+#: device programs — the jitted functions a training job dispatches, under
+#: the ONE name each has everywhere: :func:`program` gives the function it
+#: decorates that ``__name__``, so ``jax.jit`` names its XLA module
+#: ``jit_<name>`` (the ``XLA Modules`` line of a device trace, the
+#: ``compile`` timeline events), and `utils/programs.py` keeps that module
+#: name beside the registry id. A name carries its layer as a prefix, so
+#: one ``contains`` sums a layer (``jit_gbm_setup_``). Declared once here,
+#: like SCOPES: :func:`program` raises on any other name.
+PROGRAMS: tuple[str, ...] = (
+    "gbm_level",            # engine.make_train_fn: a chunk of trees
+    "gbm_setup_sketch",     # binning._sketch_core, jit and shard_map forms
+    "gbm_setup_minmax",     # binning._col_minmax: the columns' extrema
+    "gbm_setup_distinct",   # binning._distinct_values: exact small-data bins
+    "gbm_setup_bin",        # binning.bin_column / bin_matrix: values to codes
+    "gbm_setup_stack",      # chunks._stack_codes; gbm._codes_to_f32 back
+    "gbm_setup_prep",       # gbm._jit_prep: response, mask and weights
+    "gbm_setup_init",       # gbm._jit_init_f / _jit_full_like: start margin
+    "gbm_setup_keys",       # gbm._jit_keys: the per-tree PRNG keys
+    "gbm_score_raw",        # gbm._metrics_raw: margin to score0, unfused
+    "metrics_fused",        # metrics._fused_metric_kernel: mask, weights, k.
+    "metrics_regression",   # metrics._regression_kernel
+    "metrics_binomial",     # metrics._binomial_hist_kernel: AUC histograms
+    "metrics_multinomial",  # metrics._multinomial_kernel
+    "metrics_mauc",         # metrics._mauc_kernel: the multinomial AUC family
+    "glm_probe",            # glm._make_dev_kernel: the deviance probe
+    "glm_irls_sharded",     # glm._make_irls_kernel: the step under shard_map
+    # the one name without its layer's prefix: the benchmark's
+    # irls_program_s reads the XLA module jit__core by name
+    "_core",                # glm._make_irls_kernel: the IRLS step, one shard
+    "mrtask_driver",        # parallel/mrtask.py: a DrJAX-style driver program
+    "merge_expand",         # rapids/merge.py: the sharded merge's expansion
+    "uplift_level",         # models/uplift.py: a chunk of uplift trees
+)
+
+
+def program(name: str):
+    """Decorator under ``jax.jit``: the function takes the DECLARED program
+    name as its ``__name__`` (KeyError otherwise), which is what jit names
+    the XLA module after. Nothing else changes: same arguments, same body,
+    the lowered text differs in the module's name only."""
+    if name not in PROGRAMS:
+        raise KeyError(
+            f"undeclared device program {name!r} — declare it in PROGRAMS "
+            f"(h2o_tpu/utils/telemetry.py)")
+
+    def rename(fn):
+        fn.__name__ = name
+        return fn
+
+    return rename
+
+
 def _enabled() -> bool:
     return knobs.get_bool("H2O_TPU_METRICS_ENABLED")
 
@@ -1107,4 +1159,15 @@ def capture(ms: int, out_dir: str | None = None) -> str:
                 "jax.profiler failed to start a session on this backend "
                 "— see the server log for the start_trace error")
         time.sleep(ms / 1000.0)
+    # the capture's device seconds by program go into the registry before
+    # the path goes back (GET /3/Programs then shows them). Here and not in
+    # device_profile: a caller that scopes its own capture pays for no read
+    # of the dump it did not ask for
+    from . import log, programs
+
+    try:
+        programs.fold_capture(path)
+    except Exception as e:  # an unreadable dump: the capture still returns
+        log.warn(f"profiler capture {path}: not folded into the program "
+                 f"registry ({e!r:.200})")
     return path

@@ -111,8 +111,9 @@ class TestProgramRegistry:
         assert rec["dispatch_count"] == 3
         assert rec["wall"]["count"] == 3
         assert rec["wall"]["p50_s"] >= 0
-        assert rec["achieved_flops_per_s"] is None or \
-            rec["achieved_flops_per_s"] > 0
+        # host enqueue walls divide nothing (ISSUE 36): the device's side
+        # is the module's seconds from a capture, null before any
+        assert rec["module"] == "jit_<lambda>" and rec["device"] is None
 
     def test_tracked_steps_aside_under_enclosing_trace(self):
         import jax

@@ -36,6 +36,132 @@ def _train_gbm(fr, ntrees=6, interval=2):
 
 
 # ---------------------------------------------------------------------------
+# the program registry on the device's clock (ISSUE 36)
+# ---------------------------------------------------------------------------
+def _capture(tmp_path, planes: dict) -> str:
+    """A hand-built capture: {plane name: [(module event name, seconds)]} on
+    each plane's ``XLA Modules`` line, beside an ``XLA Ops`` line that no
+    module reading may count, and one more ``/device:`` plane with neither
+    (a one-chip TPU trace holds such a plane: it is no chip to average
+    over)."""
+    from jax.profiler import ProfileData
+
+    text = ""
+    for i, (plane, events) in enumerate(planes.items()):
+        names = sorted({n for n, _ in events})
+        text += f'planes {{ id: {i} name: "{plane}" lines {{ id: 1 name: ' \
+                f'"XLA Modules" '
+        at = 0
+        for n, secs in events:
+            ps = int(secs * 1e12)
+            text += (f"events {{ metadata_id: {names.index(n) + 1} "
+                     f"offset_ps: {at} duration_ps: {ps} }} ")
+            at += ps
+        text += ('} lines { id: 2 name: "XLA Ops" events { metadata_id: 1 '
+                 'offset_ps: 0 duration_ps: 5000000000000 } } ')
+        for j, n in enumerate(names):
+            text += (f'event_metadata {{ key: {j + 1} value {{ id: {j + 1} '
+                     f'name: "{n}" }} }} ')
+        text += "} "
+    text += ('planes { id: 99 name: "/device:TPU:0 other" lines { id: 1 '
+             'name: "Steps" events { metadata_id: 1 offset_ps: 0 '
+             'duration_ps: 1000 } } event_metadata { key: 1 value { id: 1 '
+             'name: "step" } } } ')
+    d = tmp_path / "capture" / "plugins" / "profile" / "run"
+    d.mkdir(parents=True)
+    (d / "host.xplane.pb").write_bytes(
+        ProfileData.text_proto_to_serialized_xspace(text))
+    return str(tmp_path / "capture")
+
+
+class TestFoldCapture:
+    def test_fold_gives_mean_seconds_executions_and_undeclared(self, tmp_path):
+        import jax
+        import jax.numpy as jnp
+
+        from h2o_tpu.utils import programs
+
+        programs.reset()
+
+        @telemetry.program("gbm_level")
+        def step(x):
+            return x * 2
+
+        programs.tracked("test.fold", jax.jit(step), "train")(jnp.ones(8))
+        ((pid, rec),) = programs.snapshot().items()
+        assert rec["module"] == "jit_gbm_level" and rec["device"] is None
+        path = _capture(tmp_path, {
+            "/device:TPU:0": [("jit_gbm_level(11)", 0.5),
+                              ("jit_gbm_level(12)", 0.7),
+                              ("jit_add(3)", 0.002),
+                              ("jit_gbm_setup_sketch(4)", 0.04)],
+            "/device:TPU:1": [("jit_gbm_level(11)", 0.6),
+                              ("jit_gbm_level(12)", 0.8),
+                              ("jit_gbm_setup_sketch(4)", 0.04)],
+            "/host:CPU": [("jit_gbm_level(11)", 9.0)]})
+        out = programs.fold_capture(path)
+        assert out["planes"] == 2 and out["capture"] == path
+        level = out["programs"]["jit_gbm_level"]
+        assert level["seconds"] == pytest.approx(1.3)       # (1.2 + 1.4) / 2
+        assert level["executions"] == 2 and level["records"] == [pid]
+        # declared in PROGRAMS, no registry record: listed, not undeclared
+        sketch = out["programs"]["jit_gbm_setup_sketch"]
+        assert sketch["seconds"] == pytest.approx(0.04)
+        assert sketch["records"] == []
+        # an eager primitive, on one plane of two
+        assert out["undeclared"] == {"jit_add": {
+            "seconds": pytest.approx(0.001), "executions": 0.5}}
+        dev = programs.snapshot()[pid]["device"]
+        assert dev == {"seconds": pytest.approx(1.3), "executions": 2,
+                       "capture": path}
+        assert programs.last_fold() is out
+        programs.reset()
+        assert programs.last_fold() is None
+
+    def test_capture_folds_its_own_and_device_profile_does_not(
+            self, tmp_path, monkeypatch):
+        """`telemetry.capture` (POST /3/Profiler/capture) folds the capture
+        it made before it returns; `device_profile`, which the benchmark's
+        slice goes through, reads nothing back."""
+        from h2o_tpu.utils import programs
+
+        folded = []
+        monkeypatch.setattr(programs, "fold_capture",
+                            lambda p: folded.append(p) or {})
+        with telemetry.device_profile("t36", out_dir=str(tmp_path)) as path:
+            pass
+        assert path and folded == []
+        got = telemetry.capture(20, out_dir=str(tmp_path))
+        assert folded == [got]
+
+
+@pytest.mark.parametrize("name", [
+    "level_program_device_s", "gbm_setup_device_s", "sketch_device_s",
+    "bin_device_s", "metrics_device_s.gbm", "metrics_device_s.glm",
+    "glm_probe_device_s", "gbm_prep_s", "glm_start_s"])
+def test_program_metrics_of_the_benchmark_name_a_reader(name):
+    """The benchmark's entries for ISSUE 36 are data: the manifest holds its
+    rules and each new file names a reader `benchmark/readers.py` has; a
+    module reader's ``contains`` is ``jit_`` and a declared prefix."""
+    from benchmark import manifest, readers
+
+    assert manifest.check() == []
+    man = manifest.load()
+    (m,) = [m for m in man["per_layer"] if m["name"] == name]
+    with open(manifest.layer_metric_file(man, name)) as f:
+        spec = json.load(f)
+    assert spec["reader"] in readers.READERS
+    if spec["reader"] == "trace_module_s":
+        assert m["source"] == "device_trace"
+        prefix = spec["args"]["contains"]
+        assert prefix.startswith("jit_") and any(
+            p.startswith(prefix[len("jit_"):]) for p in telemetry.PROGRAMS)
+    else:
+        assert (spec["reader"], m["source"]) == ("span_sum_per",
+                                                 "program_span")
+
+
+# ---------------------------------------------------------------------------
 # registry contracts
 # ---------------------------------------------------------------------------
 class TestRegistry:
@@ -435,6 +561,22 @@ def cloud(worker_port):
 
 
 class TestHTTPSurface:
+    def test_programs_carry_module_and_device_not_rooflines(self, cloud):
+        """`GET /3/Programs` (ISSUE 36): each record names its XLA module,
+        the device block is null before any capture, and nothing divides by
+        a host enqueue wall any more."""
+        import h2o_tpu.api as h2o
+
+        payload = h2o.connection().request("GET", "/3/Programs")
+        steps = [r for r in payload["programs"].values()
+                 if r["name"] == "train.tree.step"]
+        assert steps and {r["module"] for r in steps} == {"jit_gbm_level"}
+        for rec in payload["programs"].values():
+            assert rec["module"].startswith("jit_")
+            assert "device" in rec and "wall" in rec
+            assert not {"roofline_fraction", "achieved_flops_per_s"} & set(rec)
+        assert "peak_flops_per_s" not in payload and "capture" in payload
+
     def test_metrics_json_over_http(self, cloud):
         import h2o_tpu.api as h2o
 

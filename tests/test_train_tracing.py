@@ -11,11 +11,18 @@
   IRLS step's load is a ``train.program.load`` span.
 - ``compile`` timeline events carry the compiled function's name and
   whether the program came from the persistent cache.
+- Device programs (ISSUE 36): every name declared in ``telemetry.PROGRAMS``
+  is on a function the package jits, an undeclared one raises, a job
+  compiles under declared names or bare primitives only, and no jitted
+  function of the package is still called ``spmd``.
 - A train through the client is one trace from ``client.train`` down.
 
 CPU mesh, small frames: names, parents and counts, never a time.
 """
 
+import ast
+import functools
+import pathlib
 import re
 import socket
 
@@ -213,11 +220,13 @@ def test_undeclared_scope_raises():
 # ---------------------------------------------------------------------------
 #: algo -> {span: the span its parent has to be}
 _TREE = {
-    "gbm": {"train.gbm.sketch": "train.gbm",
+    "gbm": {"train.gbm.prep": "train.gbm", "train.gbm.finish": "train.gbm",
+            "train.gbm.sketch": "train.gbm",
             "train.gbm.binned_view": "train.gbm",
             "train.gbm.chunk": "train.gbm",
             "train.gbm.score": "train.gbm.chunk"},
-    "glm": {"train.glm.design": "train.glm", "train.glm.gram": "train.glm",
+    "glm": {"train.glm.design": "train.glm", "train.glm.start": "train.glm",
+            "train.glm.finish": "train.glm", "train.glm.gram": "train.glm",
             "train.glm.solve": "train.glm", "train.glm.probe": "train.glm",
             "train.glm.metrics": "train.glm",
             "train.program.load": "train.glm.gram"},
@@ -227,6 +236,7 @@ _TREE = {
 # keeps its train.glm.gram under the job)
 _TREE["glm_search"] = {
     "train.glm.design": "train.glm", "train.glm.metrics": "train.glm",
+    "train.glm.start": "train.glm", "train.glm.finish": "train.glm",
     "train.glm.path": "train.glm",
     "train.glm.gram": ("train.glm", "train.glm.path"),
     "train.glm.solve": "train.glm.path", "train.glm.probe": "train.glm.path",
@@ -262,6 +272,42 @@ def test_train_records_the_span_tree(algo):
             kids[e["parent"]] = kids.get(e["parent"], 0) + e["dur_us"]
     for sid, total in kids.items():
         assert total <= by_id[sid]["dur_us"], by_id[sid]["what"]
+    # the bare stretches of the root have names (ISSUE 36): the set-up's
+    # opens four times a tree job (three in `_setup_build`, one before the
+    # step's load), the others once
+    counts = {n: len(_spans(events, n)) for n in (
+        "train.gbm.prep", "train.gbm.finish", "train.glm.start",
+        "train.glm.finish")}
+    assert counts == ({"train.gbm.prep": 4, "train.gbm.finish": 1,
+                       "train.glm.start": 0, "train.glm.finish": 0}
+                      if algo in ("gbm", "xgboost") else
+                      {"train.gbm.prep": 0, "train.gbm.finish": 0,
+                       "train.glm.start": 1, "train.glm.finish": 1})
+
+
+@pytest.mark.parametrize("algo", ["gbm", "glm"])
+def test_peak_setting_spans_carry_the_hbm_reading(monkeypatch, algo):
+    """The spans at whose close the HBM peak may stand (sketch, coded view,
+    the GLM's two designs, the job's root) carry the fullest device's
+    ``memory_stats()`` at their close; the CPU mesh reports none, so the
+    reading is planted."""
+    from h2o_tpu.backend import memory
+
+    real = memory.hbm_stats
+    monkeypatch.setattr(memory, "hbm_stats", lambda: dict(
+        real() or {}, bytes_in_use=3_000_000_000,
+        peak_bytes_in_use=5_000_000_000, bytes_limit=16_000_000_000))
+    fr = _frame()
+    events = _events_of(lambda: _TRAIN[algo](fr))
+    names = {"gbm": ("train.gbm", "train.gbm.sketch", "train.gbm.binned_view"),
+             "glm": ("train.glm", "train.glm.design")}[algo]
+    for name in names:
+        got = _spans(events, name)
+        assert len(got) == (2 if name == "train.glm.design" else 1)
+        for e in got:
+            assert (e["hbm_in_use_gb"], e["hbm_peak_gb"]) == (3.0, 5.0), name
+    assert all("hbm_peak_gb" not in e for e in events
+               if e["kind"] == "span" and e["what"] not in names)
 
 
 @pytest.mark.parametrize("search", [True, False])
@@ -280,8 +326,9 @@ def test_a_lambda_search_records_one_path_span_and_counts_it(search):
              if e["kind"] == "span" and e["what"].startswith("train.")]
     paths = _spans(events, "train.glm.path")
     parts = [e for e in spans if e["what"] in (
-        "train.glm", "train.glm.design", "train.glm.gram", "train.glm.solve",
-        "train.glm.probe", "train.glm.metrics", "train.program.load")]
+        "train.glm", "train.glm.design", "train.glm.start", "train.glm.gram",
+        "train.glm.solve", "train.glm.probe", "train.glm.finish",
+        "train.glm.metrics", "train.program.load")]
     assert len(spans) == len(parts) + len(paths)
     if not search:
         assert paths == [] and grew == {"lambdas": 0, "iterations": 0}
@@ -398,7 +445,7 @@ def test_glm_job_records_its_program_load_and_named_compiles():
     assert compiles
     for e in compiles:
         assert e["what"] != "backend_compile" and e["cached"] is False
-    assert any("dev_eval" in e["what"] for e in compiles)
+    assert any(e["what"] == "jit(glm_probe)" for e in compiles)
 
 
 def test_compile_event_says_when_it_was_a_cache_replay():
@@ -424,6 +471,128 @@ def test_compile_event_says_when_it_was_a_cache_replay():
     assert got == [("jit(replayed)", True), ("jit(built)", False),
                    ("backend_compile", False), ("jit(stale)", True),
                    ("jit(next)", False)]
+
+
+# ---------------------------------------------------------------------------
+# (c2) device programs: one declared name from the jit site to the trace
+# ---------------------------------------------------------------------------
+_PKG = pathlib.Path(__file__).resolve().parent.parent / "h2o_tpu"
+
+
+def _dotted(node) -> str:
+    return ast.unparse(node) if isinstance(node, (ast.Name, ast.Attribute)) \
+        else ""
+
+
+def _is_jit(node) -> bool:
+    """``jax.jit`` itself, a call of it, or ``functools.partial(jax.jit, ..)``."""
+    if isinstance(node, ast.Call):
+        return _is_jit(node.func) or (
+            _dotted(node.func).endswith("partial") and bool(node.args)
+            and _is_jit(node.args[0]))
+    return _dotted(node) in ("jax.jit", "jit")
+
+
+@functools.lru_cache(maxsize=1)
+def _jit_sites():
+    """By an AST walk of the package (no import of jax): ``declared``, every
+    literal handed to ``telemetry.program`` with the function it decorates;
+    ``jitted``, the names of the functions the package jits: decorated with
+    jit, named inside a ``jax.jit(...)`` or ``shard_map(...)`` call, or
+    returned by a factory whose call is jitted (``jax.jit(factory(..))``)."""
+    declared, jitted = [], set()
+    for path in sorted(_PKG.rglob("*.py")):
+        tree = ast.parse(path.read_text())
+        defs = [n for n in ast.walk(tree) if isinstance(n, ast.FunctionDef)]
+        for fn in defs:
+            for dec in fn.decorator_list:
+                if _is_jit(dec):
+                    jitted.add((path.name, fn.name))
+                if (isinstance(dec, ast.Call)
+                        and _dotted(dec.func) == "telemetry.program"):
+                    (lit,) = dec.args
+                    declared.append((path.name, fn.name, lit.value))
+        inside = set()
+        for call in (n for n in ast.walk(tree) if isinstance(n, ast.Call)):
+            if _is_jit(call.func) or _dotted(call.func) == "shard_map":
+                inside |= {n.id for a in call.args for n in ast.walk(a)
+                           if isinstance(n, ast.Name)}
+        for fn in defs:
+            if fn.name in inside:
+                jitted.add((path.name, fn.name))
+                # a jitted factory's product: the function it returns
+                jitted |= {(path.name, r.value.id) for r in ast.walk(fn)
+                           if isinstance(r, ast.Return)
+                           and isinstance(r.value, ast.Name)}
+    return declared, jitted
+
+
+def test_undeclared_program_raises():
+    with pytest.raises(KeyError, match="nope"):
+        telemetry.program("nope")
+    assert all(re.fullmatch(r"[a-z0-9_]+", p) for p in telemetry.PROGRAMS)
+    assert len(set(telemetry.PROGRAMS)) == len(telemetry.PROGRAMS)
+
+
+@pytest.mark.parametrize("name", telemetry.PROGRAMS)
+def test_declared_program_is_on_a_jitted_function(name):
+    declared, jitted = _jit_sites()
+    on = [(mod, fn) for mod, fn, lit in declared if lit == name]
+    assert on, f"{name} decorates nothing"
+    for site in on:
+        assert site in jitted, f"{site} carries {name} and is never jitted"
+
+
+def test_no_jitted_function_is_called_spmd_and_names_are_declared():
+    """Five programs of the package were ``jit(spmd)`` in a device trace;
+    each has a declared name of its own now, and ``telemetry.program`` is
+    handed declared literals only."""
+    declared, jitted = _jit_sites()
+    named = {(mod, fn) for mod, fn, _ in declared}
+    assert [s for s in jitted if s[1] == "spmd" and s not in named] == []
+    assert {lit for *_, lit in declared} == set(telemetry.PROGRAMS)
+
+
+def test_program_decorator_renames_the_xla_module():
+    @telemetry.program("gbm_setup_keys")
+    def anything(x):
+        return x + 1
+
+    assert anything.__name__ == "gbm_setup_keys"
+    text = jax.jit(anything).lower(jnp.ones(3)).as_text()
+    assert "module @jit_gbm_setup_keys " in text
+
+
+def _compiled_names(run):
+    return [e["what"] for e in _events_of(run) if e["kind"] == "compile"]
+
+
+@pytest.mark.parametrize("algo", sorted(_TRAIN))
+def test_a_job_compiles_under_declared_names_or_bare_primitives(algo):
+    """A compile event of a job names a declared program, or a primitive jax
+    dispatched eagerly (``jit(concatenate)``): never a function the package
+    jits under a name of its own that ``PROGRAMS`` does not hold. Fresh
+    shapes, so that the job compiles its programs in this process."""
+    _, jitted = _jit_sites()
+    own = {fn for _, fn in jitted}
+    fr = _frame(_N + 8 * (1 + sorted(_TRAIN).index(algo)))
+    names = _compiled_names(lambda: _TRAIN[algo](fr))
+    assert names
+    got = {re.fullmatch(r"jit\((.*)\)", n).group(1) for n in names}
+    assert got & set(telemetry.PROGRAMS)
+    assert [n for n in got - set(telemetry.PROGRAMS) if n in own] == []
+
+
+@pytest.mark.parametrize("pipeline", ["1", "0"])
+def test_the_gbm_step_is_gbm_level(monkeypatch, pipeline):
+    monkeypatch.setenv("H2O_TPU_PIPELINE", pipeline)
+    fr = _frame(_N + 64 + 8 * int(pipeline))
+    names = _compiled_names(lambda: _train_gbm(fr))
+    assert "jit(gbm_level)" in names and "jit(spmd)" not in names
+    from h2o_tpu.utils import programs
+
+    assert {r["module"] for r in programs.snapshot().values()
+            if r["name"] == "train.tree.step"} == {"jit_gbm_level"}
 
 
 # ---------------------------------------------------------------------------

@@ -1,4 +1,5 @@
-"""Device seconds per declared scope (``telemetry.SCOPES``) in a capture:
+"""Device seconds per declared scope (``telemetry.SCOPES``) and per program
+(``telemetry.PROGRAMS``) in a capture:
 ``python tools/trace_scopes.py <capture dir or .xplane.pb>``.
 
 An op's scope is the innermost declared name on the ``jax.named_scope`` path
@@ -7,7 +8,10 @@ event-metadata entry, which ``ProfileData`` does not expose, so the file is
 read as plain protobuf fields (tsl's xplane.proto). Prints, per device, seconds
 by scope and which stat held the path in how many events (None: none); for a
 capture of several chips also the mean plane, with each scope's least and
-most over the chips.
+most over the chips. Under each plane's scope table, from the ``XLA Modules``
+line of the same protobuf (`programs.capture_modules`): device seconds and
+executions by declared program, then the undeclared programs by name (the
+eager primitives a later PR fuses).
 Imports four names of ``benchmark.trace_reduce``: keep them stable."""
 import os
 import sys
@@ -16,6 +20,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from benchmark.trace_reduce import (DEVICE_PLANE, OPS_LINE,  # noqa: E402
                                     find_xplane, is_container)
+from h2o_tpu.utils.programs import (DECLARED_MODULES,  # noqa: E402
+                                    capture_modules)
 from h2o_tpu.utils.telemetry import SCOPES  # noqa: E402
 
 
@@ -87,10 +93,23 @@ def _table(secs: dict, spread: dict | None = None) -> None:
         print(f"  {k:12s} {v:9.4f} s {100 * v / total:5.1f}%{lo_hi}")
 
 
+def _program_table(mods: dict) -> None:
+    """One plane's modules: the declared programs, then the rest by name."""
+    for title, keep in (("declared programs", True), ("undeclared", False)):
+        rows = sorted(((k, v) for k, v in mods.items()
+                       if (k in DECLARED_MODULES) == keep),
+                      key=lambda kv: -kv[1][0])
+        print(f"  {title}: {sum(v[0] for _, v in rows):.4f} s of "
+              f"{sum(v[0] for v in mods.values()):.4f} s of XLA modules")
+        for k, (secs, runs) in rows:
+            print(f"    {k:32s} {secs:9.4f} s {runs:5d} x")
+
+
 def main(path: str) -> None:
     xp = path if path.endswith(".pb") else find_xplane(path)
     with open(xp or sys.exit(f"no .xplane.pb under {path}"), "rb") as f:
         space = memoryview(f.read())
+    modules = capture_modules(xp)
     planes = []
     for plane in (v for n, v in _fields(space) if n == 1):
         name = str(dict(_fields(plane)).get(2, b""), "utf8")
@@ -103,6 +122,7 @@ def main(path: str) -> None:
                 print("  NO scope: executables replayed from a cache written "
                       "before the scopes? Empty JAX_COMPILATION_CACHE_DIR")
             _table(secs)
+            _program_table(modules.get(name, {}))
     if len(planes) > 1:
         # several chips: the mean plane, and each scope's least and most
         # over the chips (the straggler a collective waits for)
