@@ -50,18 +50,27 @@ class JobTimeoutError(Exception):
 
 
 #: long-lived server hygiene: XLA's compiler accumulates per-program state
-#: across hundreds of distinct trainings and the CPU backend has been
+#: across hundreds of DISTINCT trainings and the CPU backend has been
 #: observed to destabilize under it (the test suite resets per module —
-#: `tests/conftest.py`; a server process needs the same bound). After every
-#: H2O_TPU_CLEAR_CACHES_EVERY finished jobs (default 64, 0 disables) the
-#: NEXT job boundary drops XLA's compilation caches — compiled programs are
-#: re-derivable, so the only cost is a recompile on reuse.
-_jobs_finished = 0
+#: `tests/conftest.py`; a server process needs the same bound). A compiled
+#: program is otherwise the process's to keep (`gbm._AOT_STEP_CACHE`,
+#: `glm._kept`, `programs.Tracked`): a job of shapes it has trained before
+#: builds nothing. So what is counted is jobs that BUILT a program, told by
+#: the compile-path counter having moved since the last job finished (real
+#: compilations and persistent-cache replays alike): after every
+#: H2O_TPU_CLEAR_CACHES_EVERY of them (default 64, 0 disables) the process
+#: drops every compiled program it holds — they are re-derivable, so the
+#: only cost is a reload on reuse. A server that replays the same programs
+#: accumulates nothing and never sweeps; one that trains ever-different
+#: shapes sweeps at every 64th job.
+_jobs_built = 0       # finished jobs that entered the compile path
+_compiles_seen = 0    # compilemeter.count() when the last job finished
 _jobs_lock = threading.Lock()
 
 
 def _note_job_finished() -> None:
-    global _jobs_finished
+    global _jobs_built, _compiles_seen
+    from ..utils import compilemeter, telemetry
     from ..utils.knobs import raw
 
     # set-but-empty means DISABLED (int("" or 0) == 0 historically) — raw
@@ -69,9 +78,13 @@ def _note_job_finished() -> None:
     every = int(raw("H2O_TPU_CLEAR_CACHES_EVERY", 64) or 0)
     if every <= 0:
         return
+    seen = compilemeter.count()
     with _jobs_lock:
-        _jobs_finished += 1
-        due = _jobs_finished % every == 0
+        built, _compiles_seen = seen != _compiles_seen, seen
+        if not built:
+            return
+        _jobs_built += 1
+        due = _jobs_built % every == 0
     if due:
         import gc
 
@@ -101,12 +114,18 @@ def _note_job_finished() -> None:
         prog_mod = _sys.modules.get("h2o_tpu.utils.programs")
         if prog_mod is not None:
             prog_mod.clear_compiled()
+        # the GLM's kept IRLS steps and probes: the store goes too, so a
+        # family no job trains any more leaves nothing behind
+        glm_mod = _sys.modules.get("h2o_tpu.models.glm")
+        if glm_mod is not None:
+            glm_mod.drop_kept_programs()
         gc.collect()
         jax.clear_caches()
         from ..utils.log import info
 
-        info(f"cleared XLA compilation caches after {_jobs_finished} jobs "
-             "(H2O_TPU_CLEAR_CACHES_EVERY)")
+        telemetry.inc("jobs.cache_sweeps")
+        info(f"cleared XLA compilation caches after {_jobs_built} jobs that "
+             "built a program (H2O_TPU_CLEAR_CACHES_EVERY)")
 
 
 class Job(Keyed):

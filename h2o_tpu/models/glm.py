@@ -22,8 +22,11 @@ via device one-hot cross-products + host Henderson/EM solve).
 
 from __future__ import annotations
 
+import collections
 import contextlib
+import copy
 import functools
+import threading
 from dataclasses import dataclass, field
 
 import jax
@@ -46,6 +49,10 @@ from .model_base import Model, ModelBuilder, ModelOutput, Parameters, make_metri
 class Family:
     name = "gaussian"
     default_link = "identity"
+    #: the numeric attributes `variance` / `deviance` read: with the class
+    #: and `link_name`, everything a traced program sees of a family, and so
+    #: what a kept program is keyed by (`_program_key`)
+    program_params: tuple = ()
 
     def __init__(self, link=None, **kw):
         self.link_name = link or self.default_link
@@ -125,6 +132,7 @@ class GammaF(Family):
 class TweedieF(Family):
     name = "tweedie"
     default_link = "log"
+    program_params = ("p",)
 
     def __init__(self, link=None, tweedie_variance_power=1.5, **kw):
         super().__init__(link, **kw)
@@ -145,6 +153,7 @@ class TweedieF(Family):
 class NegBinomialF(Family):
     name = "negativebinomial"
     default_link = "log"
+    program_params = ("theta",)
 
     def __init__(self, link=None, theta=1.0, **kw):
         super().__init__(link, **kw)
@@ -235,8 +244,65 @@ def _gram_plan_attrs(X, w, offset) -> dict:
             "gram_tail_rows": tail}
 
 
+#: programs the process keeps (an IRLS step and a deviance probe a family).
+#: A search over `tweedie_variance_power` or `theta` builds a pair a value:
+#: beyond this many the least recently used goes, with its executables
+_KEPT_PROGRAMS = 32
+_KEPT: collections.OrderedDict = collections.OrderedDict()
+_KEPT_LOCK = threading.Lock()
+
+
+def _program_key(family: Family) -> tuple:
+    """What a traced IRLS step or probe reads of ``family``, and nothing
+    else: two instances with equal keys share a program, a tweedie power of
+    1.2 and one of 1.5 do not."""
+    return (type(family), family.link_name,
+            tuple(getattr(family, a) for a in family.program_params))
+
+
+def _kept(build):
+    """A program factory whose product lives as long as the process needs
+    it: ``build(family)`` runs once a `_program_key`, and every later call,
+    from any job, is handed the object it returned — jitted functions,
+    their `programs.Tracked` wrappers and, through them, the executables of
+    every signature dispatched so far. A job of a family and shapes the
+    process has trained before therefore traces, lowers and loads nothing
+    (`train.glm.program.kept` counts such a call, `.built` the other kind;
+    rebuilt per train, the two programs were 0.17-0.20 s of a 0.43 s job).
+    The program closes over a COPY of the family: the caller's instance
+    belongs to its model. `drop_kept_programs` empties the store."""
+
+    @functools.wraps(build)
+    def factory(family: Family):
+        key = (build.__name__,) + _program_key(family)
+        with _KEPT_LOCK:
+            prog = _KEPT.get(key)
+            if prog is None:
+                prog = _KEPT[key] = build(copy.copy(family))
+                if len(_KEPT) > _KEPT_PROGRAMS:
+                    _KEPT.popitem(last=False)
+                telemetry.inc("train.glm.program.built")
+            else:
+                _KEPT.move_to_end(key)
+                telemetry.inc("train.glm.program.kept")
+        return prog
+
+    return factory
+
+
+def drop_kept_programs() -> None:
+    """Forget every kept program (`backend/jobs.py`'s sweep, with the other
+    stores of compiled programs): the next train of a family builds anew.
+    A job in flight keeps the objects it was handed."""
+    with _KEPT_LOCK:
+        _KEPT.clear()
+
+
+@_kept
 def _make_irls_kernel(family: Family):
     """One GLMIterationTask: (X, y, w, beta, offset) -> (Gram, XWz, dev, neff).
+    Built once a family and kept (`_kept`): callers share the step, its
+    per-mesh ``sharded`` table and its executables.
 
     X is row-sharded; the Gram/XWz accumulation routes through the
     kernels layer (`backend/kernels/gram.py`): XᵀWX and XᵀWz accumulate in
@@ -305,20 +371,23 @@ def _make_irls_kernel(family: Family):
                         out_specs=(_P(), _P(), _P(), _P()),
                         check_vma=False)),
                     "train", shards=ns)
-                sharded[mesh] = prog
+                # the step is shared: of two jobs that built at once, one wins
+                prog = sharded.setdefault(mesh, prog)
             return prog(X, y, w, beta, offset)
         return jit_step(X, y, w, beta, offset)
 
     return step
 
 
+@_kept
 def _make_dev_kernel(family: Family):
     """Deviance-only probe: one matvec + the family deviance — ~P× cheaper
     than a full GLMIterationTask. The IRLS loop uses it to detect the
     deviance plateau WITHOUT paying the Gram a converged solution no
     longer needs (the historic loop burned one full Gram pass per lambda
     purely to confirm convergence — a third of RuleFit's lasso-path
-    wall)."""
+    wall). Built once a family and kept (`_kept`): the jitted function's
+    own cache holds a signature's executable from job to job."""
 
     @jax.jit
     @telemetry.program("glm_probe")

@@ -117,8 +117,8 @@ _knob("H2O_TPU_ASYNC_PSUM", "bool", True,
       "collective hides under compute; 0 reverts to the PR 10 shape "
       "(one joint scan, psums after). Bit-equal either way")
 _knob("H2O_TPU_CLEAR_CACHES_EVERY", "int", 64,
-      "drop live XLA executables every N models (long-server hygiene; "
-      "0 = never)")
+      "drop live XLA executables every N finished jobs that built a "
+      "program (entered the compile path; long-server hygiene; 0 = never)")
 _knob("H2O_TPU_PDP_BATCH_ROWS", "int", 2_000_000,
       "row budget per batched partial-dependence predict")
 

@@ -97,7 +97,8 @@ class OptArgs:
                                 "exact small-data cut points")
     clear_caches_every: int = _flag(0, "H2O_TPU_CLEAR_CACHES_EVERY",
                                     "drop live XLA executables every N "
-                                    "models (long-running-server hygiene; "
+                                    "finished jobs that built a program "
+                                    "(long-running-server hygiene; "
                                     "0 = never)")
     pdp_batch_rows: int = _flag(2_000_000, "H2O_TPU_PDP_BATCH_ROWS",
                                 "row budget per batched partial-dependence "

@@ -69,11 +69,14 @@ _LOCK = threading.Lock()
 _REGISTRY: dict[str, "ProgramRecord"] = {}
 
 #: live Tracked instances, weakly held — a Tracked's lifetime belongs to
-#: its caller (mrtask caches them on the map function so the gc reclaims
-#: program + closure together); the jobs.py CLEAR_CACHES_EVERY sweep
-#: calls :func:`clear_compiled` over whatever is still alive so
-#: directly-held executables honor the same long-server hygiene bound as
-#: the AOT caches
+#: whoever holds it, and with it the executables of every signature it has
+#: dispatched: mrtask caches them on the map function (the gc reclaims
+#: program + closure together), the GLM keeps its IRLS steps for the
+#: process (`glm._kept`), so a wrapper outlives the job that built it and
+#: the next job of the same shapes loads nothing. The jobs.py
+#: CLEAR_CACHES_EVERY sweep calls :func:`clear_compiled` over whatever is
+#: still alive so directly-held executables honor the same long-server
+#: hygiene bound as the AOT caches
 _TRACKED: "weakref.WeakSet" = weakref.WeakSet()
 
 
@@ -200,7 +203,10 @@ class Tracked:
     """Per-signature AOT dispatch wrapper over a jitted callable — the
     instrumentation shape of ``gbm._aot_train_step`` made reusable. One
     compile per signature either way; the compiled object additionally
-    yields its cost/memory analyses and a measured dispatch wall."""
+    yields its cost/memory analyses and a measured dispatch wall. The
+    executables live as long as the wrapper (or until :meth:`clear`): a
+    wrapper made per job reloads its program per job, one its owner keeps
+    dispatches a known signature with no load at all."""
 
     __slots__ = ("name", "kind", "labels", "_jitted", "_compiled", "_pids",
                  "__weakref__")
@@ -251,9 +257,9 @@ class Tracked:
         if ent is None:
             # a compile error surfaces HERE, once — the jitted twin would
             # hand the same program to the same compiler and fail again.
-            # The load has a span of its own (train.program.load, ...): a
-            # program re-jitted per job pays it per job, replayed from the
-            # persistent cache or not, and `compiles`/`uncached` say which
+            # The load has a span of its own (train.program.load, ...),
+            # opened once a signature for as long as the wrapper is kept;
+            # `compiles`/`uncached` say whether it built or replayed
             with telemetry.span(f"{self.kind}.program.load",
                                 program=self.name) as sp:
                 with compilemeter.scoped() as sc:

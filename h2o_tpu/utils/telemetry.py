@@ -180,6 +180,14 @@ _counter("train.glm.path.lambdas",
 _counter("train.glm.path.iterations",
          "IRLS iterations (Gram passes) a GLM lambda_search spent on its "
          "path, added once a job")
+_counter("train.glm.program.kept",
+         "calls of the GLM's two program factories (IRLS step, deviance "
+         "probe) handed the program an earlier call built: nothing is "
+         "traced, lowered or loaded")
+_counter("train.glm.program.built",
+         "calls of the GLM's two program factories that built the program: "
+         "a family's first train in the process, or its first after a cache "
+         "sweep or an eviction")
 _histogram("train.chunk.seconds",
            "wall per boosting chunk (train_fn dispatch + scoring + "
            "history, the score_tree_interval boundary)")
@@ -269,6 +277,10 @@ _counter("sanitizer.violation.count",
 _counter("xla.compile.count",
          "XLA backend compiles observed since utils/compilemeter.py "
          "installed its jax.monitoring listener")
+_counter("jobs.cache_sweeps",
+         "times backend/jobs.py dropped every compiled program the process "
+         "held (H2O_TPU_CLEAR_CACHES_EVERY finished jobs that each entered "
+         "the compile path; a process that replays what it has never does)")
 
 # -- fleet observability plane (utils/programs.py / fleetobs.py / -----------
 # -- flightrec.py + the profiler capture surface) ----------------------------

@@ -68,6 +68,21 @@ def _bound_compile_state():
 
 
 @pytest.fixture(autouse=True)
+def _no_kept_glm_programs():
+    """A GLM's IRLS step and probe are kept for the process
+    (`models/glm.py` `_kept`), so whether a train builds and loads them
+    would depend on which tests this worker ran before. Every test leaves
+    none behind, so the next starts as a fresh process does; that also
+    frees the executables they hold (`_bound_compile_state`)."""
+    import sys
+
+    yield
+    glm = sys.modules.get("h2o_tpu.models.glm")
+    if glm is not None:
+        glm.drop_kept_programs()
+
+
+@pytest.fixture(autouse=True)
 def key_leak_rule(request):
     """`water/junit/rules/CheckLeakedKeysRule.java:20-35` analog: snapshot the
     KVStore before each test, and afterwards remove every key the test left
