@@ -118,6 +118,40 @@ class DataInfo:
         bad = valid if valid is not None else jnp.zeros(X.shape[0], jnp.bool_)
         return X, ~bad
 
+    def column_plan(self, fr: Frame):
+        """``((cols, fill, shift, scale), layout)``: `expand` taken apart for a
+        caller that builds its design inside ONE jitted program
+        (`expand_columns`, `models/gam.py`). ``cols`` are the frame's columns
+        as they lie on the device, in ``self.names`` order (a categorical
+        with another domain remapped to the training one); ``fill`` (the NA
+        fill: mean or mode), ``shift`` and ``scale`` are (len(names),) ARRAYS,
+        so the program takes them as arguments and a second frame of the
+        same ``layout`` (a cardinality a column, 0 for a numeric; the first
+        level kept; Skip or not) traces nothing."""
+        cols, fill, shift, scale, cards = [], [], [], [], []
+        for n in self.names:
+            v = fr.vec(n)
+            col = v.data
+            if n in self.domains:
+                dom = self.domains[n]
+                if v.domain != dom and v.domain is not None:
+                    col = _remap_codes(v, dom)
+                cards.append(len(dom))
+                fill.append(self.cat_modes[n])
+                shift.append(0.0)
+                scale.append(1.0)
+            else:
+                cards.append(0)
+                fill.append(self.num_means[n])
+                shift.append(self.num_means[n] if self.effective_center
+                             else 0.0)
+                scale.append(self.num_sigmas[n] if self.standardize else 1.0)
+            cols.append(col)
+        layout = (tuple(cards), 0 if self.use_all_factor_levels else 1,
+                  self.missing_values_handling == "Skip")
+        f32 = lambda a: np.asarray(a, np.float32)  # noqa: E731
+        return (tuple(cols), f32(fill), f32(shift), f32(scale)), layout
+
     def expand_matrix(self, X):
         """Raw (N, len(names)) matrix → expanded (N, P) design, columns in
         ``self.names`` order with categoricals as training-domain codes.
@@ -155,6 +189,39 @@ class DataInfo:
                     x = x / self.num_sigmas[n]
                 blocks.append(x[:, None])
         return jnp.concatenate(blocks, axis=1)
+
+
+def expand_columns(cols, fill, shift, scale, layout):
+    """The traceable body of `DataInfo.expand` over `DataInfo.column_plan`'s
+    output: ``(blocks, valid)``, the expanded design as a LIST of 2-D blocks
+    in `expand`'s column order (a caller concatenates them into its one
+    buffer: a categorical's one-hot each, then ALL the numerics as one
+    block, `DataInfo.make` having put them last) and the rows that no NA
+    under Skip has flagged. Same per-column treatment as `expand`: a
+    categorical's NA or unseen level takes the mode before its one-hot, a
+    numeric's NA the mean, then shift and scale (0 and 1 where the
+    DataInfo neither centres nor standardises: exact in float32)."""
+    cards, lo, skip = layout
+    ncat = sum(1 for c in cards if c)
+    assert all(cards[:ncat]) and not any(cards[ncat:]), cards
+    blocks, bad = [], []
+    for j in range(ncat):
+        isna = jnp.isnan(cols[j]) | (cols[j] >= cards[j])
+        bad.append(isna)
+        codes = jnp.where(isna, fill[j], cols[j]).astype(jnp.int32)
+        blocks.append((codes[:, None] == jnp.arange(lo, cards[j])[None, :])
+                      .astype(jnp.float32))
+    if ncat < len(cols):
+        x = jnp.stack(cols[ncat:], axis=1)
+        isna = jnp.isnan(x)
+        bad.append(jnp.any(isna, axis=1))
+        blocks.append((jnp.where(isna, fill[None, ncat:], x)
+                       - shift[None, ncat:]) / scale[None, ncat:])
+    valid = jnp.ones(cols[0].shape, jnp.bool_)
+    if skip:
+        for b in bad:
+            valid = valid & ~b
+    return blocks, valid
 
 
 def _remap_codes(v, train_dom):

@@ -434,6 +434,23 @@ def cr_matrices(knots: np.ndarray):
     return F, S
 
 
+def sum_to_zero(c: np.ndarray) -> np.ndarray:
+    """The identifiability constraint of a smooth whose basis holds the
+    constants (Wood 2017, section 5.4.1): with ``c = Xᵀ1`` the (K,) column
+    sums of the basis over the training rows, the K x (K-1) matrix ``Z`` of
+    the last K-1 columns of the Householder reflection
+    ``H = I - 2 v vᵀ / (vᵀv)``, ``v = c + sign(c_1) |c| e_1``, which maps
+    ``c`` onto ``-sign(c_1) |c| e_1``. ``ZᵀZ = I`` and ``cᵀZ = 0``: the
+    columns ``X Z`` each sum to zero over those rows. The sign is the one
+    that adds magnitudes in ``v`` (no cancellation), and it is part of the
+    model: coefficients are of THESE columns."""
+    c = np.asarray(c, np.float64)
+    v = c.copy()
+    v[0] += np.copysign(np.linalg.norm(c), c[0])
+    H = np.eye(len(c)) - 2.0 * np.outer(v, v) / (v @ v)
+    return H[:, 1:]
+
+
 def tp_basis(x: np.ndarray, knots: np.ndarray, scale: float,
              Z: np.ndarray) -> np.ndarray:
     """1-D thin-plate regression spline basis: cubic radial bumps |x−k|³
@@ -474,9 +491,21 @@ def ispline_basis(x: np.ndarray, lo: float, hi: float, interior: np.ndarray,
     return I[:, 1:]
 
 
+def gam_columns(x: np.ndarray, spec: dict) -> np.ndarray:
+    """One gam column's block of the design from its serialized spec, as the
+    model's coefficients are of it: the basis through the sum-to-zero
+    constraint ``Zc`` where the spec holds one (`sum_to_zero`: bs 0 and 3,
+    whose bases hold the constants), else less its training column means.
+    The standalone MOJO scorer's twin of `models/gam.py`'s design program."""
+    B = gam_basis(x, spec)
+    if "Zc" in spec:
+        return B @ np.asarray(spec["Zc"], np.float64)
+    return B - np.asarray(spec["col_means"], np.float64)[None, :]
+
+
 def gam_basis(x: np.ndarray, spec: dict) -> np.ndarray:
-    """Evaluate one gam column's (uncentered) basis from its serialized spec
-    — shared by the engine and the standalone MOJO scorer."""
+    """Evaluate one gam column's (unconstrained, uncentered) basis from its
+    serialized spec: the numpy twin of the device evaluators."""
     bs = int(spec.get("bs", 3))
     if bs == 0:      # cr
         return cr_basis(x, np.asarray(spec["knots"]),

@@ -929,16 +929,14 @@ class _GamMojo(_DeepLearningMojo):
     tweedie_link_power = 0.0
 
     def score(self, X):
-        from .format import gam_basis
+        from .format import gam_columns
 
         X = np.asarray(X, dtype=np.float64)
         blocks = []
         if self.n_lin:
             blocks.append(self._expand(X[:, :self.n_lin]))
         for gi, spec in enumerate(self.gam_specs):
-            x = X[:, self.n_lin + gi]
-            B = gam_basis(x, spec)
-            blocks.append(B - np.asarray(spec["col_means"])[None, :])
+            blocks.append(gam_columns(X[:, self.n_lin + gi], spec))
         D = np.concatenate(blocks, axis=1)
         eta = D @ self.beta[:-1] + self.beta[-1]
         mu = self._linkinv(eta)

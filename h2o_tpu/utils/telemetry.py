@@ -188,6 +188,12 @@ _counter("train.glm.program.built",
          "calls of the GLM's two program factories that built the program: "
          "a family's first train in the process, or its first after a cache "
          "sweep or an eviction")
+_counter("train.gam.iterations",
+         "IRLS iterations a GAM job ran, added once a job")
+_counter("train.gam.design_bytes",
+         "bytes a GAM job's two design programs move, from shapes alone: "
+         "the smooth columns read by the sums program, every column read "
+         "and the (R, P+1) design written by the design program")
 _histogram("train.chunk.seconds",
            "wall per boosting chunk (train_fn dispatch + scoring + "
            "history, the score_tree_interval boundary)")
@@ -399,6 +405,7 @@ SCOPES: tuple[str, ...] = (
     "glm.eta",       # link, weights, working response of the IRLS step
     "glm.gram",      # kernels/gram.gram_accumulate
     "glm.deviance",  # the family deviance (IRLS step and probe)
+    "gam.basis",     # gam._design_program / _basis_sums: the smooths' columns
 )
 
 
@@ -444,6 +451,8 @@ PROGRAMS: tuple[str, ...] = (
     # the one name without its layer's prefix: the benchmark's
     # irls_program_s reads the XLA module jit__core by name
     "_core",                # glm._make_irls_kernel: the IRLS step, one shard
+    "gam_design",           # gam._design_program: the whole (R, P+1) design
+    "gam_design_sums",      # gam._basis_sums: the bases' sums over the rows
     "mrtask_driver",        # parallel/mrtask.py: a DrJAX-style driver program
     "merge_expand",         # rapids/merge.py: the sharded merge's expansion
     "uplift_level",         # models/uplift.py: a chunk of uplift trees

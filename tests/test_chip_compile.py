@@ -455,3 +455,46 @@ def test_sketch_counts_are_a_digit_contraction_on_the_mxu(v5e, chips):
              for shapes, _ in colls
              for dims in re.findall(r"\[([\d,]*)\]", shapes)]
     assert max(sizes) == F * SKETCH_NB and sizes.count(F * SKETCH_NB) == 2, colls
+
+
+# ---------------------------------------------------------------------------
+# the GAM's design program (gam._design_body) at the cell higgs_gam_train's
+# shapes: 21 linear columns, seven smooths of ten knots, the intercept
+# ---------------------------------------------------------------------------
+def test_gam_design_is_written_in_place_for_v5e_at_higgs_rows(v5e):
+    """`gam_design` at 11,010,048 x 85 builds 32,768-row blocks and writes
+    each into the one output where it lies: no ``gather`` (the interval of
+    a value comes from compares against the interior knots), no ``copy``,
+    ``pad`` or ``concatenate`` with the frame's rows in it and one
+    ``dynamic-update-slice`` a result, and temporaries of tens of MB.
+    Written as one stack of 85 row vectors the same program kept every
+    column as a temporary of its own, 3.75 GB beside the 3.89 GB design
+    (PERF.md, PR 38); `searchsorted` and two `jnp.take` a smooth are the
+    TPU's serial gather path (PERF.md section 7 nos. 14, 25)."""
+    from h2o_tpu.models import gam
+
+    mesh = make_mesh(v5e[:1])
+    n_lin, n_smooth, K = 21, 7, 10
+    col = _spec(mesh, (HIGGS_PLEN,), jnp.float32)
+    small = lambda *shape: _spec(mesh, shape, jnp.float32)  # noqa: E731
+    lin = ((col,) * n_lin, small(n_lin), small(n_lin), small(n_lin))
+    program = gam._design_program(mesh, False, ((0,) * n_lin, 1, False),
+                                  ((0, 0),) * n_smooth)
+    compiled = program.lower(
+        lin, (col,) * n_smooth, ((small(K),),) * n_smooth,
+        (small(2 * K, K - 1),) * n_smooth, (small(K - 1),) * n_smooth).compile()
+    hlo = compiled.as_text()
+    assert "jit_gam_design" in hlo.split("\n", 1)[0]    # the declared name
+    width = n_lin + n_smooth * (K - 1) + 1
+    assert f"f32[{HIGGS_PLEN},{width}]" in hlo
+    assert not re.findall(r"^.* gather\(", hlo, re.M)
+    moved = re.findall(
+        r"^\s*(?:ROOT )?%[\w.\-]+ = (\S+) (copy|copy-start|pad|concatenate|"
+        r"dynamic-update-slice)\(", hlo, re.M)
+    whole = sorted((op, sh.split("{")[0]) for sh, op in moved
+                   if str(HIGGS_PLEN) in sh)
+    assert whole == [("dynamic-update-slice", f"f32[{HIGGS_PLEN},{width}]"),
+                     ("dynamic-update-slice", f"pred[{HIGGS_PLEN}]")], whole
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 64 << 20
+    assert mem.output_size_in_bytes < 4.0e9     # 85 -> 88 sublanes, not 128

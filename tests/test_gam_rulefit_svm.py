@@ -18,8 +18,10 @@ def test_gam_fits_nonlinearity():
     z = rng.normal(size=n).astype(np.float32)
     y = (np.sin(x) * 2 + 0.5 * z + 0.1 * rng.normal(size=n)).astype(np.float32)
     fr = Frame.from_dict({"x": x, "z": z, "y": y})
+    # `scale` weighs the penalty against the MEAN objective (PR 38; it was
+    # added to the raw Gram, so 0.1 at 3000 rows smoothed 6000 times less)
     p = GAMParameters(training_frame=fr, response_column="y",
-                      gam_columns=["x"], num_knots=10, scale=0.1,
+                      gam_columns=["x"], num_knots=10, scale=0.001,
                       family="gaussian", lambda_=0.0, alpha=0.0)
     m = GAM(p).train_model()
     r2 = m.output.training_metrics.r2
@@ -187,7 +189,7 @@ class TestGamSplineFamilies:
         g = m.predict(grid).vec("predict").to_numpy()
         assert np.min(np.diff(g)) >= -1e-5, "monotone fit decreased"
 
-    @pytest.mark.parametrize("bs", [0, 1, 2])
+    @pytest.mark.parametrize("bs", [0, 1, 2, 3])
     def test_mojo_roundtrip_new_families(self, bs, tmp_path):
         from h2o_tpu.models.gam import GAM, GAMParameters
         from h2o_tpu.mojo.reader import MojoModel

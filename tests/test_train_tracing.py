@@ -150,6 +150,25 @@ def _binning_texts():
             mat.compile().as_text())
 
 
+def _gam_texts():
+    """The two design programs (plain jit, one shard) at two cubic
+    regression splines and one linear column."""
+    from h2o_tpu.models import gam
+    from h2o_tpu.parallel import mesh as meshmod
+
+    col = jnp.zeros((2048,), jnp.float32)
+    vec = lambda n: jnp.ones((n,), jnp.float32)  # noqa: E731
+    knots = jnp.linspace(-1.0, 1.0, 6)
+    kinds, args = ((0, 0),) * 2, ((knots,),) * 2
+    mesh = meshmod.default_mesh()
+    sums = gam._sums_program(mesh, False, kinds)
+    design = gam._design_program(mesh, False, ((0,), 1, False), kinds)
+    return (sums.lower((col, col), args, jnp.int32(2000)).compile().as_text(),
+            design.lower(((col,), vec(1), vec(1), vec(1)), (col, col), args,
+                         (jnp.ones((12, 5)),) * 2,
+                         (vec(5),) * 2).compile().as_text())
+
+
 @pytest.fixture(scope="module")
 def hlo():
     """The programs' compiled texts, every one compiled HERE: an executable
@@ -165,7 +184,9 @@ def hlo():
     try:
         irls, probe = _glm_texts()
         sketch, bin_col, bin_mat = _binning_texts()
-        return {"gbm_step_pipelined": _gbm_step_text("1"),
+        gam_sums, gam_design = _gam_texts()
+        return {"gam_sums": gam_sums, "gam_design": gam_design,
+                "gbm_step_pipelined": _gbm_step_text("1"),
                 "gbm_step_synchronous": _gbm_step_text("0"),
                 "sketch": sketch, "bin_column": bin_col,
                 "bin_matrix": bin_mat,
@@ -184,7 +205,8 @@ _SCOPE_CASES = (
     + [("sketch", "gbm.sketch"), ("bin_column", "gbm.bin"),
        ("bin_matrix", "gbm.bin")]
     + [("irls_step", s) for s in ("glm.eta", "glm.gram", "glm.deviance")]
-    + [("deviance_probe", s) for s in ("glm.eta", "glm.deviance")])
+    + [("deviance_probe", s) for s in ("glm.eta", "glm.deviance")]
+    + [("gam_sums", "gam.basis"), ("gam_design", "gam.basis")])
 
 
 def test_every_declared_scope_has_a_case():
@@ -245,7 +267,25 @@ _TREE["glm_search"] = {
 # train.gbm span (why the benchmark reads xgb_setup_s, not gbm_setup_s)
 _TREE["xgboost"] = {k: "train.xgboost" if v == "train.gbm" else v
                     for k, v in _TREE["gbm"].items()}
+# the GAM's job (ISSUE 38): its own parts under train.gam, the GLM's step
+# (and its load) under ITS gram span, and no train.glm span
+_TREE["gam"] = {
+    "train.gam.knots": "train.gam", "train.gam.design": "train.gam",
+    "train.gam.start": "train.gam", "train.gam.gram": "train.gam",
+    "train.gam.solve": "train.gam", "train.gam.finish": "train.gam",
+    "train.gam.metrics": "train.gam", "train.program.load": "train.gam.gram"}
+
+
+def _train_gam(fr, **kw):
+    from h2o_tpu.models.gam import GAM, GAMParameters
+
+    return GAM(GAMParameters(
+        training_frame=fr, response_column="y", family="binomial",
+        gam_columns=["x2", "x3"], num_knots=6, scale=0.001, **kw)).train_model()
+
+
 _TRAIN = {"gbm": _train_gbm, "glm": _train_glm, "xgboost": _train_xgboost,
+          "gam": _train_gam,
           "glm_search": lambda fr: _train_glm(
               fr, lambda_=None, lambda_search=True, nlambdas=6)}
 _ROOT = {"xgboost": "train.xgboost"}
@@ -258,6 +298,12 @@ def test_train_records_the_span_tree(algo):
     (root,) = _spans(events, _ROOT.get(algo, f"train.{algo[:3]}"))
     if algo == "xgboost":
         assert _spans(events, "train.gbm") == []
+    if algo == "gam":
+        assert _spans(events, "train.glm") == []
+        (design,) = _spans(events, "train.gam.design")
+        assert design["design_cols"] == (_F - 2) + 2 * 5 + 1
+        assert len(_spans(events, "train.gam.gram")) == len(
+            _spans(events, "train.gam.solve")) >= 2
     by_id = {e["span"]: e for e in events if e["kind"] == "span"}
     for name, parent in _TREE[algo].items():
         got = _spans(events, name)
@@ -281,6 +327,7 @@ def test_train_records_the_span_tree(algo):
     assert counts == ({"train.gbm.prep": 4, "train.gbm.finish": 1,
                        "train.glm.start": 0, "train.glm.finish": 0}
                       if algo in ("gbm", "xgboost") else
+                      dict.fromkeys(counts, 0) if algo == "gam" else
                       {"train.gbm.prep": 0, "train.gbm.finish": 0,
                        "train.glm.start": 1, "train.glm.finish": 1})
 
