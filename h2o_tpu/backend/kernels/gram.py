@@ -100,7 +100,7 @@ def gram_accumulate(X, W, z=None, *, block: int | None = None):
     tail rows last. The sums differ from another block size's only by the
     order of addition inside a block."""
     R, P = X.shape
-    nblk, rb, tail = block_plan(R, P, block)
+    nblk, _, tail = block_plan(R, P, block)
     if nblk == 1 and not tail:
         return block_contrib(X, W, z)
 
@@ -113,8 +113,22 @@ def gram_accumulate(X, W, z=None, *, block: int | None = None):
 
     acc = (jnp.zeros((P, P), jnp.float32),
            None if z is None else jnp.zeros((P,), jnp.float32))
-    acc = jax.lax.fori_loop(
-        0, nblk, lambda i, acc: add_block(acc, i * rb, rb), acc)
-    if tail:
-        acc = add_block(acc, nblk * rb, tail)
-    return acc
+    return over_row_blocks(R, P, acc, add_block, block=block)
+
+
+def over_row_blocks(R: int, P: int, carry, add, *, block: int | None = None):
+    """``add(carry, start, rows)`` over the row blocks of `block_plan(R,
+    P, block)`, ascending, the tail last: a `fori_loop` over the block
+    index whose body slices its rows in place. The one loop of every
+    blocked pass over a frame's rows: `gram_accumulate`, the streamed
+    interaction step (`glm._make_interact_step`, whose (rows, 407) block
+    is built in the body from the (R, 29) main design and never lies in
+    HBM) and GAM's design programs. ``P`` is the width the block budget is
+    planned on; ``carry`` is any tree of arrays."""
+    nblk, rb, tail = block_plan(R, P, block)
+    if nblk == 1 and not tail:
+        return add(carry, 0, R)
+    carry = jax.lax.fori_loop(
+        0, nblk, lambda i, c: add(c, i * rb, rb), carry)
+    return add(carry, nblk * rb, tail) if tail else carry
+

@@ -198,19 +198,6 @@ def _device_args(spec):
 _BLOCK_ROWS = 65536
 
 
-def _over_row_blocks(R: int, width: int, init, add):
-    """``add(carry, start, rows)`` over `gram.block_plan`'s blocks of an
-    R-row shard at `_BLOCK_ROWS` a block, ascending, the tail last: the
-    Gram kernel's loop shape (blocks sliced in place, at lane-tile offsets,
-    their count a multiple of 8)."""
-    nblk, rb, tail = gram_kernels.block_plan(R, width, block=_BLOCK_ROWS)
-    if nblk == 1 and not tail:
-        return add(init, 0, R)
-    carry = jax.lax.fori_loop(
-        0, nblk, lambda i, c: add(c, i * rb, rb), init)
-    return add(carry, nblk * rb, tail) if tail else carry
-
-
 def _rows(a, start, rows: int):
     return jax.lax.dynamic_slice_in_dim(a, start, rows, 0)
 
@@ -235,7 +222,8 @@ def _sums_body(gam_cols, args, nrow, *, kinds, axis=None):
 
     init = tuple(jnp.zeros((_n_weights(kind, arg),), jnp.float32)
                  for kind, arg in zip(kinds, args))
-    sums = _over_row_blocks(R, 2 * len(gam_cols), init, add)
+    sums = gram_kernels.over_row_blocks(R, 2 * len(gam_cols), init, add,
+                                       block=_BLOCK_ROWS)
     return sums if axis is None else jax.lax.psum(sums, axis)
 
 
@@ -281,9 +269,9 @@ def _design_body(lin, gam_cols, args, maps, shifts, *, layout, kinds):
         return tuple(jax.lax.dynamic_update_slice_in_dim(o, b, start, 0)
                      for o, b in zip(out, block(start, rows)))
 
-    return _over_row_blocks(
+    return gram_kernels.over_row_blocks(
         R, P1, (jnp.zeros((R, P1), jnp.float32), jnp.zeros((R,), jnp.bool_)),
-        put)
+        put, block=_BLOCK_ROWS)
 
 
 # the two design programs (`telemetry.PROGRAMS`), built once a mesh and

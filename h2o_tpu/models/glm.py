@@ -39,7 +39,7 @@ from ..backend.memory import hbm_span_attrs
 from ..frame.frame import Frame
 from ..frame.vec import Vec
 from ..utils import telemetry
-from .datainfo import DataInfo
+from .datainfo import DataInfo, lanes
 from .model_base import Model, ModelBuilder, ModelOutput, Parameters, make_metrics
 
 
@@ -231,15 +231,18 @@ def _step_row_shards(X, w, offset, mesh) -> int:
     return 1
 
 
-def _gram_plan_attrs(X, w, offset) -> dict:
+def _gram_plan_attrs(X, w, offset, width: int | None = None) -> dict:
     """``train.glm.gram``'s attributes: the row blocks `gram_accumulate`
     cuts the design into inside the step (a shard's rows under
-    ``shard_map``), from `gram.block_plan` on the shapes alone."""
+    ``shard_map``), from `gram.block_plan` on the shapes alone. ``width``:
+    the Gram's, where the step builds its blocks wider than ``X``
+    (`_make_interact_step`)."""
     from ..backend.kernels import gram as gram_kernels
     from ..parallel.mesh import default_mesh
 
     ns = _step_row_shards(X, w, offset, default_mesh())
-    nblk, rb, tail = gram_kernels.block_plan(X.shape[0] // ns, X.shape[1])
+    nblk, rb, tail = gram_kernels.block_plan(X.shape[0] // ns,
+                                             width or X.shape[1])
     return {"gram_blocks": nblk, "gram_block_rows": rb,
             "gram_tail_rows": tail}
 
@@ -270,15 +273,17 @@ def _kept(build):
     (`train.glm.program.kept` counts such a call, `.built` the other kind;
     rebuilt per train, the two programs were 0.17-0.20 s of a 0.43 s job).
     The program closes over a COPY of the family: the caller's instance
-    belongs to its model. `drop_kept_programs` empties the store."""
+    belongs to its model. ``static`` (hashable) is the rest of what a
+    program closes over, the streamed step's column layout: part of the
+    key. `drop_kept_programs` empties the store."""
 
     @functools.wraps(build)
-    def factory(family: Family):
-        key = (build.__name__,) + _program_key(family)
+    def factory(family: Family, *static):
+        key = (build.__name__,) + _program_key(family) + static
         with _KEPT_LOCK:
             prog = _KEPT.get(key)
             if prog is None:
-                prog = _KEPT[key] = build(copy.copy(family))
+                prog = _KEPT[key] = build(copy.copy(family), *static)
                 if len(_KEPT) > _KEPT_PROGRAMS:
                     _KEPT.popitem(last=False)
                 telemetry.inc("train.glm.program.built")
@@ -398,6 +403,235 @@ def _make_dev_kernel(family: Family):
             return jnp.sum(family.deviance(y, mu, w))
 
     return dev_eval
+
+
+# ---------------------------------------------------------------------------
+# numeric x numeric interactions, never stored: a product column is formed
+# from the design's own columns inside the program that reads it
+# (H2O-3's InteractionWrappedVec computes a chunk's product as the Gram
+# task reads it). ``layout`` = (Pm, cols, pairs): the main design's width,
+# the design columns the pairs read (static, ascending), and each pair as
+# two positions in ``cols``; the model's columns are the Pm mains, then the
+# pairs in order, then the intercept.
+# ---------------------------------------------------------------------------
+def _pair_products(x, pairs):
+    """(rows, len(pairs)) products ``x[:, i] * x[:, j]``, as slices: a run
+    of pairs with one ``i`` and consecutive ``j`` is one broadcast product
+    (all pairs of 28 columns: 27 of them), no gather."""
+    runs, n = [], 0
+    while n < len(pairs):
+        i, j0 = pairs[n]
+        m = n + 1
+        while (m < len(pairs) and pairs[m][0] == i
+               and pairs[m][1] == pairs[m - 1][1] + 1):
+            m += 1
+        runs.append(x[:, i:i + 1] * x[:, j0:pairs[m - 1][1] + 1])
+        n = m
+    return runs[0] if len(runs) == 1 else jnp.concatenate(runs, axis=1)
+
+
+def _interact_eta(layout, X, x, beta, pshift, pscale):
+    """The linear predictor less the offset, with no product column:
+    ``c + zm' b_m + x' G x`` where ``x`` holds the raw values of the paired
+    columns, ``G[i, j]`` sums ``b_ij / scale_ij`` over the pairs and ``c``
+    is the intercept less ``sum b_ij shift_ij / scale_ij``. One (rows, k)
+    x (k, k) product and a row sum, at highest precision (k is small).
+
+    An NA is a NaN in ``X``'s paired columns and so in ``x``
+    (`DataInfo.mark_missing`): its main column imputes to the mean (0 in
+    ``zm``) and every product it enters to the PRODUCT's mean, as an
+    appended product column's NA does. With ``u`` the rows' NA indicators
+    and ``H[i, j] = G[i, j] shift_ij``, that is ``x`` zeroed at the NAs
+    and ``u' (H + H') 1 - u' H u`` added: the ``G`` terms a zeroed value
+    drops, less the shift ``c`` already took. Nought, exactly, without
+    an NA."""
+    Pm, cols, pairs = layout
+    hi = jax.lax.Precision.HIGHEST
+    k = len(cols)
+    g = beta[Pm:Pm + len(pairs)] / pscale
+    ia, ib = (np.array(a, np.int32) for a in zip(*pairs))
+    G = jnp.zeros((k, k), jnp.float32).at[ia, ib].add(g)
+    H = jnp.zeros((k, k), jnp.float32).at[ia, ib].add(g * pshift)
+    c = beta[-1] - jnp.sum(g * pshift)
+    na = jnp.isnan(x)
+    u, x = na.astype(jnp.float32), jnp.where(na, 0.0, x)
+    zm = X[:, :Pm]
+    zm = jnp.where(jnp.isnan(zm), 0.0, zm)
+    r = jnp.sum(H, axis=0) + jnp.sum(H, axis=1)
+    return (jnp.einsum("rp,p->r", zm, beta[:Pm], precision=hi)
+            + jnp.sum(x * jnp.einsum("ra,ab->rb", x, G, precision=hi)
+                      + u * (r - jnp.einsum("ra,ab->rb", u, H,
+                                            precision=hi)), axis=1)
+            + c)
+
+
+def _row_program(spmd, mesh, in_specs, out_specs):
+    """``spmd`` (a row shard's program that psums its replicated outputs)
+    as ONE jitted ``shard_map`` over the mesh's rows."""
+    from ..parallel.mesh import shard_map
+
+    return jax.jit(shard_map(spmd, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False))
+
+
+@_kept
+def _make_interact_step(family: Family, layout):
+    """The IRLS step of a design with streamed products: (X, y, w, beta,
+    offset, xshift, xscale, pshift, pscale) -> (Gram, XWz, dev, neff), the
+    same four numbers as `_make_irls_kernel`'s step over the (R, P + 1)
+    design ``[mains, products, 1]``, which it never holds: ``X`` is the
+    (R, Pm + 1) main design with its column of ones last, and each row
+    block of `gram.block_plan` on the FULL width is built in the loop body
+    (the raw paired values ``x = X[:, cols] * xscale + xshift``, their
+    products standardised as ``(x_i x_j - pshift) / pscale``), its eta
+    computed by `_interact_eta` and contributed by `gram.block_contrib`:
+    float32 products, operands at the default matmul precision, float32
+    accumulation, as the main step. Kept like that step; registered as
+    ``train.glm.irls.<family>.interact`` (`drive_train.irls_dispatches`
+    counts ``train.glm.irls.*``)."""
+    from ..backend.kernels import gram as gram_kernels
+    from ..parallel.mesh import ROWS, default_mesh
+    from ..utils import programs
+
+    Pm, cols, pairs = layout
+    P1 = Pm + len(pairs) + 1
+
+    @telemetry.program("glm_interact_step")
+    def core(X, y, w, beta, offset, xshift, xscale, pshift, pscale):
+        def contrib(start, rows):
+            xb, yb, wb, ob = (jax.lax.dynamic_slice_in_dim(a, start, rows, 0)
+                              for a in (X, y, w, offset))
+            x = lanes(xb, cols) * xscale + xshift
+            with telemetry.scope("glm.eta"):
+                eta = _interact_eta(layout, xb, x, beta, pshift, pscale) + ob
+                mu = family.linkinv(eta)
+                d = family.dmu_deta(eta)
+                V = family.variance(mu)
+                W = wb * d * d / jnp.maximum(V, 1e-10)
+                z = eta - ob + (yb - mu) / jnp.where(jnp.abs(d) < 1e-10,
+                                                     1e-10, d)
+            with telemetry.scope("glm.gram"):
+                # an NA (NaN) imputes its main and its products to their
+                # means: nought on the standardised scale
+                zp = (_pair_products(x, pairs) - pshift) / pscale
+                Zb = jnp.concatenate([xb[:, :Pm], zp, xb[:, Pm:]], axis=1)
+                Zb = jnp.where(jnp.isnan(Zb), 0.0, Zb)
+                dG, db = gram_kernels.block_contrib(Zb, W, z)
+            with telemetry.scope("glm.deviance"):
+                dev = jnp.sum(family.deviance(yb, mu, wb))
+            return dG, db, dev, jnp.sum(wb)
+
+        def add(acc, start, rows):
+            return jax.tree.map(jnp.add, acc, contrib(start, rows))
+
+        zero = jnp.zeros((), jnp.float32)
+        return gram_kernels.over_row_blocks(
+            X.shape[0], P1,
+            (jnp.zeros((P1, P1), jnp.float32), jnp.zeros((P1,), jnp.float32),
+             zero, zero), add)
+
+    name = f"train.glm.irls.{getattr(family, 'name', 'family')}.interact"
+    jit_step = programs.tracked(name, jax.jit(core), "train")
+    sharded: dict = {}
+    row, rep = _P(ROWS), _P()
+
+    def step(X, y, w, beta, offset, *args):
+        mesh = default_mesh()
+        ns = _step_row_shards(X, w, offset, mesh)
+        if ns == 1:
+            return jit_step(X, y, w, beta, offset, *args)
+        prog = sharded.get(mesh)
+        if prog is None:
+            @telemetry.program("glm_interact_step")
+            def spmd(*a):
+                return tuple(jax.lax.psum(o, ROWS) for o in core(*a))
+
+            prog = sharded.setdefault(mesh, programs.tracked(
+                f"{name}.sharded", _row_program(
+                    spmd, mesh,
+                    (_P(ROWS, None), row, row, rep, row) + (rep,) * 4,
+                    (rep,) * 4), "train", shards=ns))
+        return prog(X, y, w, beta, offset, *args)
+
+    step.one_shard = jit_step     # its AOT face (tests/test_chip_compile.py)
+    return step
+
+
+@_kept
+def _make_interact_eta(family: Family, layout):
+    """``glm_interact_eta``: (X, y, w, beta, offset, xshift, xscale,
+    pshift, pscale) -> (mu, deviance) of a design with streamed products,
+    through `_interact_eta`'s quadratic form: no (R, P) design and no
+    product column. ``X`` is the main design, with or without its column
+    of ones. The deviance probe of `GLM._fit`, and the probabilities of the
+    final metrics and of `GLMModel.score0` (with y = w = 0)."""
+    from ..parallel.mesh import ROWS, default_mesh
+
+    Pm, cols, pairs = layout
+
+    @telemetry.program("glm_interact_eta")
+    def core(X, y, w, beta, offset, xshift, xscale, pshift, pscale):
+        with telemetry.scope("glm.eta"):
+            x = lanes(X, cols) * xscale + xshift
+            mu = family.linkinv(
+                _interact_eta(layout, X, x, beta, pshift, pscale) + offset)
+        with telemetry.scope("glm.deviance"):
+            return mu, jnp.sum(family.deviance(y, mu, w))
+
+    jitted = jax.jit(core)
+    sharded: dict = {}
+    row, rep = _P(ROWS), _P()
+
+    def eta(X, y, w, beta, offset, *args):
+        mesh = default_mesh()
+        if _step_row_shards(X, w, offset, mesh) == 1:
+            return jitted(X, y, w, beta, offset, *args)
+        prog = sharded.get(mesh)
+        if prog is None:
+            @telemetry.program("glm_interact_eta")
+            def spmd(*a):
+                mu, dev = core(*a)
+                return mu, jax.lax.psum(dev, ROWS)
+
+            prog = sharded.setdefault(mesh, _row_program(
+                spmd, mesh, (_P(ROWS, None), row, row, rep, row) + (rep,) * 4,
+                (row, rep)))
+        return prog(X, y, w, beta, offset, *args)
+
+    eta.one_shard = jitted
+    return eta
+
+
+class _Products:
+    """A DataInfo's streamed products as the two programs take them: the
+    static ``layout`` and the four small arrays (the paired columns' shift
+    and scale, to undo the design's standardisation, and the products'),
+    bound to ``family``'s kept programs."""
+
+    def __init__(self, di: DataInfo):
+        cols, pairs = di.paired_columns()
+        self.layout = (di.ncols_expanded - len(pairs), cols, pairs)
+        self.width = di.ncols_expanded + 1     # the Gram's, intercept in
+        f32 = lambda a: jnp.asarray(np.asarray(a, np.float32))  # noqa: E731
+        self.args = tuple(map(f32, di.shift_scale(
+            [di.expanded_names[j] for j in cols]) + di.shift_scale(
+            [f"{a}_{b}" for a, b in di.products])))
+
+    def step(self, family: Family):
+        prog = _make_interact_step(family, self.layout)
+        return lambda X, y, w, beta, offset: prog(X, y, w, beta, offset,
+                                                  *self.args)
+
+    def probe(self, family: Family):
+        prog = _make_interact_eta(family, self.layout)
+        return lambda X, y, w, beta, offset: prog(X, y, w, beta, offset,
+                                                  *self.args)[1]
+
+    def mu(self, family: Family, X, beta):
+        zero = jnp.zeros((X.shape[0],), jnp.float32)
+        return _make_interact_eta(family, self.layout)(
+            X, zero, zero, jnp.asarray(beta, jnp.float32), zero,
+            *self.args)[0]
 
 
 def _admm_solve(G, b, l1, l2, free: np.ndarray, rho=None, iters=500, tol=1e-6,
@@ -1041,6 +1275,7 @@ def _apply_interactions(fr: Frame, specs: list, skip_existing: bool = False):
                 f"with an existing column — rename it")
         out.add(nm, vec)
         new_names.append(nm)
+        telemetry.inc("train.glm.interact.columns_appended")
 
     if skip_existing:
         specs = [s for s in specs
@@ -1159,10 +1394,13 @@ class GLMModel(Model):
             cols = self.interaction_cols
             spec = [{"kind": "numnum", "a": a, "b": b}
                     for i, a in enumerate(cols) for b in cols[i + 1:]]
+        streamed = set(getattr(self.dinfo, "products", ()))
         if spec:
+            spec = [s for s in spec if (s["a"], s["b"]) not in streamed
+                    or s["kind"] != "numnum"]
             fr, _ = _apply_interactions(fr, spec, skip_existing=True)
         X, ok = self.dinfo.expand(fr)
-        return X
+        return self.dinfo.mark_missing(X, fr) if streamed else X
 
     def score_raw(self, X):
         """Serving-path scoring straight from the raw (B, F) feature matrix
@@ -1199,8 +1437,11 @@ class GLMModel(Model):
 
     def score0(self, X: jax.Array) -> jax.Array:
         beta = jnp.asarray(self.beta)
-        eta = X @ beta[:-1] + beta[-1]
-        mu = self.family.linkinv(eta)
+        if getattr(self.dinfo, "products", ()):
+            # X is the main design: the products are formed, not read
+            mu = _Products(self.dinfo).mu(self.family, X, beta)
+        else:
+            mu = self.family.linkinv(X @ beta[:-1] + beta[-1])
         if self.output.model_category == "Binomial":
             thr = float(getattr(self, "default_threshold", 0.5))
             label = (mu >= thr).astype(jnp.float32)
@@ -1266,6 +1507,7 @@ class GLM(ModelBuilder):
         names = self.feature_names()
         y_dev, category, resp_domain = self.response_info()
         self._interaction_spec = None
+        self._streamed_pairs = []
         if getattr(p, "interactions", None) \
                 or getattr(p, "interaction_pairs", None):
             if category == "Multinomial" or getattr(p, "HGLM", False):
@@ -1276,12 +1518,40 @@ class GLM(ModelBuilder):
             self._interaction_spec = _freeze_interaction_pairs(
                 fr, p.interactions, getattr(p, "interaction_pairs", None),
                 reserved)
-            fr, extra = _apply_interactions(fr, self._interaction_spec)
+            streamed = self._streamed_specs(fr, names, self._interaction_spec)
+            self._streamed_pairs = [(s["a"], s["b"]) for s in streamed]
+            fr, extra = _apply_interactions(
+                fr, [s for s in self._interaction_spec if s not in streamed])
             names = names + extra
         if getattr(p, "HGLM", False):
             return self._build_hglm(job, names, y_dev, category)
         return self._build_single(job, p, fr, names, y_dev, category,
                                   resp_domain)
+
+    def _streamed_specs(self, fr: Frame, names, specs) -> list:
+        """The numeric x numeric pairs `_fit`'s IRLS path forms inside its
+        step (`_make_interact_step`) instead of appending: both columns
+        are predictors of the design, and the solver reads the Gram (not
+        L-BFGS, which takes the design whole) of a design with its rows on
+        the mesh (not ``feature_parallelism``). The rest are appended
+        (`_apply_interactions`). A product's name must not be a column's or
+        another product's, as when appended."""
+        p = self.params
+        if ((p.solver or "").upper() in ("L_BFGS", "LBFGS")
+                or p.feature_parallelism > 1):
+            return []
+        cand = [s for s in specs if s["kind"] == "numnum"
+                and s["a"] in names and s["b"] in names]
+        out, seen = [], set(fr.names)
+        for s in cand:
+            nm = f"{s['a']}_{s['b']}"
+            if nm in seen:
+                raise ValueError(
+                    f"interactions: generated column name '{nm}' collides "
+                    f"with an existing column — rename it")
+            seen.add(nm)
+            out.append(s)
+        return out
 
     def _build_alpha_search(self, job: Job) -> Model:
         """`alpha` given as an ARRAY (`hex/glm/GLM.java` submodel scan over
@@ -1335,6 +1605,15 @@ class GLM(ModelBuilder):
                 fr, names, standardize=p.standardize,
                 missing_values_handling=p.missing_values_handling)
             X, okrow = dinfo.expand(fr)
+            pairs = getattr(self, "_streamed_pairs", None)
+            if pairs:
+                X = dinfo.mark_missing(X, fr, pairs)
+                with telemetry.span("train.glm.interact.moments",
+                                    pairs=len(pairs)) as mspan:
+                    dinfo.add_products(X, pairs, fr.nrow)
+                    mspan.attrs["width"] = dinfo.ncols_expanded + 1
+                telemetry.inc("train.glm.interact.pairs_streamed", len(pairs))
+            self._stream = _Products(dinfo) if pairs else None
             X, y_dev, pad_cols = _shard_cols(X, y_dev, p.feature_parallelism)
             y = jnp.nan_to_num(y_dev)
             w = ((~jnp.isnan(y_dev)).astype(jnp.float32)
@@ -1442,7 +1721,9 @@ class GLM(ModelBuilder):
         """Std errors / z-values / p-values from the inverse Fisher
         information at the solution (`hex/glm/GLM.java` computeSubmodel
         p-values path). Unpenalized-fit requirement enforced in _validate."""
-        step = _make_irls_kernel(family)
+        stream = getattr(self, "_stream", None)
+        step = (_make_irls_kernel(family) if stream is None
+                else stream.step(family))
         ones = jnp.ones((X.shape[0], 1), jnp.float32)
         Xi = jnp.concatenate([X, ones], axis=1)
         G, _, _, _ = step(Xi, y, w, jnp.asarray(beta, jnp.float32), offset)
@@ -1496,8 +1777,13 @@ class GLM(ModelBuilder):
     # -- the IRLS driver (`hex/glm/GLM.java:1682` GLMDriver.computeImpl) ------
     def _fit(self, X, y, w, offset, family, job):
         p = self.params
-        P = X.shape[1]
-        step = _make_irls_kernel(family)
+        stream = getattr(self, "_stream", None)
+        if stream is None:
+            P, width = X.shape[1], None
+            step, dev_probe = _make_irls_kernel(family), _make_dev_kernel(family)
+        else:   # numeric pairs formed in the step's row blocks, never stored
+            P, width = stream.width - 1, stream.width
+            step, dev_probe = stream.step(family), stream.probe(family)
         alpha = p.alpha if p.alpha is not None else 0.5
         with telemetry.span("train.glm.design") as design_span:
             ones = jnp.ones((X.shape[0], 1), jnp.float32)
@@ -1518,7 +1804,7 @@ class GLM(ModelBuilder):
             nulldev = float(jnp.sum(family.deviance(y, mu0, w)))
             neff = float(jnp.sum(w))
 
-            gram_plan = _gram_plan_attrs(Xi, w, offset)
+            gram_plan = _gram_plan_attrs(Xi, w, offset, width)
         if p.lambda_search:
             with telemetry.span("train.glm.gram", **gram_plan):
                 G0, b_, _, _ = step(Xi, y, w, jnp.asarray(beta, jnp.float32),
@@ -1553,7 +1839,6 @@ class GLM(ModelBuilder):
                 lo_b, hi_b = self._bounds
                 cod_lo, cod_hi = np.maximum(cod_lo, lo_b), np.minimum(cod_hi, hi_b)
 
-        dev_probe = _make_dev_kernel(family)
         admm_state: dict = {}  # (z, u) warm start across IRLS/path solves
 
         # the path: one entry a lambda fitted, each warm-started at the last

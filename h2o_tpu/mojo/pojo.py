@@ -192,6 +192,9 @@ def _glm_pojo(model, class_name) -> str:
     out = model.output
     cat = out.model_category
     di = model.dinfo
+    if getattr(di, "products", ()):
+        raise NotImplementedError(
+            "GLM POJO of numeric interactions formed in the IRLS step")
     cats = [n for n, c in zip(di.names, di.is_cat) if c]
     nums = [n for n, c in zip(di.names, di.is_cat) if not c]
     lo = 0 if di.use_all_factor_levels else 1

@@ -227,6 +227,10 @@ def _write_glm_mojo(model, path: str):
             "ordinal GLM MOJO export: follow-up (needs a threshold spec; the "
             "reference's GlmOrdinalMojoModel)")
     di = model.dinfo
+    if getattr(di, "products", ()):
+        raise NotImplementedError(
+            "GLM MOJO export of numeric interactions formed in the IRLS "
+            "step: the MOJO has no product columns to read them from")
     cats = [n for n, c in zip(di.names, di.is_cat) if c]
     nums = [n for n, c in zip(di.names, di.is_cat) if not c]
     columns = cats + nums + [model.params.response_column]

@@ -189,6 +189,13 @@ _counter("train.glm.program.built",
          "calls of the GLM's two program factories that built the program: "
          "a family's first train in the process, or its first after a cache "
          "sweep or an eviction")
+_counter("train.glm.interact.pairs_streamed",
+         "numeric x numeric interaction pairs a GLM job formed inside its "
+         "IRLS step's row blocks (never stored as columns), added once a job")
+_counter("train.glm.interact.columns_appended",
+         "interaction columns glm._apply_interactions appended to a frame "
+         "copy: categorical pairs, and numeric pairs on the paths that do "
+         "not stream them (L-BFGS, GAM, CoxPH, scoring of such models)")
 _counter("train.gam.iterations",
          "IRLS iterations a GAM job ran, added once a job")
 _counter("train.gam.design_bytes",
@@ -449,6 +456,12 @@ PROGRAMS: tuple[str, ...] = (
     "metrics_mauc",         # metrics._mauc_kernel: the multinomial AUC family
     "glm_probe",            # glm._make_dev_kernel: the deviance probe
     "glm_irls_sharded",     # glm._make_irls_kernel: the step under shard_map
+    "glm_interact_step",    # glm._make_interact_step: IRLS step, products
+                            # formed per row block (one shard or shard_map)
+    "glm_interact_eta",     # glm._make_interact_eta: probabilities and
+                            # deviance through the products' quadratic form
+    "glm_interact_moments",  # datainfo._moments_program: the products'
+                             # statistics from the design's columns
     # the one name without its layer's prefix: the benchmark's
     # irls_program_s reads the XLA module jit__core by name
     "_core",                # glm._make_irls_kernel: the IRLS step, one shard

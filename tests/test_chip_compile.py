@@ -562,3 +562,38 @@ def test_gam_design_is_written_in_place_for_v5e_at_higgs_rows(v5e):
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 64 << 20
     assert mem.output_size_in_bytes < 4.0e9     # 85 -> 88 sublanes, not 128
+
+
+# ---------------------------------------------------------------------------
+# the GLM with all pairwise interactions of HIGGS's 28 columns (the cell
+# higgs_glm_interact_train): the streamed IRLS step at P = 407
+# ---------------------------------------------------------------------------
+def test_the_streamed_interaction_step_never_holds_the_design(v5e):
+    """`glm._make_interact_step` at 11,010,048 rows, 28 mains and their 378
+    products: planned on the full width (136 blocks of 80,896 rows and an
+    8,192-row tail, `gram.block_plan(R, 407)`), it reads the (R, 29) main
+    design and builds each block's 407 columns in the loop body. The
+    compiled program holds no buffer of R x 406 cells or more (a stored
+    design would be 17.9 GB, over the chip) and under 1 GB of temporaries;
+    its XLA module is the declared ``jit_glm_interact_step``."""
+    from h2o_tpu.models import glm
+
+    mesh = make_mesh(v5e[:1])
+    k = F
+    pairs = tuple((i, j) for i in range(k) for j in range(i + 1, k))
+    P1 = k + len(pairs) + 1
+    assert (P1, gram.block_plan(HIGGS_PLEN, P1)) == (407, (136, 80896, 8192))
+    step = glm._make_interact_step(glm.BinomialF(), (k, tuple(range(k)),
+                                                     pairs))
+    vec = lambda n: _spec(mesh, (n,), jnp.float32)  # noqa: E731
+    compiled = step.one_shard.lower(
+        _spec(mesh, (HIGGS_PLEN, k + 1), jnp.float32), vec(HIGGS_PLEN),
+        vec(HIGGS_PLEN), vec(P1), vec(HIGGS_PLEN), vec(k), vec(k),
+        vec(len(pairs)), vec(len(pairs))).compile()
+    hlo = compiled.as_text()
+    assert "jit_glm_interact_step" in hlo.split("\n", 1)[0]
+    cells = [int(np.prod([int(d) for d in dims.split(",")]))
+             for dims in re.findall(r"\[([\d,]+)\]", hlo)]
+    assert max(cells) < HIGGS_PLEN * (P1 - 1), max(cells)
+    assert max(cells) == HIGGS_PLEN * (k + 1)      # the main design itself
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30

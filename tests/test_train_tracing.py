@@ -137,6 +137,21 @@ def _glm_texts():
     return irls.compile().as_text(), probe.compile().as_text()
 
 
+def _interact_texts():
+    """The streamed-interaction step and eta program (plain jit, one
+    shard) at three columns and their three pairs."""
+    from h2o_tpu.models import glm as glm_mod
+
+    fam = glm_mod.BinomialF()
+    layout = (3, (0, 1, 2), ((0, 1), (0, 2), (1, 2)))
+    X, row = jnp.ones((1024, 4), jnp.float32), jnp.ones(1024, jnp.float32)
+    beta, three = jnp.zeros(7, jnp.float32), jnp.ones(3, jnp.float32)
+    args = (X, row, row, beta, row, three, three, three, three)
+    return tuple(
+        make(fam, layout).one_shard.lower(*args).compile().as_text()
+        for make in (glm_mod._make_interact_step, glm_mod._make_interact_eta))
+
+
 def _binning_texts():
     from h2o_tpu.models.tree import binning
 
@@ -185,7 +200,9 @@ def hlo():
         irls, probe = _glm_texts()
         sketch, bin_col, bin_mat = _binning_texts()
         gam_sums, gam_design = _gam_texts()
+        interact_step, interact_eta = _interact_texts()
         return {"gam_sums": gam_sums, "gam_design": gam_design,
+                "interact_step": interact_step, "interact_eta": interact_eta,
                 "gbm_step_pipelined": _gbm_step_text("1"),
                 "gbm_step_synchronous": _gbm_step_text("0"),
                 "sketch": sketch, "bin_column": bin_col,
@@ -206,7 +223,9 @@ _SCOPE_CASES = (
        ("bin_matrix", "gbm.bin")]
     + [("irls_step", s) for s in ("glm.eta", "glm.gram", "glm.deviance")]
     + [("deviance_probe", s) for s in ("glm.eta", "glm.deviance")]
-    + [("gam_sums", "gam.basis"), ("gam_design", "gam.basis")])
+    + [("gam_sums", "gam.basis"), ("gam_design", "gam.basis")]
+    + [("interact_step", s) for s in ("glm.eta", "glm.gram", "glm.deviance")]
+    + [("interact_eta", s) for s in ("glm.eta", "glm.deviance")])
 
 
 def test_every_declared_scope_has_a_case():
@@ -276,6 +295,12 @@ _TREE["gam"] = {
     "train.gam.metrics": "train.gam", "train.program.load": "train.gam.gram"}
 
 
+# a GLM with numeric interactions (ISSUE 40): the GLM's parts, and the
+# products' one moment pass inside the first design span
+_TREE["glm_interact"] = dict(
+    _TREE["glm"], **{"train.glm.interact.moments": "train.glm.design"})
+
+
 def _train_gam(fr, **kw):
     from h2o_tpu.models.gam import GAM, GAMParameters
 
@@ -286,6 +311,8 @@ def _train_gam(fr, **kw):
 
 _TRAIN = {"gbm": _train_gbm, "glm": _train_glm, "xgboost": _train_xgboost,
           "gam": _train_gam,
+          "glm_interact": lambda fr: _train_glm(
+              fr, interactions=[f"x{i}" for i in range(_F)]),
           "glm_search": lambda fr: _train_glm(
               fr, lambda_=None, lambda_search=True, nlambdas=6)}
 _ROOT = {"xgboost": "train.xgboost"}
@@ -330,6 +357,35 @@ def test_train_records_the_span_tree(algo):
                       dict.fromkeys(counts, 0) if algo == "gam" else
                       {"train.gbm.prep": 0, "train.gbm.finish": 0,
                        "train.glm.start": 1, "train.glm.finish": 1})
+
+
+def test_an_interaction_job_names_its_moments_and_programs():
+    """ONE ``train.glm.interact.moments`` a job, with the pairs and the
+    Gram's width; the step is registered under ``train.glm.irls.*`` (the
+    benchmark counts a job's iterations by that prefix) with the module
+    ``jit_glm_interact_step``; the probe and the metrics' probabilities
+    compile as ``glm_interact_eta``, the moments as
+    ``glm_interact_moments``; the plain step and probe never run."""
+    from h2o_tpu.models import glm as glm_mod
+    from h2o_tpu.utils import programs
+
+    glm_mod.drop_kept_programs()
+    fr = _frame(_N + 24)
+    events = _events_of(lambda: _TRAIN["glm_interact"](fr))
+    (m,) = _spans(events, "train.glm.interact.moments")
+    pairs = _F * (_F - 1) // 2
+    assert (m["pairs"], m["width"]) == (pairs, _F + pairs + 1)
+    assert len(_spans(events, "train.glm.design")) == 2
+    for g in _spans(events, "train.glm.gram"):
+        assert g["gram_blocks"] == 1       # planned on the Gram's width
+    compiled = {e["what"] for e in events if e["kind"] == "compile"}
+    assert {"jit(glm_interact_step)", "jit(glm_interact_eta)",
+            "jit(glm_interact_moments)"} <= compiled
+    assert not {"jit(_core)", "jit(glm_probe)",
+                "jit(glm_irls_sharded)"} & compiled
+    recs = [r for r in programs.snapshot().values()
+            if r["name"].startswith("train.glm.irls.binomial.interact")]
+    assert recs and {r["module"] for r in recs} == {"jit_glm_interact_step"}
 
 
 @pytest.mark.parametrize("algo", ["gbm", "glm"])
