@@ -6,6 +6,11 @@ of the two accumulations every hot loop in this repo bottoms out in.
   and fused with the previous level's routing (`streamed_route_hist`).
 - `gram`: the GLM/PCA weighted Gram (XᵀWX + XᵀWz), `gram_accumulate`, and
   the row blocks it cuts a design into, `block_plan`.
+- `interact` (imported by the streamed interaction step alone): the Gram
+  of a design whose pairwise products are never stored, ONE Pallas kernel
+  that forms each row tile of ``[mains, products, 1]`` in VMEM and
+  contracts it there (`interaction_gram`, `plan`). Pallas is imported
+  when that kernel is built, never with this package.
 
 Each is a loop over row blocks whose body adds one block's contribution
 (`hist._contrib`, flat or two digits by `hist.digit_split`, under
@@ -16,7 +21,8 @@ histograms, a ``lax.fori_loop`` that slices each block out of the design in
 place for the Gram (as ``xs`` of a scan the f32 design was copied whole and
 re-tiled a block). It is the formulation that compiles for the TPU
 (tests/test_chip_compile.py) and the one the chip has run; callers own the
-mesh concerns (psum, scatter-back).
+mesh concerns (psum, scatter-back). `interact`'s kernel is the exception:
+a grid over row tiles whose body forms the design it contracts.
 """
 
 from __future__ import annotations

@@ -121,10 +121,10 @@ def over_row_blocks(R: int, P: int, carry, add, *, block: int | None = None):
     P, block)`, ascending, the tail last: a `fori_loop` over the block
     index whose body slices its rows in place. The one loop of every
     blocked pass over a frame's rows: `gram_accumulate`, the streamed
-    interaction step (`glm._make_interact_step`, whose (rows, 407) block
-    is built in the body from the (R, 29) main design and never lies in
-    HBM) and GAM's design programs. ``P`` is the width the block budget is
-    planned on; ``carry`` is any tree of arrays."""
+    interaction step's eta, weights and working response
+    (`glm._make_interact_step`; its Gram is `interact.interaction_gram`'s
+    kernel) and GAM's design programs. ``P`` is the width the block budget
+    is planned on; ``carry`` is any tree of arrays."""
     nblk, rb, tail = block_plan(R, P, block)
     if nblk == 1 and not tail:
         return add(carry, 0, R)

@@ -231,18 +231,25 @@ def _step_row_shards(X, w, offset, mesh) -> int:
     return 1
 
 
-def _gram_plan_attrs(X, w, offset, width: int | None = None) -> dict:
-    """``train.glm.gram``'s attributes: the row blocks `gram_accumulate`
-    cuts the design into inside the step (a shard's rows under
-    ``shard_map``), from `gram.block_plan` on the shapes alone. ``width``:
-    the Gram's, where the step builds its blocks wider than ``X``
-    (`_make_interact_step`)."""
-    from ..backend.kernels import gram as gram_kernels
+def _gram_plan_attrs(X, w, offset, layout=None) -> dict:
+    """``train.glm.gram``'s attributes, from the shapes alone (a shard's
+    rows under ``shard_map``): the row blocks `gram_accumulate` cuts the
+    design into inside the step (`gram.block_plan`); for a design with
+    streamed products (``layout``, `_make_interact_step`), the Pallas
+    kernel's plan (`interact.plan`): its tile rows, tiles and formed
+    width, with ``gram_form`` ``"vmem"``."""
     from ..parallel.mesh import default_mesh
 
-    ns = _step_row_shards(X, w, offset, default_mesh())
-    nblk, rb, tail = gram_kernels.block_plan(X.shape[0] // ns,
-                                             width or X.shape[1])
+    R = X.shape[0] // _step_row_shards(X, w, offset, default_mesh())
+    if layout is not None:
+        from ..backend.kernels import interact
+
+        p = interact.plan(R, layout)
+        return {"gram_tile_rows": p.tile, "gram_tiles": p.tiles,
+                "gram_width": p.width, "gram_form": "vmem"}
+    from ..backend.kernels import gram as gram_kernels
+
+    nblk, rb, tail = gram_kernels.block_plan(R, X.shape[1])
     return {"gram_blocks": nblk, "gram_block_rows": rb,
             "gram_tail_rows": tail}
 
@@ -414,22 +421,6 @@ def _make_dev_kernel(family: Family):
 # two positions in ``cols``; the model's columns are the Pm mains, then the
 # pairs in order, then the intercept.
 # ---------------------------------------------------------------------------
-def _pair_products(x, pairs):
-    """(rows, len(pairs)) products ``x[:, i] * x[:, j]``, as slices: a run
-    of pairs with one ``i`` and consecutive ``j`` is one broadcast product
-    (all pairs of 28 columns: 27 of them), no gather."""
-    runs, n = [], 0
-    while n < len(pairs):
-        i, j0 = pairs[n]
-        m = n + 1
-        while (m < len(pairs) and pairs[m][0] == i
-               and pairs[m][1] == pairs[m - 1][1] + 1):
-            m += 1
-        runs.append(x[:, i:i + 1] * x[:, j0:pairs[m - 1][1] + 1])
-        n = m
-    return runs[0] if len(runs) == 1 else jnp.concatenate(runs, axis=1)
-
-
 def _interact_eta(layout, X, x, beta, pshift, pscale):
     """The linear predictor less the offset, with no product column:
     ``c + zm' b_m + x' G x`` where ``x`` holds the raw values of the paired
@@ -480,25 +471,30 @@ def _make_interact_step(family: Family, layout):
     offset, xshift, xscale, pshift, pscale) -> (Gram, XWz, dev, neff), the
     same four numbers as `_make_irls_kernel`'s step over the (R, P + 1)
     design ``[mains, products, 1]``, which it never holds: ``X`` is the
-    (R, Pm + 1) main design with its column of ones last, and each row
-    block of `gram.block_plan` on the FULL width is built in the loop body
-    (the raw paired values ``x = X[:, cols] * xscale + xshift``, their
-    products standardised as ``(x_i x_j - pshift) / pscale``), its eta
-    computed by `_interact_eta` and contributed by `gram.block_contrib`:
-    float32 products, operands at the default matmul precision, float32
-    accumulation, as the main step. Kept like that step; registered as
+    (R, Pm + 1) main design with its column of ones last. Eta, the weights
+    and the working response are computed once over all rows, a row block
+    of `gram.over_row_blocks` at a time, by `_interact_eta`'s quadratic
+    form; the Gram and its right-hand side by the kernels layer's Pallas
+    kernel (`interact.interaction_gram`), which forms each row tile of the
+    design in VMEM (the raw paired values ``x = X[:, cols] * xscale +
+    xshift``, their products standardised as ``(x_i x_j - pshift) /
+    pscale``) and contracts it there: the Gram's operands rounded once to
+    bfloat16 with float32 accumulation, as the main step's, the right-hand
+    side in float32. Kept like that step; registered as
     ``train.glm.irls.<family>.interact`` (`drive_train.irls_dispatches`
-    counts ``train.glm.irls.*``)."""
+    counts ``train.glm.irls.*``); each dispatch adds its rows to
+    ``train.glm.interact.kernel_rows``."""
     from ..backend.kernels import gram as gram_kernels
+    from ..backend.kernels import interact
     from ..parallel.mesh import ROWS, default_mesh
     from ..utils import programs
 
-    Pm, cols, pairs = layout
-    P1 = Pm + len(pairs) + 1
+    _, cols, _ = layout
 
     @telemetry.program("glm_interact_step")
     def core(X, y, w, beta, offset, xshift, xscale, pshift, pscale):
-        def contrib(start, rows):
+        def add(acc, start, rows):
+            Wr, zr, dev, neff = acc
             xb, yb, wb, ob = (jax.lax.dynamic_slice_in_dim(a, start, rows, 0)
                               for a in (X, y, w, offset))
             x = lanes(xb, cols) * xscale + xshift
@@ -510,25 +506,22 @@ def _make_interact_step(family: Family, layout):
                 W = wb * d * d / jnp.maximum(V, 1e-10)
                 z = eta - ob + (yb - mu) / jnp.where(jnp.abs(d) < 1e-10,
                                                      1e-10, d)
-            with telemetry.scope("glm.gram"):
-                # an NA (NaN) imputes its main and its products to their
-                # means: nought on the standardised scale
-                zp = (_pair_products(x, pairs) - pshift) / pscale
-                Zb = jnp.concatenate([xb[:, :Pm], zp, xb[:, Pm:]], axis=1)
-                Zb = jnp.where(jnp.isnan(Zb), 0.0, Zb)
-                dG, db = gram_kernels.block_contrib(Zb, W, z)
             with telemetry.scope("glm.deviance"):
-                dev = jnp.sum(family.deviance(yb, mu, wb))
-            return dG, db, dev, jnp.sum(wb)
+                dev = dev + jnp.sum(family.deviance(yb, mu, wb))
+            put = lambda a, v: jax.lax.dynamic_update_slice_in_dim(  # noqa: E731
+                a, v, start, 0)
+            return put(Wr, W), put(zr, z), dev, neff + jnp.sum(wb)
 
-        def add(acc, start, rows):
-            return jax.tree.map(jnp.add, acc, contrib(start, rows))
-
+        # W and z once over all rows, a row block at a time (a block's
+        # (rows, k) paired values are the largest temporaries)
+        row = jnp.zeros(X.shape[:1], jnp.float32)
         zero = jnp.zeros((), jnp.float32)
-        return gram_kernels.over_row_blocks(
-            X.shape[0], P1,
-            (jnp.zeros((P1, P1), jnp.float32), jnp.zeros((P1,), jnp.float32),
-             zero, zero), add)
+        W, z, dev, neff = gram_kernels.over_row_blocks(
+            X.shape[0], 4 * len(cols), (row, row, zero, zero), add)
+        with telemetry.scope("glm.gram"):
+            G, b = interact.interaction_gram(X, W, z, xshift, xscale,
+                                             pshift, pscale, layout)
+        return G, b, dev, neff
 
     name = f"train.glm.irls.{getattr(family, 'name', 'family')}.interact"
     jit_step = programs.tracked(name, jax.jit(core), "train")
@@ -538,6 +531,7 @@ def _make_interact_step(family: Family, layout):
     def step(X, y, w, beta, offset, *args):
         mesh = default_mesh()
         ns = _step_row_shards(X, w, offset, mesh)
+        telemetry.inc("train.glm.interact.kernel_rows", X.shape[0])
         if ns == 1:
             return jit_step(X, y, w, beta, offset, *args)
         prog = sharded.get(mesh)
@@ -1779,10 +1773,10 @@ class GLM(ModelBuilder):
         p = self.params
         stream = getattr(self, "_stream", None)
         if stream is None:
-            P, width = X.shape[1], None
+            P, layout = X.shape[1], None
             step, dev_probe = _make_irls_kernel(family), _make_dev_kernel(family)
-        else:   # numeric pairs formed in the step's row blocks, never stored
-            P, width = stream.width - 1, stream.width
+        else:   # numeric pairs formed in the step's row tiles, never stored
+            P, layout = stream.width - 1, stream.layout
             step, dev_probe = stream.step(family), stream.probe(family)
         alpha = p.alpha if p.alpha is not None else 0.5
         with telemetry.span("train.glm.design") as design_span:
@@ -1804,7 +1798,7 @@ class GLM(ModelBuilder):
             nulldev = float(jnp.sum(family.deviance(y, mu0, w)))
             neff = float(jnp.sum(w))
 
-            gram_plan = _gram_plan_attrs(Xi, w, offset, width)
+            gram_plan = _gram_plan_attrs(Xi, w, offset, layout)
         if p.lambda_search:
             with telemetry.span("train.glm.gram", **gram_plan):
                 G0, b_, _, _ = step(Xi, y, w, jnp.asarray(beta, jnp.float32),

@@ -192,6 +192,10 @@ _counter("train.glm.program.built",
 _counter("train.glm.interact.pairs_streamed",
          "numeric x numeric interaction pairs a GLM job formed inside its "
          "IRLS step's row blocks (never stored as columns), added once a job")
+_counter("train.glm.interact.kernel_rows",
+         "rows the streamed interaction step's Pallas kernel formed and "
+         "contracted (`interact.interaction_gram`), added at each dispatch "
+         "from shapes alone: R a pass, summed over row shards")
 _counter("train.glm.interact.columns_appended",
          "interaction columns glm._apply_interactions appended to a frame "
          "copy: categorical pairs, and numeric pairs on the paths that do "

@@ -570,21 +570,26 @@ def test_gam_design_is_written_in_place_for_v5e_at_higgs_rows(v5e):
 # ---------------------------------------------------------------------------
 def test_the_streamed_interaction_step_never_holds_the_design(v5e):
     """`glm._make_interact_step` at 11,010,048 rows, 28 mains and their 378
-    products: planned on the full width (136 blocks of 80,896 rows and an
-    8,192-row tail, `gram.block_plan(R, 407)`), it reads the (R, 29) main
-    design and builds each block's 407 columns in the loop body. The
-    compiled program holds no buffer of R x 406 cells or more (a stored
-    design would be 17.9 GB, over the chip) and under 1 GB of temporaries;
-    its XLA module is the declared ``jit_glm_interact_step``."""
+    products: it reads the (R, 29) main design, and its Gram is ONE Mosaic
+    kernel (`interact.interaction_gram`, a ``tpu_custom_call``) that forms
+    each tile of 2,048 rows of the 407 columns in VMEM (5,376 tiles, the
+    design and z padded to 512 rows, one Gram block). The compiled program
+    holds no buffer of R x 406 cells or more (a stored design would be
+    17.9 GB, over the chip), no buffer of 378 or 407 columns by more than a
+    tile's rows (the (80896, 378) product block the loop body once built
+    is gone) and under 1 GB of temporaries; ``X.T`` is a bitcast, not a
+    copy; its XLA module is the declared ``jit_glm_interact_step``."""
+    from h2o_tpu.backend.kernels import interact
     from h2o_tpu.models import glm
 
     mesh = make_mesh(v5e[:1])
     k = F
     pairs = tuple((i, j) for i in range(k) for j in range(i + 1, k))
     P1 = k + len(pairs) + 1
-    assert (P1, gram.block_plan(HIGGS_PLEN, P1)) == (407, (136, 80896, 8192))
-    step = glm._make_interact_step(glm.BinomialF(), (k, tuple(range(k)),
-                                                     pairs))
+    layout = (k, tuple(range(k)), pairs)
+    plan = interact.plan(HIGGS_PLEN, layout)
+    assert (P1, plan) == (407, (2048, 5376, 512, 512))
+    step = glm._make_interact_step(glm.BinomialF(), layout)
     vec = lambda n: _spec(mesh, (n,), jnp.float32)  # noqa: E731
     compiled = step.one_shard.lower(
         _spec(mesh, (HIGGS_PLEN, k + 1), jnp.float32), vec(HIGGS_PLEN),
@@ -592,8 +597,17 @@ def test_the_streamed_interaction_step_never_holds_the_design(v5e):
         vec(len(pairs)), vec(len(pairs))).compile()
     hlo = compiled.as_text()
     assert "jit_glm_interact_step" in hlo.split("\n", 1)[0]
-    cells = [int(np.prod([int(d) for d in dims.split(",")]))
-             for dims in re.findall(r"\[([\d,]+)\]", hlo)]
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
+                          hlo)) == 1
+    shapes = [[int(d) for d in dims.split(",")]
+              for dims in re.findall(r"\[([\d,]+)\]", hlo)]
+    cells = [int(np.prod(dims)) for dims in shapes]
     assert max(cells) < HIGGS_PLEN * (P1 - 1), max(cells)
     assert max(cells) == HIGGS_PLEN * (k + 1)      # the main design itself
+    wide = [dims for dims in shapes if {len(pairs), P1} & set(dims)]
+    by = [int(np.prod(dims)) // max({len(pairs), P1} & set(dims))
+          for dims in wide]
+    assert wide and max(by) <= plan.tile, wide
+    moved = re.findall(r"= (\S+) (?:copy|transpose)\(", hlo)
+    assert not [sh for sh in moved if str(HIGGS_PLEN) in sh], moved
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30

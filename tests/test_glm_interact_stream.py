@@ -260,3 +260,166 @@ def test_lambda_search_and_p_values_run_on_the_streamed_step(data):
     assert set(pv.p_values) == set(names)
     assert all(np.isfinite(pv.std_errs[n]) and pv.std_errs[n] > 0
                for n in names)
+
+
+# ---------------------------------------------------------------------------
+# the step's Pallas kernel (`interact.interaction_gram`), interpreted on the CPU:
+# (G, b) against numpy's float64 Gram of the explicitly built design, and
+# the step's (G, b, deviance, neff) around it
+# ---------------------------------------------------------------------------
+def _kernel_case(case):
+    """(layout, X, y, w, beta, xshift, xscale, pshift, pscale, budget)."""
+    rng = np.random.default_rng(41)
+    R, Pm, k = 2048, 5, 5
+    cols, budget = tuple(range(5)), None
+    pairs = tuple((i, j) for i in range(k) for j in range(i + 1, k))
+    if case == "partial_tile":
+        R = 3001
+    if case == "cols_subset":        # paired columns 0, 2, 3, 5, 6 of 7
+        Pm, cols = 7, (0, 2, 3, 5, 6)
+        pairs = ((0, 1), (0, 3), (1, 2), (1, 4), (2, 3), (3, 4))
+    if case == "gram_blocks":        # 16 columns: P1 + 1 = 138 -> 2 x 128
+        Pm = k = 16
+        cols = tuple(range(k))
+        pairs = tuple((i, j) for i in range(k) for j in range(i + 1, k))
+        budget = 1 << 20
+    X = (rng.normal(size=(R, Pm + 1)) * 1.3 + 0.2).astype(np.float32)
+    X[:, -1] = 1.0
+    if case == "nan":
+        X[::13, cols[1]] = np.nan
+        X[5::29, cols[3]] = np.nan
+    y = (rng.random(R) < 0.4).astype(np.float32)
+    w = np.ones(R, np.float32)
+    if case == "zero_weights":
+        w[R // 3:R // 2] = 0.0
+        w[::7] = 0.0
+    xshift = rng.normal(size=len(cols)).astype(np.float32)
+    xscale = (0.5 + rng.random(len(cols))).astype(np.float32)
+    pshift = rng.normal(size=len(pairs)).astype(np.float32)
+    pscale = (0.5 + 2 * rng.random(len(pairs))).astype(np.float32)
+    if case == "unstandardised":
+        xshift, xscale = np.zeros_like(xshift), np.ones_like(xscale)
+        pshift, pscale = np.zeros_like(pshift), np.ones_like(pscale)
+    beta = (0.05 * rng.normal(size=Pm + len(pairs) + 1)).astype(np.float32)
+    return ((Pm, cols, pairs), X, y, w, beta, xshift, xscale, pshift,
+            pscale, budget)
+
+
+def _built_design(layout, X, xshift, xscale, pshift, pscale):
+    """The float32 ``[mains, products, 1]`` design the kernel forms, built
+    here in numpy by the kernel's own float32 steps, NaN set to 0."""
+    Pm, cols, pairs = layout
+    x = X[:, list(cols)] * xscale + xshift
+    ia, ib = (np.array(a) for a in zip(*pairs))
+    prods = (x[:, ia] * x[:, ib] - pshift) * (np.float32(1) / pscale)
+    Z = np.concatenate([X[:, :Pm], prods, X[:, Pm:]], axis=1)
+    return np.where(np.isnan(Z), np.float32(0), Z).astype(np.float32)
+
+
+def _bf16(a):
+    import jax.numpy as jnp
+
+    return np.asarray(jnp.asarray(a, jnp.float32).astype(jnp.bfloat16)
+                      .astype(jnp.float32), np.float64)
+
+
+@pytest.mark.parametrize("case", ["partial_tile", "nan", "zero_weights",
+                                  "cols_subset", "unstandardised",
+                                  "gram_blocks"])
+def test_the_kernel_is_the_built_design_s_gram(case):
+    """The kernel with its Gram's operands rounded to bfloat16 (the chip's
+    precision) against the float64 Gram of the same bfloat16 operands and
+    the float64 sum of its float32 right-hand side; the step (float32
+    operands where the kernel is interpreted, as the step's matmul had
+    there) against the float64 Gram, right-hand side and deviance of the
+    unrounded design. Tolerances, of the largest entry: the kernel's Gram
+    3e-5 (float32 sums, about 1e-7, and the few operands whose float32
+    value it forms one ulp apart from numpy, a fused multiply-add, and so
+    rounds to the other bfloat16 neighbour: up to 1.5e-6 on these cases;
+    the unrounded Gram lies 1e-4 to 3e-4 away, so a kernel that skipped
+    the rounding fails); every float32 sum 3e-6 (read: up to 3.4e-7; a
+    right-hand side from bfloat16 operands lies 1e-4 and more away)."""
+    import jax.numpy as jnp
+
+    from h2o_tpu.backend.kernels import interact
+    from h2o_tpu.models import glm
+
+    (layout, X, y, w, beta, xshift, xscale, pshift, pscale,
+     budget) = _kernel_case(case)
+    plan = interact.plan(X.shape[0], layout, budget)
+    if case == "gram_blocks":
+        assert plan.width // plan.block == 2
+    if case == "partial_tile":
+        assert X.shape[0] % plan.tile
+    Z = _built_design(layout, X, xshift, xscale, pshift, pscale)
+    Z64 = Z.astype(np.float64)
+    eta = Z64 @ beta.astype(np.float64)
+    mu = 1 / (1 + np.exp(-eta))
+    W = w * mu * (1 - mu)
+    z = eta + (y - mu) / (mu * (1 - mu))
+    W32, z32 = W.astype(np.float32), z.astype(np.float32)
+    small = tuple(map(jnp.asarray, (xshift, xscale, pshift, pscale)))
+
+    G, b = interact.interaction_gram(
+        jnp.asarray(X), jnp.asarray(W32), jnp.asarray(z32), *small, layout,
+        budget=budget, operands=jnp.bfloat16)
+    ZW32 = Z * W32[:, None]
+    want_G = _bf16(ZW32).T @ _bf16(Z)
+    want_b = ZW32.astype(np.float64).T @ z32
+    np.testing.assert_allclose(np.asarray(G), want_G,
+                               atol=3e-5 * np.abs(want_G).max())
+    np.testing.assert_allclose(np.asarray(b), want_b,
+                               atol=3e-6 * np.abs(want_b).max())
+
+    step = glm._make_interact_step(glm.BinomialF(), layout).one_shard
+    G, b, dev, neff = step(jnp.asarray(X), jnp.asarray(y), jnp.asarray(w),
+                           jnp.asarray(beta), jnp.zeros(X.shape[0]), *small)
+    ZW = Z64 * W[:, None]
+    want_G, want_b = ZW.T @ Z64, ZW.T @ z
+    np.testing.assert_allclose(np.asarray(G), want_G,
+                               atol=3e-6 * np.abs(want_G).max())
+    np.testing.assert_allclose(np.asarray(b), want_b,
+                               atol=3e-6 * np.abs(want_b).max())
+    want_dev = -2 * np.sum(w * (y * np.log(mu) + (1 - y) * np.log(1 - mu)))
+    assert float(dev) == pytest.approx(want_dev, rel=1e-5)
+    assert float(neff) == pytest.approx(w.sum(), rel=1e-6)
+
+
+def test_only_a_streamed_step_imports_pallas():
+    """`jax.experimental.pallas` costs about a second to import; a plain
+    GLM and a GBM never pay it (one fresh process trains both, then a GLM
+    with streamed products, which must)."""
+    import os
+    import subprocess
+    import sys
+
+    code = """
+import sys
+import numpy as np
+from h2o_tpu.frame.frame import Frame
+from h2o_tpu.frame.vec import T_CAT, Vec
+from h2o_tpu.models.gbm import GBM, GBMParameters
+from h2o_tpu.models.glm import GLM, GLMParameters
+
+rng = np.random.default_rng(0)
+X = rng.normal(size=(2000, 3)).astype(np.float32)
+fr = Frame(["a", "b", "c"], [Vec.from_numpy(X[:, i]) for i in range(3)])
+fr.add("y", Vec.from_numpy((X[:, 0] > 0).astype(np.float32), type=T_CAT,
+                           domain=["n", "p"]))
+pallas = lambda: sorted(m for m in sys.modules if m.startswith("jax._src.pallas"))
+GLM(GLMParameters(training_frame=fr, response_column="y",
+                  family="binomial", lambda_=0.0)).train_model()
+GBM(GBMParameters(training_frame=fr, response_column="y", ntrees=2,
+                  max_depth=2)).train_model()
+print("plain", len(pallas()))
+GLM(GLMParameters(training_frame=fr, response_column="y", family="binomial",
+                  lambda_=0.0, interactions=["a", "b"])).train_model()
+print("streamed", len(pallas()) > 0)
+"""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    out = subprocess.run([sys.executable, "-c", code], cwd=root, env=env,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    lines = out.stdout.split("\n")
+    assert "plain 0" in lines and "streamed True" in lines, out.stdout

@@ -361,7 +361,11 @@ def test_train_records_the_span_tree(algo):
 
 def test_an_interaction_job_names_its_moments_and_programs():
     """ONE ``train.glm.interact.moments`` a job, with the pairs and the
-    Gram's width; the step is registered under ``train.glm.irls.*`` (the
+    Gram's width; every ``train.glm.gram`` span carries the Pallas kernel's
+    plan (``gram_form`` ``vmem``, its tile rows, tiles and formed width) and
+    each dispatch adds the design's rows to
+    ``train.glm.interact.kernel_rows``; the step is registered under
+    ``train.glm.irls.*`` (the
     benchmark counts a job's iterations by that prefix) with the module
     ``jit_glm_interact_step``; the probe and the metrics' probabilities
     compile as ``glm_interact_eta``, the moments as
@@ -369,15 +373,32 @@ def test_an_interaction_job_names_its_moments_and_programs():
     from h2o_tpu.models import glm as glm_mod
     from h2o_tpu.utils import programs
 
+    from h2o_tpu.backend.kernels import interact
+    from h2o_tpu.parallel.mesh import default_mesh, n_row_shards
+
     glm_mod.drop_kept_programs()
     fr = _frame(_N + 24)
+    rows0 = telemetry.value("train.glm.interact.kernel_rows")
     events = _events_of(lambda: _TRAIN["glm_interact"](fr))
     (m,) = _spans(events, "train.glm.interact.moments")
     pairs = _F * (_F - 1) // 2
     assert (m["pairs"], m["width"]) == (pairs, _F + pairs + 1)
     assert len(_spans(events, "train.glm.design")) == 2
-    for g in _spans(events, "train.glm.gram"):
-        assert g["gram_blocks"] == 1       # planned on the Gram's width
+    # the kernel's plan on a shard's rows, and its rows at every dispatch
+    grams = _spans(events, "train.glm.gram")
+    R = fr.vec("x0").data.shape[0]
+    plan = interact.plan(R // n_row_shards(default_mesh()),
+                         (_F, tuple(range(_F)),
+                          tuple((i, j) for i in range(_F)
+                                for j in range(i + 1, _F))))
+    for g in grams:
+        assert {a: g[a] for a in ("gram_form", "gram_tile_rows",
+                                  "gram_tiles", "gram_width")} == {
+            "gram_form": "vmem", "gram_tile_rows": plan.tile,
+            "gram_tiles": plan.tiles, "gram_width": 128}
+        assert "gram_blocks" not in g
+    assert grams and telemetry.value(
+        "train.glm.interact.kernel_rows") - rows0 == len(grams) * R
     compiled = {e["what"] for e in events if e["kind"] == "compile"}
     assert {"jit(glm_interact_step)", "jit(glm_interact_eta)",
             "jit(glm_interact_moments)"} <= compiled
