@@ -398,8 +398,11 @@ def _make_dev_kernel(family: Family):
     deviance plateau WITHOUT paying the Gram a converged solution no
     longer needs (the historic loop burned one full Gram pass per lambda
     purely to confirm convergence — a third of RuleFit's lasso-path
-    wall). Built once a family and kept (`_kept`): the jitted function's
-    own cache holds a signature's executable from job to job."""
+    wall), and only where no lambda follows: a fit at one lambda, or a
+    path's last planned one. Elsewhere on a path the step at the new beta,
+    which the next iteration or lambda takes, gives the deviance. Built
+    once a family and kept (`_kept`): the jitted function's own cache
+    holds a signature's executable from job to job."""
 
     @jax.jit
     @telemetry.program("glm_probe")
@@ -1799,11 +1802,26 @@ class GLM(ModelBuilder):
             neff = float(jnp.sum(w))
 
             gram_plan = _gram_plan_attrs(Xi, w, offset, layout)
-        if p.lambda_search:
+
+        def step_at(beta):
+            """The step at ``beta``: its dispatch, the wait, and (G, b, dev)
+            copied out in one fetch (the copy drains the step)."""
             with telemetry.span("train.glm.gram", **gram_plan):
-                G0, b_, _, _ = step(Xi, y, w, jnp.asarray(beta, jnp.float32),
+                G, b, dev, _ = step(Xi, y, w, jnp.asarray(beta, jnp.float32),
                                     offset)
-                grad0 = np.abs(np.asarray(b_) - np.asarray(G0) @ beta)[:-1]
+                G, b, dev = jax.device_get((G, b, dev))
+            return (np.asarray(G, np.float64), np.asarray(b, np.float64),
+                    float(dev))
+
+        # the step at the current beta when it is already in hand: G and b
+        # depend on beta alone, never on lambda, so the next iteration, or
+        # the next lambda's first, takes it instead of dispatching its own
+        # (an L-BFGS fit runs no IRLS iteration and never takes it)
+        pending = None
+        if p.lambda_search:
+            pending = step_at(beta)
+            G0, b_, _ = pending
+            grad0 = np.abs(b_ - G0 @ beta)[:-1]
             lmax = float(grad0.max()) / max(alpha, 1e-3) / max(neff, 1.0)
             nlambdas = p.nlambdas if p.nlambdas > 0 else (
                 100 if alpha > 0 else 30)
@@ -1844,13 +1862,21 @@ class GLM(ModelBuilder):
         # full Gram passes
         path: list = []
         stopped_early = False
+        steps_as_probe = probes = 0
         with (telemetry.span("train.glm.path", lambdas_planned=len(lambdas))
               if p.lambda_search else contextlib.nullcontext()) as path_span:
-            for lam in lambdas:
+            for k, lam in enumerate(lambdas):
                 job.check_cancelled()
                 if path and job.time_exceeded():
                     break  # keep the last lambda fitted (partial path)
                 lam = float(lam)
+                # while another lambda follows, the step at each new beta is
+                # dispatched at once and its deviance answers the plateau
+                # test: the next iteration, or the next lambda's first, needs
+                # that step anyway. The last planned lambda, a one-lambda
+                # fit's only one, probes instead: there a step dispatched
+                # ahead could be thrown away
+                ahead = k + 1 < len(lambdas)
                 its, dev_final = 0, None
                 if lbfgs:  # its own solver at this lambda: no IRLS below
                     beta, dev_final, its = self._fit_lbfgs(
@@ -1861,13 +1887,9 @@ class GLM(ModelBuilder):
                 for it in range(0 if lbfgs else max(p.max_iterations, 1)):
                     if it and job.time_exceeded():
                         break
-                    # dispatch, the wait for the Gram and its copy out
-                    # (the copy drains the step)
-                    with telemetry.span("train.glm.gram", **gram_plan):
-                        G, b, dev, _ = step(
-                            Xi, y, w, jnp.asarray(beta, jnp.float32), offset)
-                        Gn = np.asarray(G, np.float64)
-                        bn = np.asarray(b, np.float64)
+                    if pending is None:
+                        pending = step_at(beta)
+                    (Gn, bn, dev), pending = pending, None
                     its += 1
                     with telemetry.span("train.glm.solve"):
                         lincon = getattr(self, "_lincon", None)
@@ -1920,24 +1942,33 @@ class GLM(ModelBuilder):
                     # to pay at least two Gram passes)
                     diff = np.max(np.abs(beta_new - beta))
                     beta = beta_new
-                    if diff < p.beta_epsilon:
+                    if ahead:  # the step the next dispatch would make
+                        pending = step_at(beta)
+                        dev_final = pending[2]
+                        steps_as_probe += 1
+                    elif diff < p.beta_epsilon:
                         dev_final = None  # beta moved since `dev` — probe below
                         break
-                    # deviance-plateau check via the CHEAP probe (one matvec)
-                    # at the post-solve beta, instead of discovering the
-                    # plateau one full Gram pass later: same epsilon, same
-                    # criterion, measured one iteration earlier and ~P× cheaper
-                    with telemetry.span("train.glm.probe"):
-                        dev_new = float(dev_probe(
-                            Xi, y, w, jnp.asarray(beta, jnp.float32), offset))
-                    dev_final = dev_new
-                    if (abs(float(dev) - dev_new)
+                    else:
+                        # deviance-plateau check via the CHEAP probe (one
+                        # matvec) at the post-solve beta, instead of
+                        # discovering the plateau one full Gram pass later:
+                        # same epsilon, same criterion, measured one
+                        # iteration earlier and ~P× cheaper
+                        with telemetry.span("train.glm.probe"):
+                            dev_final = float(dev_probe(
+                                Xi, y, w, jnp.asarray(beta, jnp.float32),
+                                offset))
+                        probes += 1
+                    if diff < p.beta_epsilon or (
+                            abs(dev - dev_final)
                             < p.objective_epsilon * abs(nulldev)):
                         break
                 if dev_final is None:
                     with telemetry.span("train.glm.probe"):
                         dev_final = float(dev_probe(
                             Xi, y, w, jnp.asarray(beta, jnp.float32), offset))
+                    probes += 1
                 path.append((lam, dev_final, its, beta.copy()))
                 if (p.lambda_search and p.early_stopping and len(path) > 1
                         and path[-2][1] - dev_final < 1e-4 * abs(nulldev)):
@@ -1948,9 +1979,12 @@ class GLM(ModelBuilder):
                 path_span.attrs.update(
                     lambdas_fit=len(path), iterations=iters_total,
                     active=int(np.count_nonzero(beta[:-1])),
-                    lambda_final=path[-1][0], stopped_early=stopped_early)
+                    lambda_final=path[-1][0], stopped_early=stopped_early,
+                    steps_as_probe=steps_as_probe, probes=probes)
                 telemetry.inc("train.glm.path.lambdas", len(path))
                 telemetry.inc("train.glm.path.iterations", iters_total)
+                telemetry.inc("train.glm.path.steps_as_probe", steps_as_probe)
+                telemetry.inc("train.glm.path.probes", probes)
         lam, dev = path[-1][:2]
         return beta, lam, dev, nulldev, neff, iters_total, path
 

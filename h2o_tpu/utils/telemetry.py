@@ -181,6 +181,13 @@ _counter("train.glm.path.lambdas",
 _counter("train.glm.path.iterations",
          "IRLS iterations (Gram passes) a GLM lambda_search spent on its "
          "path, added once a job")
+_counter("train.glm.path.steps_as_probe",
+         "plateau tests of a GLM lambda_search answered by the deviance of "
+         "the step dispatched ahead at the new beta (another lambda "
+         "follows), added once a job")
+_counter("train.glm.path.probes",
+         "deviance probes a GLM lambda_search dispatched on its path (at "
+         "its last planned lambda only), added once a job")
 _counter("train.glm.program.kept",
          "calls of the GLM's two program factories (IRLS step, deviance "
          "probe) handed the program an earlier call built: nothing is "

@@ -274,13 +274,15 @@ _TREE = {
 }
 # a lambda search has the one recording path: the same parts on the ring,
 # the lambda loop's inside its one train.glm.path (the lambda_max pass
-# keeps its train.glm.gram under the job)
+# keeps its train.glm.gram under the job). A search that stops before its
+# last planned lambda, as this one does, opens NO train.glm.probe: the step
+# dispatched ahead at each new beta answers the plateau test (None: absent)
 _TREE["glm_search"] = {
     "train.glm.design": "train.glm", "train.glm.metrics": "train.glm",
     "train.glm.start": "train.glm", "train.glm.finish": "train.glm",
     "train.glm.path": "train.glm",
     "train.glm.gram": ("train.glm", "train.glm.path"),
-    "train.glm.solve": "train.glm.path", "train.glm.probe": "train.glm.path",
+    "train.glm.solve": "train.glm.path", "train.glm.probe": None,
     "train.program.load": "train.glm.gram"}
 # the XGBoost builder's job: the GBM's parts under ITS root, and no
 # train.gbm span (why the benchmark reads xgb_setup_s, not gbm_setup_s)
@@ -313,8 +315,11 @@ _TRAIN = {"gbm": _train_gbm, "glm": _train_glm, "xgboost": _train_xgboost,
           "gam": _train_gam,
           "glm_interact": lambda fr: _train_glm(
               fr, interactions=[f"x{i}" for i in range(_F)]),
+          # x0 left out: on all four columns the path's deviance keeps
+          # falling to its last lambda; on three it stops at the fifth of six
           "glm_search": lambda fr: _train_glm(
-              fr, lambda_=None, lambda_search=True, nlambdas=6)}
+              fr, lambda_=None, lambda_search=True, nlambdas=6,
+              ignored_columns=["x0"])}
 _ROOT = {"xgboost": "train.xgboost"}
 
 
@@ -334,6 +339,9 @@ def test_train_records_the_span_tree(algo):
     by_id = {e["span"]: e for e in events if e["kind"] == "span"}
     for name, parent in _TREE[algo].items():
         got = _spans(events, name)
+        if parent is None:
+            assert got == [], f"a {name} span"
+            continue
         assert got, f"no {name} span"
         for e in got:
             assert e["trace"] == root["trace"], name
@@ -437,12 +445,14 @@ def test_peak_setting_spans_carry_the_hbm_reading(monkeypatch, algo):
 @pytest.mark.parametrize("search", [True, False])
 def test_a_lambda_search_records_one_path_span_and_counts_it(search):
     """ONE ``train.glm.path`` a ``lambda_search`` job, around the lambda
-    loop, with what the walk did; the two declared counters grow by it
+    loop, with what the walk did; the four declared counters grow by it
     once a job; the ring holds the parts' events plus that one. A job
-    without a search opens none and counts nothing."""
+    without a search opens none and counts nothing. The search stops
+    before its last planned lambda, so every plateau test is answered by
+    the step dispatched ahead (``steps_as_probe``) and none by a probe."""
     fr = _frame()
-    before = {k: telemetry.value(f"train.glm.path.{k}")
-              for k in ("lambdas", "iterations")}
+    counters = ("lambdas", "iterations", "steps_as_probe", "probes")
+    before = {k: telemetry.value(f"train.glm.path.{k}") for k in counters}
     events = _events_of(lambda: _TRAIN["glm_search" if search else "glm"](fr))
     grew = {k: telemetry.value(f"train.glm.path.{k}") - v
             for k, v in before.items()}
@@ -455,22 +465,25 @@ def test_a_lambda_search_records_one_path_span_and_counts_it(search):
         "train.glm.metrics", "train.program.load")]
     assert len(spans) == len(parts) + len(paths)
     if not search:
-        assert paths == [] and grew == {"lambdas": 0, "iterations": 0}
+        assert paths == [] and grew == dict.fromkeys(counters, 0)
         return
     (path,) = paths
     assert path["lambdas_planned"] == 6
-    assert 1 <= path["lambdas_fit"] <= 6
-    assert path["stopped_early"] == (path["lambdas_fit"] < 6)
-    # every iteration is a .gram and a .solve inside the path; the
-    # lambda_max pass is the one .gram outside it
+    assert 1 <= path["lambdas_fit"] < 6 and path["stopped_early"]
+    # every iteration is a .solve inside the path and dispatches the step
+    # at its new beta, a .gram inside it; the lambda_max pass is the one
+    # .gram outside it, and the first iteration's step
     inside = [e for e in spans if e.get("parent") == path["span"]]
     grams = [e for e in inside if e["what"] == "train.glm.gram"]
     assert path["iterations"] == len(grams) == len(
         [e for e in inside if e["what"] == "train.glm.solve"])
     assert len(_spans(events, "train.glm.gram")) == len(grams) + 1
     assert 0 <= path["active"] <= _F and path["lambda_final"] > 0
+    assert _spans(events, "train.glm.probe") == []
+    assert (path["steps_as_probe"], path["probes"]) == (path["iterations"], 0)
     assert grew == {"lambdas": path["lambdas_fit"],
-                    "iterations": path["iterations"]}
+                    "iterations": path["iterations"],
+                    "steps_as_probe": path["iterations"], "probes": 0}
 
 
 @pytest.mark.parametrize("algo", ["glm", "glm_search"])
@@ -482,7 +495,8 @@ def test_glm_gram_spans_carry_the_block_plan(monkeypatch, algo):
     from h2o_tpu.backend.kernels import gram
     from h2o_tpu.parallel import mesh as meshmod
 
-    P = _F + 1                                    # the intercept column
+    # the intercept column; the search leaves x0 out
+    P = _F + (0 if algo == "glm_search" else 1)
     monkeypatch.setattr(gram, "_BLOCK_CELLS", 1024 * P)
     n = 30_000
     rows = meshmod.padded_len(n) // meshmod.n_row_shards()
