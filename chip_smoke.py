@@ -288,7 +288,7 @@ def main(argv=None) -> int:
     import h2o_tpu.api as h2o
     from h2o_tpu.utils import compilemeter
 
-    import bench
+    from benchmark import datagen
 
     warn = _Warnings()
     logging.getLogger("h2o_tpu").addHandler(warn)
@@ -311,7 +311,7 @@ def main(argv=None) -> int:
         ph.done(ready=health["ready"])
 
         ph = _Phase("frame", compile_secs)
-        fr = bench._higgs_frame(args.rows)   # seeded; Frame.from_dict
+        fr, _cols = datagen.higgs_frame(42, args.rows)   # made on the mesh
         jax.block_until_ready([v.data for v in fr.vecs])
         _check_placement(jax, fr, args.rows)
         feats = [n for n in fr.names if n != "response"]

@@ -282,7 +282,7 @@ def _scan_grouped(xgs, lc, vv, groups, n_lv, na_global, rb):
 
 
 # ---------------------------------------------------------------------------
-# public entry — what engine._build_level_hist calls
+# public entries — what the engine's level passes call
 # ---------------------------------------------------------------------------
 def level_hist_one_group(xg, lc, vv, *, Bg: int, mode: str, n_lv: int,
                          nbins_tot: int, block: int):
@@ -302,13 +302,13 @@ def level_hist_one_group(xg, lc, vv, *, Bg: int, mode: str, n_lv: int,
 @telemetry.scope("gbm.hist")
 def streamed_route_hist(Xb, node, vals, route_fn, *, offset: int, n_lv: int,
                         nbins_tot: int, block: int, groups=None):
-    """Fused route→accumulate single pass — the column-block stream of the
-    pipelined level program (``H2O_TPU_PIPELINE``).
+    """Fused route→accumulate single pass — the row-block stream of the
+    tree engine's level program.
 
-    The synchronous level program walks the row blocks TWICE per level: once
-    to route rows off the previous level's splits, once to accumulate the
-    new level's histogram. This pass decodes each (rb, F) block once (one
-    upcast of the int8/int16 codes, shared by both halves): ``route_fn``
+    Each level routes rows off the previous level's splits and accumulates
+    the new level's histogram in ONE walk over the row blocks. This pass
+    decodes each (rb, F) block once (one upcast of the int8/int16 codes,
+    shared by both halves): ``route_fn``
     (the previous level's routing, `engine._route_rows` closed over its
     splits) advances the block's node ids, the level window localizes
     them, and the block's histogram contribution accumulates immediately.
@@ -322,11 +322,11 @@ def streamed_route_hist(Xb, node, vals, route_fn, *, offset: int, n_lv: int,
     half does on the same block (PERF.md section 6, PR 27, has the chip's
     milliseconds).
 
-    Bit-parity with the two-pass shape is by construction: routing is
-    integer/boolean work (any formulation that picks the same children is
-    exact), and the histogram contributions are the same `_flat_contrib` /
-    `_group_contrib` over the same block contents in the same block order,
-    carried in the contraction's own (n_lv * V, F, B) shape and laid out as
+    Bit-parity with routing first and then `level_hist_blocks` is by
+    construction: routing is integer/boolean work, and the histogram
+    contributions are the same `_flat_contrib` / `_group_contrib` over the
+    same block contents in the same block order, carried in the
+    contraction's own (n_lv * V, F, B) shape and laid out as
     (F, n_lv, B, V) after the scan, as `_scan_flat` / `_scan_grouped` do.
     ``route_fn=None`` (level 0) skips the routing half."""
     Rl = Xb.shape[0]

@@ -621,7 +621,7 @@ class GBM(ModelBuilder):
             cfg = dataclasses.replace(cfg, use_sets=use_sets, block_rows=blk,
                                       hist_groups=hist_groups)
             # per-tree ICI reduction payload (per-level hist psums + the node-
-            # totals psum) — static accounting the sharded bench leg records
+            # totals psum) — static accounting, beside the matrix bytes
             LAST_TRAIN_MATRIX_BYTES["psum_bytes_per_tree"] = \
                 psum_payload_bytes(cfg, len(names))
             if not self.drf_mode and K == 1 and dist.name in ("laplace",
@@ -635,12 +635,10 @@ class GBM(ModelBuilder):
                 # hybrid gamma leaves (`GBM.java:685`); the split-search
                 # gradients still clip at unit delta (documented residue)
                 cfg = dataclasses.replace(cfg, huber_leaf_alpha=p.huber_alpha)
-            # async pipelined training knobs (ISSUE 12): the pipelined level
-            # program and the overlapped reduction are BIT-equal to the
-            # synchronous oracle, so they default on
+            # the overlapped reduction is BIT-equal to the joint scan, so
+            # it defaults on
             cfg = dataclasses.replace(
-                cfg, pipeline=get_bool("H2O_TPU_PIPELINE"),
-                async_psum=get_bool("H2O_TPU_ASYNC_PSUM"))
+                cfg, async_psum=get_bool("H2O_TPU_ASYNC_PSUM"))
             # the cache key must pin everything grad_fn's behavior depends on;
             # custom distribution UDFs bypass the cache entirely (an id()-based
             # key could alias a new UDF at a recycled address after GC)
@@ -792,14 +790,13 @@ class GBM(ModelBuilder):
                       for i in range(0, n_new, interval)]
             from jax.sharding import PartitionSpec as _Pspec
 
-            # pipelined chunk dispatch (ISSUE 12): fold cadence scoring into
-            # the train step (the score0-layout raw predictions come out of
-            # the program that already holds the final margin), and donate the
-            # carried margin's buffer across chunk dispatches. Both ride
-            # cfg.pipeline; DRF keeps standalone scoring (its cadence metrics
-            # are the OOB path's, computed from the OOB accumulators).
-            fused_score = bool(cfg.pipeline) and not self.drf_mode
-            donate_f = bool(cfg.pipeline)
+            # pipelined chunk dispatch: fold cadence scoring into the train
+            # step (the score0-layout raw predictions come out of the
+            # program that already holds the final margin), and donate the
+            # carried margin's buffer across chunk dispatches. DRF keeps
+            # standalone scoring (its cadence metrics are the OOB path's,
+            # computed from the OOB accumulators).
+            fused_score = not self.drf_mode
             score_fn = score_spec = None
             if fused_score:
                 cfg = dataclasses.replace(cfg, fused_score=True)
@@ -819,7 +816,7 @@ class GBM(ModelBuilder):
             train_fn = make_train_fn(dataclasses.replace(cfg, ntrees=interval),
                                      grad_fn, mesh, cache_key=grad_key,
                                      score_fn=score_fn, score_spec=score_spec,
-                                     donate=donate_f)
+                                     donate=True)
             # pin the carried f to the trainer's OUTPUT sharding before the AOT
             # lower: chunk 0's freshly-broadcast f can come back replicated
             # (GSPMD's choice for a data-independent broadcast) while every
@@ -843,7 +840,7 @@ class GBM(ModelBuilder):
         train_step = None
         if chunks and grad_key is not None:
             aot_key = (dataclasses.replace(cfg, ntrees=interval), grad_key,
-                       id(mesh), donate_f)
+                       id(mesh))
             # a compile error surfaces HERE, once: the jitted twin would
             # hand the same program to the same compiler and fail again
             train_step = _aot_train_step(

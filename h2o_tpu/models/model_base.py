@@ -487,7 +487,7 @@ class ModelBuilder:
                 # /3/Models reports. This is also the CONTRACT every
                 # caller times against — graftlint's timing-without-sync
                 # rule treats train_model as self-syncing because of this
-                # block (bench.py legs rely on it)
+                # block
                 import jax
 
                 from ..utils.blocking import device_arrays

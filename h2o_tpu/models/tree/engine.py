@@ -106,22 +106,6 @@ class TreeConfig:
                                    # code<=cut splits (pre-round-4 behavior,
                                    # kept for RuleFit's threshold-language
                                    # rules and models without categoricals).
-    pipeline: bool = False         # async pipelined level program
-                                   # (H2O_TPU_PIPELINE): route(L-1) fuses
-                                   # into level L's histogram pass (one
-                                   # streamed decode per block instead of
-                                   # two), a row reads its node's split and
-                                   # its code at the split feature through
-                                   # integer selects over the level's nodes
-                                   # and the block's F codes (`_route_rows`)
-                                   # instead of one-hot matmuls, and the
-                                   # carried margin is donated across chunk
-                                   # dispatches. Bit-equal to the
-                                   # synchronous oracle (pipeline=False) by
-                                   # construction — routing is integer/
-                                   # boolean work and every float
-                                   # accumulation keeps the oracle's
-                                   # per-block math and order.
     async_psum: bool = False       # overlapped per-level reduction
                                    # (H2O_TPU_ASYNC_PSUM): each hist
                                    # group's psum is issued as soon as its
@@ -290,7 +274,7 @@ def _psum_hist(hist):
 
 @telemetry.scope("gbm.hist")
 def _build_level_hist(Xb, node, vals, offset, n_lv, nbins_tot, block,
-                      groups=None, async_psum=False):
+                      groups=None):
     """Accumulate hist (F, n_lv, nbins_tot, V) for nodes [offset, offset+n_lv).
 
     Xb: (Rl, F) int32 bins; node: (Rl,) int32 global node ids; vals: (Rl, V)
@@ -328,21 +312,10 @@ def _build_level_hist(Xb, node, vals, offset, n_lv, nbins_tot, block,
         return _psum_hist(hist)
 
     groups = _norm_groups(groups)
-    if async_psum:
-        # overlapped reduction (H2O_TPU_ASYNC_PSUM): one scan PER group,
-        # each group's psum issued before the next group's scan is traced —
-        # on a real ICI the collective for bucket g overlaps bucket g+1's
-        # local accumulation. Values are bit-equal to the joint scan (same
-        # per-block contributions, same block order, same per-group psum).
-        hists = [_psum_hist(hist_kernels.level_hist_one_group(
-            Xb[:, list(idxs)], lc, v, Bg=Bg, mode=mode, n_lv=n_lv,
-            nbins_tot=nbins_tot, block=block))
-            for idxs, Bg, mode in groups]
-    else:
-        hists = [_psum_hist(hg)
-                 for hg in hist_kernels.level_hist_blocks(
-                     Xb, lc, v, n_lv=n_lv, nbins_tot=nbins_tot, block=block,
-                     groups=groups)]
+    hists = [_psum_hist(hg)
+             for hg in hist_kernels.level_hist_blocks(
+                 Xb, lc, v, n_lv=n_lv, nbins_tot=nbins_tot, block=block,
+                 groups=groups)]
     # psum per group BEFORE the scatter-back: the wire carries Σ F_g·B_g
     # cells instead of the padded F·B_max the flat path reduces
     return _scatter_group_hists(hists, groups, F, n_lv, nbins_tot,
@@ -352,10 +325,7 @@ def _build_level_hist(Xb, node, vals, offset, n_lv, nbins_tot, block,
 @telemetry.scope("gbm.hist")
 def _scatter_group_hists(hists, groups, F, n_lv, nbins_tot, V):
     """Per-group accumulators back into the global (F, n_lv, B, V) layout,
-    each group's NA slot (its LAST bin) restored to the global NA bucket.
-    The ONE definition both the synchronous and pipelined level programs
-    scatter through — bit-parity between them rides on this block staying
-    single-sourced."""
+    each group's NA slot (its LAST bin) restored to the global NA bucket."""
     na_global = nbins_tot - 1
     full = jnp.zeros((F, n_lv, nbins_tot, V), jnp.float32)
     for (idxs, Bg, _mode), hg in zip(groups, hists):
@@ -366,7 +336,7 @@ def _scatter_group_hists(hists, groups, F, n_lv, nbins_tot, V):
 
 
 # ---------------------------------------------------------------------------
-# Pipelined level program (H2O_TPU_PIPELINE) — fused route→hist streaming.
+# The level program — fused route→hist streaming.
 # ---------------------------------------------------------------------------
 def _route_rows(xb_blk, node_blk, route_args, cfg: "TreeConfig"):
     """One block's routing off the previous level's splits, inside the
@@ -380,8 +350,7 @@ def _route_rows(xb_blk, node_blk, route_args, cfg: "TreeConfig"):
     histogram half builds from the same decoded block, whatever F, n_lv
     and the code dtype are.
     Routing is integer/boolean work end to end (int32 for int8, int16 and
-    int32 codes alike), so the node ids are BIT-identical to the
-    one-hot-matmul `_route` in `_grow_tree` (the synchronous oracle)."""
+    int32 codes alike): the node ids are exact."""
     bf, bb, bnal, do_split, catd_lv, isset, offset, n_lv = route_args
     local = node_blk - offset
     active = (local >= 0) & (local < n_lv)
@@ -410,8 +379,8 @@ def _route_rows(xb_blk, node_blk, route_args, cfg: "TreeConfig"):
 
 @telemetry.scope("gbm.route")
 def _route_all(Xb, node, route_args, cfg: "TreeConfig"):
-    """Blocked standalone routing pass (`_route_rows` per block) — the
-    pipelined path's final route after the last level's splits."""
+    """Blocked standalone routing pass (`_route_rows` per block) — a
+    tree's final route after the last level's splits."""
     Rl, F = Xb.shape
     rb = _block_rows(Rl, cfg.block_rows)
     _, node_b = jax.lax.scan(
@@ -422,11 +391,10 @@ def _route_all(Xb, node, route_args, cfg: "TreeConfig"):
 
 def _pipelined_level_hist(Xb, node, vals3, route_args, offset, n_lv,
                           nbins_tot, cfg: "TreeConfig"):
-    """One pipelined level: advance ``node`` off the previous level's
-    splits and accumulate this level's histogram, returning ``(hist,
-    node)`` with ``hist`` already psummed and scattered back into the
-    global (F, n_lv, B, V) layout — the drop-in replacement for the
-    synchronous route-then-`_build_level_hist` pair.
+    """One level: advance ``node`` off the previous level's splits and
+    accumulate this level's histogram, returning ``(hist, node)`` with
+    ``hist`` already psummed and scattered back into the global
+    (F, n_lv, B, V) layout.
 
     ONE streamed pass per level (`kernels.hist.streamed_route_hist`) —
     each row block is decoded once, routed, and accumulated while the next
@@ -756,17 +724,12 @@ def _grow_tree(Xb, g, h, w, edges, edge_ok, colkey, cfg: TreeConfig,
                  < cfg.col_sample_rate_per_tree)
     tree_cols = jnp.where(jnp.any(tree_cols), tree_cols, True)
 
-    route_args = None   # pipelined: previous level's splits, routed lazily
+    route_args = None   # the previous level's splits, routed lazily
     for level in range(cfg.max_depth):
         n_lv = 2 ** level
         offset = n_lv - 1
-        if cfg.pipeline:
-            hist, node = _pipelined_level_hist(Xb, node, vals3, route_args,
-                                               offset, n_lv, B, cfg)
-        else:
-            hist = _build_level_hist(Xb, node, vals3, offset, n_lv, B,
-                                     cfg.block_rows, groups=cfg.hist_groups,
-                                     async_psum=cfg.async_psum)
+        hist, node = _pipelined_level_hist(Xb, node, vals3, route_args,
+                                           offset, n_lv, B, cfg)
 
         cmask = _level_col_mask(jax.random.fold_in(colkey, level), F, n_lv,
                                 cfg, tree_cols, level)
@@ -815,76 +778,13 @@ def _grow_tree(Xb, g, h, w, edges, edge_ok, colkey, cfg: TreeConfig,
         if use_sets:
             catd = jax.lax.dynamic_update_slice(catd, catd_lv, (offset, 0))
 
-        if cfg.pipeline:
-            # defer this level's routing into the NEXT level's streamed
-            # pass (or the final route below) — the split params are all
-            # the route needs, and carrying them keeps each row block's
-            # decode single-pass
-            route_args = (bf, bb.astype(jnp.int32), bnal, do_split,
-                          catd_lv if use_sets else None, isset, offset,
-                          n_lv)
-            continue
+        # defer this level's routing into the NEXT level's streamed pass
+        # (or the final route below) — the split params are all the route
+        # needs, and carrying them keeps each row block's decode single-pass
+        route_args = (bf, bb.astype(jnp.int32), bnal, do_split,
+                      catd_lv if use_sets else None, isset, offset, n_lv)
 
-        # Route rows: only rows at split nodes of this level descend.
-        # Per-row dynamic gathers (bf[lc], Xb[r, bf]) are catastrophically
-        # slow on TPU (~20-40 ns/row on the VPU's serial gather path); instead
-        # every per-node quantity is broadcast to rows through one-hot
-        # matmuls, which ride the MXU (SURVEY.md §"hard parts" — TPUs lack
-        # fast generic scatter/gather).
-        # TPU matmuls default to bf16 multiplies; these dots move small
-        # INTEGERS (bin ids < nbins, 0/1 flags) through 0/1 one-hots, which
-        # bf16 represents exactly up to 256 — above that, force full f32.
-        prec = (jax.lax.Precision.HIGHEST if cfg.nbins >= 255
-                else jax.lax.Precision.DEFAULT)
-        S = jax.nn.one_hot(bf, F, dtype=jnp.float32)              # (n_lv, F)
-
-        def _route(xb_blk, node_blk):
-            local = node_blk - offset
-            active = (local >= 0) & (local < n_lv)
-            lc = jnp.clip(local, 0, n_lv - 1)
-            n_oh = jax.nn.one_hot(lc, n_lv, dtype=jnp.float32)  # (rb, n_lv)
-            # bin of each row's split feature: Σ_n n_oh[r,n]·(Xb·Sᵀ)[r,n]
-            xbs = jnp.dot(xb_blk.astype(jnp.float32), S.T, precision=prec,
-                          preferred_element_type=jnp.float32)   # (rb, n_lv)
-            rb_val = jnp.sum(xbs * n_oh, axis=1)
-            row_bb = jnp.dot(n_oh, bb.astype(jnp.float32), precision=prec)
-            row_nal = jnp.dot(n_oh, bnal.astype(jnp.float32)) > 0.5
-            row_split = (jnp.dot(n_oh, do_split.astype(jnp.float32))
-                         > 0.5) & active
-            num_right = rb_val > row_bb
-            if use_sets:
-                # table route: the row's direction is its bin's entry in the
-                # node's direction row — two more small matmuls, no gathers
-                Drow = jnp.dot(n_oh, catd_lv,
-                               preferred_element_type=jnp.float32)  # (rb, nb)
-                bin_oh = jax.nn.one_hot(rb_val.astype(jnp.int32), cfg.nbins,
-                                        dtype=jnp.float32)
-                cat_right = jnp.sum(bin_oh * Drow, axis=1) > 0.5
-                row_isset = jnp.dot(n_oh, isset.astype(jnp.float32)) > 0.5
-                num_right = jnp.where(row_isset, cat_right, num_right)
-            go_right = jnp.where(rb_val == cfg.nbins, ~row_nal, num_right)
-            return jnp.where(row_split,
-                             2 * node_blk + 1 + go_right.astype(jnp.int32),
-                             node_blk)
-
-        with telemetry.scope("gbm.route"):
-            if use_sets or Xb.dtype.itemsize < 4:
-                # blocked: the (rows, nbins) bin one-hot lives per block,
-                # never materializing an (Rl, nbins) intermediate at wide
-                # nbins_cats — and for int8/int16 binned views the f32 cast
-                # feeding the routing matmul stays block-sized instead of
-                # re-materializing a raw-matrix-sized (Rl, F) f32
-                # intermediate
-                rb_ = _block_rows(Rl, cfg.block_rows)
-                _, node_b = jax.lax.scan(
-                    lambda c, blk: (c, _route(*blk)), None,
-                    (Xb.reshape(Rl // rb_, rb_, F),
-                     node.reshape(Rl // rb_, rb_)))
-                node = node_b.reshape(Rl)
-            else:
-                node = _route(Xb, node)
-
-    if cfg.pipeline and route_args is not None:
+    if route_args is not None:
         # the last level's routing was deferred — apply it so leaf/stop
         # totals see the final node assignment
         node = _route_all(Xb, node, route_args, cfg)
@@ -1009,9 +909,10 @@ def hist_onehot_cells(cfg: TreeConfig, rows: int, F: int,
 def psum_payload_bytes(cfg: TreeConfig, F: int, nvals: int = 3) -> int:
     """Bytes ONE tree's ICI reductions move per shard: the per-level
     histogram psums (`hist_psum_bytes`) plus the final per-node totals
-    psum. Pure accounting off the static config — the bench ``sharded``
-    leg records it next to the per-shard matrix bytes so the
-    compute-vs-wire tradeoff of a shard count is on the record."""
+    psum. Pure accounting off the static config —
+    `gbm.LAST_TRAIN_MATRIX_BYTES` records it next to the per-shard matrix
+    bytes so the compute-vs-wire tradeoff of a shard count is on the
+    record."""
     return hist_psum_bytes(cfg, F, nvals) + cfg.n_nodes * nvals * 4
 
 
@@ -1251,8 +1152,8 @@ def predict_forest(X, feat, thr, nanL, val, max_depth: int, catd=None,
     tree outputs (R,) or (R, K).
 
     Traversal broadcasts per-node split params to rows through one-hot
-    matmuls instead of per-row gathers (same MXU-over-gather rationale as the
-    training-side routing in _grow_tree). ``catd`` (T, [K,] N, nb) +
+    matmuls instead of per-row gathers (TPUs lack a fast generic gather;
+    SURVEY.md §"hard parts"). ``catd`` (T, [K,] N, nb) +
     ``iscat``/``nedges`` (F,) activate categorical set-split routing."""
     multi = feat.ndim == 3
     N = feat.shape[-1]

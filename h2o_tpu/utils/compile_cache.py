@@ -1,6 +1,6 @@
 """Persistent XLA compilation cache placement — ONE rule, shared by the
 server entry point, cluster init, `h2o.init()`, the first `train_model` of
-a process, `bench.py` and `chip_smoke.py`.
+a process and `chip_smoke.py`.
 
 The cache directory is part of the cache's key, so it must not move
 between processes that are meant to share it:

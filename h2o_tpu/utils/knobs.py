@@ -104,13 +104,6 @@ _knob("H2O_TPU_HIST_SEG_WIDTH", "int", 8,
       "bin widths at/below this accumulate via segment-sum instead of the "
       "one-hot matmul in the histogram scan (0 disables the path; "
       "backend/kernels/hist.py)")
-_knob("H2O_TPU_PIPELINE", "bool", True,
-      "async pipelined GBM/DRF level program: route->hist fused into one "
-      "streamed pass per row block, routing by integer selects over the "
-      "block's codes (no per-row gather), cadence scoring fused into the "
-      "chunk step, donated margin carry. BIT-equal to the synchronous "
-      "oracle; 0 reverts to the two-pass one-hot-matmul level program "
-      "(models/tree/engine.py)")
 _knob("H2O_TPU_ASYNC_PSUM", "bool", True,
       "overlapped per-level histogram reduction: each width bucket's ICI "
       "psum is issued before the next bucket's local scan so the "
@@ -391,50 +384,6 @@ _knob("H2O_TPU_HDFS_USER", "str", "",
 _knob("H2O_TPU_HIVE_JDBC", "str", "",
       "Hive JDBC endpoint for ImportHiveTable")
 
-# -- bench.py ---------------------------------------------------------------
-_knob("H2O_TPU_BENCH_ROWS", "int", 11_000_000,
-      "rows for the HIGGS-shaped bench frame")
-_knob("H2O_TPU_BENCH_TREES", "int", 100,
-      "trees for the bench GBM legs")
-_knob("H2O_TPU_BENCH_SORT_ROWS", "int", 100_000_000,
-      "rows for the sort/merge bench legs")
-_knob("H2O_TPU_BENCH_AIRLINES_ROWS", "int", 116_000_000,
-      "rows for the airlines train-to-AUC leg")
-_knob("H2O_TPU_BENCH_BINNED_ROWS", "int", 8_000_000,
-      "rows for the binned-store stacked-vs-binned leg")
-_knob("H2O_TPU_BENCH_WORKLOADS", "str",
-      "gbm,glm,cod,gam,rulefit,sort,merge,binned,serving,serving_wire,"
-      "recovery,sharded,airlines,workload",
-      "comma list of bench workloads to run")
-_knob("H2O_TPU_BENCH_WORKLOAD_TENANTS", "int", 3,
-      "tenants for the multi-tenant workload bench leg (each runs "
-      "ingest + train + score under the managed scheduler)")
-_knob("H2O_TPU_BENCH_WORKLOAD_ROWS", "int", 40_000,
-      "rows per tenant frame in the workload bench leg")
-_knob("H2O_TPU_BENCH_SHARDED_ROWS", "int", 400_000,
-      "rows for the sharded leg (same GBM at 1 vs N row shards; "
-      "per-shard peak matrix bytes + psum payload + wall land in the "
-      "sidecar)")
-_knob("H2O_TPU_BENCH_RECOVERY_ROWS", "int", 500_000,
-      "rows for the recovery leg (checkpoint overhead + resume-to-parity)")
-_knob("H2O_TPU_BENCH_SERVING_REQS", "int", 4000,
-      "single-row requests issued by the concurrent serving bench leg")
-_knob("H2O_TPU_BENCH_SERVING_THREADS", "int", 16,
-      "concurrent client threads for the serving bench leg")
-_knob("H2O_TPU_BENCH_WIRE_REQS", "int", 600,
-      "sequential single-row HTTP requests per wire mode (pooled / "
-      "per-request) in the serving_wire bench leg")
-_knob("H2O_TPU_BENCH_SKIP_CADENCE", "bool", False,
-      "skip the score_tree_interval=10 GBM cadence leg")
-_knob("H2O_TPU_BENCH_SIDECAR", "str", "",
-      "path of the crash-proof per-workload JSONL sidecar "
-      "(default: BENCH_partial.jsonl next to bench.py)")
-_knob("H2O_TPU_BENCH_GATE_BANDS", "str", "",
-      "tolerance-band overrides for tools/bench_gate.py as "
-      "'metric=frac' pairs, comma-separated, optionally leg-scoped "
-      "('wall=0.4,peak=0.5,gbm.wall=0.6'); empty = the gate's documented "
-      "defaults (wall +25%, peak bytes +25%, AUC drop 0.02)")
-
 # -- test harness -----------------------------------------------------------
 _knob("H2O_TPU_KEY_STRICT", "bool", False,
       "fail tests on leaked KVStore keys instead of reaping them")
@@ -460,8 +409,7 @@ def raw(name: str, default=None):
 
 def get_str(name: str, default=_MISSING) -> str:
     """SET wins, even when set to the empty string — an exported-but-empty
-    string knob means "nothing" (e.g. H2O_TPU_BENCH_WORKLOADS= runs no
-    legs), not "give me the default"."""
+    string knob means "nothing", not "give me the default"."""
     k = _lookup(name)
     v = os.environ.get(name)
     if v is not None:

@@ -626,8 +626,7 @@ def snapshot() -> dict:
 def snapshot_delta(before: dict, after: dict | None = None) -> dict:
     """What happened between two snapshots, compact: counters report the
     delta (zero deltas dropped), gauges the after-value (+ peak when
-    tracked), histograms the count/sum delta. This is the per-leg record
-    bench.py embeds in its fsync'd JSONL sidecar."""
+    tracked), histograms the count/sum delta."""
     after = snapshot() if after is None else after
     out: dict[str, dict] = {}
     for name, rec in after.items():

@@ -153,9 +153,9 @@ def pytest_configure(config):
                    "oracle, shard-aware checkpoints, per-device ledger "
                    "(pytest -m sharded, tests/test_sharded_frames.py)")
     config.addinivalue_line(
-        "markers", "pipeline: async pipelined GBM training — pipelined-"
-                   "vs-synchronous bit parity across the knob matrix, "
-                   "donated-margin chunk dispatch "
+        "markers", "pipeline: the tree level program and its chunk loop "
+                   "— overlapped-reduction bit parity, fused cadence "
+                   "scoring, donated-margin chunk dispatch "
                    "(pytest -m pipeline, tests/test_pipeline.py)")
     config.addinivalue_line(
         "markers", "fleetobs: fleet observability plane — program cost "

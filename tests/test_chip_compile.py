@@ -103,7 +103,6 @@ def default_train_step(v5e):
         budget_bytes=12 << 30, n_lv_max=16, nvals=3)
     cfg = dataclasses.replace(
         cfg, ntrees=INTERVAL, block_rows=blk, hist_groups=groups,
-        pipeline=get_bool("H2O_TPU_PIPELINE"),
         async_psum=get_bool("H2O_TPU_ASYNC_PSUM"), fused_score=True)
     train_fn = make_train_fn(
         cfg, b._make_grad_fn(dist, 1), mesh,
@@ -201,7 +200,6 @@ def xgb_train_step(v5e):
     assert (groups, blk) == (None, 8192)
     cfg = dataclasses.replace(
         cfg, ntrees=INTERVAL, block_rows=blk, hist_groups=groups,
-        pipeline=get_bool("H2O_TPU_PIPELINE"),
         async_psum=get_bool("H2O_TPU_ASYNC_PSUM"), fused_score=True)
     train_fn = make_train_fn(
         cfg, b._make_grad_fn(dist, 1), mesh,

@@ -400,26 +400,3 @@ def test_uplift_train_engages_hist_groups():
     assert out.names[0] == "uplift_predict"
 
 
-# ---------------------------------------------------------------------------
-# bench sidecar leg
-# ---------------------------------------------------------------------------
-@pytest.mark.slow  # 4 airlines-width GBM trains; the reduction itself is
-                   # also pinned (cheaper) by test_gbm_binned_vs_raw_...
-def test_bench_binned_store_leg_records_reduction(tmp_path, monkeypatch):
-    import json
-    import sys
-
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    import bench
-
-    sidecar = tmp_path / "BENCH_partial.jsonl"
-    monkeypatch.setenv("H2O_TPU_BENCH_SIDECAR", str(sidecar))
-    rec = bench.bench_binned_store(20_000, ntrees=3)
-    bench._emit_workload({}, "binned_store", rec)
-    assert rec["reduction_x"] >= 3.0
-    assert rec["auc_delta"] == 0.0
-    assert rec["peak_matrix_bytes_binned"] > 0
-    lines = [json.loads(l) for l in sidecar.read_text().splitlines()]
-    assert lines[-1]["workload"] == "binned_store"
-    assert lines[-1]["record"]["reduction_x"] >= 3.0

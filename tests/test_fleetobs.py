@@ -1,8 +1,7 @@
 """Fleet observability plane (PR 13): program cost registry populated by
 real GBM/GLM/serving programs, cross-process metric merge over live peer
 processes, span-scoped device profiler capture, the crash flight
-recorder, the bench perf-regression gate, concurrent trace-writer
-integrity, and the always-on overhead bound re-asserted with program +
+recorder, concurrent trace-writer integrity, and the always-on overhead bound re-asserted with program +
 trace accounting enabled."""
 
 from __future__ import annotations
@@ -526,109 +525,6 @@ class TestFlightRecorder:
                             lambda *a: (_ for _ in ()).throw(
                                 RuntimeError("sick recorder")))
         assert flightrec.dump("whatever") is None
-
-
-# ---------------------------------------------------------------------------
-# bench sidecar schema + perf-regression gate
-# ---------------------------------------------------------------------------
-def _load_bench():
-    import importlib.util
-
-    path = os.path.join(REPO_ROOT, "bench.py")
-    spec = importlib.util.spec_from_file_location("h2o_tpu_bench", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-class TestBenchGate:
-    BASELINE = os.path.join(REPO_ROOT, "BENCH_r06_baseline.jsonl")
-
-    def _gate(self, run_path, env=None):
-        return subprocess.run(
-            [sys.executable, os.path.join(REPO_ROOT, "tools",
-                                          "bench_gate.py"),
-             "--run", str(run_path), "--baseline", self.BASELINE],
-            capture_output=True, text=True, cwd=REPO_ROOT,
-            env={**os.environ, **(env or {})})
-
-    def test_unmodified_run_passes(self):
-        r = self._gate(self.BASELINE)
-        assert r.returncode == 0, r.stdout + r.stderr
-        assert "all compared legs within bands" in r.stdout
-
-    def test_seeded_wall_regression_fails_named(self, tmp_path):
-        lines = [json.loads(ln) for ln in open(self.BASELINE)]
-        for d in lines:
-            if d.get("workload") == "gbm":
-                d["record"]["score_once_s"] = round(
-                    d["record"]["score_once_s"] * 1.3, 3)  # 30% slower
-        run = tmp_path / "regressed.jsonl"
-        run.write_text("".join(json.dumps(d) + "\n" for d in lines))
-        r = self._gate(run)
-        assert r.returncode == 1
-        assert "gbm.score_once_s" in r.stdout  # leg + metric, named
-
-    def test_seeded_parity_flip_fails(self, tmp_path):
-        lines = [json.loads(ln) for ln in open(self.BASELINE)]
-        for d in lines:
-            if d.get("workload") == "sharded":
-                d["record"]["forest_struct_equal"] = False
-        run = tmp_path / "parity.jsonl"
-        run.write_text("".join(json.dumps(d) + "\n" for d in lines))
-        r = self._gate(run)
-        assert r.returncode == 1
-        assert "sharded.forest_struct_equal" in r.stdout
-
-    def test_band_override_widens_the_gate(self, tmp_path):
-        lines = [json.loads(ln) for ln in open(self.BASELINE)]
-        for d in lines:
-            if d.get("workload") == "gbm":
-                d["record"]["score_once_s"] = round(
-                    d["record"]["score_once_s"] * 1.3, 3)
-        run = tmp_path / "regressed.jsonl"
-        run.write_text("".join(json.dumps(d) + "\n" for d in lines))
-        r = self._gate(run, env={"H2O_TPU_BENCH_GATE_BANDS": "wall=0.5"})
-        assert r.returncode == 0, r.stdout
-
-    def test_zero_overlap_is_not_a_green_gate(self, tmp_path):
-        """A run sharing no leg with the baseline (typo'd workload list,
-        renamed legs) must fail loudly, not pass by vacuity."""
-        run = tmp_path / "disjoint.jsonl"
-        run.write_text(
-            json.dumps({"bench_run": {"rows": 1}}) + "\n"
-            + json.dumps({"workload": "nosuchleg",
-                          "record": {"wall_s": 1.0}}) + "\n")
-        r = self._gate(run)
-        assert r.returncode == 1
-        assert "no metric was actually compared" in r.stdout
-
-    def test_scale_mismatch_skips_walls_keeps_flags(self, tmp_path):
-        lines = [json.loads(ln) for ln in open(self.BASELINE)]
-        for d in lines:
-            if "bench_run" in d:
-                d["bench_run"]["rows"] = 999  # different config
-            if d.get("workload") == "gbm":
-                d["record"]["score_once_s"] = 9999.0  # huge "regression"
-        run = tmp_path / "rescaled.jsonl"
-        run.write_text("".join(json.dumps(d) + "\n" for d in lines))
-        r = self._gate(run)
-        assert r.returncode == 0  # cross-scale walls are noise, not gated
-        assert "skip (scale)" in r.stdout
-
-    def test_sidecar_lines_carry_schema_version_and_programs(
-            self, tmp_path, monkeypatch):
-        bench = _load_bench()
-        sidecar = tmp_path / "side.jsonl"
-        monkeypatch.setenv("H2O_TPU_BENCH_SIDECAR", str(sidecar))
-        bench._sidecar_start({"rows": 1})
-        bench._leg({}, "noop", lambda: {"wall_s": 0.0})
-        lines = [json.loads(ln) for ln in open(sidecar)]
-        assert lines[0]["bench_run"]["schema_version"] == \
-            bench.SIDECAR_SCHEMA_VERSION
-        assert lines[1]["schema_version"] == bench.SIDECAR_SCHEMA_VERSION
-        assert "programs" in lines[1]["record"]
-        assert "telemetry" in lines[1]["record"]
 
 
 # ---------------------------------------------------------------------------

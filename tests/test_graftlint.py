@@ -8,8 +8,8 @@ Three layers:
 2. framework mechanics — baseline matching survives line drift, regeneration
    is byte-deterministic, the --fix rewrites are behavior-preserving text
    edits, the knob registry accessors enforce registration;
-3. the repo-wide gate — `h2o_tpu/ tests/ bench.py` lints clean against the
-   checked-in baseline (tier-1: a new violation fails this test, not a
+3. the repo-wide gate — `h2o_tpu/ tests/ chip_smoke.py` lints clean against
+   the checked-in baseline (tier-1: a new violation fails this test, not a
    reviewer's patience).
 
 No jax import in the linter itself — these tests run in milliseconds.
@@ -23,7 +23,8 @@ import pytest
 
 from tools.graftlint import (apply_baseline, lint_paths, lint_source,
                              load_baseline, main, write_baseline)
-from tools.graftlint.core import REPO_ROOT, Violation, iter_py_files
+from tools.graftlint.core import (DEFAULT_PATHS, REPO_ROOT, Violation,
+                                  iter_py_files)
 from tools.graftlint.fixes import fix_source
 from tools.graftlint.rules import ALL_RULES, registered_knobs
 
@@ -462,7 +463,7 @@ def test_fix_import_insertion_respects_shebang():
            "# -*- coding: utf-8 -*-\n"
            "def f():\n"
            "    import os\n"
-           '    return os.environ.get("H2O_TPU_BENCH_ROWS", "1")\n')
+           '    return os.environ.get("H2O_TPU_HIST_SEG_WIDTH", "1")\n')
     fixed = fix_source(src, "h2o_tpu/models/script.py")
     lines = fixed.splitlines()
     assert lines[0] == "#!/usr/bin/env python"
@@ -588,9 +589,9 @@ def test_fix_leaves_module_form_shard_map_import_alone():
 
 def test_fix_knob_read_is_behavior_preserving():
     src = ('import os\n'
-           'rows = int(os.environ.get("H2O_TPU_BENCH_ROWS", 11_000_000))\n')
+           'width = int(os.environ.get("H2O_TPU_HIST_SEG_WIDTH", 8))\n')
     fixed = fix_source(src, "h2o_tpu/models/new.py")
-    assert 'knobs.raw("H2O_TPU_BENCH_ROWS", 11_000_000)' in fixed
+    assert 'knobs.raw("H2O_TPU_HIST_SEG_WIDTH", 8)' in fixed
     assert "from h2o_tpu.utils import knobs" in fixed
 
 
@@ -649,7 +650,7 @@ def test_registry_covers_every_knob_the_tree_reads():
     names = registered_knobs()
     # the knobs the satellite explicitly migrates
     for knob in ("H2O_TPU_BINNED_STORE", "H2O_TPU_HIST_SEG_WIDTH",
-                 "H2O_TPU_BENCH_ROWS", "H2O_TPU_BENCH_SIDECAR",
+                 "H2O_TPU_SERVING_BUCKETS", "H2O_TPU_TRACE_DIR",
                  "H2O_TPU_HBM_LIMIT_BYTES"):
         assert knob in names
 
@@ -659,7 +660,7 @@ def test_knob_accessors(monkeypatch):
 
     # the asserts below exercise unset-knob fallbacks — scrub any ambient
     # values a dev/CI shell may have exported
-    for var in ("H2O_TPU_BENCH_SIDECAR", "H2O_TPU_BENCH_WORKLOADS",
+    for var in ("H2O_TPU_TRACE_DIR", "H2O_TPU_SERVING_BUCKETS",
                 "H2O_TPU_HIST_SEG_WIDTH", "H2O_TPU_BINNED_STORE"):
         monkeypatch.delenv(var, raising=False)
     monkeypatch.setenv("H2O_TPU_HIST_SEG_WIDTH", "4")
@@ -675,16 +676,16 @@ def test_knob_accessors(monkeypatch):
     # per-site defaults
     monkeypatch.setenv("H2O_TPU_BINNED_STORE", "")
     assert knobs.get_bool("H2O_TPU_BINNED_STORE") is True
-    assert knobs.raw("H2O_TPU_BENCH_SIDECAR", "dflt") == "dflt"
+    assert knobs.raw("H2O_TPU_TRACE_DIR", "dflt") == "dflt"
     with pytest.raises(KeyError):
         knobs.raw("H2O_TPU_NEVER_DECLARED")
     assert "H2O_TPU_BINNED_STORE" in knobs.describe()
     # set-but-EMPTY string knob means "nothing", not "the default" —
-    # H2O_TPU_BENCH_WORKLOADS= must run zero bench legs, not all of them
-    monkeypatch.setenv("H2O_TPU_BENCH_WORKLOADS", "")
-    assert knobs.get_str("H2O_TPU_BENCH_WORKLOADS") == ""
-    monkeypatch.delenv("H2O_TPU_BENCH_WORKLOADS")
-    assert "gbm" in knobs.get_str("H2O_TPU_BENCH_WORKLOADS")
+    # H2O_TPU_SERVING_BUCKETS= reads as no bucket list, not the ladder
+    monkeypatch.setenv("H2O_TPU_SERVING_BUCKETS", "")
+    assert knobs.get_str("H2O_TPU_SERVING_BUCKETS") == ""
+    monkeypatch.delenv("H2O_TPU_SERVING_BUCKETS")
+    assert "128" in knobs.get_str("H2O_TPU_SERVING_BUCKETS")
     # ...while an empty INT knob falls back (there is no int reading of "")
     monkeypatch.setenv("H2O_TPU_HIST_SEG_WIDTH", "")
     assert knobs.get_int("H2O_TPU_HIST_SEG_WIDTH") == 8
@@ -739,8 +740,8 @@ def test_cli_module_entrypoint_runs():
 
 def test_scan_set_includes_the_advertised_tree():
     files = {p.replace("\\", "/").rsplit("/", 1)[-1]
-             for p in iter_py_files(("h2o_tpu", "tests", "bench.py"))}
-    assert {"bench.py", "engine.py", "mesh.py", "conftest.py"} <= files
+             for p in iter_py_files(DEFAULT_PATHS)}
+    assert {"chip_smoke.py", "engine.py", "mesh.py", "conftest.py"} <= files
 
 
 def test_every_rule_registered_exactly_once():

@@ -6,12 +6,14 @@ as its ``route_fn``) does two things to every row: it moves the row from its
 node of the PREVIOUS level to a child, by that level's splits, and it adds
 the row's ``[w, g, h]`` into the cell (feature, node, bin) of the level's
 histogram. The reference below does the same in NumPy and float64, a row at
-a time, and imports nothing from ``h2o_tpu/models/tree/``: until it stood,
-the only reference for a whole level was the synchronous level program
-(``H2O_TPU_PIPELINE=0``), which is engine code too (ROADMAP D1(a)).
+a time, and imports nothing from ``h2o_tpu/models/tree/``: it is the one
+reference for a whole level of the engine's one level program.
 
 Shapes are the HIGGS cells': 28 features, 20 bins + the NA bucket, three
-statistics; a few row blocks.
+statistics; a few row blocks. The grouped cases run the whole level
+(`engine._pipelined_level_hist`: route, width-bucketed accumulation,
+psum, scatter back) under ``shard_map`` on the test mesh, with a plan of
+three width buckets and the overlapped reduction off and on.
 """
 
 import types
@@ -119,6 +121,23 @@ CASES = [pytest.param(n_lv, dtype, sets,
          for sets in (False, True)]
 
 
+def _assert_not_vacuous(codes, node, split, want_h, want_nd, parent_off,
+                        parent_n, n_lv, sets):
+    """Rows moved to both sides, rows stayed (below the root there are
+    leaves), NA rows were routed, every node of the level got rows, and
+    with sets a row went by the direction table."""
+    moved = want_nd != node
+    assert moved.any() and ((~moved).any() or n_lv == 2)
+    assert (want_nd[moved] % 2 == 0).any() and (want_nd[moved] % 2 == 1).any()
+    parent = np.clip(node - parent_off, 0, parent_n - 1)
+    at_split = codes[np.arange(ROWS), split["feature"][parent]]
+    assert (moved & (at_split == NBINS)).any()
+    live = split["do_split"].repeat(2)
+    assert (want_h[0, :, :, 0].sum(axis=1) > 0)[live].all()
+    if sets:
+        assert (moved & split["is_set"][parent] & (at_split < NBINS)).any()
+
+
 @pytest.mark.parametrize("n_lv,dtype,sets", CASES)
 def test_streamed_level_pass_equals_the_reference(n_lv, dtype, sets):
     from h2o_tpu.backend.kernels import hist
@@ -144,30 +163,97 @@ def test_streamed_level_pass_equals_the_reference(n_lv, dtype, sets):
     assert h.shape == (F, n_lv, B, V)
     np.testing.assert_allclose(np.asarray(h, np.float64), want_h,
                                rtol=1e-5, atol=1e-4)
-    # the case is not vacuous: rows moved to both sides, rows stayed (below
-    # the root there are leaves), NA rows were routed, every node of the
-    # level got rows, and with sets a row went by the direction table
-    moved = want_nd != node
-    assert moved.any() and ((~moved).any() or n_lv == 2)
-    assert (want_nd[moved] % 2 == 0).any() and (want_nd[moved] % 2 == 1).any()
-    parent = np.clip(node - parent_off, 0, parent_n - 1)
-    at_split = codes[np.arange(ROWS), split["feature"][parent]]
-    assert (moved & (at_split == NBINS)).any()
-    live = split["do_split"].repeat(2)
-    assert (want_h[0, :, :, 0].sum(axis=1) > 0)[live].all()
-    if sets:
-        assert (moved & split["is_set"][parent] & (at_split < NBINS)).any()
+    _assert_not_vacuous(codes, node, split, want_h, want_nd, parent_off,
+                        parent_n, n_lv, sets)
 
 
-@pytest.mark.parametrize("n_lv,sets", [(2, False), (2, True), (16, False),
-                                       (16, True)])
-def test_final_route_equals_the_reference(n_lv, sets):
+#: a width-bucketed plan, ``(features, width, mode)`` as `plan_hist_groups`
+#: makes one: narrow columns summed by segment (data codes 0..6), the
+#: numerics at 16 (codes 0..14), and a wide one-hot bucket at the full B
+#: that holds the set splits' categorical column (codes 0..19). A bucket's
+#: NA slot is its last bin; the global NA code stays NBINS.
+GROUPS = ((tuple(range(5)), 8, "segsum"),
+          (tuple(range(8, F)), 16, "onehot"),
+          ((CAT_COL, 6, 7), B, "onehot"))
+GROUP_BLOCK = 128              # two row blocks a shard on the 8-device mesh
+
+
+def _grouped_case(n_lv, sets, seed):
+    """`_case` with every column's data codes inside its bucket's width."""
+    codes, node, stats, split, parent_off, parent_n = _case(
+        n_lv, np.int8, sets, seed)
+    for idxs, width, _mode in GROUPS:
+        cols = codes[:, list(idxs)]
+        codes[:, list(idxs)] = np.where(cols == NBINS, NBINS,
+                                        cols % (width - 1))
+    return codes, node, stats, split, parent_off, parent_n
+
+
+GROUPED = [pytest.param(n_lv, sets, async_psum,
+                        id=f"nodes{n_lv}-{'sets' if sets else 'numeric'}-"
+                           f"async_psum_{'on' if async_psum else 'off'}")
+           for n_lv in (2, 8) for sets in (False, True)
+           for async_psum in (False, True)]
+
+
+@pytest.mark.parametrize("n_lv,sets,async_psum", GROUPED)
+def test_grouped_level_pass_equals_the_reference(n_lv, sets, async_psum):
+    """The whole level under ``shard_map`` over the rows axis: each shard
+    routes and accumulates its rows by width bucket, the buckets' psums
+    (issued one by one with ``async_psum``) sum the shards, and the
+    buckets scatter back into the (F, n_lv, B, V) layout."""
+    from jax.sharding import PartitionSpec as P
+
+    from h2o_tpu.models.tree.engine import _pipelined_level_hist
+    from h2o_tpu.parallel.mesh import ROWS as ROW_AXIS
+    from h2o_tpu.parallel.mesh import default_mesh, shard_map
+
+    codes, node, stats, split, parent_off, parent_n = _grouped_case(
+        n_lv, sets, seed=1000 + 10 * n_lv + sets)
+    cfg = types.SimpleNamespace(nbins=NBINS, block_rows=GROUP_BLOCK,
+                                hist_groups=GROUPS, async_psum=async_psum)
+    args = _route_args(split, parent_off, parent_n)
+
+    def spmd(c, n, v):
+        return _pipelined_level_hist(c, n, v, args, n_lv - 1, n_lv, B, cfg)
+
+    fn = shard_map(spmd, mesh=default_mesh(),
+                   in_specs=(P(ROW_AXIS, None), P(ROW_AXIS),
+                             P(ROW_AXIS, None)),
+                   out_specs=(P(), P(ROW_AXIS)), check_vma=False)
+    h, nd = jax.jit(fn)(jnp.asarray(codes), jnp.asarray(node),
+                        jnp.asarray(stats))
+    want_h, want_nd = ref_level_pass(codes, node, stats, split, n_lv)
+
+    np.testing.assert_array_equal(np.asarray(nd), want_nd)
+    assert h.shape == (F, n_lv, B, V)
+    np.testing.assert_allclose(np.asarray(h, np.float64), want_h,
+                               rtol=1e-5, atol=1e-4)
+    _assert_not_vacuous(codes, node, split, want_h, want_nd, parent_off,
+                        parent_n, n_lv, sets)
+    # every bucket holds rows in its data bins and in its NA slot
+    for idxs, width, _mode in GROUPS:
+        mass = want_h[list(idxs), :, :, 0].sum(axis=(0, 1))
+        assert (mass[:width - 1] > 0).all() and mass[NBINS] > 0
+        assert not mass[width - 1:NBINS].any()
+
+
+FINAL_CASES = [pytest.param(n_lv, sets, dtype,
+                            id=f"{n_lv}-{sets}" + (
+                                "" if dtype is np.int8 else "-int16"))
+               for dtype in (np.int8, np.int16)
+               for n_lv in (2, 16) for sets in (False, True)]
+
+
+@pytest.mark.parametrize("n_lv,sets,dtype", FINAL_CASES)
+def test_final_route_equals_the_reference(n_lv, sets, dtype):
     """`engine._route_all`, the standalone route after a tree's last level
     (leaf totals read its node ids), against the same per-row walk."""
     from h2o_tpu.models.tree.engine import _route_all
 
     codes, node, _stats, split, parent_off, parent_n = _case(
-        n_lv, np.int8, sets, seed=7 * n_lv + sets)
+        n_lv, dtype, sets,
+        seed=7 * n_lv + sets + 50 * (np.dtype(dtype).itemsize - 1))
     cfg = types.SimpleNamespace(nbins=NBINS, block_rows=BLOCK)
     nd = jax.jit(lambda c, n: _route_all(
         c, n, _route_args(split, parent_off, parent_n), cfg))(

@@ -1,20 +1,26 @@
-"""Async pipelined GBM training (ISSUE 12) — the acceptance pins.
+"""The tree engine's level program and its chunk loop — the acceptance pins.
 
 Everything here runs on the suite's 8-device virtual CPU mesh
-(tests/conftest.py), so the pipelined-vs-synchronous parity pins exercise
-REAL psums on the 8-shard mesh; the single-shard pin re-runs the same
-comparison on a one-device mesh.
+(tests/conftest.py), so the parity pins exercise REAL psums on the 8-shard
+mesh; the single-shard pin re-runs the same comparison on a one-device
+mesh. Each case compares two runs of the one program that differ only in
+what the case is about:
 
-- Pipelined forests AND predictions are BIT-equal to the synchronous
-  oracle across the knob matrix (pipeline × async-psum), on the
-  8-shard mesh and single-shard, at the one-chunk and multi-chunk
-  (fused cadence scoring + dispatch-ahead + donated margin) cadences;
-- the fused-scoring metric series is identical to the oracle's;
-- an in-flight pipelined dispatch killed by the `mrtask.dispatch`
-  failpoint fails TYPED (no hang) and re-runs clean to the oracle forest.
+- the overlapped reduction (``H2O_TPU_ASYNC_PSUM``) on against off, on a
+  frame whose histogram plan is grouped (the two reductions differ only
+  there): forests AND predictions BIT-equal, 8-shard and single-shard, at
+  one chunk and several, for GBM, DRF and a multinomial GBM;
+- a multi-chunk cadence (fused scoring + dispatch-ahead + donated margin)
+  against one chunk, and its fused metric series against the partial
+  forests scored standalone;
+- an in-flight dispatch killed by a failpoint fails TYPED (no hang) and
+  its re-run lands the uninterrupted run's forest.
+
+The level pass itself is held to a NumPy reference in
+tests/test_level_pass_reference.py.
 """
 
-import os
+import copy
 
 import numpy as np
 import pytest
@@ -33,7 +39,9 @@ pytestmark = pytest.mark.pipeline
 _RNG = np.random.default_rng(12)
 _N = 4096
 #: mixed widths on purpose: a 40-level categorical (wide one-hot bucket +
-#: SET splits), a 5-level categorical (segsum-width bucket), two numerics
+#: SET splits), a 5-level categorical (segsum-width bucket), two numerics;
+#: at ``nbins=8`` the numerics bucket at 16 and the plan is grouped
+_NBINS = 8
 _C1 = _RNG.integers(0, 40, size=_N).astype(np.float32)
 _C2 = _RNG.integers(0, 5, size=_N).astype(np.float32)
 _X1 = _RNG.normal(size=_N).astype(np.float32)
@@ -59,12 +67,11 @@ def _frame(rows=slice(None), mesh=None):
     return fr
 
 
-def _train(fr, monkeypatch, pipeline, async_psum="1", interval=None,
-           ntrees=8, seed=7, **kw):
-    monkeypatch.setenv("H2O_TPU_PIPELINE", pipeline)
+def _train(fr, monkeypatch, async_psum="1", interval=None, ntrees=8,
+           seed=7, **kw):
     monkeypatch.setenv("H2O_TPU_ASYNC_PSUM", async_psum)
     p = GBMParameters(training_frame=fr, response_column="y",
-                      ntrees=ntrees, max_depth=4, nbins=16, seed=seed,
+                      ntrees=ntrees, max_depth=4, nbins=_NBINS, seed=seed,
                       learn_rate=0.2,
                       score_tree_interval=interval or ntrees, **kw)
     return GBM(p).train_model()
@@ -82,54 +89,62 @@ def _preds_equal(a, b, fr):
                                np.asarray(b.score0(X))))
 
 
-# ---------------------------------------------------------------------------
-# Bit parity: pipelined vs the synchronous oracle, knob matrix
-# ---------------------------------------------------------------------------
-@pytest.mark.parametrize("async_psum", ["0", "1"])
-def test_pipelined_bit_parity_8shard(monkeypatch, async_psum):
-    fr = _frame()
-    oracle = _train(fr, monkeypatch, pipeline="0", async_psum="0")
-    m = _train(fr, monkeypatch, pipeline="1", async_psum=async_psum)
-    assert _forest_equal(oracle, m)
-    assert _preds_equal(oracle, m, fr)
+def _assert_async_parity(off, on, fr):
+    """The overlapped reduction against the joint scan: the plan must be
+    grouped (flat, the two are one program) and the models bit-equal."""
+    assert not off.cfg.async_psum and on.cfg.async_psum
+    assert on.cfg.hist_groups and off.cfg.hist_groups == on.cfg.hist_groups
+    assert _forest_equal(off, on)
+    assert _preds_equal(off, on, fr)
 
 
-def test_async_psum_alone_bit_parity(monkeypatch):
+# ---------------------------------------------------------------------------
+# Bit parity: the overlapped reduction on against off
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("interval", [None, 4],
+                         ids=["one_chunk", "two_chunks"])
+def test_pipelined_bit_parity_8shard(monkeypatch, interval):
     fr = _frame()
-    oracle = _train(fr, monkeypatch, pipeline="0", async_psum="0")
-    m = _train(fr, monkeypatch, pipeline="0", async_psum="1")
-    assert _forest_equal(oracle, m)
-    assert _preds_equal(oracle, m, fr)
+    off = _train(fr, monkeypatch, async_psum="0", interval=interval)
+    on = _train(fr, monkeypatch, async_psum="1", interval=interval)
+    _assert_async_parity(off, on, fr)
 
 
 def test_pipelined_bit_parity_single_shard(monkeypatch):
     one = meshmod.make_mesh(devices=jax.devices()[:1])
     with meshmod.use_mesh(one):
         fr = _frame(mesh=one)
-        oracle = _train(fr, monkeypatch, pipeline="0", async_psum="0")
-        m = _train(fr, monkeypatch, pipeline="1")
-        assert _forest_equal(oracle, m)
-        assert _preds_equal(oracle, m, fr)
+        off = _train(fr, monkeypatch, async_psum="0")
+        on = _train(fr, monkeypatch, async_psum="1")
+        _assert_async_parity(off, on, fr)
 
 
 def test_cadence_parity_and_fused_metric_series(monkeypatch):
-    """Multi-chunk cadence engages fused scoring + dispatch-ahead + the
-    donated margin carry; forests, predictions AND the per-boundary
-    metric series must match the oracle's exactly."""
+    """A multi-chunk cadence engages fused scoring + dispatch-ahead + the
+    donated margin carry: its forest and predictions must equal one
+    chunk's, and each boundary's fused metrics must be the metrics of the
+    partial forest at that boundary, scored standalone."""
+    from h2o_tpu.models.model_base import make_metrics
+
     fr = _frame()
-    oracle = _train(fr, monkeypatch, pipeline="0", async_psum="0",
-                    interval=2)
-    m = _train(fr, monkeypatch, pipeline="1", interval=2)
-    assert _forest_equal(oracle, m)
-    assert _preds_equal(oracle, m, fr)
-    h0 = [h["training_metrics"].auc for h in oracle.output.scoring_history]
-    h1 = [h["training_metrics"].auc for h in m.output.scoring_history]
-    assert len(h0) == len(h1) == 4
-    assert h0 == h1
-    ll0 = [h["training_metrics"].logloss
-           for h in oracle.output.scoring_history]
-    ll1 = [h["training_metrics"].logloss for h in m.output.scoring_history]
-    assert ll0 == ll1
+    whole = _train(fr, monkeypatch)
+    m = _train(fr, monkeypatch, interval=2)
+    assert _forest_equal(whole, m)
+    assert _preds_equal(whole, m, fr)
+    hist = m.output.scoring_history
+    assert [h["number_of_trees"] for h in hist] == [2, 4, 6, 8]
+    X = m.adapt_frame(fr)
+    y = fr.vec("y").data
+    for h in hist:
+        part = copy.copy(m)
+        part.forest = {k: v[:h["number_of_trees"]]
+                       for k, v in m.forest.items()}
+        want = make_metrics("Binomial", y[:X.shape[0]], part.score0(X))
+        got = h["training_metrics"]
+        assert got.auc == pytest.approx(want.auc, abs=1e-6)
+        assert got.logloss == pytest.approx(want.logloss, rel=1e-5)
+    assert hist[-1]["training_metrics"].logloss == \
+        whole.output.scoring_history[-1]["training_metrics"].logloss
 
 
 def test_drf_pipelined_parity(monkeypatch):
@@ -137,16 +152,14 @@ def test_drf_pipelined_parity(monkeypatch):
 
     fr = _frame()
 
-    def drf(pipeline):
-        monkeypatch.setenv("H2O_TPU_PIPELINE", pipeline)
+    def drf(async_psum):
+        monkeypatch.setenv("H2O_TPU_ASYNC_PSUM", async_psum)
         p = DRFParameters(training_frame=fr, response_column="y",
-                          ntrees=6, max_depth=4, nbins=16, seed=7,
+                          ntrees=6, max_depth=4, nbins=_NBINS, seed=7,
                           sample_rate=0.8)
         return DRF(p).train_model()
 
-    oracle, m = drf("0"), drf("1")
-    assert _forest_equal(oracle, m)
-    assert _preds_equal(oracle, m, fr)
+    _assert_async_parity(drf("0"), drf("1"), fr)
 
 
 def test_multinomial_pipelined_parity(monkeypatch):
@@ -154,55 +167,50 @@ def test_multinomial_pipelined_parity(monkeypatch):
     fr = _frame()
     fr.add("y3", Vec.from_numpy(y3, type=T_CAT, domain=["a", "b", "c"]))
 
-    def tri(pipeline):
-        monkeypatch.setenv("H2O_TPU_PIPELINE", pipeline)
+    def tri(async_psum):
+        monkeypatch.setenv("H2O_TPU_ASYNC_PSUM", async_psum)
         p = GBMParameters(training_frame=fr, response_column="y3",
-                          ntrees=4, max_depth=3, nbins=16, seed=7)
+                          ntrees=4, max_depth=3, nbins=_NBINS, seed=7)
         return GBM(p).train_model()
 
-    oracle, m = tri("0"), tri("1")
-    assert _forest_equal(oracle, m)
-    assert _preds_equal(oracle, m, fr)
+    _assert_async_parity(tri("0"), tri("1"), fr)
 
 
 # ---------------------------------------------------------------------------
-# Failpoint drill: in-flight pipelined dispatch fails typed, re-runs clean
+# Failpoint drill: an in-flight dispatch fails typed, re-runs clean
 # ---------------------------------------------------------------------------
 def test_pipelined_dispatch_failpoint_typed_and_rerun_clean(monkeypatch):
+    # the uninterrupted run, on a frame of its own
+    want = _train(_frame(), monkeypatch)
     # a FRESH frame: its rollups ride an mr_reduce dispatch during build
-    # setup, so the armed failpoint hits an in-flight pipelined build
-    # (an already-rolled-up frame would dodge the site)
+    # setup, so the armed failpoint hits an in-flight build (an
+    # already-rolled-up frame would dodge the site)
     fr = _frame()
     fp.reset()
     try:
         fp.arm("mrtask.dispatch", "raise(fault)@1")
         with pytest.raises(fp.InjectedFault):
-            _train(fr, monkeypatch, pipeline="1")
+            _train(fr, monkeypatch)
     finally:
         fp.reset()
     # the fault unwound typed (no hang, no corrupted caches): the re-run
-    # lands the oracle forest bit-equal
-    oracle = _train(fr, monkeypatch, pipeline="0", async_psum="0")
-    m = _train(fr, monkeypatch, pipeline="1")
-    assert _forest_equal(oracle, m)
+    # lands the uninterrupted forest bit-equal
+    assert _forest_equal(want, _train(fr, monkeypatch))
 
 
 def test_chunk_failpoint_mid_cadence_typed(monkeypatch):
-    """Kill the pipelined chunk loop at the second boundary — with
-    dispatch-ahead in flight — and verify the typed unwind + clean
-    re-run."""
+    """Kill the chunk loop at the second boundary — with dispatch-ahead in
+    flight — and verify the typed unwind + clean re-run."""
     fr = _frame()
+    want = _train(fr, monkeypatch, interval=2)
     fp.reset()
     try:
         fp.arm("train.gbm.chunk", "raise(fault)@2")
         with pytest.raises(fp.InjectedFault):
-            _train(fr, monkeypatch, pipeline="1", interval=2)
+            _train(fr, monkeypatch, interval=2)
     finally:
         fp.reset()
-    oracle = _train(fr, monkeypatch, pipeline="0", async_psum="0",
-                    interval=2)
-    m = _train(fr, monkeypatch, pipeline="1", interval=2)
-    assert _forest_equal(oracle, m)
+    assert _forest_equal(want, _train(fr, monkeypatch, interval=2))
 
 
 def test_knob_armed_recovery_disables_dispatch_ahead(monkeypatch, tmp_path):
@@ -214,13 +222,12 @@ def test_knob_armed_recovery_disables_dispatch_ahead(monkeypatch, tmp_path):
     monkeypatch.setenv("H2O_TPU_AUTO_RECOVERY_DIR", str(tmp_path))
     monkeypatch.setenv("H2O_TPU_CHECKPOINT_SECS", "0")
     fr = _frame(rows=slice(0, 1024))
-    m = _train(fr, monkeypatch, pipeline="1", interval=2, ntrees=6)
+    m = _train(fr, monkeypatch, interval=2, ntrees=6)
     assert m.output.scoring_history  # trained through every boundary
     monkeypatch.delenv("H2O_TPU_AUTO_RECOVERY_DIR")
     monkeypatch.delenv("H2O_TPU_CHECKPOINT_SECS")
-    oracle = _train(fr, monkeypatch, pipeline="0", async_psum="0",
-                    interval=2, ntrees=6)
-    assert _forest_equal(oracle, m)
+    want = _train(fr, monkeypatch, interval=2, ntrees=6)
+    assert _forest_equal(want, m)
 
 
 # ---------------------------------------------------------------------------

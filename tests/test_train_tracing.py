@@ -1,8 +1,8 @@
 """What a training job names about itself (ISSUE 26).
 
 - Device scopes: every name declared in ``telemetry.SCOPES`` reaches the
-  compiled HLO of the program it belongs to (the GBM train step with the
-  pipelined level program on and off, the sketch, the coding, the IRLS
+  compiled HLO of the program it belongs to (the GBM train step with its
+  fused score, the DRF train step, the sketch, the coding, the IRLS
   step, the deviance probe) as a path element of an ``op_name``; an
   undeclared name raises.
 - Host spans: a GBM and a GLM train record the spans inside ``train.gbm``
@@ -87,37 +87,55 @@ def _spans(events, name):
 # ---------------------------------------------------------------------------
 # (a) device scopes in the compiled programs
 # ---------------------------------------------------------------------------
-def _gbm_step_text(pipeline: str) -> str:
-    """HLO text of the train step a small GBM job compiled: THIS job's
-    entry of the process-wide step cache, by its own key (the level program
-    asked for, this job's depth and chunk length, this frame's coded
-    matrix: tests/test_pipeline.py trains on the same shape in the same
-    process at depth 4). Under ``-n 6 --dist load`` the
+def _train_drf(fr, **kw):
+    from h2o_tpu.models.drf import DRF, DRFParameters
+
+    return DRF(DRFParameters(
+        training_frame=fr, response_column="y", ntrees=4, max_depth=3,
+        seed=1, score_tree_interval=2, **kw)).train_model()
+
+
+def _tree_step_text(drf: bool) -> str:
+    """HLO text of the train step a small GBM (or DRF: no fused score)
+    job compiled: THIS job's entry of the process-wide step cache, by its
+    own key (the builder, this job's depth and chunk length, this frame's
+    coded matrix: tests/test_pipeline.py trains on the same shape in the
+    same process at depth 4). Under ``-n 6 --dist load`` the
     process may also be serving another worker's REST jobs (``h2o.init``
     at a module's fixed port connects to whichever worker bound it first),
-    and their steps land in the same cache while this one trains."""
+    and their steps land in the same cache while this one trains. The step
+    is taken as `gbm._aot_train_step` returns it, not read back from the
+    cache, which the jobs' cache sweep may clear as the job finishes."""
     from h2o_tpu.models import gbm as gbm_mod
 
     def mine(key):
         (cfg, *_), sig = key
-        return (cfg.pipeline == (pipeline == "1")
+        return (cfg.drf_mode == drf
                 and (cfg.max_depth, cfg.ntrees) == (3, 2)
                 and sig[0] == ((_N, _F), "int8"))
 
+    built = []
+    aot = gbm_mod._aot_train_step
+
+    def recorded(train_fn, args, key_base):
+        compiled = aot(train_fn, args, key_base)
+        sig = tuple((tuple(a.shape), str(a.dtype)) for a in args)
+        built.append(((key_base, sig), compiled))
+        return compiled
+
+    # an earlier test of this worker may have built the same key with a
+    # persistent compile cache on: that entry is a replay and carries no
+    # scope names, so this job compiles its step afresh
+    for key in [k for k in gbm_mod._AOT_STEP_CACHE if mine(k)]:
+        del gbm_mod._AOT_STEP_CACHE[key]
     mp = pytest.MonkeyPatch()
     try:
-        mp.setenv("H2O_TPU_PIPELINE", pipeline)
-        # an earlier test of this worker may have built the same key with a
-        # persistent compile cache on: that entry is a replay and carries
-        # no scope names, so this job compiles its step afresh
-        for key in [k for k in gbm_mod._AOT_STEP_CACHE if mine(k)]:
-            del gbm_mod._AOT_STEP_CACHE[key]
-        _train_gbm(_frame())
-        (compiled,) = [c for k, c in list(gbm_mod._AOT_STEP_CACHE.items())
-                       if mine(k)]
-        return compiled.as_text()
+        mp.setattr(gbm_mod, "_aot_train_step", recorded)
+        (_train_drf if drf else _train_gbm)(_frame())
     finally:
         mp.undo()
+    (compiled,) = [c for k, c in built if mine(k)]
+    return compiled.as_text()
 
 
 def _glm_texts():
@@ -203,8 +221,8 @@ def hlo():
         interact_step, interact_eta = _interact_texts()
         return {"gam_sums": gam_sums, "gam_design": gam_design,
                 "interact_step": interact_step, "interact_eta": interact_eta,
-                "gbm_step_pipelined": _gbm_step_text("1"),
-                "gbm_step_synchronous": _gbm_step_text("0"),
+                "gbm_step_pipelined": _tree_step_text(drf=False),
+                "drf_step": _tree_step_text(drf=True),
                 "sketch": sketch, "bin_column": bin_col,
                 "bin_matrix": bin_mat,
                 "irls_step": irls, "deviance_probe": probe}
@@ -218,7 +236,7 @@ _LEVEL = ("gbm.grad", "gbm.route", "gbm.hist", "gbm.psum", "gbm.split",
 #: (program, scope): where each declared scope has to arrive
 _SCOPE_CASES = (
     [("gbm_step_pipelined", s) for s in _LEVEL + ("gbm.score",)]
-    + [("gbm_step_synchronous", s) for s in _LEVEL]
+    + [("drf_step", s) for s in _LEVEL]
     + [("sketch", "gbm.sketch"), ("bin_column", "gbm.bin"),
        ("bin_matrix", "gbm.bin")]
     + [("irls_step", s) for s in ("glm.eta", "glm.gram", "glm.deviance")]
@@ -729,10 +747,8 @@ def test_a_job_compiles_under_declared_names_or_bare_primitives(algo):
     assert [n for n in got - set(telemetry.PROGRAMS) if n in own] == []
 
 
-@pytest.mark.parametrize("pipeline", ["1", "0"])
-def test_the_gbm_step_is_gbm_level(monkeypatch, pipeline):
-    monkeypatch.setenv("H2O_TPU_PIPELINE", pipeline)
-    fr = _frame(_N + 64 + 8 * int(pipeline))
+def test_the_gbm_step_is_gbm_level():
+    fr = _frame(_N + 72)
     names = _compiled_names(lambda: _train_gbm(fr))
     assert "jit(gbm_level)" in names and "jit(spmd)" not in names
     from h2o_tpu.utils import programs

@@ -5,9 +5,6 @@
 #
 # Usage:
 #   tools/ci_gate.sh                 # text findings
-#   tools/ci_gate.sh --bench-smoke   # + the 50k-row pipelined GBM bench leg
-#   tools/ci_gate.sh --bench-gate    # + smoke bench at baseline config,
-#                                    #   gated vs BENCH_r06_baseline.jsonl
 #   tools/ci_gate.sh --sanitize-stress  # + serving+train+sweep stress with
 #                                    #   ALL FOUR sanitizer arms armed
 #   tools/ci_gate.sh --health-gate   # + boot a server, assert /3/Health
@@ -19,19 +16,6 @@
 #                                    #   held, zero sanitizer violations
 #   GRAFTLINT_FORMAT=github tools/ci_gate.sh   # ::error annotations
 #   GRAFTLINT_JOBS=4 tools/ci_gate.sh          # parallel lint scan
-#
-# --bench-smoke runs the airlines bench leg (the pipelined-training
-# scoreboard) at 50k rows with H2O_TPU_PIPELINE on and asserts rc=0,
-# forest_parity=true (pipelined forest + predictions bit-equal to the
-# synchronous oracle) and 0 steady-state uncached compiles on the warm
-# train. The >=1.25x speedup stays a recorded number, not a gate — CI
-# machines' walls are noisy; parity and compile hygiene are not.
-#
-# --bench-gate runs the gbm+glm legs at the BENCH_r06 baseline's exact
-# config (60k rows / 100 trees, so walls are comparable) and pipes the
-# sidecar through tools/bench_gate.py: per-leg tolerance bands on wall,
-# peak HBM bytes, AUC, parity flags — nonzero exit names the regressed
-# (leg, metric). Band overrides: H2O_TPU_BENCH_GATE_BANDS.
 #
 # --health-gate boots a REAL server (watchdog armed at a 100ms sweep),
 # asserts GET /3/Health reports ready over the wire, arms the registered
@@ -66,15 +50,11 @@ cd "$(dirname "$0")/.."
 
 fmt="${GRAFTLINT_FORMAT:-text}"
 jobs="${GRAFTLINT_JOBS:-2}"
-bench_smoke=0
-bench_gate=0
 sanitize_stress=0
 health_gate=0
 workload_gate=0
 for arg in "$@"; do
     case "$arg" in
-        --bench-smoke) bench_smoke=1 ;;
-        --bench-gate) bench_gate=1 ;;
         --sanitize-stress) sanitize_stress=1 ;;
         --health-gate) health_gate=1 ;;
         --workload-gate) workload_gate=1 ;;
@@ -91,59 +71,6 @@ timeout -k 10 870 env JAX_PLATFORMS=cpu python -m pytest tests/ -q \
     -m 'not slow' --continue-on-collection-errors \
     -p no:cacheprovider -p no:xdist -p no:randomly
 test_rc=$?
-
-bench_rc=0
-if [ "$bench_smoke" -eq 1 ]; then
-    echo "== bench smoke (pipelined 50k-row GBM) =="
-    sidecar="$(mktemp /tmp/h2o_tpu_bench_smoke.XXXXXX.jsonl)"
-    timeout -k 10 900 env JAX_PLATFORMS=cpu \
-        H2O_TPU_BENCH_WORKLOADS=airlines \
-        H2O_TPU_BENCH_AIRLINES_ROWS=50000 \
-        H2O_TPU_PIPELINE=1 \
-        H2O_TPU_BENCH_SIDECAR="$sidecar" \
-        python bench.py > /dev/null
-    bench_rc=$?
-    if [ "$bench_rc" -eq 0 ]; then
-        python - "$sidecar" <<'EOF'
-import json, sys
-
-rec = None
-for line in open(sys.argv[1]):
-    d = json.loads(line)
-    if d.get("workload") == "airlines116m":
-        rec = d["record"]
-assert rec is not None, "airlines leg record missing from sidecar"
-assert rec["forest_parity"] is True, \
-    f"pipelined forest NOT bit-equal to the synchronous oracle: {rec}"
-assert rec["uncached_compiles_warm"] == 0, \
-    f"steady-state uncached compiles: {rec['uncached_compiles_warm']}"
-print(json.dumps({"bench_smoke": "ok",
-                  "wall_s": rec["wall_s"],
-                  "wall_sync_s": rec["wall_sync_s"],
-                  "pipeline_speedup_x": rec["pipeline_speedup_x"]}))
-EOF
-        bench_rc=$?
-    fi
-    rm -f "$sidecar"
-fi
-
-gate_rc=0
-if [ "$bench_gate" -eq 1 ]; then
-    echo "== bench gate (gbm+glm @ BENCH_r06 config vs baseline bands) =="
-    sidecar="$(mktemp /tmp/h2o_tpu_bench_gate.XXXXXX.jsonl)"
-    timeout -k 10 1500 env JAX_PLATFORMS=cpu \
-        H2O_TPU_BENCH_WORKLOADS=gbm,glm \
-        H2O_TPU_BENCH_ROWS=60000 \
-        H2O_TPU_BENCH_TREES=100 \
-        H2O_TPU_BENCH_SIDECAR="$sidecar" \
-        python bench.py > /dev/null
-    gate_rc=$?
-    if [ "$gate_rc" -eq 0 ]; then
-        python tools/bench_gate.py --run "$sidecar"
-        gate_rc=$?
-    fi
-    rm -f "$sidecar"
-fi
 
 stress_rc=0
 if [ "$sanitize_stress" -eq 1 ]; then
@@ -363,8 +290,8 @@ EOF
     workload_rc=$?
 fi
 
-echo "== gate: lint rc=${lint_rc}, tests rc=${test_rc}, bench rc=${bench_rc}, bench-gate rc=${gate_rc}, sanitize-stress rc=${stress_rc}, health rc=${health_rc}, workload rc=${workload_rc} =="
-if [ "$lint_rc" -ne 0 ] || [ "$test_rc" -ne 0 ] || [ "$bench_rc" -ne 0 ] || [ "$gate_rc" -ne 0 ] || [ "$stress_rc" -ne 0 ] || [ "$health_rc" -ne 0 ] || [ "$workload_rc" -ne 0 ]; then
+echo "== gate: lint rc=${lint_rc}, tests rc=${test_rc}, sanitize-stress rc=${stress_rc}, health rc=${health_rc}, workload rc=${workload_rc} =="
+if [ "$lint_rc" -ne 0 ] || [ "$test_rc" -ne 0 ] || [ "$stress_rc" -ne 0 ] || [ "$health_rc" -ne 0 ] || [ "$workload_rc" -ne 0 ]; then
     exit 1
 fi
 exit 0

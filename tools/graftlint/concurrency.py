@@ -37,8 +37,8 @@ fixed or baselined with a written reason — the gate ships at 0
 non-baselined violations, the rules 1-13 discipline.
 
 Scope: everything scanned except the test tree — on the default scan set
-that is ``h2o_tpu/`` + ``bench.py``, the host-side driver whose
-race-freedom the MapReduce determinism story depends on. Tests spawn
+that is ``h2o_tpu/``, whose race-freedom the MapReduce determinism
+story depends on, and the ``chip_smoke.py`` driver. Tests spawn
 threads with their own lifecycles and stay per-file-linted only.
 """
 
@@ -57,7 +57,8 @@ _CLOSURE_DEPTH = 12
 def in_scope(relpath: str) -> bool:
     """Interprocedural scope: everything scanned EXCEPT the test tree —
     tests spawn threads with their own lifecycles and stay per-file-
-    linted only. On the default scan set this means h2o_tpu/ + bench.py;
+    linted only. On the default scan set this means h2o_tpu/ +
+    chip_smoke.py;
     an explicit out-of-tree path gets the full analysis too."""
     p = relpath.replace(os.sep, "/")
     return not (p.startswith("tests/") or "/tests/" in p)

@@ -58,7 +58,7 @@ REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 BASELINE_PATH = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                              "baseline.json")
 #: default scan set — the CLI and the pytest gate lint the same tree
-DEFAULT_PATHS = ("h2o_tpu", "tests", "bench.py")
+DEFAULT_PATHS = ("h2o_tpu", "tests", "chip_smoke.py")
 
 _SUPPRESS_RE = re.compile(
     r"#\s*graftlint:\s*disable(?:=([A-Za-z0-9_\-, ]+))?")

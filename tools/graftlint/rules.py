@@ -16,8 +16,8 @@ CHANGES.md entries):
    Cleaner ledger (`backend/memory.py`) and silently distort every
    budget-driven planner; residency must be Cleaner-tracked.
 5. timing-without-sync   — jax dispatch is async: a wall-clock delta over
-   un-synced device work measures dispatch, not compute (the bench JSONL
-   sidecar numbers exist to be trusted).
+   un-synced device work measures dispatch, not compute (a span's wall
+   exists to be trusted).
 6. host-sync-in-trace    — `.item()`/`float()`/`np.asarray` on traced
    values fail under jit, or worse: silently bake a trace-time constant in.
 7. nondeterminism-in-trace — `np.random`/`time.time()` inside traced code
@@ -867,7 +867,7 @@ class UseAfterDonate(Rule):
     #: through the call graph and star-dispatch through tuple packs, so
     #: that flow IS lint-visible now; this list keeps the cheap per-file
     #: rule useful for same-file reads (tests included — pass 3 scopes
-    #: to h2o_tpu/ + bench.py).
+    #: to h2o_tpu/ + chip_smoke.py).
     _DONATING_FACTORIES = {"make_train_fn": frozenset([3])}
 
     def _binding_positions(self, value: ast.expr, ctx) -> frozenset | None:
